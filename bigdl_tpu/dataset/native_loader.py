@@ -10,15 +10,18 @@ Two pieces:
 
 - ``NativeBatcher``: gathers + channel-normalizes minibatches through the
   C++ kernel (native/batch_assembler.cpp, built on first use with g++,
-  ctypes binding -- no pybind11).  Falls back to numpy transparently.
+  ctypes binding -- no pybind11).  Falls back to numpy, with a warning,
+  where no compiler exists; ``NativeBatcher.lib`` says which ran.
 - ``Prefetcher``: a bounded background queue that assembles the next batches
   while the device is busy -- the ctypes call releases the GIL so assembly
   overlaps with the training step.
 """
 
 import ctypes
+import hashlib
 import logging
 import os
+import platform
 import queue
 import subprocess
 import threading
@@ -33,22 +36,27 @@ _TRIED = False
 
 
 def build_native_lib(name: str):
-    """Build (if stale) and load ``native/<name>.cpp`` as
-    ``build/lib<name>.so``.  Prebuilt artifacts from `make -C native` are
-    used as-is; otherwise g++ compiles on demand; callers fall back to
-    pure python/numpy when neither works."""
+    """Build ``native/<name>.cpp`` with g++ into ``build/`` (git-ignored)
+    and load it.  The file name carries a digest of the source and the
+    machine's architecture, so what loads was built from the current
+    source: a stale ``build/*.so`` that came along with a copy of the
+    tree is never picked up.  Callers fall back to pure python/numpy
+    when the build fails."""
     here = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
     src = os.path.join(here, "native", f"{name}.cpp")
+    with open(src, "rb") as f:
+        digest = hashlib.sha256(
+            f.read() + platform.machine().encode()).hexdigest()[:12]
     out_dir = os.path.join(here, "build")
-    so_path = os.path.join(out_dir, f"lib{name}.so")
-    if (not os.path.exists(so_path)
-            or os.path.getmtime(so_path) < os.path.getmtime(src)):
+    so_path = os.path.join(out_dir, f"lib{name}-{digest}.so")
+    if not os.path.exists(so_path):
         os.makedirs(out_dir, exist_ok=True)
+        tmp = f"{so_path}.{os.getpid()}.tmp"
         subprocess.run(
-            ["g++", "-O3", "-shared", "-fPIC", "-o", so_path, src,
-             "-lpthread"],
+            ["g++", "-O3", "-shared", "-fPIC", "-o", tmp, src, "-lpthread"],
             check=True, capture_output=True)
+        os.replace(tmp, so_path)     # atomic: concurrent builders agree
     return ctypes.CDLL(so_path)
 
 

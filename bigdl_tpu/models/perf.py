@@ -145,13 +145,7 @@ def run_perf(model_name="resnet50", batch=32, iterations=20,
     return batch / med
 
 
-def _honor_env_platforms():
-    from bigdl_tpu.utils.config import honor_env_platforms
-    honor_env_platforms()
-
-
 def main(argv=None):
-    _honor_env_platforms()
     p = argparse.ArgumentParser(prog="bigdl_tpu.models.perf")
     p.add_argument("--model", default="resnet50",
                    choices=sorted(MODELS) + sorted(TOKEN_MODELS))

@@ -105,10 +105,6 @@ def _common_flags(p, default_epochs=5):
                    help="prefetch queue depth (batches held ahead)")
     p.add_argument("--syncEvery", type=int, default=1, dest="sync_every",
                    help="block on the device loss every k-th step only")
-    p.add_argument("--compilationCache", default=None,
-                   dest="compilation_cache", metavar="DIR",
-                   help="persistent XLA compilation cache dir: repeat "
-                        "runs of the same program skip recompilation")
 
 
 def cmd_lenet_train(args):
@@ -408,13 +404,7 @@ def cmd_transformer_train(args):
     opt.optimize()
 
 
-def _honor_env_platforms():
-    from bigdl_tpu.utils.config import honor_env_platforms
-    honor_env_platforms()
-
-
 def main(argv=None):
-    _honor_env_platforms()
     # progress must be visible out of the box (epoch/iteration/loss lines
     # come through logging.INFO); jax/XLA noise goes to bigdl.log via the
     # LoggerFilter analogue
@@ -502,11 +492,11 @@ def main(argv=None):
     args = parser.parse_args(argv)
     from bigdl_tpu.utils.config import (compilation_cache_note,
                                         enable_compilation_cache)
-    # every invocation activates the cache (an explicit --compilationCache
-    # DIR overrides the env/default path) and logs the warm/cold note, so
-    # cache reuse across runs/legs is always visible; a telemetry-carrying
-    # run additionally stamps the same status on its JSONL header
-    enable_compilation_cache(getattr(args, "compilation_cache", None))
+    # every invocation activates the cache (JAX_COMPILATION_CACHE_DIR, or
+    # the checkout's fixed default) and logs the warm/cold note, so cache
+    # reuse across runs/legs is always visible; a telemetry-carrying run
+    # additionally stamps the same status on its JSONL header
+    enable_compilation_cache()
     logging.getLogger("bigdl_tpu").info(compilation_cache_note())
     args.fn(args)
 

@@ -44,6 +44,10 @@ def dot_product_attention(q, k, v, causal=False, mask=None, scale=None):
     return jnp.einsum("...hqk,...khd->...qhd", weights, v)
 
 
+def _on_tpu():
+    return jax.devices()[0].platform == "tpu"
+
+
 class MultiHeadAttention(Module):
     """Self-attention with fused qkv projection (one big MXU matmul)."""
 
@@ -66,8 +70,9 @@ class MultiHeadAttention(Module):
         self.seq_axis_name = seq_axis_name
         self.seq_mode = seq_mode
         #: "auto": the Pallas flash kernel (ops/flash_attention.py) on TPU
-        #: when T is block-aligned; plain attention otherwise.  "interpret"
-        #: forces the kernel in interpreter mode (CPU tests).
+        #: when T is block-aligned and one head's K/V fit the kernel's
+        #: VMEM budget; plain attention otherwise.  "interpret" forces
+        #: the kernel in interpreter mode (CPU tests).
         self.use_flash = use_flash
 
     @staticmethod
@@ -83,17 +88,18 @@ class MultiHeadAttention(Module):
             return t % 8 == 0
         return t % 128 == 0
 
-    def _flash_ok(self, t):
+    def _kv_fit(self, rows, dtype, quantized=False):
+        from bigdl_tpu.ops.flash_attention import kv_blocks_fit
+
+        return kv_blocks_fit(rows, self.head_dim, dtype, quantized)
+
+    def _flash_ok(self, t, dtype=jnp.float32):
         if self.use_flash == "never" or self.seq_axis_name is not None:
             return False
         if self.use_flash in ("always", "interpret"):
             return True
-        if not self._flash_block_ok(t):
-            return False
-        try:
-            return jax.devices()[0].platform == "tpu"
-        except Exception:
-            return False
+        return (self._flash_block_ok(t) and _on_tpu()
+                and self._kv_fit(t, dtype))
 
     def setup(self, rng, input_spec):
         d = self.hidden_size
@@ -144,7 +150,7 @@ class MultiHeadAttention(Module):
         shape = (batch, int(max_len), self.num_heads, self.head_dim)
         return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
-    def _flash_decode_ok(self, max_len):
+    def _flash_decode_ok(self, max_len, dtype=jnp.float32):
         if self.use_flash == "never" or self.seq_axis_name is not None:
             return False
         # the decode kernel tiles the cache with block_k = min(128,
@@ -156,10 +162,7 @@ class MultiHeadAttention(Module):
             return False
         if self.use_flash in ("always", "interpret"):
             return True
-        try:
-            return jax.devices()[0].platform == "tpu"
-        except Exception:
-            return False
+        return _on_tpu() and self._kv_fit(max_len, dtype)
 
     def _apply_cached(self, params, input, cache, pos):
         """Incremental attention against a K/V cache.
@@ -200,7 +203,7 @@ class MultiHeadAttention(Module):
             # prompt rung that doesn't tile (e.g. an unaligned
             # decode_max_len on the ladder) would trip the kernel's
             # shape assert on every prefill -- take the plain path
-            if self._flash_ok(t) and self._flash_block_ok(t):
+            if self._flash_ok(t, dt) and self._flash_block_ok(t):
                 from bigdl_tpu.ops.flash_attention import flash_attention
 
                 bq = t if t < 128 else 128
@@ -220,7 +223,7 @@ class MultiHeadAttention(Module):
             new_cache = {"k": write(cache["k"], k.astype(cdt), pos),
                          "v": write(cache["v"], v.astype(cdt), pos)}
             max_len = cache["k"].shape[1]
-            if self._flash_decode_ok(max_len):
+            if self._flash_decode_ok(max_len, dt):
                 from bigdl_tpu.ops.flash_attention import \
                     flash_decode_attention
 
@@ -291,7 +294,7 @@ class MultiHeadAttention(Module):
                                    self.head_dim)
         return out.reshape(q8.shape).astype(dt)
 
-    def _flash_paged_ok(self, block_size):
+    def _flash_paged_ok(self, num_blocks, block_size, dtype, quantized):
         if self.use_flash == "never" or self.seq_axis_name is not None:
             return False
         if self.use_flash in ("always", "interpret"):
@@ -299,13 +302,12 @@ class MultiHeadAttention(Module):
         # on real TPU the paged kernel walks the pool in block_size
         # strides; tiny blocks (the useful CPU/bench sizes) are far
         # below the 128-lane tile, so auto mode only takes the kernel
-        # when blocks themselves tile
+        # when blocks themselves tile -- and when each head's whole pool
+        # plane, which the kernel keeps in VMEM, fits there
         if block_size % 128:
             return False
-        try:
-            return jax.devices()[0].platform == "tpu"
-        except Exception:
-            return False
+        return _on_tpu() and self._kv_fit(num_blocks * block_size, dtype,
+                                          quantized)
 
     def _apply_paged(self, params, input, pool, tables, pos, lengths):
         """Incremental attention against a paged K/V pool.  Returns
@@ -401,7 +403,8 @@ class MultiHeadAttention(Module):
                 tables, (pos // bs)[:, None], axis=1)[:, 0]
             off = pos % bs
             new_pool = scatter(phys, off, k[:, 0], v[:, 0])
-            if self._flash_paged_ok(bs):
+            if self._flash_paged_ok(pool["k"].shape[0], bs,
+                                    jnp.int8 if quant else dt, quant):
                 from bigdl_tpu.ops.flash_attention import \
                     flash_paged_decode_attention
 
@@ -451,7 +454,7 @@ class MultiHeadAttention(Module):
             y = ring_self_attention(q.reshape(shape), k.reshape(shape),
                                     v.reshape(shape), self.seq_axis_name,
                                     causal=self.causal)
-        elif self._flash_ok(t):
+        elif self._flash_ok(t, dt):
             from bigdl_tpu.ops.flash_attention import flash_attention
 
             bq = t if t < 128 else 128
@@ -656,11 +659,20 @@ class TransformerLM(Container):
             raise ValueError(
                 f"cache max_len {max_len} exceeds the model's positional "
                 f"table ({self.max_len})")
-        per_block = [b.init_cache(batch, max_len, dtype)
-                     for b in self.blocks]
+        return self._layer_caches(
+            lambda b: b.init_cache(batch, max_len, dtype))
+
+    def _layer_caches(self, init):
+        """``init(block)`` for every block, in this model's layout.  The
+        stacked layout is allocated directly (the blocks are alike and a
+        cache starts as zeros): stacking per-layer arrays would hold the
+        whole cache twice while it is built."""
         if self.scan_layers:
-            return {"blocks": stack_layer_trees(per_block)}
-        return {f"block{i}": c for i, c in enumerate(per_block)}
+            n = len(self.blocks)
+            return {"blocks": jax.tree.map(
+                lambda s: jnp.zeros((n,) + s.shape, s.dtype),
+                jax.eval_shape(lambda: init(self.blocks[0])))}
+        return {f"block{i}": init(b) for i, b in enumerate(self.blocks)}
 
     def init_paged_cache(self, num_blocks: int, block_size: int,
                          dtype=jnp.float32):
@@ -670,12 +682,9 @@ class TransformerLM(Container):
         the allocator's pool size; every layer gets ONE EXTRA block on
         top -- the TRASH block, id ``num_blocks`` -- that padded table
         entries and inactive rows write into (serving/paging.py)."""
-        per_block = [b.init_paged_cache(int(num_blocks) + 1, block_size,
-                                        dtype)
-                     for b in self.blocks]
-        if self.scan_layers:
-            return {"blocks": stack_layer_trees(per_block)}
-        return {f"block{i}": c for i, c in enumerate(per_block)}
+        return self._layer_caches(
+            lambda b: b.init_paged_cache(int(num_blocks) + 1, block_size,
+                                         dtype))
 
     def apply_paged(self, params, input, pool, tables, *, pos,
                     lengths=None):

@@ -52,7 +52,7 @@ Audit an existing artifact from the command line::
 import json
 import time
 
-#: the four-verdict trust taxonomy (docs/observability.md)
+#: the four trust verdicts (docs/observability.md)
 TRUSTED = "trusted"
 SUSPECT_ASYNC_DISPATCH = "suspect:async_dispatch"
 INVALID_OFF_TPU = "invalid:off_tpu"

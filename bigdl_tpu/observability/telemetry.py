@@ -65,24 +65,34 @@ DURABLE_KINDS = frozenset({"health", "anomaly", "timing_audit",
 log = logging.getLogger("bigdl_tpu.observability")
 
 
+#: peak dense bf16 FLOP/s of one chip, keyed by ``device_kind`` exactly as
+#: JAX reports it (source: Google Cloud TPU documentation, the "TPU v4",
+#: "TPU v5e", "TPU v5p" and "TPU v6e" system-architecture pages)
+PEAK_BF16_FLOPS = {
+    "TPU v4": 275e12,
+    "TPU v5 lite": 197e12,   # v5e
+    "TPU v5": 459e12,        # v5p
+    "TPU v6 lite": 918e12,   # v6e
+}
+
+
 def peak_flops(device=None):
-    """Peak bf16 FLOP/s for a device kind (bench.py's table); CPU and
-    unknown hosts get a nominal 1 TFLOP/s so MFU stays computable (and
-    obviously not chip-meaningful)."""
+    """Peak bf16 FLOP/s of ``device`` (default: the first JAX device)
+    from ``PEAK_BF16_FLOPS``.  Off a TPU there is no peak, and so no MFU:
+    returns ``None``.  A TPU whose ``device_kind`` is not in the table is
+    an error, not a default."""
     if device is None:
         import jax
         device = jax.devices()[0]
-    kind = (getattr(device, "device_kind", "") or "").lower()
-    platform = getattr(device, "platform", "cpu")
-    if platform != "tpu":
-        return 1e12
-    if "v6" in kind:
-        return 918e12
-    if "v5p" in kind:
-        return 459e12
-    if "v4" in kind:
-        return 275e12
-    return 197e12  # v5e and unknown TPUs
+    if getattr(device, "platform", "cpu") != "tpu":
+        return None
+    kind = getattr(device, "device_kind", "") or ""
+    if kind not in PEAK_BF16_FLOPS:
+        raise ValueError(
+            f"no peak FLOP/s known for TPU device_kind {kind!r}; add it, "
+            f"with its source, to PEAK_BF16_FLOPS "
+            f"(bigdl_tpu/observability/telemetry.py)")
+    return PEAK_BF16_FLOPS[kind]
 
 
 def device_memory_stats():
@@ -260,19 +270,16 @@ class StepTelemetry:
             if self._wrote_header:   # concurrent first event can't land
                 return None          # ahead of the header line
             self._wrote_header = True
-            fields = {"run": self.run_name, "schema_version": SCHEMA_VERSION}
-            try:
-                import jax
-                dev = jax.devices()[0]
-                fields.update(
-                    jax_version=jax.__version__,
-                    platform=dev.platform,
-                    device_kind=getattr(dev, "device_kind", ""),
-                    device_count=jax.device_count(),
-                    process_count=jax.process_count(),
-                    peak_flops=peak_flops(dev))
-            except Exception:
-                pass
+            import jax
+
+            dev = jax.devices()[0]
+            fields = {"run": self.run_name, "schema_version": SCHEMA_VERSION,
+                      "jax_version": jax.__version__,
+                      "platform": dev.platform,
+                      "device_kind": getattr(dev, "device_kind", ""),
+                      "device_count": jax.device_count(),
+                      "process_count": jax.process_count(),
+                      "peak_flops": peak_flops(dev)}
             try:
                 # per-device allocator stats at run start, bounded to 8
                 # devices so a big pod doesn't bloat every header; None

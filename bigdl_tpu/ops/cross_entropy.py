@@ -114,6 +114,7 @@ def _ce_fwd(logits, labels, block_n, block_v, interpret):
             pltpu.VMEM((block_n, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="fused_softmax_cross_entropy",
     )(x, y)
     return loss[:, 0], (logits, labels, lse)
 
@@ -143,6 +144,7 @@ def _ce_bwd_rule(block_n, block_v, interpret, res, g):
         out_specs=pl.BlockSpec((block_n, bv), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n, v), logits.dtype),
         interpret=interpret,
+        name="fused_softmax_cross_entropy_grad",
     )(x, y, lse, gcol)
     return dx[:, :v_orig], None
 

@@ -29,6 +29,7 @@ import logging
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from bigdl_tpu.ops.quantization import (CompressionSpec,
@@ -45,7 +46,6 @@ from bigdl_tpu.parallel.zero import (FlatParamSpace, refit_flat_plane,
 from bigdl_tpu.utils import file_io
 from bigdl_tpu.utils.engine import Engine
 from bigdl_tpu.utils.random_generator import RNG
-from bigdl_tpu.utils.compat import shard_map
 
 log = logging.getLogger("bigdl_tpu.optim")
 
@@ -462,14 +462,14 @@ class DistriOptimizer(BaseOptimizer):
         # ZeRO-1: optimizer state over the full flat vector, sharded on the
         # data axis => each device holds state for its chunk only.
         vec_sharding = NamedSharding(self.mesh, P(self.axis))
-        rep_sharding = NamedSharding(self.mesh, P(None))
-        scalar_sharding = NamedSharding(self.mesh, P())
+        # P(), not P(None): replicated at every rank, scalars included
+        rep_sharding = NamedSharding(self.mesh, P())
 
         opt_state_eval = jax.eval_shape(
             self.optim_method.init_state,
             jax.ShapeDtypeStruct((flat_space.padded_size,), jnp.float32))
         opt_shardings = jax.tree.map(
-            lambda l: vec_sharding if l.ndim >= 1 else scalar_sharding,
+            lambda l: vec_sharding if l.ndim >= 1 else rep_sharding,
             opt_state_eval)
         opt_state = jax.jit(
             self.optim_method.init_state, out_shardings=opt_shardings,
@@ -739,7 +739,11 @@ class DistriOptimizer(BaseOptimizer):
 
         train_iter, first_batch = self._resume_data_stream(
             train_iter, first_batch)
+        # both replicated planes go onto the mesh before the first step:
+        # module state left on the first device would give step 1 other
+        # input shardings than step 2 sees, and so a second full compile
         params_flat = jax.device_put(params_flat, rep_sharding)
+        mstate = jax.device_put(mstate, rep_sharding)
 
         mon = self.health_monitor
         use_health = mon is not None and mon.enabled
@@ -887,6 +891,10 @@ class DistriOptimizer(BaseOptimizer):
         params_tree = jax.jit(flat_space.unflatten)(params_flat)
         self.model.set_parameters(params_tree)
         self.model.set_state(mstate)
+        #: the optimizer state as the last step left it: under ZeRO-1
+        #: every leaf over the flat plane is sharded over the data axis
+        #: (where it lives is what a caller checks on real devices)
+        self.opt_state = opt_state
         return self.model
 
 

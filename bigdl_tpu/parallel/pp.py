@@ -25,11 +25,10 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from bigdl_tpu.nn.module import child_rng
-from bigdl_tpu.utils.compat import shard_map
 
 
 def stack_stage_params(model, n_stages: int):

@@ -34,12 +34,11 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 from bigdl_tpu.nn.module import child_rng
 from bigdl_tpu.optim.train_step import _cast_params, _cast_tree
-from bigdl_tpu.utils.compat import shard_map
 
 
 def partition_sequential(model, n_stages: int,

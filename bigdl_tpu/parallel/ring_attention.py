@@ -23,8 +23,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-from bigdl_tpu.utils.compat import shard_map
+from jax import lax, shard_map
 
 
 def ring_self_attention(q, k, v, axis_name: str, causal: bool = False):

@@ -13,11 +13,10 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from bigdl_tpu.optim.train_step import _cast_params, _cast_tree
-from bigdl_tpu.utils.compat import shard_map
 
 
 def make_sp_train_step(model, criterion, optim_method, mesh,

@@ -17,14 +17,13 @@ import jax
 from jax import lax
 
 from bigdl_tpu.nn.attention import dot_product_attention
-from bigdl_tpu.utils.compat import axis_size
 
 
 def ulysses_self_attention(q, k, v, axis_name, causal=False):
     """q, k, v: (N, T_local, H, Dh), sequence sharded over ``axis_name``
     (shard_map context).  -> (N, T_local, H, Dh).
     """
-    p = axis_size(axis_name)
+    p = lax.axis_size(axis_name)
     h = q.shape[2]
     if h % p:
         raise ValueError(

@@ -1772,7 +1772,9 @@ class ServingEngine:
 
     def close(self, timeout: Optional[float] = 10.0):
         """Stop accepting requests, drain the queue, join the
-        dispatcher.  Idempotent."""
+        dispatcher; the generation scheduler gives its KV cache back to
+        the device (a closed engine holds no cache, collected or not).
+        Idempotent."""
         with self._lock:
             self._running = False
             self._not_empty.notify_all()

@@ -340,7 +340,11 @@ class SubprocessReplica(Replica):
     ``spawn(attempt) -> (Popen, port)`` must return a STARTED worker
     that is ready to serve (the CLI blocks on the worker's port file);
     it is called again -- with the attempt number -- on every
-    supervisor restart.
+    supervisor restart.  The platform the worker comes up on is
+    ``spawn``'s to set in the child's environment: a chip belongs to one
+    process at a time, so a process that has initialised JAX on a chip
+    must not spawn a worker that asks for the same one (it would fail or
+    hang; ``tools/serve_fleet.py`` pins every role to the CPU).
 
     ``transport="binary"`` (default) keeps a capped
     ``transport.WirePool`` of persistent multiplexed connections to

@@ -768,11 +768,23 @@ class GenerateScheduler:
         return True
 
     def close(self, timeout: Optional[float] = 10.0):
+        """Stop the dispatcher, give the device cache back and let go of
+        the owner's hooks.  The owning engine and this scheduler refer
+        to each other (the hooks are its bound methods); left alone, a
+        closed engine's cache would stay in device memory until a cycle
+        collection, and the next engine of the process would not fit
+        beside it."""
         with self._lock:
             self._running = False
             self._work.notify_all()
             self._not_full.notify_all()
         self._dispatcher.join(timeout)
+        if not self._dispatcher.is_alive():
+            self._release_cache()
+            self._admission_check = self._exhausted_hook = None
+
+    def _release_cache(self):
+        self._cache = None
 
     def __enter__(self):
         return self
@@ -1393,6 +1405,10 @@ class SpeculativeScheduler(PagedGenerateScheduler):
     def _reset_pool(self):
         super()._reset_pool()
         self._build_drafter_pool()
+
+    def _release_cache(self):
+        super()._release_cache()
+        self._dcache = None
 
     def cache_bytes(self) -> int:
         """Verifier pool + drafter pool -- the speculative price is

@@ -18,9 +18,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
 def main(argv=None):
-    from bigdl_tpu.utils.config import honor_env_platforms
-    honor_env_platforms()
-
     parser = argparse.ArgumentParser()
     parser.add_argument("--records", type=int, default=256)
     parser.add_argument("--batch", type=int, default=64)

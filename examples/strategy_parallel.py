@@ -18,10 +18,6 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-from bigdl_tpu.utils.config import honor_env_platforms  # noqa: E402
-
-honor_env_platforms()
-
 
 def main():
     p = argparse.ArgumentParser()

@@ -12,11 +12,6 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-if os.environ.get("JAX_PLATFORMS"):
-    import jax
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
-
 def complete_tree(leaves):
     """Dense tree encoding over ``leaves`` words (nNodes, 3):
     leaf rows [0, 0, word_pos_1based]; internal [left, right, 0]; root

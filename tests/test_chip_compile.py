@@ -1,0 +1,166 @@
+"""The Pallas kernels of the main path, compiled for a v5e that is
+described and not attached (the TPU compiler is installed with jaxlib), at
+the widths of ``transformer_lm("medium")`` (head 64) and ``"large"`` (head
+96) and at the sequence, cache and pool sizes ``chip_smoke.py`` drives.
+What interpret mode cannot see -- a block shape the lowering refuses, more
+VMEM than a kernel may take -- fails here, at no chip time.
+
+All of these stay in ONE file: only one process may load the TPU library,
+and the xdist worker that is handed this file is the one that does.
+"""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from bigdl_tpu.ops.cross_entropy import fused_softmax_cross_entropy
+from bigdl_tpu.ops.flash_attention import (flash_attention,
+                                           flash_decode_attention,
+                                           flash_paged_decode_attention,
+                                           kv_blocks_fit)
+
+f32, bf16, i8, i32 = jnp.float32, jnp.bfloat16, jnp.int8, jnp.int32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """One described chip, with the persistent compile cache off around
+    the compiles: an entry written for a described device cannot be read
+    back without the chip, and the next run would only warn about it."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile(one_chip, fn, *shapes):
+    """Compile ``fn`` for the described chip; returns the number of Pallas
+    kernels (``tpu_custom_call``) in the compiled program."""
+    args = [None if s is None
+            else jax.ShapeDtypeStruct(s[0], s[1], sharding=one_chip)
+            for s in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    return text.count("tpu_custom_call")
+
+
+def _flash_grad(q, k, v):
+    # value AND grad: the backward recomputes through plain attention, so
+    # only the returned value keeps the forward kernel in the program
+    return jax.value_and_grad(
+        lambda *a: flash_attention(*a).astype(f32).sum(),
+        argnums=(0, 1, 2))(q, k, v)
+
+
+def _ce_grad(x, y):
+    return jax.grad(lambda a: fused_softmax_cross_entropy(a, y).sum())(x)
+
+
+def _qkv(b, t, d, dt, h=16):
+    return [((b, t, h, d), dt)] * 3
+
+
+def _decode(b, t, d, dt, h=16):
+    return [((b, 1, h, d), dt), ((b, t, h, d), dt), ((b, t, h, d), dt),
+            ((b,), i32)]
+
+
+def _paged(b, nb, bs, d, quantized, h=16, mb=16):
+    pool = ((nb, bs, h, d), i8 if quantized else f32)
+    scale = ((nb, bs, h, 1), f32) if quantized else None
+    return [((b, 1, h, d), f32), pool, pool, ((b, mb), i32), ((b,), i32),
+            scale, scale]
+
+
+# (kernel, shapes, what the auto gate is asked: rows, head_dim, dtype, int8)
+CASES = {
+    # train_lm's sequence, bf16 compute
+    "flash-medium": (flash_attention, _qkv(2, 2048, 64, bf16),
+                     (2048, 64, bf16, False)),
+    "flash-large": (flash_attention, _qkv(2, 2048, 96, bf16),
+                    (2048, 96, bf16, False)),
+    "flash-grad-medium": (_flash_grad, _qkv(2, 2048, 64, bf16),
+                          (2048, 64, bf16, False)),
+    # serve_lm's contiguous cache: 8 slots + trash row, 2048 long, fp32;
+    # and the longest fp32 cache the gate admits
+    "decode-medium": (flash_decode_attention, _decode(9, 2048, 64, f32),
+                      (2048, 64, f32, False)),
+    "decode-large-longest": (flash_decode_attention,
+                             _decode(9, 6144, 96, f32),
+                             (6144, 96, f32, False)),
+    # the largest pools whose per-head plane the gate admits
+    "paged-medium-fp32": (flash_paged_decode_attention,
+                          _paged(8, 48, 128, 64, False),
+                          (48 * 128, 64, f32, False)),
+    "paged-large-int8": (flash_paged_decode_attention,
+                         _paged(8, 38, 128, 96, True),
+                         (38 * 128, 96, i8, True)),
+    # train_lm's head: 2 sequences of 2048 tokens, vocab 32000
+    "ce-forward": (fused_softmax_cross_entropy,
+                   [((4096, 32000), f32), ((4096,), i32)], None),
+    "ce-gradient": (_ce_grad, [((4096, 32000), f32), ((4096,), i32)], None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_kernel_compiles_for_v5e(one_chip, case):
+    fn, shapes, gate = CASES[case]
+    if gate is not None:
+        assert kv_blocks_fit(*gate), "the auto gate must admit this shape"
+    assert _compile(one_chip, fn, *shapes) >= 1
+
+
+def test_gate_refuses_what_the_compiler_refuses(one_chip):
+    """T=8192 fp32 keeps 16.25 MiB of K/V in VMEM against a 16 MiB limit:
+    the compiler refuses it, so the gate must not admit it."""
+    assert not kv_blocks_fit(8192, 64, f32)
+    with pytest.raises(Exception, match="vmem"):
+        _compile(one_chip, flash_attention, *_qkv(1, 8192, 64, f32))
+
+
+def test_lm_gradient_through_flash_matches_plain():
+    """Finding 1 of ISSUE 22: ``jax.grad`` through a ``TransformerLM``
+    whose attention takes the flash kernel (interpret mode here, ``auto``
+    on a TPU) used to raise; with the kernel's ``custom_vjp`` it agrees
+    with the plain path."""
+    from bigdl_tpu.nn.attention import TransformerLM
+    from bigdl_tpu.utils.random_generator import RNG
+
+    def loss_and_grads(mode):
+        RNG.set_seed(0)
+        model = TransformerLM(64, 32, 4, 2, max_len=16, scan_layers=True)
+        for block in model.blocks:
+            block.attn.use_flash = mode
+        model.build(jax.ShapeDtypeStruct((2, 16), i32))
+        x = jnp.asarray(
+            np.random.default_rng(0).integers(0, 64, (2, 16)), i32)
+
+        def loss(p):
+            logits, _ = model.apply(p, (), x, training=True,
+                                    rng=jax.random.key(0))
+            return jnp.mean(jnp.square(logits))
+
+        return jax.value_and_grad(loss)(model.parameters()[0])
+
+    loss_plain, grads_plain = loss_and_grads("never")
+    loss_flash, grads_flash = loss_and_grads("interpret")
+    np.testing.assert_allclose(loss_flash, loss_plain, rtol=1e-5)
+    for got, want in zip(jax.tree.leaves(grads_flash),
+                         jax.tree.leaves(grads_plain)):
+        np.testing.assert_allclose(got, want, rtol=2e-4, atol=1e-6)

@@ -443,11 +443,11 @@ class TestWatchdogEdgeCases:
     """Satellite: the PR-1 watchdogs beyond their happy paths."""
 
     def test_recompile_cache_fallback_without_monitoring(self, caplog):
-        """Old-jax path (utils/compat.py regime): no jax.monitoring
-        listener -- the watch()-ed function's jit-cache size is the
-        compile signal and still catches the static-arg leak."""
+        """Without the process-wide jax.monitoring listener the
+        watch()-ed function's jit-cache size is the compile signal and
+        still catches the static-arg leak."""
         wd = RecompileWatchdog(warmup_steps=1)
-        wd._use_monitoring = False            # simulate pre-monitoring jax
+        wd._use_monitoring = False            # listener not registered
         f = wd.watch(jax.jit(lambda x, n: x * n, static_argnums=1))
         x = jnp.ones(3)
         with caplog.at_level(logging.WARNING,

@@ -85,7 +85,7 @@ class TestHloParsers:
     def test_collectives_counted_under_shard_map(self):
         from jax.sharding import Mesh, PartitionSpec as P
 
-        from bigdl_tpu.utils.compat import shard_map
+        from jax import shard_map
 
         if len(jax.devices()) < 2:
             pytest.skip("psum over a 1-device axis is elided at lowering")

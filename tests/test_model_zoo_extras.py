@@ -42,32 +42,46 @@ class TestCliMains:
                   "--maxIteration", "2"])
         run.main(["lenet-test", "--synthN", "128", "-b", "32"])
 
-    def test_compilation_cache_flag(self, tmp_path, monkeypatch):
-        """--compilationCache DIR routes through
-        utils.config.enable_compilation_cache (the bench's warm-compile
-        path) and populates the cache; the note helper reports state."""
+    def test_compilation_cache_placed_from_outside(self, tmp_path,
+                                                   monkeypatch):
+        """Where JAX_COMPILATION_CACHE_DIR is set the program uses that
+        directory and sets no other in code; where it is not, the cache
+        is the one fixed, git-ignored directory inside the checkout.
+        Every CLI run turns the cache on and the note helper reports its
+        state."""
         import os
+
+        import jax
 
         from bigdl_tpu.models import run
         from bigdl_tpu.utils import config
 
-        cache = str(tmp_path / "xla_cache")
-        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        was = jax.config.jax_compilation_cache_dir
+        outside = str(tmp_path / "xla_cache")
         try:
+            # the variable wins: nothing is set in code (JAX reads the
+            # variable itself at start-up; stand in for that here)
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", outside)
+            jax.config.update("jax_compilation_cache_dir", outside)
             run.main(["lenet-train", "--synthN", "64", "-b", "32",
-                      "--maxIteration", "1", "--compilationCache", cache])
-            assert os.environ["JAX_COMPILATION_CACHE_DIR"] == cache
-            note = config.compilation_cache_note()
-            assert cache in note
-            # the explicit flag wins over a pre-set env var too
-            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
-                               "/tmp/elsewhere")
-            assert config.enable_compilation_cache(cache) == cache
+                      "--maxIteration", "1"])
+            assert jax.config.jax_compilation_cache_dir == outside
+            assert config.enable_compilation_cache() == outside
+            assert outside in config.compilation_cache_note()
+            # unset: the fixed directory inside the checkout
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+            inside = config.enable_compilation_cache()
+            assert inside == config.DEFAULT_COMPILATION_CACHE_DIR
+            repo = os.path.dirname(os.path.dirname(
+                os.path.abspath(__file__)))
+            assert os.path.dirname(inside) == repo
+            assert jax.config.jax_compilation_cache_dir == inside
+            with open(os.path.join(repo, ".gitignore")) as f:
+                assert os.path.basename(inside) + "/" in f.read().split()
         finally:
-            # tmp_path dies with the test; point the GLOBAL jax config
-            # back at the durable default so later tests never compile
+            # tmp_path dies with the test; later tests must never compile
             # against a deleted cache dir
-            config.enable_compilation_cache("/tmp/jax_cache")
+            jax.config.update("jax_compilation_cache_dir", was)
 
     def test_perf_driver(self):
         from bigdl_tpu.models import perf

@@ -1,6 +1,5 @@
 """MoE layer + expert-parallel training tests on the 8-device mesh."""
 
-import pytest
 import numpy as np
 
 import jax
@@ -13,12 +12,6 @@ from bigdl_tpu.nn.moe import MoE, MoETransformerLM
 from bigdl_tpu.parallel.ep import (ep_shard_params, ep_sharding_for_params,
                                    init_ep_opt_state, make_ep_train_step)
 from bigdl_tpu.utils.random_generator import RNG
-
-requires_modern_jax = pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="old-jax compat fallback lacks the donation/resharding "
-           "semantics this test depends on")
-
 
 
 def ep_mesh():
@@ -94,9 +87,6 @@ class TestExpertParallel:
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                    rtol=2e-4, atol=2e-4)
 
-    # old-jax (pre-0.5, utils/compat.py fallback) lacks the donation/
-    # resharding semantics this path depends on; auto-re-enables on new jax
-    @requires_modern_jax
     def test_ep_train_step_descends(self):
         RNG.set_seed(5)
         model = MoETransformerLM(64, 32, 4, 2, num_experts=4, max_len=32,

@@ -101,13 +101,16 @@ class TestMovieLensTrainingAndServing:
             # padded-row inertness: the engine's bucket-4 result for one
             # request equals the refreshed model's own forward on the
             # same row padded with zero rows (no valid sparse entries)
+            # (jitted like the engine's step: op-by-op eager execution is
+            # not bit-equal to a fused program on every XLA CPU backend)
+            forward = jax.jit(lambda p, s, rows: serve.apply(
+                p, s, rows, training=False)[0])
             np.testing.assert_array_equal(
                 after,
-                np.asarray(serve.apply(
+                np.asarray(forward(
                     serve._params, serve._state,
                     jnp.asarray(np.vstack([x[:1], np.zeros((3, 2),
-                                                           np.float32)])),
-                    training=False)[0][0]))
+                                                           np.float32)])))[0]))
             outs = [np.asarray(eng.predict(r)) for r in x[:10]]
             assert all(o.shape == (5,) for o in outs)
             # coalesced vs unbatched reference at the same bucket:

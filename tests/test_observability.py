@@ -75,21 +75,53 @@ class TestStepTelemetrySchema:
         assert header["run"] == "obs-smoke"
         assert header["platform"] == "cpu"
         assert header["device_count"] >= 1
-        assert header["peak_flops"] > 0
+        # off a TPU there is no peak, and so no MFU
+        assert header["peak_flops"] is None
         # cost_analysis of the compiled step rode in on the header
         assert header["cost"]["flops_per_step"] > 0
         assert header["cost"]["records_per_step"] == 32
 
-    def test_header_notes_compilation_cache(self, tmp_path, monkeypatch):
+    @pytest.mark.parametrize("kind,peak", [
+        ("TPU v4", 275e12), ("TPU v5 lite", 197e12), ("TPU v5", 459e12),
+        ("TPU v6 lite", 918e12)])
+    def test_peak_flops_by_device_kind(self, kind, peak):
+        from types import SimpleNamespace
+
+        from bigdl_tpu.observability import peak_flops
+
+        dev = SimpleNamespace(platform="tpu", device_kind=kind)
+        assert peak_flops(dev) == peak
+
+    def test_peak_flops_unknown_tpu_is_an_error(self):
+        """A device that is not in the table is an error, not a default;
+        off a TPU there is no peak at all."""
+        from types import SimpleNamespace
+
+        from bigdl_tpu.observability import peak_flops
+
+        with pytest.raises(ValueError, match="TPU v9"):
+            peak_flops(SimpleNamespace(platform="tpu",
+                                       device_kind="TPU v9"))
+        assert peak_flops(SimpleNamespace(platform="cpu",
+                                          device_kind="cpu")) is None
+        assert peak_flops() is None        # the test platform is the CPU
+
+    def test_header_notes_compilation_cache(self, tmp_path):
         """The hit/miss note: a configured XLA compilation cache shows
         up on the header with its entry count (warm vs cold)."""
+        import jax
+
         d = str(tmp_path / "cache")
         os.makedirs(d)
         open(os.path.join(d, "entry0"), "w").close()
-        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", d)
-        tel = StepTelemetry(str(tmp_path / "run"), trace=False)
-        header = tel.write_header()
-        tel.close()
+        was = jax.config.jax_compilation_cache_dir
+        jax.config.update("jax_compilation_cache_dir", d)
+        try:
+            tel = StepTelemetry(str(tmp_path / "run"), trace=False)
+            header = tel.write_header()
+            tel.close()
+        finally:
+            jax.config.update("jax_compilation_cache_dir", was)
         assert header["compilation_cache"] == {
             "dir": d, "entries": 1, "warm": True}
 
@@ -175,7 +207,7 @@ class TestObsReportCLI:
         assert rep["n_steps"] == 3
         assert rep["steps"]["wall_s_p50"] > 0
         assert 0 <= rep["steps"]["data_wait_fraction"] <= 1
-        assert rep["steps"]["mfu_p50"] > 0
+        assert "mfu_p50" not in rep["steps"]    # no peak, no MFU, on the CPU
         assert rep["header"]["cost"]["flops_per_step"] > 0
 
 

@@ -20,16 +20,6 @@ from bigdl_tpu.nn.attention import TransformerLM
 from bigdl_tpu.nn.moe import MoETransformerLM
 from bigdl_tpu.utils.random_generator import RNG
 
-requires_modern_jax = pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="old-jax (pre-0.5) SPMD partitioner cannot lower the 3-D "
-           "manual(data,pipe)+auto(model) composition (PartitionId "
-           "UNIMPLEMENTED) -- a genuine shard_map gap, auto-re-enables "
-           "on new jax; the resume-resharding-strictness skips this "
-           "marker used to cover are retired (ISSUE 12: restore under "
-           "the snapshot's own layout, then redistribute)")
-
-
 pytestmark = pytest.mark.skipif(
     jax.device_count() < 8, reason="needs the 8-device virtual CPU mesh")
 
@@ -136,9 +126,6 @@ class TestPPEquivalence:
 
 
 class Test3DComposition:
-    # old-jax (pre-0.5, utils/compat.py fallback) lacks the donation/
-    # resharding semantics this path depends on; auto-re-enables on new jax
-    @requires_modern_jax
     def test_pp_tp_dp_one_step_matches_single_device(self):
         """3-D mesh (data x pipe x model): GPipe shard_map manual on
         data/pipe, Megatron shardings on the model axis left to GSPMD

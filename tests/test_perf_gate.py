@@ -55,17 +55,30 @@ def _bench_dir(tmp_path, files):
 
 
 class TestTrajectory:
-    def test_checked_in_history_builds_and_passes(self, gate, capsys):
+    def test_checked_in_history_builds_and_passes(self, gate, tmp_path,
+                                                  capsys):
         """The REAL repo artifacts: r02 (superseded async artifact) is
-        excluded, r02_judge is the one trusted baseline, r04/r05 CPU
-        fallbacks are invalid:off_tpu -- and the gate passes."""
+        excluded, r02_judge is the one trusted baseline -- and the gate
+        passes.  A CPU-fallback record of the shape bench.py emits when
+        no chip answers (platform "cpu" under the device metric's name)
+        is invalid:off_tpu and neither sets nor breaks a baseline."""
         rc = gate.main(["--dir", REPO])
         out = capsys.readouterr().out
         assert rc == 0
         assert "gate: PASS" in out
         assert "r02_judge" in out and "trusted" in out
         assert "SUPERSEDED" in out
-        assert "invalid:off_tpu" in out
+
+        cpu_fallback = {
+            "metric": "resnet50_train_imgs_per_sec_per_chip", "value": 0.6,
+            "unit": "images/sec", "vs_baseline": 0.0,
+            "extra": {"platform": "cpu", "device_kind": "cpu", "batch": 8,
+                      "steps": 2, "sec_per_step": 13.4346,
+                      "cpu_fallback": True}}
+        d = _bench_dir(tmp_path, {
+            "BENCH_r04.json": _wrapper([cpu_fallback], n=4)})
+        assert gate.main(["--dir", d]) == 0
+        assert "invalid:off_tpu" in capsys.readouterr().out
 
     def test_round_ordering_and_judge_subrank(self, gate):
         assert gate._round_key("/x/BENCH_r02.json") \
@@ -84,7 +97,7 @@ class TestTrajectory:
 
     def test_ratio_records_are_baseline_eligible(self, gate):
         # host-side A/B ratios carry no platform/timing claim: the
-        # device trust taxonomy does not apply, the ratio still gates
+        # device trust verdicts do not apply, the ratio still gates
         rec = {"metric": "serving_coalesced_rps_speedup", "value": 4.0,
                "unit": "x", "extra": {"concurrency": 8}}
         assert gate.classify_trust(rec) == "ratio"
@@ -342,7 +355,7 @@ class TestObsReportHollowRuns:
 
 def _serve_record(value, metric="serving_int8_rps_ratio"):
     """The BENCH_SERVE_INT8 A/B shape: a host-side ratio -- no platform
-    claim, no per-step timing claim -- so the timing taxonomy does not
+    claim, no per-step timing claim -- so the timing verdicts do not
     apply and the gate classes it ``ratio``."""
     return {"metric": metric, "value": value, "unit": "x",
             "vs_baseline": value,

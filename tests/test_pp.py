@@ -16,12 +16,6 @@ from bigdl_tpu.parallel.pp import (init_pp_opt_state, make_pp_loss_fn,
                                    stack_stage_params, unstack_stage_params)
 from bigdl_tpu.utils.random_generator import RNG
 
-requires_modern_jax = pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="old-jax compat fallback lacks the donation/resharding "
-           "semantics this test depends on")
-
-
 
 def pipe_mesh():
     return Mesh(np.asarray(jax.devices()).reshape(2, 4), ("data", "pipe"))
@@ -425,9 +419,6 @@ class Test1F1BSchedule:
         loss_f = run(make_pp_1f1b_train_step)
         assert abs(loss_f - loss_g) / abs(loss_g) < 5e-3, (loss_f, loss_g)
 
-    # old-jax (pre-0.5, utils/compat.py fallback) lacks the donation/
-    # resharding semantics this test depends on; auto-re-enables on new jax
-    @requires_modern_jax
     def test_1f1b_composes_with_tensor_parallel_3d(self):
         """1F1B on the 3-D data x pipe x model mesh: shard_map manual on
         (data, pipe), the model axis left to GSPMD (pp_tp_shardings) --

@@ -546,7 +546,7 @@ class TestDriverWiring:
         it into the next step's gradient: a transient Inf costs one
         step's block signal, not the whole run."""
         from bigdl_tpu.ops.quantization import quantized_reduce_chunks
-        from bigdl_tpu.utils.compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         mesh = jax.sharding.Mesh(

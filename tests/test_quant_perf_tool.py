@@ -2,8 +2,8 @@
 
 Reference headline it measures: BigQuant's ~4x size / up-to-2x inference
 speedup (docs/docs/whitepaper.md:192); the size ratio is asserted here,
-the speedup is hardware evidence collected on-chip (tools/quant_perf.py,
-tools/onchip_autorun.sh).
+the speedup is hardware evidence to be collected on the chip
+(tools/quant_perf.py): not measured.
 """
 
 import os

@@ -5,6 +5,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 import bigdl_tpu.nn as nn
@@ -14,7 +15,6 @@ from bigdl_tpu.nn.attention import (MultiHeadAttention, TransformerLM,
 from bigdl_tpu.parallel.ring_attention import sequence_shard_attention
 from bigdl_tpu.parallel.sequence import make_sp_train_step, shard_tokens
 from bigdl_tpu.utils.random_generator import RNG
-from bigdl_tpu.utils.compat import shard_map
 
 
 def seq_mesh(n=8):

@@ -327,7 +327,9 @@ class TestFleetTracingE2E:
             spans = _wait_spans(tmp_path, {"fleet_request"})
         finally:
             fleet.close()
-        tid = spans[-1]["trace"]
+        # the request's own span: the tick's span (a trace of its own)
+        # may land in the file after it
+        tid = [s for s in spans if s["name"] == "fleet_request"][-1]["trace"]
         evs = [e for e in _events(tmp_path, "inference")
                if e.get("request_traces")]
         assert evs, "no inference event carried request_traces"

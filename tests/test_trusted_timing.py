@@ -36,7 +36,7 @@ def _load_by_path(name, relpath):
 
 
 # --------------------------------------------------------------------------- #
-# TimingAuditor: the trust taxonomy
+# TimingAuditor: the trust verdicts
 # --------------------------------------------------------------------------- #
 
 #: a plausible honest v5e measurement: blocked 0.119 s/step at 3.04e12
@@ -437,16 +437,16 @@ class TestBenchProbe:
             lambda env, t: (None, "timeout after 60s; stderr tail: "))
         assert info["probe_result"] == "timeout"
         assert left == 0
-        assert any("dead tunnel" in f for f in failures)
+        assert any("never answered" in f for f in failures)
 
     def test_transient_error_keeps_retry_budget(self):
         # round-1's failure story: fast transient init errors must keep
         # the full retry budget
         info, left, failures = self._probe(
-            lambda env, t: (None, "rc=1; stderr tail: tunnel reset"))
+            lambda env, t: (None, "rc=1; stderr tail: connection reset"))
         assert info["probe_result"] == "error"
         assert left == 3
-        assert any("tunnel reset" in f for f in failures)
+        assert any("connection reset" in f for f in failures)
 
     def test_no_budget_skips_probe(self):
         import bench

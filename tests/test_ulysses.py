@@ -6,6 +6,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 import bigdl_tpu.nn as nn
@@ -14,7 +15,6 @@ from bigdl_tpu.nn.attention import TransformerLM, dot_product_attention
 from bigdl_tpu.parallel.sequence import make_sp_train_step, shard_tokens
 from bigdl_tpu.parallel.ulysses import ulysses_self_attention
 from bigdl_tpu.utils.random_generator import RNG
-from bigdl_tpu.utils.compat import shard_map
 
 pytestmark = pytest.mark.skipif(
     jax.device_count() < 8, reason="needs the 8-device virtual CPU mesh")

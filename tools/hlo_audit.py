@@ -264,8 +264,6 @@ def main(argv=None):
         ap.error(f"--gate names unknown drivers {unknown}; "
                  f"valid: {list(DRIVERS)}")
 
-    from bigdl_tpu.utils.config import honor_env_platforms
-    honor_env_platforms()
     report, ok = run_audits(drivers, min_bytes=args.min_bytes,
                             donate=not args.no_donate,
                             gate_drivers=gate_drivers)

@@ -836,10 +836,13 @@ def build_report(run_dir, xplane_dir=None, top=10):
         basis_key = "step_blocked_s" if blocked else "wall_s"
         basis_p50 = (rep["steps"]["step_blocked_s_p50"] if blocked
                      else rep["steps"]["wall_s_p50"])
+        # the basis is stated whether or not an MFU follows from it: off
+        # a TPU the header carries no peak FLOP/s, so there is no MFU,
+        # but an un-fenced step time still must not read as a fenced one
+        rep["steps"]["mfu_basis"] = basis_key
         if cost.get("flops_per_step") and peak and basis_p50:
             rep["steps"]["mfu_p50"] = (
                 cost["flops_per_step"] / basis_p50 / peak)
-            rep["steps"]["mfu_basis"] = basis_key
         mems = [e["memory"] for e in steps if e.get("memory")]
         if mems:
             rep["memory_last"] = mems[-1]
@@ -972,14 +975,16 @@ def format_report(rep):
                        f"{s['sync_skew_max']} steps (loss/throughput "
                        f"fresh at sync points only)")
         out.append(f"loss: {s['loss_first']:.6f} -> {s['loss_last']:.6f}")
+        basis_note = ("blocking-fenced step time"
+                      if s.get("mfu_basis", "wall_s") == "step_blocked_s"
+                      else "UN-FENCED wall time -- not publishable")
         if s.get("mfu_p50") is not None:
-            basis = s.get("mfu_basis", "wall_s")
-            basis_note = ("blocking-fenced step time"
-                          if basis == "step_blocked_s"
-                          else "UN-FENCED wall time -- not publishable")
             out.append(f"MFU @ p50 step time: {s['mfu_p50']:.2%} "
                        f"(peak {h.get('peak_flops', 0):.0f} FLOP/s assumed; "
                        f"basis: {basis_note})")
+        else:
+            out.append(f"MFU: none (no peak FLOP/s off a TPU, or no cost "
+                       f"attached); step-time basis: {basis_note}")
     pf = rep.get("profiling")
     if pf:
         line = "profiling:"

@@ -5,7 +5,7 @@ record, usually inside the driver's ``{n, cmd, rc, tail, parsed}``
 wrapper; judge re-measurements are bare records).  This tool folds ALL
 of them into one per-metric trajectory and gates on it:
 
-- records are re-audited through the PR 6 trust taxonomy
+- records are re-audited through the PR 6 trust verdicts
   (``TimingAuditor``): a record carrying its own ``trust`` verdict
   keeps it, an older record claiming a platform is re-audited, and a
   pure host-side A/B ratio record (no platform/timing claim -- the
@@ -67,7 +67,7 @@ TimingAuditor = _profiling.TimingAuditor
 
 #: trust classes a record may hold after re-audit; ``ratio`` is this
 #: tool's addition: a host-side A/B ratio that never claimed a device
-#: measurement, so the timing taxonomy does not apply to it
+#: measurement, so the timing verdicts do not apply to it
 TRUST_BASELINE_OK = ("trusted", "ratio")
 
 
@@ -142,7 +142,7 @@ def classify_trust(record):
     A record that stamped its own verdict (PR 6 onward) keeps it; one
     that claims a platform (it measured a device) is re-audited through
     ``TimingAuditor.audit_record``; one claiming neither platform nor
-    per-step timing is a host-side A/B ``ratio`` -- the taxonomy's
+    per-step timing is a host-side A/B ``ratio`` -- the auditor's
     device checks do not apply, and the ratio is reproducible evidence.
 
     A bench manifest confessing always-sample tracing overrides even

@@ -35,6 +35,13 @@ Artifacts under ``--out``: ``ckpt/`` (trainer snapshots),
 ``registry.json``, ``serve*/telemetry.jsonl`` (deploy + fleet audit
 trail, obs_report-renderable), ``replica_<i>.log`` / ``.port``,
 ``trainer.log``, ``result.json``.
+
+One process for each chip: this drill's replicas are PROCESSES whose
+probe digests must agree bit for bit, and the driver (replica 0 runs in
+it) would hold the chip that every worker and the trainer then ask for.
+So every role runs on the CPU: ``main`` pins ``JAX_PLATFORMS=cpu`` for
+itself and its children, refuses a caller who asked for another
+platform, and every role prints the platform it came up on.
 """
 
 import argparse
@@ -135,6 +142,8 @@ def build_args(argv=None):
 
 
 def run_worker(args):
+    import jax
+
     from tools.serve_live import build_workload
 
     from bigdl_tpu.serving import ServingEngine
@@ -173,7 +182,8 @@ def run_worker(args):
         with open(tmp, "w") as f:           # atomic: a half-written port
             f.write(str(srv.port))          # file must not be readable
         os.replace(tmp, args.portFile)
-    print(f"[worker {args.replicaId}] serving on port {srv.port}"
+    print(f"[worker {args.replicaId}] platform "
+          f"{jax.devices()[0].platform}, serving on port {srv.port}"
           + (f", booted v{booted[0]}" if booted else ", boot weights"),
           file=sys.stderr)
     sys.stderr.flush()
@@ -209,9 +219,8 @@ def make_spawn(args, rid):
                "--registry", os.path.join(args.out, "registry.json")]
         if args.traceSample is not None:
             cmd += ["--traceSample", str(args.traceSample)]
-        env = dict(os.environ)
+        env = dict(os.environ)        # carries main()'s JAX_PLATFORMS=cpu
         env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-        env.setdefault("JAX_PLATFORMS", "cpu")
         logf = open(os.path.join(args.out, f"replica_{rid}.log"), "a")
         logf.write(f"--- spawn attempt {attempt} ---\n")
         proc = subprocess.Popen(cmd, env=env, stdout=logf,
@@ -235,6 +244,7 @@ def make_spawn(args, rid):
 
 
 def run_driver(args):
+    import jax
     import numpy as np
 
     from tools.serve_live import build_workload
@@ -367,9 +377,8 @@ def run_driver(args):
                "--datasetSize", str(args.datasetSize),
                "--ckptEvery", str(args.ckptEvery), "--lr", str(args.lr),
                "--seed", str(args.seed)]
-        env = dict(os.environ)
+        env = dict(os.environ)        # carries main()'s JAX_PLATFORMS=cpu
         env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-        env.setdefault("JAX_PLATFORMS", "cpu")
         logf = open(os.path.join(args.out, "trainer.log"), "w")
         trainer = subprocess.Popen(cmd, env=env, stdout=logf,
                                    stderr=subprocess.STDOUT, cwd=REPO)
@@ -505,7 +514,13 @@ def run_driver(args):
                                            "replica")}
                     for e in ctl.events],
         "versions": registry.describe(),
+        "platforms": {"driver": jax.devices()[0].platform,
+                      "workers": os.environ["JAX_PLATFORMS"],
+                      "trainer": None if args.noTrainer
+                      else os.environ["JAX_PLATFORMS"]},
     }
+    print(f"[serve_fleet] platforms: {result['platforms']}",
+          file=sys.stderr)
     tmp = os.path.join(args.out, "result.json.tmp")
     with open(tmp, "w") as f:
         json.dump(result, f, indent=1)
@@ -521,7 +536,14 @@ def run_driver(args):
 
 def main(argv=None):
     args = build_args(argv)
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    asked = os.environ.get("JAX_PLATFORMS")
+    if asked not in (None, "", "cpu"):
+        raise SystemExit(
+            f"serve_fleet runs every role on the CPU (JAX_PLATFORMS="
+            f"{asked!r} asked otherwise): its replicas are processes, a "
+            f"chip belongs to one process at a time, and the driver "
+            f"would hold the one its workers need")
+    os.environ["JAX_PLATFORMS"] = "cpu"
     if args.role == "worker":
         return run_worker(args)
     return run_driver(args)

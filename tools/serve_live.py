@@ -39,6 +39,12 @@ Artifacts under ``--out``:
 Both workloads build their model under a fixed seed, so the trainer
 child and the serving driver agree on the tree structure (and the
 baseline version's weights) by construction.
+
+One process for each chip: the DRIVER serves on whatever platform JAX
+finds, so on a chip machine it holds the chip, and the trainer child it
+starts would fail or hang there.  The trainer is therefore always pinned
+to the CPU (snapshots are host arrays; nothing else crosses).  Both
+roles print their platform, and ``result.json`` records them.
 """
 
 import argparse
@@ -52,6 +58,8 @@ import threading
 import time
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+#: where the trainer child runs: never on the accelerator the driver holds
+TRAINER_PLATFORM = "cpu"
 
 
 def build_args(argv=None):
@@ -168,9 +176,13 @@ def build_workload(args):
 
 
 def run_trainer(args):
+    import jax
+
     from bigdl_tpu import optim
     from bigdl_tpu.dataset import SampleToMiniBatch, array_dataset
 
+    print(f"[trainer] platform {jax.devices()[0].platform}",
+          file=sys.stderr, flush=True)
     model, x, y, crit = build_workload(args)
     ds = array_dataset(x, y, seed=args.seed) >> SampleToMiniBatch(args.batch)
     opt = optim.LocalOptimizer(
@@ -277,6 +289,7 @@ def probe_digest(engine, probe_rows, bucket):
 
 
 def run_driver(args):
+    import jax
     import numpy as np
 
     from bigdl_tpu.observability import StepTelemetry
@@ -392,11 +405,15 @@ def run_driver(args):
                "--seed", str(args.seed)]
         env = dict(os.environ)
         env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        # this process holds whatever accelerator JAX found: the child
+        # must not ask for it
+        env["JAX_PLATFORMS"] = TRAINER_PLATFORM
         logf = open(os.path.join(args.out, "trainer.log"), "w")
         trainer = subprocess.Popen(cmd, env=env, stdout=logf,
                                    stderr=subprocess.STDOUT, cwd=REPO)
-        print(f"[serve_live] trainer pid {trainer.pid}", file=sys.stderr)
+        print(f"[serve_live] trainer pid {trainer.pid} on "
+              f"{TRAINER_PLATFORM}; driver on "
+              f"{jax.devices()[0].platform}", file=sys.stderr)
 
     # the loop: poll -> rollout -> watch, until the trainer is done and
     # the checkpoint dir has gone quiet
@@ -457,6 +474,9 @@ def run_driver(args):
         "compiles_after_precompile": compiles,
         "deploys": deploys,
         "versions": registry.describe(),
+        "platforms": {"driver": jax.devices()[0].platform,
+                      "trainer": None if args.noTrainer
+                      else TRAINER_PLATFORM},
     }
     tmp = os.path.join(args.out, "result.json.tmp")
     with open(tmp, "w") as f:
@@ -470,7 +490,6 @@ def run_driver(args):
 
 def main(argv=None):
     args = build_args(argv)
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     if args.role == "trainer":
         return run_trainer(args)
     return run_driver(args)

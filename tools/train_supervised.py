@@ -26,6 +26,13 @@ Artifacts under ``--out``:
 - ``supervisor/``      -- the supervisor's telemetry.jsonl (header +
                           recovery events)
 
+One process for each chip: the supervisor never needs an accelerator and
+always pins ITSELF to the CPU before anything imports jax, because a
+parent that has initialised JAX on the chip holds it, and the worker it
+spawns would then fail or hang.  Only the worker may be on the chip
+(``--platform native``); every role's platform is printed and lands in
+the final JSON line.
+
 The workload is a small synthetic-classification MLP trained
 data-parallel (ZeRO-1) over every visible device -- a drill, not a
 benchmark; swap in a real entry point by supervising your own command
@@ -96,8 +103,15 @@ def build_args(argv=None):
 
 def worker_env(base_env, args, attempt):
     """The child's environment: platform pin + per-attempt device count
-    (restarts may come up on FEWER devices -- the N->M drill)."""
+    (restarts may come up on FEWER devices -- the N->M drill).  Under
+    ``--platform native`` the worker gets back the ``JAX_PLATFORMS`` the
+    supervisor was started with, not the supervisor's own CPU pin."""
     env = dict(base_env)
+    inherited = getattr(args, "inherited_platforms", None)
+    if inherited is None:
+        env.pop("JAX_PLATFORMS", None)
+    else:
+        env["JAX_PLATFORMS"] = inherited
     # the child is spawned by FILE path (sys.path[0] = tools/); the repo
     # root must be importable regardless of how the supervisor was run
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
@@ -169,6 +183,8 @@ def _build_tp(args, nn, optim, array_dataset, SampleToMiniBatch):
 
 
 def run_worker(args):
+    import jax
+
     import bigdl_tpu.nn as nn
     from bigdl_tpu import optim
     from bigdl_tpu.dataset import SampleToMiniBatch, array_dataset
@@ -206,7 +222,9 @@ def run_worker(args):
     result = {"neval": opt.driver_state["neval"],   # had no steps left
               "epoch": opt.driver_state["epoch"],
               "final_loss": None if loss is None else float(loss),
-              "attempt": args.attempt}
+              "attempt": args.attempt,
+              "platform": jax.devices()[0].platform,
+              "device_count": jax.device_count()}
     with open(os.path.join(run_dir, "result.json"), "w") as f:
         json.dump(result, f)
     print(json.dumps(result))
@@ -302,19 +320,24 @@ def run_supervisor(args):
     if rc == 0 and os.path.isfile(result_path):
         with open(result_path) as f:
             result = json.load(f)
+    platforms = {"supervisor": "cpu",
+                 "worker": (result or {}).get("platform")}
+    print(f"[supervisor] platforms: {platforms}", file=sys.stderr)
     print(json.dumps({"restarts": restarts, "rc": rc, "result": result,
+                      "platforms": platforms,
                       "recovery_events": sup.events}))
     return rc
 
 
 def main(argv=None):
     args = build_args(argv)
-    if args.role == "supervisor" and args.platform == "cpu":
-        # the supervisor itself never needs an accelerator; pin it to
-        # CPU BEFORE any jax-importing bigdl_tpu module loads
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
     if args.role == "worker":
         return run_worker(args)
+    # the supervisor itself never needs an accelerator and must not hold
+    # the chip its worker needs: pin it to the CPU BEFORE any
+    # jax-importing bigdl_tpu module loads, whatever --platform says
+    args.inherited_platforms = os.environ.get("JAX_PLATFORMS")
+    os.environ["JAX_PLATFORMS"] = "cpu"
     return run_supervisor(args)
 
 
