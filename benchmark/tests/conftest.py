@@ -1,0 +1,12 @@
+"""Tests of the benchmark's own code: CPU, seconds.  Run from the root of
+the checkout: ``JAX_PLATFORMS=cpu python -m pytest benchmark/tests -q``."""
+
+import os
+import sys
+
+BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+ROOT = os.path.dirname(BENCH)
+for p in (ROOT, BENCH):
+    if p not in sys.path:
+        sys.path.insert(0, p)
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
