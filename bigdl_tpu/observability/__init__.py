@@ -5,8 +5,10 @@ Four layers over the one shared driver loop:
 - ``StepTelemetry`` -- structured per-step JSONL events (split
   wall/data-wait/device timers, loss, records/s, memory stats) plus a
   run header with the compiled step's flops (``telemetry.py``).
-- ``SpanTracer`` / ``span`` -- host-side chrome-trace spans, Perfetto-
-  viewable alongside the device xplane traces (``spans.py``).
+- ``span`` / ``recorder`` -- the span recorder: every span the program
+  opens lands in one in-memory ring on the device trace's clock, with
+  no telemetry object attached; ``SpanTracer`` is a sink that also
+  streams them to a Perfetto-viewable chrome trace (``spans.py``).
 - ``RecompileWatchdog`` / ``MemoryWatchdog`` -- WARNING-level detectors
   for silent per-step recompiles and monotonic device-memory growth
   (``watchdogs.py``).
@@ -49,7 +51,8 @@ from bigdl_tpu.observability.metrics import (Counter, Gauge, Histogram,
                                              SloTracker)
 from bigdl_tpu.observability.profiling import (BlockingStepTimer,
                                                TimingAuditor)
-from bigdl_tpu.observability.spans import (SpanTracer, read_trace_events,
+from bigdl_tpu.observability.spans import (SpanTracer, instant,
+                                           read_trace_events, recorder,
                                            span)
 from bigdl_tpu.observability.telemetry import (StepTelemetry,
                                                device_memory_stats,
@@ -64,7 +67,8 @@ from bigdl_tpu.observability.watchdogs import (LossSpikeWatchdog,
                                                backend_compile_count)
 
 __all__ = [
-    "StepTelemetry", "SpanTracer", "span", "RecompileWatchdog",
+    "StepTelemetry", "SpanTracer", "span", "recorder", "instant",
+    "RecompileWatchdog",
     "MemoryWatchdog", "NonFiniteWatchdog", "LossSpikeWatchdog",
     "HealthMonitor", "backend_compile_count", "device_memory_stats",
     "peak_flops", "layer_labels", "per_layer_grad_norms",

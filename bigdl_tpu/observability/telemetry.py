@@ -11,8 +11,10 @@ One ``StepTelemetry`` instance owns a run directory and produces:
   the deferred-loss-sync staleness (``sync_skew``, 0 when the loss is
   fresh) and -- when a ``PrefetchDataSet`` feeds the run -- the
   prefetch queue occupancy (``queue_depth`` / ``queue_capacity``).
-- ``trace.json`` -- chrome-trace host spans (see ``spans.SpanTracer``),
-  viewable in Perfetto next to the device xplane traces.
+- ``trace.json`` -- chrome-trace host spans: every span the process
+  records (``spans.span``) while this object is open, streamed by a
+  ``spans.SpanTracer``; viewable in Perfetto next to the device xplane
+  traces.
 
 The watchdogs (``watchdogs.py``) ride on the same step cadence:
 ``step_begin``/``record_step`` bracket the no-compile window for the
@@ -158,7 +160,11 @@ class StepTelemetry:
         # silently merge runs in obs_report); pick a fresh dir to keep
         # a previous attempt's artifacts
         self._f = open(self.jsonl_path, "w")
-        self.tracer = SpanTracer(os.path.join(out_dir, "trace.json")) \
+        # a sink of the span recorder (spans.py) from now until close():
+        # the run's trace.json holds every span the process completes
+        # meanwhile; what is RECORDED does not depend on this object
+        self.tracer = SpanTracer(
+            os.path.join(out_dir, "trace.json")).activate() \
             if trace else None
         # distributed request-trace spans (docs/observability.md,
         # "Request tracing"): opened lazily on the first record_trace
@@ -506,14 +512,6 @@ class StepTelemetry:
             self.tracer.complete_at(name, t_wall, dur_s, **args)
         return rec
 
-    # ----- spans ------------------------------------------------------------ #
-    def span(self, name, **args):
-        import contextlib
-
-        if self.tracer is None:
-            return contextlib.nullcontext()
-        return self.tracer.span(name, **args)
-
     # ----- lifecycle -------------------------------------------------------- #
     def flush(self):
         with self._write_lock:   # same shared-owner ordering as record():
@@ -550,11 +548,6 @@ class StepTelemetry:
             self.tracer.close()           # deactivates + terminates JSON
 
     def __enter__(self):
-        """Context use additionally makes the tracer ambient, so
-        module-level ``span()`` calls anywhere (user code, serving)
-        land in this run's trace until exit."""
-        if self.tracer is not None:
-            self.tracer.activate()
         return self
 
     def __exit__(self, *exc):

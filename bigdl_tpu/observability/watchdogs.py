@@ -27,10 +27,9 @@ import logging
 import math
 import threading
 
-log = logging.getLogger("bigdl_tpu.observability")
+from bigdl_tpu.observability.spans import COMPILE_EVENT as _COMPILE_EVENT
 
-#: duration events that indicate a real backend (XLA) compile
-_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+log = logging.getLogger("bigdl_tpu.observability")
 
 _counter_lock = threading.Lock()
 _compile_count = 0

@@ -8,7 +8,6 @@ TPU-native: no replica threads -- one jitted step fuses fwd/bwd/update and
 saturates the chip; the host loop only feeds batches and evaluates triggers.
 """
 
-import contextlib
 import logging
 import time
 from typing import Dict, List, Optional
@@ -18,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from bigdl_tpu.dataset.dataset import AbstractDataSet
+from bigdl_tpu.observability.spans import span
 from bigdl_tpu.optim.metrics import Metrics
 from bigdl_tpu.optim.optim_method import OptimMethod, SGD
 from bigdl_tpu.optim.train_step import make_train_step
@@ -724,8 +724,6 @@ class BaseOptimizer:
         mon = self.health_monitor
         health_on = (mon is not None and mon.enabled
                      and health_cb is not None)
-        sp = tel.span if tel is not None else \
-            (lambda name, **kw: contextlib.nullcontext())
         timer = None
         if getattr(self, "blocking_timing", False):
             # trusted-timing mode (set_blocking_timing): every dispatch
@@ -741,147 +739,150 @@ class BaseOptimizer:
             """Force a loss sync outside the cadence (validation/
             checkpoint firing): consumers there need a fresh value."""
             nonlocal loss, sync_skew
-            with sp("loss_sync", step=state["neval"], forced=reason):
+            with span("loss_sync", step=state["neval"], forced=reason):
                 loss = float(loss_dev)
             sync_skew = 0
             state["loss"] = loss
 
         try:
             while not self.end_trigger(state):
-                t0 = time.perf_counter()
-                if batch is None:  # exotic trigger defeated the prediction
-                    with sp("data_wait", step=state["neval"]):
-                        batch, train_iter = self._stage_next_batch(
-                            train_iter, state, 0, epoch_size, force=True)
-                if dev is None:    # first iteration / deferred-fetch path
-                    with sp("device_stage", step=state["neval"]):
-                        dev = stage_device(batch)
-                data_wait = time.perf_counter() - t0
-                if tel is not None:   # open the no-compile watchdog window
-                    tel.step_begin(state["neval"])
-                with sp("dispatch", step=state["neval"]):
+                with span("step", step=state["neval"]):
+                    t0 = time.perf_counter()
+                    if batch is None:  # exotic trigger defeated the prediction
+                        with span("data_wait", step=state["neval"]):
+                            batch, train_iter = self._stage_next_batch(
+                                train_iter, state, 0, epoch_size, force=True)
+                    if dev is None:    # first iteration / deferred-fetch path
+                        with span("device_stage", step=state["neval"]):
+                            dev = stage_device(batch)
+                    data_wait = time.perf_counter() - t0
+                    if tel is not None:   # open the no-compile watchdog window
+                        tel.step_begin(state["neval"])
+                    with span("dispatch", step=state["neval"]):
+                        if timer is not None:
+                            timer.begin()
+                        loss_dev = dispatch(dev)
+                        if timer is not None:
+                            # fence: the loss is an output of the step's one
+                            # XLA program, so its readiness is the step's
+                            step_blocked = timer.end(loss_dev)
+                    n = records_of(batch)
+                    qdepth = queue_stats() if queue_stats is not None else None
+                    t_fetch = time.perf_counter()
+                    with span("stage_next_batch", step=state["neval"]):
+                        next_batch, train_iter = self._stage_next_batch(
+                            train_iter, state, n, epoch_size)
+                    next_dev = None
+                    if next_batch is not None \
+                            and next_batch is not PREDICTED_END:
+                        # double buffering: batch k+1's host->device transfer
+                        # overlaps step k's execution
+                        with span("device_stage", step=state["neval"] + 1):
+                            next_dev = stage_device(next_batch)
+                    # the in-loop fetch runs while the device executes, but it
+                    # is still host time the loop cannot dispatch through --
+                    # the input-pipeline cost prefetch workers are there to
+                    # take off this path
+                    data_wait += time.perf_counter() - t_fetch
+                    health_due = health_on and mon.due(state["neval"])
+                    if sync_skew + 1 >= sync_every or health_due:
+                        # a health sample forces a point sync (same contract
+                        # as validation triggers): the published event pairs
+                        # the stats with a FRESH loss
+                        with span("loss_sync", step=state["neval"]):
+                            loss = float(loss_dev)
+                        sync_skew = 0
+                    else:
+                        sync_skew += 1    # deferred: host runs ahead of device
+                    wall = time.perf_counter() - t0
+                    device_s = wall - data_wait
+                    state["loss"] = loss
+                    state["record_count"] += n
+                    # batches consumed by COMPLETED steps this epoch -- the
+                    # prefetched-but-not-dispatched next batch is NOT counted,
+                    # so a snapshot's position replays it after resume
+                    state["batches_consumed"] = \
+                        state.get("batches_consumed", 0) + 1
+                    state["throughput"] = n / max(wall, 1e-9)
+                    self.metrics.add("data_wait_s", data_wait)
+                    self.metrics.add("device_s", device_s)
+                    event = {"step": state["neval"], "epoch": state["epoch"],
+                             "wall_s": wall, "data_wait_s": data_wait,
+                             "device_s": device_s, "loss": loss, "records": n,
+                             "records_per_s": state["throughput"],
+                             "sync_skew": sync_skew}
                     if timer is not None:
-                        timer.begin()
-                    loss_dev = dispatch(dev)
-                    if timer is not None:
-                        # fence: the loss is an output of the step's one
-                        # XLA program, so its readiness is the step's
-                        step_blocked = timer.end(loss_dev)
-                n = records_of(batch)
-                qdepth = queue_stats() if queue_stats is not None else None
-                t_fetch = time.perf_counter()
-                with sp("stage_next_batch", step=state["neval"]):
-                    next_batch, train_iter = self._stage_next_batch(
-                        train_iter, state, n, epoch_size)
-                next_dev = None
-                if next_batch is not None and next_batch is not PREDICTED_END:
-                    # double buffering: batch k+1's host->device transfer
-                    # overlaps step k's execution
-                    with sp("device_stage", step=state["neval"] + 1):
-                        next_dev = stage_device(next_batch)
-                # the in-loop fetch runs while the device executes, but it
-                # is still host time the loop cannot dispatch through --
-                # the input-pipeline cost prefetch workers are there to
-                # take off this path
-                data_wait += time.perf_counter() - t_fetch
-                health_due = health_on and mon.due(state["neval"])
-                if sync_skew + 1 >= sync_every or health_due:
-                    # a health sample forces a point sync (same contract
-                    # as validation triggers): the published event pairs
-                    # the stats with a FRESH loss
-                    with sp("loss_sync", step=state["neval"]):
-                        loss = float(loss_dev)
-                    sync_skew = 0
-                else:
-                    sync_skew += 1    # deferred: host runs ahead of device
-                wall = time.perf_counter() - t0
-                device_s = wall - data_wait
-                state["loss"] = loss
-                state["record_count"] += n
-                # batches consumed by COMPLETED steps this epoch -- the
-                # prefetched-but-not-dispatched next batch is NOT counted,
-                # so a snapshot's position replays it after resume
-                state["batches_consumed"] = \
-                    state.get("batches_consumed", 0) + 1
-                state["throughput"] = n / max(wall, 1e-9)
-                self.metrics.add("data_wait_s", data_wait)
-                self.metrics.add("device_s", device_s)
-                event = {"step": state["neval"], "epoch": state["epoch"],
-                         "wall_s": wall, "data_wait_s": data_wait,
-                         "device_s": device_s, "loss": loss, "records": n,
-                         "records_per_s": state["throughput"],
-                         "sync_skew": sync_skew}
-                if timer is not None:
-                    event["step_blocked_s"] = step_blocked
-                if qdepth is not None:
-                    event["queue_depth"], event["queue_capacity"] = qdepth
-                if event_fields:
-                    event.update(event_fields)
-                if tel is not None:
-                    tel.record_step(event)
-                self._log_progress(loss, state["throughput"], data_wait,
-                                   sync_skew)
-                if self.train_summary is not None:
-                    # scalars derive from the SAME event dict the JSONL
-                    # records -- the two channels cannot disagree
-                    add_event = getattr(
-                        self.train_summary, "add_step_event", None)
-                    if add_event is not None:
-                        add_event(event)
-                    else:   # duck-typed summary: raw scalars
-                        self.train_summary.add_scalar(
-                            "Loss", loss, state["neval"])
-                        self.train_summary.add_scalar(
-                            "Throughput", state["throughput"],
-                            state["neval"])
-                    if extra_summaries is not None:
-                        extra_summaries(state)
-                if health_on and mon.policy != "warn":
-                    # incident-bundle event ring (kind-tagged like the
-                    # JSONL); only dump_incident ever reads it, so a
-                    # warn-policy or disabled monitor pays nothing
-                    mon.note_event({"kind": "step", **event})
-                if health_due:
-                    # fetch the on-device stats (blocks on the step, the
-                    # point sync above already did) and hand them to the
-                    # monitor: health event + watchdogs + warn/dump/halt
-                    with sp("health_sample", step=state["neval"]):
-                        mon.on_sample(state, health_cb(), loss=loss,
-                                      batch=batch, telemetry=tel,
-                                      summary=self.train_summary)
-                state["neval"] += 1
-                if state["record_count"] >= epoch_size:
-                    state["epoch"] += 1
-                    state["record_count"] = 0
-                    state["batches_consumed"] = 0
-                    if next_batch is None:  # fetch deferred past the reset:
-                        self._reshuffle_pending = True
+                        event["step_blocked_s"] = step_blocked
+                    if qdepth is not None:
+                        event["queue_depth"], event["queue_capacity"] = qdepth
+                    if event_fields:
+                        event.update(event_fields)
+                    if tel is not None:
+                        tel.record_step(event)
+                    self._log_progress(loss, state["throughput"], data_wait,
+                                       sync_skew)
+                    if self.train_summary is not None:
+                        # scalars derive from the SAME event dict the JSONL
+                        # records -- the two channels cannot disagree
+                        add_event = getattr(
+                            self.train_summary, "add_step_event", None)
+                        if add_event is not None:
+                            add_event(event)
+                        else:   # duck-typed summary: raw scalars
+                            self.train_summary.add_scalar(
+                                "Loss", loss, state["neval"])
+                            self.train_summary.add_scalar(
+                                "Throughput", state["throughput"],
+                                state["neval"])
+                        if extra_summaries is not None:
+                            extra_summaries(state)
+                    if health_on and mon.policy != "warn":
+                        # incident-bundle event ring (kind-tagged like the
+                        # JSONL); only dump_incident ever reads it, so a
+                        # warn-policy or disabled monitor pays nothing
+                        mon.note_event({"kind": "step", **event})
+                    if health_due:
+                        # fetch the on-device stats (blocks on the step, the
+                        # point sync above already did) and hand them to the
+                        # monitor: health event + watchdogs + warn/dump/halt
+                        with span("health_sample", step=state["neval"]):
+                            mon.on_sample(state, health_cb(), loss=loss,
+                                          batch=batch, telemetry=tel,
+                                          summary=self.train_summary)
+                    state["neval"] += 1
+                    if state["record_count"] >= epoch_size:
+                        state["epoch"] += 1
+                        state["record_count"] = 0
+                        state["batches_consumed"] = 0
+                        if next_batch is None:
+                            # fetch deferred past the reset
+                            self._reshuffle_pending = True
 
-                if (self.validation_trigger is not None
-                        and self.validation_trigger(state)):
-                    if sync_skew:
-                        point_sync("validation")
-                    with sp("validation", step=state["neval"]):
-                        self._record_validation(validate_cb(), state)
-                        if feed_plateau is not None:
-                            feed_plateau(state)
-                if (self.checkpoint_trigger is not None
-                        and self.checkpoint_trigger(state)):
-                    if sync_skew:
-                        point_sync("checkpoint")
-                    # snapshot the RNG stream position with the counters,
-                    # and the mid-epoch dataset position (shuffle state +
-                    # consumed-batch count) so resume can fast-forward to
-                    # the exact sample-stream position
-                    state["rng_state"] = RNG.get_state()
-                    state["data_position"] = self._capture_data_position()
-                    with sp("checkpoint", step=state["neval"]):
-                        checkpoint_cb(state)
+                    if (self.validation_trigger is not None
+                            and self.validation_trigger(state)):
+                        if sync_skew:
+                            point_sync("validation")
+                        with span("validation", step=state["neval"]):
+                            self._record_validation(validate_cb(), state)
+                            if feed_plateau is not None:
+                                feed_plateau(state)
+                    if (self.checkpoint_trigger is not None
+                            and self.checkpoint_trigger(state)):
+                        if sync_skew:
+                            point_sync("checkpoint")
+                        # snapshot the RNG stream position with the counters,
+                        # and the mid-epoch dataset position (shuffle state +
+                        # consumed-batch count) so resume can fast-forward to
+                        # the exact sample-stream position
+                        state["rng_state"] = RNG.get_state()
+                        state["data_position"] = self._capture_data_position()
+                        with span("checkpoint", step=state["neval"]):
+                            checkpoint_cb(state)
 
-                # next_batch None = deferred: the top-of-loop fetch runs
-                # only after the end trigger decided training continues
-                batch = None if next_batch is PREDICTED_END else next_batch
-                dev = next_dev
+                    # next_batch None = deferred: the top-of-loop fetch runs
+                    # only after the end trigger decided training continues
+                    batch = None if next_batch is PREDICTED_END else next_batch
+                    dev = next_dev
             if sync_skew and loss_dev is not None:
                 # drain: the run's final loss lands in driver_state even
                 # when the last steps deferred their sync

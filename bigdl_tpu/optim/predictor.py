@@ -70,12 +70,6 @@ class Predictor:
         x = jax.device_put(batch.get_input())   # one async tree transfer
         return self._eval(self.model.parameters()[0], self.model.state(), x)
 
-    def _span(self, name, **kw):
-        """Own telemetry's tracer when attached, else the ambient one."""
-        if self.telemetry is not None:
-            return self.telemetry.span(name, **kw)
-        return span(name, **kw)
-
     def _bucket_for(self, n: int, run_max: int) -> int:
         """The pad target for an ``n``-row batch: the caller-supplied
         ladder when one is set (auto-extended past its max so an
@@ -98,7 +92,7 @@ class Predictor:
         """
         outs = []
         it = self._batches(data)
-        with self._span("predict_fetch"):
+        with span("predict_fetch"):
             batch = next(it, None)
         step = 0
         run_max = 0
@@ -119,10 +113,10 @@ class Predictor:
             except TypeError:
                 staged, bucket = batch, n
             run_max = max(run_max, bucket)
-            with self._span("predict_batch", step=step, bucket=bucket):
+            with span("predict_batch", step=step, bucket=bucket):
                 y = self.predict_minibatch(staged)
                 tf = time.perf_counter()
-                with self._span("predict_fetch"):
+                with span("predict_fetch"):
                     next_batch = next(it, None)     # overlapped fetch
                 data_wait = time.perf_counter() - tf
                 # host sync FIRST, then numpy-slice the padded tail: a

@@ -920,11 +920,6 @@ class ServingEngine:
             x = pad_length_axis(x, self.length_ladder, self.length_select)
         return x
 
-    def _span(self, name, **kw):
-        if self.telemetry is not None:
-            return self.telemetry.span(name, **kw)
-        return span(name, **kw)
-
     def _executables(self):
         """Current executable count of the shared compiled step (the
         per-tick delta is the live recompile signal: nonzero after
@@ -952,7 +947,7 @@ class ServingEngine:
                 on_canary = True
         reached_eval = False
         try:
-            with self._span("serve_tick", tick=self._tick, records=len(reqs)):
+            with span("serve_tick", tick=self._tick, records=len(reqs)):
                 n = len(feats)
                 bucket = self.ladder.bucket_for(n)
                 if bucket is None:        # can't happen: take <= ladder.max

@@ -52,6 +52,7 @@ KV cache").
 """
 
 import collections
+import itertools
 import logging
 import os
 import queue
@@ -64,10 +65,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bigdl_tpu.observability.spans import span
+from bigdl_tpu.observability.spans import now_ns, record_span, span, to_ns
 from bigdl_tpu.serving.buckets import BucketLadder
 
 log = logging.getLogger("bigdl_tpu.serving")
+
+#: ``request_id`` of the recorder's ``request`` spans: one per future
+_REQUEST_IDS = itertools.count(1)
 
 
 def _scatter_rows(slot_leaf, frag_leaf, slot_ids, t):
@@ -152,11 +156,16 @@ class GenerateFuture(Future):
         #: mixed number hides queue pressure behind decode speed)
         self.queue_wait_s: Optional[float] = None
         self.decode_s: Optional[float] = None
+        #: the request's stamps (``perf_counter`` seconds): submit, slot
+        #: admission, first streamed token.  The recorder's ``request``
+        #: span and every latency field are made of these and of one
+        #: more reading when the future finishes
         self._t_submit = time.perf_counter()
-        #: wall-clock twin of _t_submit, anchoring trace records
-        self._t_submit_wall = time.time()
-        #: perf_counter stamp when a prefill tick admitted us to a slot
         self._t_admit: Optional[float] = None
+        self._t_first: Optional[float] = None
+        self.request_id = next(_REQUEST_IDS)
+        #: prefill device calls this prompt took (chunks, paged; else 1)
+        self._chunks = 0
         #: sampled TraceContext from the submitting engine, or None
         self._trace = None
         #: SamplingParams for this request (None = greedy argmax);
@@ -416,22 +425,34 @@ class GenerateScheduler:
     def _loop(self):
         while True:
             with self._lock:
-                while self._running and not self._pending \
+                if self._running and not self._pending \
                         and not self._active():
-                    self._idle.notify_all()
-                    self._work.wait()
+                    with span("dispatcher_idle"):
+                        while self._running and not self._pending \
+                                and not self._active():
+                            self._idle.notify_all()
+                            self._work.wait()
                 if not self._running and not self._pending \
                         and not self._active():
                     self._idle.notify_all()
                     return
-                admit = []
-                if self._pending and self._free:
-                    take = min(len(self._free), len(self._pending))
-                    admit = [self._pending.popleft() for _ in range(take)]
-                    self._in_flight += len(admit)
-                    self._not_full.notify_all()
-                qdepth = len(self._pending)
-            try:
+            with span("tick", tick=self._tick,
+                      slots_total=self.slots) as tick:
+                self._iterate(tick)
+
+    def _iterate(self, tick):
+        """One dispatcher iteration: admission, then the device work."""
+        admit = []
+        try:
+            with span("admit") as adm:
+                with self._lock:
+                    if self._pending and self._free:
+                        take = min(len(self._free), len(self._pending))
+                        admit = [self._pending.popleft()
+                                 for _ in range(take)]
+                        self._in_flight += len(admit)
+                        self._not_full.notify_all()
+                    qdepth = len(self._pending)
                 # a cancelled future's prompt is dropped here (its slot
                 # was never assigned); claiming moves PENDING->RUNNING
                 # so result-setting can't race a caller's cancel().  A
@@ -445,23 +466,44 @@ class GenerateScheduler:
                     else:
                         f._stream.put(None)
                 self._sweep_abandoned()
-                if claimed:
-                    # by the time _run_prefill returns, every claimed
-                    # request is slotted (visible to _active) or failed
-                    self._run_prefill(claimed, qdepth)
-                if self._active():
-                    self._run_decode(qdepth)
-            except Exception:
-                # defensive: per-tick failures are already surfaced on
-                # the affected futures; this keeps an unexpected
-                # scheduler bug from silently killing the dispatcher
-                log.exception("generation scheduler tick failed")
-            finally:
-                with self._lock:
-                    self._in_flight -= len(admit)
-                    if not self._pending and not self._in_flight \
-                            and not self._active():
-                        self._idle.notify_all()
+                placed = self._admit(claimed) if claimed else None
+                adm.set(requests=len(claimed))
+            tick.set(queue_depth=qdepth, **self._occupancy(claimed))
+            # by the time this returns, every claimed request is
+            # slotted (visible to _active) or failed
+            self._run_device(claimed, placed, qdepth)
+        except Exception:
+            # defensive: per-tick failures are already surfaced on
+            # the affected futures; this keeps an unexpected
+            # scheduler bug from silently killing the dispatcher
+            log.exception("generation scheduler tick failed")
+        finally:
+            with self._lock:
+                self._in_flight -= len(admit)
+                if not self._pending and not self._in_flight \
+                        and not self._active():
+                    self._idle.notify_all()
+
+    def _admit(self, reqs):
+        """Give each claimed request a free slot (queue wait ends here);
+        returns the slot ids for ``_run_prefill``."""
+        t_admit = time.perf_counter()
+        with self._lock:
+            slots = [self._free.popleft() for _ in reqs]
+        for _p, f in reqs:
+            f._t_admit = t_admit
+        return slots
+
+    def _occupancy(self, claimed):
+        """The ``tick`` span's view of the slots once admission is done."""
+        return {"slots_decoding": len(self._active()),
+                "slots_prefilling": len(claimed)}
+
+    def _run_device(self, claimed, slots, qdepth):
+        if claimed:
+            self._run_prefill(claimed, slots, qdepth)
+        if self._active():
+            self._run_decode_tick(qdepth)
 
     def _compiles(self):
         if self.telemetry is None:
@@ -470,77 +512,87 @@ class GenerateScheduler:
 
         return backend_compile_count()
 
-    def _run_prefill(self, reqs, qdepth):
-        t0 = time.perf_counter()
-        for _p, f in reqs:
-            f._t_admit = t0          # queue wait ends at slot admission
+    def _run_prefill(self, reqs, slots, qdepth):
         execs_before = self._compiles()
         n = len(reqs)
-        bucket = self.batch_ladder.bucket_for(n) or self.batch_ladder.add(n)
-        longest = max(int(p.size) for p, _ in reqs)
-        t_pad = self.prompt_ladder.bucket_for(longest) \
-            or self.prompt_ladder.add(longest)
-        tokens = np.zeros((bucket, t_pad), np.int32)
-        lengths = np.ones((bucket,), np.int32)
-        slot_ids = np.full((bucket,), self._trash, np.int32)
-        slots = []
-        with self._lock:
+        with span("prefill_prep", rows=n, slots_total=self.slots) as prep:
+            bucket = self.batch_ladder.bucket_for(n) \
+                or self.batch_ladder.add(n)
+            longest = max(int(p.size) for p, _ in reqs)
+            t_pad = self.prompt_ladder.bucket_for(longest) \
+                or self.prompt_ladder.add(longest)
+            tokens = np.zeros((bucket, t_pad), np.int32)
+            lengths = np.ones((bucket,), np.int32)
+            slot_ids = np.full((bucket,), self._trash, np.int32)
             for i, (p, _f) in enumerate(reqs):
                 tokens[i, : p.size] = p
                 lengths[i] = p.size
-                slot_ids[i] = self._free.popleft()
-                slots.append(slot_ids[i])
+                slot_ids[i] = slots[i]
+            prep.set(bucket=int(bucket),
+                     prompt_tokens=int(lengths[:n].sum()))
         try:
             with span("generate_prefill", tick=self._tick, records=n):
-                first, self._cache = self._prefill_fn(
-                    self._params(), self._cache, tokens, lengths, slot_ids)
-                first = np.asarray(first)            # host sync
+                with span("launch"):
+                    first, self._cache = self._prefill_fn(
+                        self._params(), self._cache, tokens, lengths,
+                        slot_ids)
+                with span("fetch"):
+                    first = np.asarray(first)        # host sync
         except Exception as e:
             log.exception("prefill tick failed (%d prompts)", n)
             self._tick_failed(e, [f for _p, f in reqs], slots)
             return
         done_lat = []
-        for i, (p, f) in enumerate(reqs):
-            slot = _Slot(f, int(first[i]), pos=int(p.size))
-            self._slots[slots[i]] = slot
-            self._deliver(slots[i], slot, done_lat)
+        with span("deliver", tokens=n) as dlv:
+            for i, (p, f) in enumerate(reqs):
+                f._chunks = 1
+                slot = _Slot(f, int(first[i]), pos=int(p.size))
+                self._slots[slots[i]] = slot
+                self._deliver(slots[i], slot, done_lat)
+            dlv.set(finished=len(done_lat))
         self._tick += 1
-        self._record_tick("prefill", t0, records=n, tokens=n,
-                          bucket=int(bucket), prompt_bucket=int(t_pad),
-                          qdepth=qdepth, execs_before=execs_before,
-                          latencies=done_lat,
+        self._record_tick("prefill", prep.start_ns, dlv.end_ns, records=n,
+                          tokens=n, bucket=int(bucket),
+                          prompt_bucket=int(t_pad), qdepth=qdepth,
+                          execs_before=execs_before, latencies=done_lat,
                           riders=[f for _p, f in reqs])
 
-    def _run_decode(self, qdepth):
-        t0 = time.perf_counter()
+    def _run_decode_tick(self, qdepth):
         execs_before = self._compiles()
         s = self.slots + 1
-        tokens = np.zeros((s,), np.int32)
-        pos = np.zeros((s,), np.int32)
         active = self._active()
-        for i, slot in active:
-            tokens[i] = slot.last
-            pos[i] = slot.pos
+        with span("decode_prep", rows=len(active),
+                  slots_total=self.slots) as prep:
+            tokens = np.zeros((s,), np.int32)
+            pos = np.zeros((s,), np.int32)
+            for i, slot in active:
+                tokens[i] = slot.last
+                pos[i] = slot.pos
         try:
             with span("generate_decode", tick=self._tick,
                       records=len(active)):
-                nxt, self._cache = self._decode_fn(
-                    self._params(), self._cache, tokens, pos)
-                nxt = np.asarray(nxt)                # host sync
+                with span("launch"):
+                    nxt, self._cache = self._decode_fn(
+                        self._params(), self._cache, tokens, pos)
+                with span("fetch"):
+                    nxt = np.asarray(nxt)            # host sync
         except Exception as e:
             log.exception("decode tick failed (%d slots)", len(active))
             self._tick_failed(e, [], [])
             return
         done_lat = []
-        for i, slot in active:
-            slot.pos += 1
-            slot.last = int(nxt[i])
-            slot.tokens.append(slot.last)
-            self._deliver(i, slot, done_lat)
+        with span("deliver", tokens=len(active)) as dlv:
+            for i, slot in active:
+                slot.pos += 1
+                slot.last = int(nxt[i])
+                slot.tokens.append(slot.last)
+                self._deliver(i, slot, done_lat)
+            dlv.set(finished=len(done_lat))
         self._tick += 1
-        self._record_tick("decode", t0, records=0, tokens=len(active),
-                          qdepth=qdepth, execs_before=execs_before,
-                          latencies=done_lat, slots_before=len(active),
+        self._record_tick("decode", prep.start_ns, dlv.end_ns, records=0,
+                          tokens=len(active), qdepth=qdepth,
+                          execs_before=execs_before, latencies=done_lat,
+                          slots_before=len(active),
                           riders=[slot.fut for _i, slot in active])
 
     def _tick_failed(self, e, futs, extra_free):
@@ -561,9 +613,7 @@ class GenerateScheduler:
         self._reset_pool()
         for f in failed:
             if not f.done():
-                f._stream.put(e)
-                f._stream.put(None)
-                f.set_exception(e)
+                self._fail_request(f, e)
 
     def _abandon(self, fut):
         """Give up on a generation nobody will read (the sibling of
@@ -596,11 +646,7 @@ class GenerateScheduler:
             if not fut._abandoned or fut.done():
                 continue
             self._release_slot(i, slot)
-            fut.finish_reason = "abandoned"
-            self._stamp_latency(fut)
-            fut._stream.put(None)
-            fut.set_result(list(slot.tokens))
-            self._record_request_trace(fut, len(slot.tokens))
+            self._finish_request(fut, slot.tokens, "abandoned")
 
     def _release_slot(self, index, slot):
         """Return a slot to the free pool (every eviction path funnels
@@ -614,6 +660,8 @@ class GenerateScheduler:
         EOS or the request's token budget."""
         fut = slot.fut
         tok = slot.tokens[-1]
+        if len(slot.tokens) == 1:
+            fut._t_first = time.perf_counter()
         fut._stream.put(tok)
         reason = None
         if fut.eos_id is not None and tok == fut.eos_id:
@@ -623,23 +671,48 @@ class GenerateScheduler:
         if reason is None:
             return
         self._release_slot(index, slot)
-        fut.finish_reason = reason
-        self._stamp_latency(fut)
         done_lat.append(fut)
         self._served += 1
+        self._finish_request(fut, slot.tokens, reason)
+
+    def _finish_request(self, fut, tokens, reason):
+        """End a request with the tokens it has: the latency fields, the
+        stream's sentinel, the result, and its records."""
+        fut.finish_reason = reason
+        self._stamp_request(fut, len(tokens))
         fut._stream.put(None)
-        fut.set_result(list(slot.tokens))
-        self._record_request_trace(fut, len(slot.tokens))
+        fut.set_result(list(tokens))
+        self._record_request_trace(fut, len(tokens))
+
+    def _fail_request(self, fut, e):
+        """End a request with an exception (a lost pool, a shed)."""
+        fut.finish_reason = "error:" + type(e).__name__
+        self._stamp_request(fut, 0)
+        fut._stream.put(e)
+        fut._stream.put(None)
+        fut.set_exception(e)
 
     @staticmethod
-    def _stamp_latency(fut):
-        """Set latency_s and its queue-wait/decode split on a finished
-        future (admit stamp missing => the whole latency was a wait)."""
+    def _stamp_request(fut, n_tokens):
+        """One more clock reading, when the request ends: with the stamps
+        the future already holds it gives ``latency_s`` and its split
+        (admit stamp missing => the whole latency was a wait), and the
+        recorder's ``request`` span, whose three parts add up to it."""
         now = time.perf_counter()
-        fut.latency_s = now - fut._t_submit
         admit = fut._t_admit if fut._t_admit is not None else now
+        first = fut._t_first if fut._t_first is not None else now
+        fut.latency_s = now - fut._t_submit
         fut.queue_wait_s = max(0.0, admit - fut._t_submit)
         fut.decode_s = max(0.0, now - admit)
+        record_span(
+            "request", to_ns(fut._t_submit), to_ns(now),
+            request_id=fut.request_id, prompt_tokens=fut.prompt_len,
+            new_tokens=n_tokens, prefix_hit_tokens=fut.prefix_hit_tokens,
+            chunks=fut._chunks,
+            queue_wait_ns=int(fut.queue_wait_s * 1e9),
+            prefill_ns=int(max(0.0, first - admit) * 1e9),
+            decode_ns=int(max(0.0, now - first) * 1e9),
+            finish_reason=fut.finish_reason)
 
     def _record_request_trace(self, fut, n_tokens):
         """Completion span for one traced generation -- the decode-side
@@ -658,22 +731,24 @@ class GenerateScheduler:
                 # cause in the trace story
                 kw["prefix_hit_tokens"] = fut.prefix_hit_tokens
             emit("generate_request", fut._trace.child(),
-                 fut._t_submit_wall, fut.latency_s or 0.0,
+                 to_ns(fut._t_submit) * 1e-9, fut.latency_s or 0.0,
                  queue_wait_s=round(fut.queue_wait_s or 0.0, 6),
                  decode_s=round(fut.decode_s or 0.0, 6),
                  tokens=n_tokens, finish_reason=fut.finish_reason, **kw)
         except Exception:
             log.exception("generation trace record failed")
 
-    def _record_tick(self, kind, t0, records, tokens, qdepth,
+    def _record_tick(self, kind, start_ns, end_ns, records, tokens, qdepth,
                      execs_before, latencies, bucket=None,
                      prompt_bucket=None, slots_before=None,
                      riders=None, extra=None):
+        """The tick's telemetry event and trace record; its times are the
+        tick's spans' (start of its prep to end of its deliver)."""
         self._tokens_out += tokens
         if self.telemetry is None:
             return
         try:
-            wall = time.perf_counter() - t0
+            wall = (end_ns - start_ns) * 1e-9
             active = slots_before if slots_before is not None \
                 else len(self._active())
             event = dict(step=self._tick, wall_s=wall, tick_kind=kind,
@@ -726,12 +801,14 @@ class GenerateScheduler:
                 # nonzero after precompile() = a generation shape leak
                 event["compiles"] = after - execs_before
             self.telemetry.record("inference", **event)
-            self._record_tick_trace(kind, wall, riders, records, tokens)
+            self._record_tick_trace(kind, start_ns, wall, riders, records,
+                                    tokens)
         except Exception:
             log.exception("generation telemetry record failed (tick %d)",
                           self._tick)
 
-    def _record_tick_trace(self, kind, wall, riders, records, tokens):
+    def _record_tick_trace(self, kind, start_ns, wall, riders, records,
+                           tokens):
         """One span per tick with links to every traced sequence that
         rode it -- the continuous-batching shape (one tick, N resident
         requests) is a links relationship, not parent/child, because
@@ -746,7 +823,7 @@ class GenerateScheduler:
         from bigdl_tpu.observability.tracing import TraceContext
 
         emit("%s_tick" % kind, TraceContext.mint(),
-             time.time() - wall, wall, links=links, tick=self._tick,
+             start_ns * 1e-9, wall, links=links, tick=self._tick,
              records=records, tokens=tokens)
 
     # ----- lifecycle -------------------------------------------------------- #
@@ -1081,16 +1158,16 @@ class PagedGenerateScheduler(GenerateScheduler):
             self._hit_tokens_delta = 0
         return extra
 
-    def _run_prefill(self, reqs, qdepth):
+    def _admit(self, reqs):
         """ADMISSION only (no device work): assign a slot, match the
         prefix cache, reserve the worst-case block need.  The actual
         prompt compute happens one chunk per dispatcher iteration in
-        ``_run_decode``, interleaved with decode ticks."""
+        ``_run_device``, interleaved with decode ticks."""
         from bigdl_tpu.serving.paging import BlockPoolExhausted
 
-        t0 = time.perf_counter()
+        t_admit = time.perf_counter()
         for p, f in reqs:
-            f._t_admit = t0          # queue wait ends at slot admission
+            f._t_admit = t_admit     # queue wait ends at slot admission
         for p, f in reqs:
             sp = f.sampling
             seed = 0
@@ -1115,9 +1192,7 @@ class PagedGenerateScheduler(GenerateScheduler):
                         hook(e)
                     except Exception:
                         log.exception("exhausted_hook failed")
-                f._stream.put(e)
-                f._stream.put(None)
-                f.set_exception(e)
+                self._fail_request(f, e)
                 continue
             f.prefix_hit_tokens = cached
             self._hits_delta += cached // self.block_size
@@ -1140,7 +1215,14 @@ class PagedGenerateScheduler(GenerateScheduler):
         top_p[r] = sp.top_p
         seed[r] = slot.seed
 
-    def _run_decode(self, qdepth):
+    def _occupancy(self, claimed):
+        active = self._active()
+        prefilling = sum(s.prefilling for _i, s in active)
+        return {"slots_decoding": len(active) - prefilling,
+                "slots_prefilling": prefilling,
+                "blocks_free": self._alloc.stats()["blocks_free"]}
+
+    def _run_device(self, claimed, placed, qdepth):
         """One dispatcher iteration of device work: at most ONE prefill
         chunk per currently-prefilling sequence, then one decode tick
         over every decoding slot -- the interleave that keeps chunked
@@ -1151,32 +1233,36 @@ class PagedGenerateScheduler(GenerateScheduler):
             self._run_decode_tick(qdepth)
 
     def _run_chunk_tick(self, qdepth):
-        t0 = time.perf_counter()
         execs_before = self._compiles()
         rows = [(i, s) for i, s in self._active() if s.prefilling]
         n = len(rows)
-        bucket = self.batch_ladder.bucket_for(n) or self.batch_ladder.add(n)
-        tc = self.prefill_chunk
-        mb = self.max_blocks_per_seq
-        tokens = np.zeros((bucket, tc), np.int32)
-        start = np.zeros((bucket,), np.int32)
-        lens = np.zeros((bucket,), np.int32)
-        tables = np.full((bucket, mb), self._alloc.trash, np.int32)
-        knobs = self._sampling_rows(bucket)
-        for r, (i, s) in enumerate(rows):
-            chunk = s.prompt[s.consumed:s.consumed + tc]
-            tokens[r, :chunk.size] = chunk
-            start[r] = s.consumed
-            lens[r] = chunk.size
-            self._cow_guard(s, s.consumed, s.consumed + chunk.size - 1)
-            tables[r] = self._alloc.table_row(s.seq, mb)
-            self._fill_sampling(knobs, r, s)
+        with span("prefill_prep", rows=n, slots_total=self.slots) as prep:
+            bucket = self.batch_ladder.bucket_for(n) \
+                or self.batch_ladder.add(n)
+            tc = self.prefill_chunk
+            mb = self.max_blocks_per_seq
+            tokens = np.zeros((bucket, tc), np.int32)
+            start = np.zeros((bucket,), np.int32)
+            lens = np.zeros((bucket,), np.int32)
+            tables = np.full((bucket, mb), self._alloc.trash, np.int32)
+            knobs = self._sampling_rows(bucket)
+            for r, (i, s) in enumerate(rows):
+                chunk = s.prompt[s.consumed:s.consumed + tc]
+                tokens[r, :chunk.size] = chunk
+                start[r] = s.consumed
+                lens[r] = chunk.size
+                self._cow_guard(s, s.consumed, s.consumed + chunk.size - 1)
+                tables[r] = self._alloc.table_row(s.seq, mb)
+                self._fill_sampling(knobs, r, s)
+            prep.set(bucket=int(bucket), prompt_tokens=int(lens.sum()))
         try:
             with span("generate_prefill", tick=self._tick, records=n):
-                first, self._cache = self._chunk_fn(
-                    self._params(), self._cache, tokens, start, lens,
-                    tables, *knobs)
-                first = np.asarray(first)            # host sync
+                with span("launch"):
+                    first, self._cache = self._chunk_fn(
+                        self._params(), self._cache, tokens, start, lens,
+                        tables, *knobs)
+                with span("fetch"):
+                    first = np.asarray(first)        # host sync
                 self._mirror_chunk(tokens, start, lens, tables, knobs)
         except Exception as e:
             log.exception("chunk prefill tick failed (%d prompts)", n)
@@ -1184,62 +1270,71 @@ class PagedGenerateScheduler(GenerateScheduler):
             return
         done_lat = []
         emitted = 0
-        for r, (i, s) in enumerate(rows):
-            s.consumed += int(lens[r])
-            # full prompt blocks now hold real K/V: register their
-            # hashes so later admissions can share them
-            self._alloc.commit_full_blocks(s.seq, s.consumed)
-            if not s.prefilling:                     # prompt complete
-                s.last = int(first[r])
-                s.tokens = [s.last]
-                s.pos = int(s.prompt.size)
-                emitted += 1
-                self._deliver(i, s, done_lat)
+        with span("deliver") as dlv:
+            for r, (i, s) in enumerate(rows):
+                s.fut._chunks += 1
+                s.consumed += int(lens[r])
+                # full prompt blocks now hold real K/V: register their
+                # hashes so later admissions can share them
+                self._alloc.commit_full_blocks(s.seq, s.consumed)
+                if not s.prefilling:                 # prompt complete
+                    s.last = int(first[r])
+                    s.tokens = [s.last]
+                    s.pos = int(s.prompt.size)
+                    emitted += 1
+                    self._deliver(i, s, done_lat)
+            dlv.set(tokens=emitted, finished=len(done_lat))
         self._tick += 1
-        self._record_tick("prefill", t0, records=n, tokens=emitted,
-                          bucket=int(bucket), prompt_bucket=tc,
-                          qdepth=qdepth, execs_before=execs_before,
-                          latencies=done_lat,
+        self._record_tick("prefill", prep.start_ns, dlv.end_ns, records=n,
+                          tokens=emitted, bucket=int(bucket),
+                          prompt_bucket=tc, qdepth=qdepth,
+                          execs_before=execs_before, latencies=done_lat,
                           riders=[s.fut for _i, s in rows],
                           extra=self._kv_extra())
 
     def _run_decode_tick(self, qdepth):
-        t0 = time.perf_counter()
         execs_before = self._compiles()
         s_n = self.slots
-        mb = self.max_blocks_per_seq
-        tokens = np.zeros((s_n,), np.int32)
-        pos = np.zeros((s_n,), np.int32)
-        tables = np.full((s_n, mb), self._alloc.trash, np.int32)
-        knobs = self._sampling_rows(s_n)
         active = [(i, s) for i, s in self._active() if not s.prefilling]
-        for i, s in active:
-            self._cow_guard(s, s.pos, s.pos)
-            tokens[i] = s.last
-            pos[i] = s.pos
-            tables[i] = self._alloc.table_row(s.seq, mb)
-            self._fill_sampling(knobs, i, s)
+        with span("decode_prep", rows=len(active),
+                  slots_total=s_n) as prep:
+            mb = self.max_blocks_per_seq
+            tokens = np.zeros((s_n,), np.int32)
+            pos = np.zeros((s_n,), np.int32)
+            tables = np.full((s_n, mb), self._alloc.trash, np.int32)
+            knobs = self._sampling_rows(s_n)
+            for i, s in active:
+                self._cow_guard(s, s.pos, s.pos)
+                tokens[i] = s.last
+                pos[i] = s.pos
+                tables[i] = self._alloc.table_row(s.seq, mb)
+                self._fill_sampling(knobs, i, s)
         try:
             with span("generate_decode", tick=self._tick,
                       records=len(active)):
-                nxt, self._cache = self._decode_fn(
-                    self._params(), self._cache, tokens, pos, tables,
-                    *knobs)
-                nxt = np.asarray(nxt)                # host sync
+                with span("launch"):
+                    nxt, self._cache = self._decode_fn(
+                        self._params(), self._cache, tokens, pos, tables,
+                        *knobs)
+                with span("fetch"):
+                    nxt = np.asarray(nxt)            # host sync
         except Exception as e:
             log.exception("decode tick failed (%d slots)", len(active))
             self._tick_failed(e, [], [])
             return
         done_lat = []
-        for i, s in active:
-            s.pos += 1
-            s.last = int(nxt[i])
-            s.tokens.append(s.last)
-            self._deliver(i, s, done_lat)
+        with span("deliver", tokens=len(active)) as dlv:
+            for i, s in active:
+                s.pos += 1
+                s.last = int(nxt[i])
+                s.tokens.append(s.last)
+                self._deliver(i, s, done_lat)
+            dlv.set(finished=len(done_lat))
         self._tick += 1
-        self._record_tick("decode", t0, records=0, tokens=len(active),
-                          qdepth=qdepth, execs_before=execs_before,
-                          latencies=done_lat, slots_before=len(active),
+        self._record_tick("decode", prep.start_ns, dlv.end_ns, records=0,
+                          tokens=len(active), qdepth=qdepth,
+                          execs_before=execs_before, latencies=done_lat,
+                          slots_before=len(active),
                           riders=[s.fut for _i, s in active],
                           extra=self._kv_extra())
 
@@ -1502,7 +1597,7 @@ class SpeculativeScheduler(PagedGenerateScheduler):
            next token; stream them through the normal ``_deliver``
            path (EOS / token budget truncate the run mid-emission).
         """
-        t0 = time.perf_counter()
+        t0_ns = now_ns()
         execs_before = self._compiles()
         s_n = self.slots
         k = self.spec_k
@@ -1572,8 +1667,9 @@ class SpeculativeScheduler(PagedGenerateScheduler):
         extra["spec_drafted"] = drafted
         extra["spec_accepted"] = accepted
         self._tick += 1
-        self._record_tick("decode", t0, records=0, tokens=emitted,
-                          qdepth=qdepth, execs_before=execs_before,
+        self._record_tick("decode", t0_ns, now_ns(), records=0,
+                          tokens=emitted, qdepth=qdepth,
+                          execs_before=execs_before,
                           latencies=done_lat, slots_before=len(active),
                           riders=[s.fut for _i, s in active],
                           extra=extra)

@@ -234,6 +234,19 @@ class TestCompiledEvalStepCache:
         assert Predictor(model)._eval is compiled_eval_step(model, None)
 
 
+def _hold_ticks(eng, gate):
+    """Make every serving tick wait (at most 10 s) for ``gate`` before its
+    device call: the dispatcher sits inside the tick, its requests
+    claimed."""
+    evaluate = eng._backend.eval
+
+    def held(*a, **kw):
+        gate.wait(10)
+        return evaluate(*a, **kw)
+
+    eng._backend.eval = held
+
+
 class TestServingEngine:
     def test_burst_coalesces_into_one_full_tick(self, tmp_path):
         model = _mlp(seed=4)
@@ -378,10 +391,6 @@ class TestServingEngine:
             def record(self, *a, **k):
                 raise RuntimeError("telemetry sink is broken")
 
-            def span(self, name, **kw):
-                from bigdl_tpu.observability.spans import span
-                return span(name, **kw)
-
         eng = ServingEngine(model, max_batch_size=4, max_wait_ms=20.0,
                             telemetry=Boom())
         try:
@@ -434,25 +443,14 @@ class TestServingEngine:
         import concurrent.futures
 
         gate = threading.Event()
-
-        class Hold:
-            """Blocks the dispatcher inside its first tick so the queue
-            behind it stays full for the duration of the assertion."""
-
-            def record(self, *a, **kw):
-                pass
-
-            def span(self, name, **kw):
-                from bigdl_tpu.observability.spans import span
-                if name == "serve_tick":
-                    gate.wait(10)
-                return span(name, **kw)
-
         model = _mlp(seed=28)
         eng = ServingEngine(model, max_batch_size=2, max_wait_ms=5.0,
-                            queue_capacity=1, telemetry=Hold())
+                            queue_capacity=1)
         try:
             eng.precompile()
+            # blocks the dispatcher inside its first tick so the queue
+            # behind it stays full for the duration of the assertion
+            _hold_ticks(eng, gate)
             fut1 = eng.submit(_xs(1)[0])
             deadline = time.perf_counter() + 5
             while not fut1.running():    # wait until the tick claims it
@@ -495,22 +493,12 @@ class TestServingEngine:
         import concurrent.futures
 
         gate = threading.Event()
-
-        class Hold:
-            def record(self, *a, **kw):
-                pass
-
-            def span(self, name, **kw):
-                from bigdl_tpu.observability.spans import span
-                if name == "serve_tick":
-                    gate.wait(10)
-                return span(name, **kw)
-
         model = _mlp(seed=30)
         eng = ServingEngine(model, max_batch_size=2, max_wait_ms=5.0,
-                            queue_capacity=4, telemetry=Hold())
+                            queue_capacity=4)
         try:
             eng.precompile()
+            _hold_ticks(eng, gate)
             first = eng.submit(_xs(1)[0])
             deadline = time.perf_counter() + 5
             while not first.running():

@@ -99,13 +99,18 @@ def _spans(d):
     return [json.loads(l) for l in open(path) if l.strip()]
 
 
-def _wait_spans(d, names, timeout=5.0):
+def _wait_spans(d, names, timeout=5.0, counts=None):
     """Engine tick spans land on the dispatcher thread slightly after
-    the request future resolves -- poll instead of racing them."""
+    the request future resolves -- poll instead of racing them.
+    ``counts`` asks for that many of a name: the record of the LAST tick
+    a request rode is written after the request's own."""
     deadline = time.time() + timeout
+    counts = counts or {}
     while True:
         spans = _spans(d)
-        if set(names) <= {s["name"] for s in spans}:
+        seen = [s["name"] for s in spans]
+        if set(names) <= set(seen) and all(
+                seen.count(n) >= k for n, k in counts.items()):
             return spans
         if time.time() > deadline:
             raise AssertionError(
@@ -467,7 +472,8 @@ class TestGenerateTracing:
                        - fut.latency_s) < 1e-3
             spans = _wait_spans(tmp_path, {"generate_request",
                                            "prefill_tick",
-                                           "decode_tick"})
+                                           "decode_tick"},
+                                counts={"decode_tick": 5})
         gen = [s for s in spans if s["name"] == "generate_request"][0]
         assert gen["trace"] == ctx.trace_id
         assert gen["parent"] == ctx.span_id
