@@ -70,17 +70,19 @@ class MultiHeadAttention(Module):
         self.seq_axis_name = seq_axis_name
         self.seq_mode = seq_mode
         #: "auto": the Pallas flash kernel (ops/flash_attention.py) on TPU
-        #: when T is block-aligned and one head's K/V fit the kernel's
-        #: VMEM budget; plain attention otherwise.  "interpret" forces
-        #: the kernel in interpreter mode (CPU tests).
+        #: when T is block-aligned (the forward streams K/V a block at a
+        #: time, so no length is too long for it; the two decode kernels
+        #: keep a head's whole K/V in VMEM and also ask ``kv_blocks_fit``);
+        #: plain attention otherwise.  "interpret" forces the kernel in
+        #: interpreter mode (CPU tests).
         self.use_flash = use_flash
 
     @staticmethod
     def _flash_block_ok(t):
-        """Whether T tiles into flash blocks: the kernel's call site uses
-        ``block_q = t`` for short sequences, so any sublane-aligned
-        ``t < 128`` is block-alignable (a single (t, d) VMEM tile);
-        longer sequences must tile exactly into 128-blocks.  (The old
+        """Whether T tiles into flash blocks: the kernel takes a short
+        sequence as one block, so any sublane-aligned ``t < 128`` is
+        block-alignable (a single (t, d) VMEM tile); longer sequences
+        must tile exactly into 128-blocks.  (The old
         ``t % 128`` test rejected EVERY short sequence even though the
         kernel handles them -- tests/test_flash_attention.py pins the
         short-T flash-vs-plain agreement.)"""
@@ -93,13 +95,12 @@ class MultiHeadAttention(Module):
 
         return kv_blocks_fit(rows, self.head_dim, dtype, quantized)
 
-    def _flash_ok(self, t, dtype=jnp.float32):
+    def _flash_ok(self, t):
         if self.use_flash == "never" or self.seq_axis_name is not None:
             return False
         if self.use_flash in ("always", "interpret"):
             return True
-        return (self._flash_block_ok(t) and _on_tpu()
-                and self._kv_fit(t, dtype))
+        return self._flash_block_ok(t) and _on_tpu()
 
     def setup(self, rng, input_spec):
         d = self.hidden_size
@@ -203,12 +204,10 @@ class MultiHeadAttention(Module):
             # prompt rung that doesn't tile (e.g. an unaligned
             # decode_max_len on the ladder) would trip the kernel's
             # shape assert on every prefill -- take the plain path
-            if self._flash_ok(t, dt) and self._flash_block_ok(t):
+            if self._flash_ok(t) and self._flash_block_ok(t):
                 from bigdl_tpu.ops.flash_attention import flash_attention
 
-                bq = t if t < 128 else 128
                 y = flash_attention(q, k, v, causal=self.causal,
-                                    block_q=bq, block_k=bq,
                                     interpret=self.use_flash == "interpret")
             else:
                 y = dot_product_attention(q, k, v, causal=self.causal)
@@ -454,13 +453,11 @@ class MultiHeadAttention(Module):
             y = ring_self_attention(q.reshape(shape), k.reshape(shape),
                                     v.reshape(shape), self.seq_axis_name,
                                     causal=self.causal)
-        elif self._flash_ok(t, dt):
+        elif self._flash_ok(t):
             from bigdl_tpu.ops.flash_attention import flash_attention
 
-            bq = t if t < 128 else 128
             y = flash_attention(q.reshape(shape), k.reshape(shape),
                                 v.reshape(shape), causal=self.causal,
-                                block_q=bq, block_k=bq,
                                 interpret=self.use_flash == "interpret")
         else:
             y = dot_product_attention(q.reshape(shape), k.reshape(shape),

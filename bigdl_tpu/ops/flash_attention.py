@@ -6,28 +6,40 @@ isn't enough, drop to Pallas.  Attention is the one op where manual tiling
 pays -- the (T, T) score matrix never materialises in HBM; each (block_q,
 block_k) tile lives in VMEM with a flash-style online softmax.
 
-Layout: q/k/v (BH, T, D) fp32/bf16; softmax state fp32.  Causal masking by
-global position.  Grid: (BH, T/block_q); the k-loop is a lax.fori_loop
-inside the kernel.  ``interpret=True`` runs on CPU for tests.
+Layout: q/k/v (BH, T, D) fp32/bf16.  Causal masking by global position.
+``interpret=True`` runs on CPU for tests.
 
-All three kernels keep one head's whole K and V (or pool plane) resident
-in VMEM, so what the TPU compiler accepts is bounded by bytes, not only
-by tile alignment: ``kv_blocks_fit`` is that bound, and the ``auto``
-gates in nn/attention.py ask it before selecting a kernel.
+``flash_attention`` (the forward kernel): grid (BH, T/block_q, T/block_k)
+with the key axis last and sequential.  VMEM holds one query block, one
+key and one value block (double-buffered by the pipeline, so the next
+block's copy overlaps this one's compute) and the fp32 softmax state of
+the query block (running maximum, running sum, accumulator) as scratch --
+never a head's whole K/V, so no sequence is too long for it.  Under
+``causal`` a key block wholly above the diagonal is neither computed nor
+fetched, and only the blocks that straddle the diagonal build a mask.
+The MXU takes the operands in the dtype they come in (bf16 in training)
+and accumulates in fp32.
+
+The two decode kernels keep one head's whole K and V (or pool plane)
+resident in VMEM, so what the TPU compiler accepts of them is bounded by
+bytes, not only by tile alignment: ``kv_blocks_fit`` is that bound, and
+their ``auto`` gates in nn/attention.py ask it before selecting a kernel.
 """
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-#: VMEM the resident K/V blocks may take.  The compiler's default scoped
-#: limit on a v5e is 16 MiB (compiling T=8192 fp32 there is refused with
-#: "16.25M and limit 16.00M"); the rest is left to the q/o blocks and the
-#: (block_q, block_k) score tiles.
+#: VMEM the decode kernels' resident K/V blocks may take.  The compiler's
+#: default scoped limit on a v5e is 16 MiB (``flash_decode_attention`` over
+#: a cache of 8192 fp32 positions is refused there with "size 16.00M and
+#: limit 16.00M exceeded ... by 1.0K"); the rest is left to the q/o rows
+#: and the score tiles.
 _VMEM_KV_BUDGET = 12 * 2 ** 20
 
 
@@ -41,9 +53,9 @@ def _vmem_block_bytes(rows: int, cols: int, dtype) -> int:
 
 def kv_blocks_fit(rows: int, head_dim: int, dtype,
                   quantized: bool = False) -> bool:
-    """Whether a kernel of this file compiles with ``rows`` K/V positions
-    per head resident: the sequence for ``flash_attention``, the cache
-    length for ``flash_decode_attention``, the whole pool plane
+    """Whether a decode kernel of this file compiles with ``rows`` K/V
+    positions per head resident: the cache length for
+    ``flash_decode_attention``, the whole pool plane
     (``num_blocks * block_size``) for ``flash_paged_decode_attention``.
     K and V are each double-buffered by the pipeline; an int8 pool adds
     two fp32 scale columns, which pad to full 128-lane rows."""
@@ -53,43 +65,99 @@ def kv_blocks_fit(rows: int, head_dim: int, dtype,
     return need <= _VMEM_KV_BUDGET
 
 
-def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int, causal: bool,
-                 scale: float):
+#: What a masked score is set to: finite, so that no ``inf - inf`` can
+#: arise in the online softmax; ``exp`` of it less any row maximum is 0.
+_MASKED = -0.7 * float(jnp.finfo(jnp.float32).max)
+
+_LANES = 128
+_CONTRACT_LAST = (((1,), (1,)), ((), ()))
+
+
+def _across(x, n):
+    """A lane-replicated ``(rows, 128)`` column statistic as ``(rows, n)``:
+    whole vregs repeated where ``n`` is a multiple of 128, a slice where
+    it is narrower, and a broadcast of lane 0 otherwise."""
+    if n % _LANES == 0:
+        return jnp.tile(x, (1, n // _LANES))
+    if n < _LANES:
+        return x[:, :n]
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+
+
+def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
+                 causal: bool, scale: float):
+    """One (query block, key block) step of the online softmax.  The key
+    axis is the last, sequential grid axis: ``m_ref``/``l_ref`` (running
+    maximum and sum, lane-replicated) and ``acc_ref`` carry the state in
+    fp32 from the first key step to the last, which writes ``o_ref``."""
     block_q, d = q_ref.shape
-    t = k_ref.shape[0]
-    iq = pl.program_id(1)
+    block_k = k_ref.shape[0]
+    iq, ik = pl.program_id(1), pl.program_id(2)
 
-    q = q_ref[:].astype(jnp.float32) * scale
-    nk = t // block_k
+    @pl.when(ik == 0)
+    def _():
+        m_ref[:] = jnp.full(m_ref.shape, -jnp.inf, jnp.float32)
+        l_ref[:] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[:] = jnp.zeros(acc_ref.shape, jnp.float32)
 
-    def body(j, carry):
-        acc, m, l = carry
-        kblk = k_ref[pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        vblk = v_ref[pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        s = q @ kblk.T  # (block_q, block_k)
-        if causal:
-            qpos = iq * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            kpos = j * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            mask = kpos <= qpos
-            s = jnp.where(mask, s, -jnp.inf)
-        bm = jnp.max(s, axis=1)
-        new_m = jnp.maximum(m, bm)
-        safe_m = jnp.where(jnp.isfinite(new_m), new_m, 0.0)
-        p = jnp.exp(s - safe_m[:, None])
-        if causal:
-            p = jnp.where(mask, p, 0.0)
-        corr = jnp.where(jnp.isfinite(m), jnp.exp(m - safe_m), 0.0)
-        l = l * corr + jnp.sum(p, axis=1)
-        acc = acc * corr[:, None] + p @ vblk
-        return acc, new_m, l
+    # first query position less first key position: key c of the block is
+    # visible to query r where c - r <= ahead
+    ahead = iq * block_q - ik * block_k
 
-    acc0 = jnp.zeros((block_q, d), jnp.float32)
-    m0 = jnp.full((block_q,), -jnp.inf, jnp.float32)
-    l0 = jnp.zeros((block_q,), jnp.float32)
-    acc, m, l = jax.lax.fori_loop(0, nk, body, (acc0, m0, l0))
-    o_ref[:] = (acc / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
+    # Operands in the dtype that came in, fp32 out of the MXU.  Products of
+    # bf16 values are exact in fp32, so for them a higher matmul precision
+    # from the caller's context has no meaning (and Mosaic refuses it);
+    # fp32 operands keep the context's.
+    precision = (None if q_ref.dtype == jnp.float32
+                 else jax.lax.Precision.DEFAULT)
+
+    def step(straddles):
+        s = jax.lax.dot_general(q_ref[:], k_ref[:], _CONTRACT_LAST,
+                                precision=precision,
+                                preferred_element_type=jnp.float32) * scale
+        if straddles:
+            shape = (block_q, block_k)
+            c_less_r = (jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+                        - jax.lax.broadcasted_iota(jnp.int32, shape, 0))
+            s = jnp.where(c_less_r <= ahead, s, _MASKED)
+        m_prev = m_ref[:]
+        m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - _across(m_next, block_k))
+        alpha = jnp.exp(m_prev - m_next)
+        l_ref[:] = alpha * l_ref[:] + jnp.sum(p, axis=1, keepdims=True)
+        m_ref[:] = m_next
+        acc_ref[:] = acc_ref[:] * _across(alpha, d) + jnp.dot(
+            p.astype(v_ref.dtype), v_ref[:], precision=precision,
+            preferred_element_type=jnp.float32)
+
+    if causal:
+        # key 0 is visible to every query, so the first step leaves a
+        # finite maximum in every row.  A block wholly above the diagonal
+        # does nothing (and its index map fetched nothing); one wholly
+        # below it needs no mask.
+        visible = -ahead <= block_q - 1
+        below = block_k - 1 <= ahead
+        pl.when(below)(functools.partial(step, False))
+        pl.when(jnp.logical_and(visible, jnp.logical_not(below)))(
+            functools.partial(step, True))
+    else:
+        step(False)
+
+    @pl.when(ik == pl.num_programs(2) - 1)
+    def _():
+        o_ref[:] = (acc_ref[:] / _across(l_ref[:], d)).astype(o_ref.dtype)
+
+
+def _tile(t: int) -> int:
+    """The block size, of queries and of keys, for a sequence of ``t``:
+    ``t`` itself below 128 (one block), else the largest of 1024, 512,
+    256, 128 that divides it.  Large tiles win on a v5e although they
+    skip fewer masked tiles: a grid step and a rescale of the state cost
+    more than the masked half of a tile (PERF.md section 6, PR 28, has
+    the sweep)."""
+    if t < 128:
+        return t
+    return next(b for b in (1024, 512, 256, 128) if t % b == 0)
 
 
 def _flash_forward(q, k, v, causal, block_q, block_k, interpret):
@@ -101,17 +169,31 @@ def _flash_forward(q, k, v, causal, block_q, block_k, interpret):
 
     qb, kb, vb = to_bh(q), to_bh(k), to_bh(v)
 
+    def q_map(bh, i, j):
+        return bh, i, 0
+
+    def kv_map(bh, i, j):
+        if causal:
+            # past the last block this query block can see, stay on it:
+            # an unchanged block index copies nothing
+            j = jnp.minimum(j, (i * block_q + block_q - 1) // block_k)
+        return bh, j, 0
+
     out = pl.pallas_call(
-        functools.partial(_attn_kernel, block_k=block_k, causal=causal,
-                          scale=scale),
-        grid=(b * h, t // block_q),
+        functools.partial(_attn_kernel, causal=causal, scale=scale),
+        grid=(b * h, t // block_q, t // block_k),
         in_specs=[
-            pl.BlockSpec((None, block_q, d), lambda bh, i: (bh, i, 0)),
-            pl.BlockSpec((None, t, d), lambda bh, i: (bh, 0, 0)),
-            pl.BlockSpec((None, t, d), lambda bh, i: (bh, 0, 0)),
+            pl.BlockSpec((None, block_q, d), q_map),
+            pl.BlockSpec((None, block_k, d), kv_map),
+            pl.BlockSpec((None, block_k, d), kv_map),
         ],
-        out_specs=pl.BlockSpec((None, block_q, d), lambda bh, i: (bh, i, 0)),
+        out_specs=pl.BlockSpec((None, block_q, d), q_map),
         out_shape=jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
+        scratch_shapes=[pltpu.VMEM((block_q, _LANES), jnp.float32),
+                        pltpu.VMEM((block_q, _LANES), jnp.float32),
+                        pltpu.VMEM((block_q, d), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="flash_attention",
     )(qb, kb, vb)
@@ -144,18 +226,24 @@ _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
                                              "interpret"))
-def flash_attention(q, k, v, causal: bool = True, block_q: int = 128,
-                    block_k: int = 128, interpret: bool = False):
+def flash_attention(q, k, v, causal: bool = True,
+                    block_q: Optional[int] = None,
+                    block_k: Optional[int] = None, interpret: bool = False):
     """q, k, v: (B, T, H, D) -> (B, T, H, D).
 
-    T must be a multiple of the block sizes (pad upstream; the reference
-    pipeline pads too -- dataset/MiniBatch.scala:523 PaddingParam).
+    The kernel chooses its tiles from T (``_tile``); ``block_q`` and
+    ``block_k`` override them (tests do).  T must be a multiple of both
+    (pad upstream; the reference pipeline pads too --
+    dataset/MiniBatch.scala:523 PaddingParam).  The MXU takes q, k, v in
+    the dtype they come in and accumulates in fp32; the softmax state is
+    fp32, and the weights are rounded to v's dtype before ``p @ v`` as
+    ``nn.attention.dot_product_attention`` rounds them.
     Differentiable: the forward is the kernel, the backward recomputes
-    through ``nn.attention.dot_product_attention``.
+    through ``dot_product_attention``.
     """
     t = q.shape[1]
-    block_q = min(block_q, t)
-    block_k = min(block_k, t)
+    block_q = min(block_q or _tile(t), t)
+    block_k = min(block_k or _tile(t), t)
     assert t % block_q == 0 and t % block_k == 0, (t, block_q, block_k)
     return _flash(q, k, v, causal, block_q, block_k, interpret)
 
@@ -165,7 +253,7 @@ def _online_softmax_step(q, kblk, vblk, kpos, p, carry):
     decode kernels: ``q (1, d)``, ``kblk/vblk (n, d)`` fp32, ``kpos (1,
     n)`` the block's logical positions, ``p`` the row's frontier."""
     acc, m, l = carry
-    s = jax.lax.dot_general(q, kblk, (((1,), (1,)), ((), ())),
+    s = jax.lax.dot_general(q, kblk, _CONTRACT_LAST,
                             preferred_element_type=jnp.float32)  # (1, n)
     mask = kpos <= p
     s = jnp.where(mask, s, -jnp.inf)

@@ -90,13 +90,14 @@ def _paged(b, nb, bs, d, quantized, h=16, mb=16):
 
 # (kernel, shapes, what the auto gate is asked: rows, head_dim, dtype, int8)
 CASES = {
-    # train_lm's sequence, bf16 compute
-    "flash-medium": (flash_attention, _qkv(2, 2048, 64, bf16),
-                     (2048, 64, bf16, False)),
-    "flash-large": (flash_attention, _qkv(2, 2048, 96, bf16),
-                    (2048, 96, bf16, False)),
-    "flash-grad-medium": (_flash_grad, _qkv(2, 2048, 64, bf16),
-                          (2048, 64, bf16, False)),
+    # train_lm's sequence, bf16 compute.  The forward kernel streams K/V
+    # a block at a time: no gate bounds it by bytes (gate None)
+    "flash-medium": (flash_attention, _qkv(2, 2048, 64, bf16), None),
+    "flash-large": (flash_attention, _qkv(2, 2048, 96, bf16), None),
+    "flash-grad-medium": (_flash_grad, _qkv(2, 2048, 64, bf16), None),
+    # the benchmark's training cell: 16 sequences of 1024, 16 heads of 64
+    "flash-cell": (flash_attention, _qkv(16, 1024, 64, bf16), None),
+    "flash-grad-cell": (_flash_grad, _qkv(16, 1024, 64, bf16), None),
     # serve_lm's contiguous cache: 8 slots + trash row, 2048 long, fp32;
     # and the longest fp32 cache the gate admits
     "decode-medium": (flash_decode_attention, _decode(9, 2048, 64, f32),
@@ -126,12 +127,22 @@ def test_kernel_compiles_for_v5e(one_chip, case):
     assert _compile(one_chip, fn, *shapes) >= 1
 
 
+def test_forward_outgrows_the_gate(one_chip):
+    """T=8192 fp32 was refused while the forward kept one head's whole K
+    and V in VMEM (16.25 MiB against a 16 MiB limit).  With the key blocks
+    on the grid it compiles, though ``kv_blocks_fit`` -- still the decode
+    kernels' gate -- would not admit that many resident rows."""
+    assert not kv_blocks_fit(8192, 64, f32)
+    assert _compile(one_chip, flash_attention, *_qkv(1, 8192, 64, f32)) == 1
+
+
 def test_gate_refuses_what_the_compiler_refuses(one_chip):
-    """T=8192 fp32 keeps 16.25 MiB of K/V in VMEM against a 16 MiB limit:
-    the compiler refuses it, so the gate must not admit it."""
+    """``flash_decode_attention`` keeps a head's whole cache in VMEM: at
+    8192 fp32 positions the gate refuses it, and so does the compiler
+    ("size 16.00M and limit 16.00M exceeded scoped vmem limit by 1.0K")."""
     assert not kv_blocks_fit(8192, 64, f32)
     with pytest.raises(Exception, match="vmem"):
-        _compile(one_chip, flash_attention, *_qkv(1, 8192, 64, f32))
+        _compile(one_chip, flash_decode_attention, *_decode(9, 8192, 64, f32))
 
 
 def test_lm_gradient_through_flash_matches_plain():

@@ -3,15 +3,16 @@
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from bigdl_tpu.nn.attention import dot_product_attention
 from bigdl_tpu.ops import flash_attention
 
 
-def rand(b=2, t=64, h=4, d=16, seed=0):
+def rand(b=2, t=64, h=4, d=16, seed=0, dtype=jnp.float32):
     r = np.random.default_rng(seed)
-    mk = lambda: jnp.asarray(r.standard_normal((b, t, h, d)), jnp.float32)
+    mk = lambda: jnp.asarray(r.standard_normal((b, t, h, d)), dtype)
     return mk(), mk(), mk()
 
 
@@ -40,6 +41,86 @@ class TestFlashAttention:
                               interpret=True)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=1e-5, atol=1e-5)
+
+
+#: how far the kernel may lie from ``dot_product_attention`` on inputs of
+#: unit variance (outputs of order 1).  float32: both sum in float32, in
+#: another order.  bfloat16: both round the weights to bfloat16 before
+#: ``p @ v`` (the plain path after normalising, the kernel before) and the
+#: result to bfloat16, whose spacing below 4 is 2**-6.
+TOLERANCE = {jnp.float32: 2e-5, jnp.bfloat16: 3e-2}
+
+#: T -> (batch, heads, head_dim, [(block_q, block_k), ...]); None: the
+#: kernel's own choice.  64 is one block; 1024 is the benchmark cell's
+#: sequence with its 16 heads of 64.
+SHAPES = {
+    64: (2, 4, 64, [(None, None)]),
+    256: (2, 4, 64, [(None, None), (128, 64), (64, 128), (128, 128)]),
+    1024: (1, 16, 64, [(None, None), (512, 256), (256, 512)]),
+}
+
+
+class TestKernelAgainstPlain:
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["float32", "bfloat16"])
+    @pytest.mark.parametrize("causal", [True, False],
+                             ids=["causal", "full"])
+    @pytest.mark.parametrize(
+        "t,block_q,block_k",
+        [(t, bq, bk) for t, (_, _, _, tiles) in SHAPES.items()
+         for bq, bk in tiles],
+        ids=lambda x: "auto" if x is None else str(x))
+    def test_forward(self, dtype, causal, t, block_q, block_k):
+        b, h, d, _ = SHAPES[t]
+        q, k, v = rand(b, t, h, d, seed=t, dtype=dtype)
+        want = dot_product_attention(q, k, v, causal=causal)
+        got = flash_attention(q, k, v, causal=causal, block_q=block_q,
+                              block_k=block_k, interpret=True)
+        assert got.dtype == dtype and got.shape == q.shape
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32), np.asarray(want, np.float32),
+            rtol=0, atol=TOLERANCE[dtype])
+
+    @pytest.mark.parametrize("causal", [True, False],
+                             ids=["causal", "full"])
+    def test_masked_blocks_are_not_read(self, causal):
+        """Keys a causal query block cannot see may hold anything: blocks
+        wholly above the diagonal are skipped and the straddling ones
+        masked, so NaN there never reaches the result.  Without the mask
+        every key is read and the NaN shows."""
+        q, k, v = rand(1, 256, 2, 64, seed=3)
+        first = flash_attention(q[:, :64], k[:, :64], v[:, :64],
+                                causal=causal, interpret=True)
+        k = k.at[:, 64:].set(jnp.nan)
+        v = v.at[:, 64:].set(jnp.nan)
+        got = flash_attention(q, k, v, causal=causal, block_q=64,
+                              block_k=64, interpret=True)[:, :64]
+        if causal:
+            np.testing.assert_allclose(np.asarray(got), np.asarray(first),
+                                       rtol=0, atol=2e-6)
+        else:
+            assert np.isnan(np.asarray(got)).all()
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["float32", "bfloat16"])
+    def test_gradient_is_the_plain_paths(self, dtype):
+        """The backward rule recomputes through plain attention on the
+        saved q/k/v, so the cotangents are the plain path's own."""
+        q, k, v = rand(1, 256, 2, 64, seed=5, dtype=dtype)
+        w = rand(1, 256, 2, 64, seed=6)[0]
+
+        def through(attend):
+            return jax.grad(
+                lambda *a: (attend(*a).astype(jnp.float32) * w).sum(),
+                argnums=(0, 1, 2))(q, k, v)
+
+        got = through(lambda *a: flash_attention(*a, causal=True,
+                                                 interpret=True))
+        want = through(lambda *a: dot_product_attention(*a, causal=True))
+        for g, p in zip(got, want):
+            assert g.dtype == dtype
+            np.testing.assert_array_equal(np.asarray(g, np.float32),
+                                          np.asarray(p, np.float32))
 
 
 class TestFlashBlockAlignment:
