@@ -30,9 +30,12 @@ from bigdl_tpu.nn.normalization import (
     Dropout, SpatialCrossMapLRN, Normalize,
 )
 from bigdl_tpu.nn.attention import (
-    MultiHeadAttention, TransformerBlock, TransformerLM,
-    stack_block_params, unstack_block_params,
+    MultiHeadAttention, GroupedQueryAttention, TransformerBlock,
+    TransformerLM, rotary_embedding, stack_block_params,
+    unstack_block_params,
 )
+from bigdl_tpu.nn.gated import GatedMLP, GatedShortConv
+from bigdl_tpu.nn.moe import DroplessMoE
 from bigdl_tpu.nn.activations import (
     ReLU, Tanh, Sigmoid, SoftMax, SoftMin, LogSoftMax, HardTanh, Clamp,
     ReLU6, ELU, SoftPlus, SoftSign, LeakyReLU, Threshold, HardSigmoid,

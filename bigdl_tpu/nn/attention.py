@@ -470,6 +470,116 @@ class MultiHeadAttention(Module):
         return y, state
 
 
+def rotary_embedding(x, theta: float):
+    """Rotary positions on ``x (N, T, H, Dh)``, rotate-half convention:
+    the head's first and second halves are the two components that
+    position ``t`` turns by ``t * theta ** (-2i / Dh)``.  Computed in
+    float32, returned in ``x``'s dtype."""
+    t, dh = x.shape[1], x.shape[-1]
+    inv_freq = theta ** (-jnp.arange(0, dh, 2, dtype=jnp.float32) / dh)
+    angles = jnp.arange(t, dtype=jnp.float32)[:, None] * inv_freq[None]
+    cos = jnp.cos(angles)[None, :, None, :]
+    sin = jnp.sin(angles)[None, :, None, :]
+    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)
+
+
+class GroupedQueryAttention(Module):
+    """Causal self-attention of today's open models: ``num_heads`` query
+    heads over ``num_kv_heads`` key/value heads (each serving
+    ``num_heads // num_kv_heads`` query heads), RMSNorm with a learned
+    weight over each head's width on q and on k, rotary positions, no
+    bias.  Training path only (no cache).  Shares ``flash_attention``
+    with ``MultiHeadAttention``: on the TPU the forward is the Pallas
+    kernel and the backward its plain recompute.
+
+    ``kv_heads_per_call``: the (row, KV head) pairs one attention call
+    takes; the calls run one after the other (``lax.map``), so the
+    backward's float32 scores are ``(pairs, group, T, T)`` at a time
+    and not ``(N, H, T, T)``.  None: one call for everything.
+    """
+
+    def __init__(self, hidden_size: int, num_heads: int, num_kv_heads: int,
+                 rope_theta: float = 10000.0, norm_eps: float = 1e-5,
+                 kv_heads_per_call: Optional[int] = None,
+                 use_flash: str = "auto", name=None):
+        super().__init__(name)
+        assert hidden_size % num_heads == 0
+        assert num_heads % num_kv_heads == 0
+        assert use_flash in ("auto", "never", "interpret")
+        self.hidden_size = hidden_size
+        self.num_heads = num_heads
+        self.num_kv_heads = num_kv_heads
+        self.head_dim = hidden_size // num_heads
+        self.rope_theta = float(rope_theta)
+        self.norm_eps = norm_eps
+        self.kv_heads_per_call = kv_heads_per_call
+        self.use_flash = use_flash
+
+    def setup(self, rng, input_spec):
+        d, dh = self.hidden_size, self.head_dim
+        rows = (self.num_heads + 2 * self.num_kv_heads) * dh
+        init = Xavier()
+        return {
+            "qkv_weight": init.init(child_rng(rng, 0), (rows, d), d, d),
+            "q_norm": jnp.ones((dh,), jnp.float32),
+            "k_norm": jnp.ones((dh,), jnp.float32),
+            "out_weight": init.init(child_rng(rng, 1), (d, d), d, d),
+        }, ()
+
+    def _head_norm(self, x, weight):
+        sq = jnp.mean(jnp.square(x.astype(jnp.float32)), -1, keepdims=True)
+        return (x * jax.lax.rsqrt(sq + self.norm_eps).astype(x.dtype)
+                * weight.astype(x.dtype))
+
+    def _attend(self, q, k, v):
+        """q ``(P, T, G, Dh)``, k and v ``(P, T, 1, Dh)`` -> ``(P, T, G,
+        Dh)``: every pair's ``G`` query heads against its one KV head."""
+        k = jnp.broadcast_to(k, q.shape)
+        v = jnp.broadcast_to(v, q.shape)
+        t = q.shape[1]
+        flash = self.use_flash == "interpret" or (
+            self.use_flash == "auto" and _on_tpu()
+            and MultiHeadAttention._flash_block_ok(t))
+        if flash:
+            from bigdl_tpu.ops.flash_attention import flash_attention
+
+            return flash_attention(q, k, v, causal=True,
+                                   interpret=self.use_flash == "interpret")
+        return dot_product_attention(q, k, v, causal=True)
+
+    def apply(self, params, state, input, *, training=False, rng=None):
+        n, t, d = input.shape
+        dt = input.dtype
+        h, hkv, dh = self.num_heads, self.num_kv_heads, self.head_dim
+        qkv = input @ params["qkv_weight"].astype(dt).T
+        q, k, v = jnp.split(qkv, [h * dh, (h + hkv) * dh], axis=-1)
+        q = self._head_norm(q.reshape(n, t, h, dh), params["q_norm"])
+        k = self._head_norm(k.reshape(n, t, hkv, dh), params["k_norm"])
+        q = rotary_embedding(q, self.rope_theta)
+        k = rotary_embedding(k, self.rope_theta)
+        v = v.reshape(n, t, hkv, dh)
+
+        def pairs(x):                    # (N, T, Hkv, G, Dh) -> (N Hkv, T, G, Dh)
+            g = x.shape[2] // hkv
+            return x.reshape(n, t, hkv, g, dh).transpose(0, 2, 1, 3, 4) \
+                .reshape(n * hkv, t, g, dh)
+
+        q, k, v = pairs(q), pairs(k), pairs(v)
+        per = self.kv_heads_per_call or n * hkv
+        if per >= n * hkv:
+            y = self._attend(q, k, v)
+        else:
+            assert (n * hkv) % per == 0, (n, hkv, per)
+            split = lambda x: x.reshape((n * hkv // per, per) + x.shape[1:])
+            y = jax.lax.map(lambda a: self._attend(*a),
+                            (split(q), split(k), split(v)))
+        y = y.reshape(n, hkv, t, h // hkv, dh).transpose(0, 2, 1, 3, 4) \
+            .reshape(n, t, d)
+        return y @ params["out_weight"].astype(dt).T, state
+
+
 class TransformerBlock(Container):
     """Pre-LN block: x + MHA(LN(x)); x + MLP(LN(x))."""
 

@@ -17,6 +17,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from bigdl_tpu.nn.initialization import Xavier
 from bigdl_tpu.nn.module import Module, child_rng
@@ -110,6 +111,180 @@ class MoE(Module):
         mean_prob = probs.mean(0)
         aux = (frac * mean_prob).sum() * e
         return out.reshape(n, t, d), {"aux_loss": aux}
+
+
+def _no_cotangent(x):
+    return np.zeros(x.shape, jax.dtypes.float0)
+
+
+@jax.custom_vjp
+def _take_rows(x, take, readers):
+    """``y[i] = x[take[i]]`` (a row of zeros where ``take[i]`` is
+    ``len(x)``), with the backward written as a gather too: row ``r`` of
+    ``x`` is read by exactly the outputs ``readers[r, :]`` (``len(y)``
+    where fewer read it), so its cotangent is their sum and no scatter
+    runs."""
+    return x.at[take].get(mode="fill", fill_value=0)
+
+
+def _take_rows_fwd(x, take, readers):
+    return _take_rows(x, take, readers), (take, readers)
+
+
+def _take_rows_bwd(res, g):
+    take, readers = res
+    dx = g.at[readers].get(mode="fill", fill_value=0).sum(1)
+    return dx.astype(g.dtype), _no_cotangent(take), _no_cotangent(readers)
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+class DroplessMoE(Module):
+    """Mixture of gated-MLP experts that drops no token, as one chip's
+    share of an expert-parallel layer: ``(N, T, D) -> (N, T, D)``.
+
+    The router scores every token against ALL ``num_experts`` in float32
+    (``s = sigmoid(u W_g)``), chooses the top ``k`` of ``s + b`` (``b``,
+    the selection bias, chooses and does not weigh; it gets no gradient:
+    a balancing rule outside the loss moves it) and weighs the chosen
+    with ``s`` over ``(their sum + 1e-6)``.  Of the ``num_experts`` this
+    layer HOLDS ``experts_held = (first, count)``: the assignments to held
+    experts are sorted by expert into a buffer sized for the worst case,
+    three grouped products (``ops/grouped_matmul.py``) run over the rows
+    in use, and each token gets back the weighted rows of its held
+    experts.  What the absent experts would add is left out: on a mesh
+    the other shares add it (``sum`` over the expert axis); on one chip
+    nothing stands in for them.
+
+    apply() returns ``(out, {"moe_load": int32[4]})``: assignments routed,
+    assignments to experts held here, those of the busiest held expert,
+    and ``rows_here`` over the experts held (whole rows).  The trainer's
+    loop puts them into a ``moe_load`` span (``state_spans``).
+    """
+
+    #: leaves that the train step keeps in float32 under a compute dtype
+    full_precision_params = ("router_weight",)
+    #: model state the trainer's loop records after each synced step: a
+    #: span of the key's name with these attributes, one fetch a key
+    state_spans = {"moe_load": ("rows_routed", "rows_here",
+                                "rows_busiest_expert", "rows_mean_expert")}
+
+    def __init__(self, hidden_size: int, expert_width: int, num_experts: int,
+                 k: int, experts_held=None, norm_topk_prob: bool = True,
+                 routed_scaling_factor: float = 1.0,
+                 use_kernel: str = "auto", name=None):
+        super().__init__(name)
+        assert use_kernel in ("auto", "never", "interpret")
+        self.hidden_size = hidden_size
+        self.expert_width = expert_width
+        self.num_experts = num_experts
+        self.k = k
+        self.experts_held = tuple(experts_held or (0, num_experts))
+        first, count = self.experts_held
+        assert 0 <= first and first + count <= num_experts and count >= 1
+        self.norm_topk_prob = norm_topk_prob
+        self.routed_scaling_factor = routed_scaling_factor
+        self.use_kernel = use_kernel
+
+    def setup(self, rng, input_spec):
+        d, f, e = self.hidden_size, self.expert_width, self.num_experts
+        held = self.experts_held[1]
+        init = Xavier()
+
+        def stack(seed, shape, fan_in, fan_out):
+            return jnp.stack([init.init(child_rng(rng, seed + i), shape,
+                                        fan_in, fan_out)
+                              for i in range(held)])
+
+        return {
+            "router_weight": init.init(child_rng(rng, 0), (e, d), d, e),
+            "router_bias": jnp.zeros((e,), jnp.float32),
+            "w1": stack(1000, (d, f), d, f),       # (held, D, F)
+            "w3": stack(2000, (d, f), d, f),
+            "w2": stack(3000, (f, d), f, d),       # (held, F, D)
+        }, {"moe_load": jnp.zeros((4,), jnp.int32)}
+
+    def route(self, params, x):
+        """``(expert ids (T, k), weights (T, k))`` in float32."""
+        logits = jnp.einsum("td,ed->te", x.astype(jnp.float32),
+                            params["router_weight"].astype(jnp.float32),
+                            precision="highest")
+        scores = jax.nn.sigmoid(logits)
+        chosen = scores + jax.lax.stop_gradient(
+            params["router_bias"].astype(jnp.float32))
+        _, idx = jax.lax.top_k(chosen, self.k)
+        w = jnp.take_along_axis(scores, idx, axis=-1)
+        if self.norm_topk_prob:
+            w = w / (w.sum(-1, keepdims=True) + 1e-6)
+        return idx, w * self.routed_scaling_factor
+
+    def _products(self, on_tpu):
+        from bigdl_tpu.ops import grouped_matmul as gm
+
+        kernel = self.use_kernel == "interpret" or (
+            self.use_kernel == "auto" and on_tpu)
+        block = gm.BLOCK_ROWS if on_tpu else 8
+        if kernel:
+            interpret = self.use_kernel == "interpret"
+            return block, lambda a, w, sizes: gm.grouped_matmul(
+                a, w, sizes, block_rows=block, interpret=interpret)
+        return block, lambda a, w, sizes: gm.grouped_matmul_reference(
+            a, w, sizes, block)
+
+    def apply(self, params, state, input, *, training=False, rng=None):
+        from bigdl_tpu.nn.attention import _on_tpu
+        from bigdl_tpu.ops.grouped_matmul import buffer_rows, group_layout
+
+        n, t, d = input.shape
+        tokens, k = n * t, self.k
+        first, held = self.experts_held
+        block, product = self._products(_on_tpu())
+        x = input.reshape(tokens, d)
+        dt = x.dtype
+        with jax.named_scope("moe_router"):
+            idx, weights = self.route(params, x)
+        with jax.named_scope("moe_dispatch"):
+            # assignments (token-major) sorted by held expert, each
+            # expert's rows from a multiple of the tile; those of absent
+            # experts get no slot.  One stable sort and one running count:
+            # no scatter, here or in the backward.
+            assigned = tokens * k
+            rows = buffer_rows(assigned, held, block)
+            local = idx.reshape(assigned) - first
+            group = jnp.where((local >= 0) & (local < held), local, held)
+            member = (group[None] == jnp.arange(held + 1)[:, None])
+            count = jnp.cumsum(member.astype(jnp.int32), axis=1)
+            sizes_all = count[:, -1]
+            sizes = sizes_all[:held]
+            offsets, tile_group, _ = group_layout(sizes, rows, block)
+            rank = jnp.take_along_axis(count, group[None], axis=0)[0] - 1
+            slot_of = jnp.where(group < held,
+                                jnp.append(offsets, 0)[group] + rank, rows)
+            order = jnp.argsort(group, stable=True).astype(jnp.int32)
+            starts = jnp.cumsum(sizes_all) - sizes_all
+            slot = jnp.arange(rows, dtype=jnp.int32)
+            slot_group = jnp.repeat(tile_group, block,
+                                    total_repeat_length=rows)
+            within = slot - offsets[slot_group]
+            assignment_at = jnp.where(
+                within < sizes[slot_group],
+                order[jnp.minimum(starts[slot_group] + within,
+                                  assigned - 1)], assigned)
+            token_at = jnp.where(assignment_at < assigned,
+                                 assignment_at // k, tokens)
+            xs = _take_rows(x, token_at, slot_of.reshape(tokens, k))
+        with jax.named_scope("moe_experts"):
+            hidden = jax.nn.silu(product(xs, params["w1"].astype(dt), sizes)) \
+                * product(xs, params["w3"].astype(dt), sizes)
+            ys = product(hidden, params["w2"].astype(dt), sizes)
+        with jax.named_scope("moe_combine"):
+            picked = _take_rows(ys, slot_of, assignment_at[:, None])
+            out = (picked.reshape(tokens, k, d)
+                   * weights[..., None].astype(dt)).sum(1)
+        load = jnp.stack([jnp.int32(assigned), sizes.sum(), sizes.max(),
+                          sizes.sum() // held])
+        return out.reshape(n, t, d), {"moe_load": load}
 
 
 class MoETransformerBlock(Module):

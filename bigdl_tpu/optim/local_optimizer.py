@@ -644,7 +644,7 @@ class BaseOptimizer:
                         stage_device=None, records_of=None,
                         extra_summaries=None, validate_cb=None,
                         feed_plateau=None, checkpoint_cb=None,
-                        health_cb=None, event_fields=None):
+                        health_cb=None, event_fields=None, state_cb=None):
         """The ONE training driver loop shared by Local/Distri/Strategy
         optimizers (they differ only in the step signature and how
         batches reach the devices, injected via the callbacks).
@@ -681,6 +681,11 @@ class BaseOptimizer:
         - ``event_fields``: a static dict merged into every step event
           (e.g. the dp driver's ``wire_bytes`` / ``compression_ratio``
           communication footprint).
+        - ``state_cb() -> model state``: read for a model that declares
+          ``state_spans = {state key: attribute names}`` (an expert
+          layer's routing counts): right after each loss sync, when the
+          step's outputs are ready, every such key is fetched once and
+          recorded as a span of its name with those attributes.
 
         The per-step loss sync (``float(loss)``) runs every
         ``sync_every``-th step only (default 1 = classic behavior; see
@@ -724,6 +729,10 @@ class BaseOptimizer:
         mon = self.health_monitor
         health_on = (mon is not None and mon.enabled
                      and health_cb is not None)
+        # model state that the model asks to be recorded as spans
+        state_spans = {} if state_cb is None else {
+            k: v for k, v in getattr(self.model, "state_spans", {}).items()
+            if k in state_cb()}
         timer = None
         if getattr(self, "blocking_timing", False):
             # trusted-timing mode (set_blocking_timing): every dispatch
@@ -792,6 +801,10 @@ class BaseOptimizer:
                         with span("loss_sync", step=state["neval"]):
                             loss = float(loss_dev)
                         sync_skew = 0
+                        for key, fields in state_spans.items():
+                            with span(key, step=state["neval"]) as sp:
+                                sp.set(**dict(zip(fields, np.asarray(
+                                    state_cb()[key]).tolist())))
                     else:
                         sync_skew += 1    # deferred: host runs ahead of device
                     wall = time.perf_counter() - t0
@@ -1001,7 +1014,8 @@ class LocalOptimizer(BaseOptimizer):
             checkpoint_cb=lambda state: self._checkpoint(
                 params, mstate, opt_state),
             health_cb=(lambda: jax.device_get(stats_holder[0]))
-            if use_health else None)
+            if use_health else None,
+            state_cb=lambda: mstate)
 
         self.model.set_parameters(params)
         self.model.set_state(mstate)

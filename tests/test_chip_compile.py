@@ -17,6 +17,7 @@ import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
 from bigdl_tpu.ops.cross_entropy import fused_softmax_cross_entropy
+from bigdl_tpu.ops.grouped_matmul import buffer_rows, grouped_matmul
 from bigdl_tpu.ops.flash_attention import (flash_attention,
                                            flash_decode_attention,
                                            flash_paged_decode_attention,
@@ -72,6 +73,20 @@ def _ce_grad(x, y):
     return jax.grad(lambda a: fused_softmax_cross_entropy(a, y).sum())(x)
 
 
+def _grouped_grad(lhs, rhs, sizes):
+    # the forward product and both backward products, all grouped kernels
+    return jax.value_and_grad(
+        lambda a, w: grouped_matmul(a, w, sizes).astype(f32).sum(),
+        argnums=(0, 1))(lhs, rhs)
+
+
+def _grouped(k, n, rows=65536, groups=8):
+    """The expert layer of the LFM2 cell: a buffer for the worst case of
+    ``rows`` assignments over ``groups`` experts held."""
+    m = buffer_rows(rows, groups, 512)
+    return [((m, k), bf16), ((groups, k, n), bf16), ((groups,), i32)]
+
+
 def _qkv(b, t, d, dt, h=16):
     return [((b, t, h, d), dt)] * 3
 
@@ -112,6 +127,14 @@ CASES = {
     "paged-large-int8": (flash_paged_decode_attention,
                          _paged(8, 38, 128, 96, True),
                          (38 * 128, 96, i8, True)),
+    # the LFM2 cell: one call of the attention layer takes a row's 8 KV
+    # heads as 8 pairs of 4 query heads at 4096 positions
+    "flash-grad-lfm2": (_flash_grad, _qkv(8, 4096, 64, bf16, h=4), None),
+    # its experts: 2048 -> 1792 (w1, w3) and 1792 -> 2048 (w2), 8 groups,
+    # 65,536 rows at worst; forward and both backward products
+    "grouped-up": (grouped_matmul, _grouped(2048, 1792), None),
+    "grouped-grad-up": (_grouped_grad, _grouped(2048, 1792), None),
+    "grouped-grad-down": (_grouped_grad, _grouped(1792, 2048), None),
     # train_lm's head: 2 sequences of 2048 tokens, vocab 32000
     "ce-forward": (fused_softmax_cross_entropy,
                    [((4096, 32000), f32), ((4096,), i32)], None),

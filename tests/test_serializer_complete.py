@@ -255,6 +255,13 @@ EXAMPLES = {
         lambda: _r(2, 4)),
     "MultiHeadAttention": (lambda: nn.MultiHeadAttention(8, 2, causal=True),
                            lambda: _r(2, 5, 8)),
+    "GroupedQueryAttention": (
+        lambda: nn.GroupedQueryAttention(8, 4, 2, rope_theta=1e4),
+        lambda: _r(2, 5, 8)),
+    "GatedShortConv": (lambda: nn.GatedShortConv(8, 3), lambda: _r(2, 5, 8)),
+    "GatedMLP": (lambda: nn.GatedMLP(8, 12), lambda: _r(2, 5, 8)),
+    "DroplessMoE": (lambda: nn.DroplessMoE(8, 4, 4, 2, experts_held=(1, 2)),
+                    lambda: _r(2, 5, 8)),
     "TransformerBlock": (lambda: nn.TransformerBlock(8, 2),
                          lambda: _r(2, 5, 8)),
     "TransformerLM": (lambda: nn.TransformerLM(11, 8, 2, 2, max_len=6),
