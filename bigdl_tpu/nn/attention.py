@@ -491,13 +491,14 @@ class GroupedQueryAttention(Module):
     ``num_heads // num_kv_heads`` query heads), RMSNorm with a learned
     weight over each head's width on q and on k, rotary positions, no
     bias.  Training path only (no cache).  Shares ``flash_attention``
-    with ``MultiHeadAttention``: on the TPU the forward is the Pallas
-    kernel and the backward its plain recompute.
+    with ``MultiHeadAttention``: on the TPU forward and backward are its
+    Pallas kernels, over K and V broadcast to a group's query heads (XLA
+    sums dk and dv over the group).
 
     ``kv_heads_per_call``: the (row, KV head) pairs one attention call
-    takes; the calls run one after the other (``lax.map``), so the
-    backward's float32 scores are ``(pairs, group, T, T)`` at a time
-    and not ``(N, H, T, T)``.  None: one call for everything.
+    takes; the calls run one after the other (``lax.map``).  It bounds
+    the plain path's float32 scores to ``(pairs, group, T, T)`` at a
+    time; the kernels write no scores.  None: one call for everything.
     """
 
     def __init__(self, hidden_size: int, num_heads: int, num_kv_heads: int,

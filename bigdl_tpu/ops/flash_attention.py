@@ -1,10 +1,11 @@
-"""Fused flash-attention forward kernel in Pallas (Mosaic/TPU).
+"""Fused flash-attention kernels in Pallas (Mosaic/TPU): forward, backward
+and the two decode-shaped forwards.
 
 The TPU-native analogue of the reference's hand-tuned native kernels
 (bigdl-core MKL-DNN primitives, SURVEY.md section 2.8): where XLA's fusion
 isn't enough, drop to Pallas.  Attention is the one op where manual tiling
-pays -- the (T, T) score matrix never materialises in HBM; each (block_q,
-block_k) tile lives in VMEM with a flash-style online softmax.
+pays -- the (T, T) score matrix never materialises in HBM, forward or
+backward; each (block_q, block_k) tile lives in VMEM.
 
 Layout: q/k/v (BH, T, D) fp32/bf16.  Causal masking by global position.
 ``interpret=True`` runs on CPU for tests.
@@ -18,7 +19,20 @@ never a head's whole K/V, so no sequence is too long for it.  Under
 ``causal`` a key block wholly above the diagonal is neither computed nor
 fetched, and only the blocks that straddle the diagonal build a mask.
 The MXU takes the operands in the dtype they come in (bf16 in training)
-and accumulates in fp32.
+and accumulates in fp32.  Where the call is differentiated it also writes
+each query row's log-sum-exp, as ``(BH, 1, T)`` fp32.
+
+``attention_bwd`` (the backward kernel; its name must not hold
+``flash_attention``, by which the benchmark finds forward calls): grid
+(BH, T/block_k, T/block_q) with the query axis last, one fused kernel for
+dq, dk and dv from q, k, v, the output's cotangent, the saved log-sum-exp
+and ``delta = rowsum(do * o)``: per tile ``s``, ``p = exp(s - lse)``,
+``dv += p^T do``, ``dp = do v^T``, ``ds = p (dp - delta)``, ``dk += ds^T
+q``, ``dq += ds k`` -- five products where separate dk/dv and dq kernels
+take seven.  dk and dv accumulate over the query axis in fp32 scratch; a
+head's whole dq (``T x D`` fp32) stays in VMEM across both axes, the one
+part that grows with T and what ``_bwd_vmem_bytes`` asks the compiler for.
+The same blocks are skipped and masked as in the forward.
 
 The two decode kernels keep one head's whole K and V (or pool plane)
 resident in VMEM, so what the TPU compiler accepts of them is bounded by
@@ -71,6 +85,8 @@ _MASKED = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 _LANES = 128
 _CONTRACT_LAST = (((1,), (1,)), ((), ()))
+_MATMUL = (((1,), (0,)), ((), ()))
+_CONTRACT_FIRST = (((0,), (0,)), ((), ()))
 
 
 def _across(x, n):
@@ -84,12 +100,47 @@ def _across(x, n):
     return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
 
 
-def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
-                 causal: bool, scale: float):
+def _operand_precision(dtype):
+    """Operands go to the MXU in the dtype that came in, fp32 comes out.
+    Products of bf16 values are exact in fp32, so for them a higher matmul
+    precision from the caller's context has no meaning (and Mosaic refuses
+    it); fp32 operands keep the context's."""
+    return None if dtype == jnp.float32 else jax.lax.Precision.DEFAULT
+
+
+def _causal_steps(step, causal, ahead, block_q, block_k):
+    """Run ``step(straddles)`` for this (query block, key block) pair as
+    the mask asks: a pair wholly above the diagonal does nothing (and its
+    index map fetched nothing), one wholly below it builds no mask.  Key 0
+    is visible to every query, so a query block's first key step is never
+    skipped and leaves a finite maximum in every row."""
+    if not causal:
+        step(False)
+        return
+    visible = -ahead <= block_q - 1
+    below = block_k - 1 <= ahead
+    pl.when(below)(functools.partial(step, False))
+    pl.when(jnp.logical_and(visible, jnp.logical_not(below)))(
+        functools.partial(step, True))
+
+
+def _as_row(x):
+    """A lane-replicated ``(rows, 128)`` column statistic as one row ``(1,
+    rows)``: the layout in which it is stored narrow (4 bytes a position)
+    and in which the backward, whose tiles have the queries on the lanes,
+    broadcasts it over the keys."""
+    return x.T[:1]
+
+
+def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *rest, causal: bool,
+                 scale: float):
     """One (query block, key block) step of the online softmax.  The key
     axis is the last, sequential grid axis: ``m_ref``/``l_ref`` (running
     maximum and sum, lane-replicated) and ``acc_ref`` carry the state in
-    fp32 from the first key step to the last, which writes ``o_ref``."""
+    fp32 from the first key step to the last, which writes ``o_ref`` and,
+    where the call is differentiated (``rest`` then starts with that
+    output), the rows' log-sum-exp as one row ``(1, block_q)``."""
+    *lse_ref, m_ref, l_ref, acc_ref = rest
     block_q, d = q_ref.shape
     block_k = k_ref.shape[0]
     iq, ik = pl.program_id(1), pl.program_id(2)
@@ -103,13 +154,7 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     # first query position less first key position: key c of the block is
     # visible to query r where c - r <= ahead
     ahead = iq * block_q - ik * block_k
-
-    # Operands in the dtype that came in, fp32 out of the MXU.  Products of
-    # bf16 values are exact in fp32, so for them a higher matmul precision
-    # from the caller's context has no meaning (and Mosaic refuses it);
-    # fp32 operands keep the context's.
-    precision = (None if q_ref.dtype == jnp.float32
-                 else jax.lax.Precision.DEFAULT)
+    precision = _operand_precision(q_ref.dtype)
 
     def step(straddles):
         s = jax.lax.dot_general(q_ref[:], k_ref[:], _CONTRACT_LAST,
@@ -130,65 +175,73 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
             p.astype(v_ref.dtype), v_ref[:], precision=precision,
             preferred_element_type=jnp.float32)
 
-    if causal:
-        # key 0 is visible to every query, so the first step leaves a
-        # finite maximum in every row.  A block wholly above the diagonal
-        # does nothing (and its index map fetched nothing); one wholly
-        # below it needs no mask.
-        visible = -ahead <= block_q - 1
-        below = block_k - 1 <= ahead
-        pl.when(below)(functools.partial(step, False))
-        pl.when(jnp.logical_and(visible, jnp.logical_not(below)))(
-            functools.partial(step, True))
-    else:
-        step(False)
+    _causal_steps(step, causal, ahead, block_q, block_k)
 
     @pl.when(ik == pl.num_programs(2) - 1)
     def _():
         o_ref[:] = (acc_ref[:] / _across(l_ref[:], d)).astype(o_ref.dtype)
+        if lse_ref:
+            lse_ref[0][:] = _as_row(m_ref[:] + jnp.log(l_ref[:]))
 
 
 def _tile(t: int) -> int:
-    """The block size, of queries and of keys, for a sequence of ``t``:
-    ``t`` itself below 128 (one block), else the largest of 1024, 512,
-    256, 128 that divides it.  Large tiles win on a v5e although they
-    skip fewer masked tiles: a grid step and a rescale of the state cost
-    more than the masked half of a tile (PERF.md section 6, PR 28, has
-    the sweep)."""
+    """The forward's block size, of queries and of keys, for a sequence
+    of ``t``: ``t`` itself below 128 (one block), else the largest of
+    1024, 512, 256, 128 that divides it.  Large tiles win on a v5e
+    although they skip fewer masked tiles: a grid step and a rescale of
+    the state cost more than the masked half of a tile (PERF.md section 6,
+    PR 28, has the sweep)."""
     if t < 128:
         return t
     return next(b for b in (1024, 512, 256, 128) if t % b == 0)
 
 
-def _flash_forward(q, k, v, causal, block_q, block_k, interpret):
-    b, t, h, d = q.shape
+def _to_bh(x):
+    """``(B, T, H, D)`` -> ``(B H, T, D)``: one head's rows contiguous."""
+    b, t, h, d = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b * h, t, d)
+
+
+def _from_bh(x, b):
+    bh, t, d = x.shape
+    return x.reshape(b, bh // b, t, d).transpose(0, 2, 1, 3)
+
+
+def _flash_forward(qb, kb, vb, causal, block_q, block_k, interpret,
+                   save_lse=False):
+    """The forward kernel over ``(BH, T, D)`` operands.  ``save_lse``: also
+    return each query row's log-sum-exp ``(BH, 1, T)`` fp32, which is all
+    the backward needs of the softmax; the undifferentiated call writes
+    none."""
+    bh, t, d = qb.shape
     scale = 1.0 / math.sqrt(d)
 
-    def to_bh(x):
-        return x.transpose(0, 2, 1, 3).reshape(b * h, t, d)
+    def q_map(h, i, j):
+        return h, i, 0
 
-    qb, kb, vb = to_bh(q), to_bh(k), to_bh(v)
-
-    def q_map(bh, i, j):
-        return bh, i, 0
-
-    def kv_map(bh, i, j):
+    def kv_map(h, i, j):
         if causal:
             # past the last block this query block can see, stay on it:
             # an unchanged block index copies nothing
             j = jnp.minimum(j, (i * block_q + block_q - 1) // block_k)
-        return bh, j, 0
+        return h, j, 0
 
+    out_specs = [pl.BlockSpec((None, block_q, d), q_map)]
+    out_shape = [jax.ShapeDtypeStruct((bh, t, d), qb.dtype)]
+    if save_lse:
+        out_specs.append(pl.BlockSpec((None, 1, block_q),
+                                      lambda h, i, j: (h, 0, i)))
+        out_shape.append(jax.ShapeDtypeStruct((bh, 1, t), jnp.float32))
     out = pl.pallas_call(
         functools.partial(_attn_kernel, causal=causal, scale=scale),
-        grid=(b * h, t // block_q, t // block_k),
+        grid=(bh, t // block_q, t // block_k),
         in_specs=[
             pl.BlockSpec((None, block_q, d), q_map),
             pl.BlockSpec((None, block_k, d), kv_map),
             pl.BlockSpec((None, block_k, d), kv_map),
         ],
-        out_specs=pl.BlockSpec((None, block_q, d), q_map),
-        out_shape=jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
+        out_specs=out_specs,
+        out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((block_q, _LANES), jnp.float32),
                         pltpu.VMEM((block_q, _LANES), jnp.float32),
                         pltpu.VMEM((block_q, d), jnp.float32)],
@@ -197,28 +250,170 @@ def _flash_forward(q, k, v, causal, block_q, block_k, interpret):
         interpret=interpret,
         name="flash_attention",
     )(qb, kb, vb)
-    return out.reshape(b, h, t, d).transpose(0, 2, 1, 3)
+    return out if save_lse else out[0]
+
+
+def _attn_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                     dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
+                     causal: bool, scale: float):
+    """One (key block, query block) step of the backward.  The tiles are
+    transposed, keys on the sublanes and queries on the lanes, so that
+    ``lse`` and ``delta`` come in as rows ``(1, block_q)`` and four of the
+    five products need no transpose: ``s^T = k q^T``, ``dv += p^T do``,
+    ``dp^T = v do^T``, ``dk += ds^T q``; the fifth is ``dq^T += k^T ds^T``,
+    so dq is summed as ``(D, block_q)`` tiles.
+
+    The query axis is the last grid axis: ``dk_acc``/``dv_acc`` carry one
+    key block's sums over it in fp32.  ``dq_acc`` holds a whole head's dq
+    (``T x D`` fp32) across both axes; the last key block turns each tile
+    over into ``dq_ref``, the head's ``(T, D)`` result.  ``scale``
+    multiplies the sums of dq and dk once, in fp32, and not every tile of
+    ``ds``."""
+    block_q = q_ref.shape[0]
+    block_k = k_ref.shape[0]
+    ik, iq = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(iq == 0)
+    def _():
+        dk_acc[:] = jnp.zeros(dk_acc.shape, jnp.float32)
+        dv_acc[:] = jnp.zeros(dv_acc.shape, jnp.float32)
+
+    @pl.when(ik == 0)
+    def _():
+        dq_acc[iq] = jnp.zeros(dq_acc.shape[1:], jnp.float32)
+
+    ahead = iq * block_q - ik * block_k
+    precision = _operand_precision(q_ref.dtype)
+    dot = functools.partial(jax.lax.dot_general, precision=precision,
+                            preferred_element_type=jnp.float32)
+
+    def step(straddles):
+        q, k, v, do = q_ref[:], k_ref[:], v_ref[:], do_ref[:]
+        s = dot(k, q, _CONTRACT_LAST) * scale             # (block_k, block_q)
+        if straddles:
+            shape = (block_k, block_q)
+            c_less_r = (jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+                        - jax.lax.broadcasted_iota(jnp.int32, shape, 1))
+            s = jnp.where(c_less_r <= ahead, s, _MASKED)
+        p = jnp.exp(s - lse_ref[:])
+        dv_acc[:] += dot(p.astype(do.dtype), do, _MATMUL)
+        dp = dot(v, do, _CONTRACT_LAST)
+        ds = (p * (dp - delta_ref[:])).astype(q.dtype)
+        dk_acc[:] += dot(ds, q, _MATMUL)
+        dq_acc[iq] += dot(k, ds, _CONTRACT_FIRST)         # (D, block_q)
+
+    _causal_steps(step, causal, ahead, block_q, block_k)
+
+    @pl.when(iq == pl.num_programs(2) - 1)
+    def _():
+        dk_ref[:] = (dk_acc[:] * scale).astype(dk_ref.dtype)
+        dv_ref[:] = dv_acc[:].astype(dv_ref.dtype)
+
+    @pl.when(ik == pl.num_programs(1) - 1)
+    def _():
+        rows = pl.ds(pl.multiple_of(iq * block_q, block_q), block_q)
+        dq_ref[rows, :] = (dq_acc[iq] * scale).T.astype(dq_ref.dtype)
+
+
+def _bwd_tile(t: int) -> int:
+    """The backward's block size, of queries and of keys: ``t`` itself
+    below 128, else the largest of 512, 256, 128 that divides it.  Its
+    four fp32 tiles (``s``, ``p``, ``dp``, ``ds``) are 1 MiB each at 512;
+    at the forward's 1024 they alone would fill the scoped VMEM."""
+    if t < 128:
+        return t
+    return next(b for b in (512, 256, 128) if t % b == 0)
+
+
+def _bwd_vmem_bytes(t, d, block_q, block_k, dtype):
+    """Scoped VMEM the backward is given: its tiles (``s``, ``p``, ``dp``,
+    ``ds`` and a mask's two iotas in 32 bits, ``p`` and ``ds`` once more
+    as rounded), the operand and result blocks double-buffered, the
+    accumulators, and a head's dq (fp32 sums and the result block): the
+    one part that grows with T.  Never under the compiler's own 16 MiB."""
+    item = jnp.dtype(dtype).itemsize
+    tiles = block_q * block_k * (6 * 4 + 2 * item)
+    blocks = (4 * _vmem_block_bytes(block_q, d, dtype)
+              + 8 * _vmem_block_bytes(block_k, d, dtype)
+              + 2 * _vmem_block_bytes(block_k, d, jnp.float32)
+              + 4 * _vmem_block_bytes(8, block_q, jnp.float32))
+    head = ((t // block_q) * _vmem_block_bytes(d, block_q, jnp.float32)
+            + 2 * _vmem_block_bytes(t, d, dtype))
+    return max(tiles + blocks + head + 2 ** 20, 16 * 2 ** 20)
+
+
+def _flash_backward(qb, kb, vb, ob, lse, dob, causal, block_q, block_k,
+                    interpret):
+    """dq, dk, dv ``(BH, T, D)`` from the saved operands, output and
+    log-sum-exp and the output's cotangent.  No array with two sequence
+    axes leaves VMEM."""
+    bh, t, d = qb.shape
+    nq, nk = t // block_q, t // block_k
+    scale = 1.0 / math.sqrt(d)
+    delta = jnp.sum(dob.astype(jnp.float32) * ob.astype(jnp.float32),
+                    axis=-1)[:, None, :]                  # (BH, 1, T)
+
+    def q_row(i, j):
+        if causal:
+            # before the first query block this key block is visible to,
+            # stay on that one
+            j = jnp.maximum(j, i * block_k // block_q)
+        return j
+
+    def q_map(h, i, j):
+        return h, q_row(i, j), 0
+
+    def row_map(h, i, j):
+        return h, 0, q_row(i, j)
+
+    def kv_map(h, i, j):
+        return h, i, 0
+
+    q_spec = pl.BlockSpec((None, block_q, d), q_map)
+    kv_spec = pl.BlockSpec((None, block_k, d), kv_map)
+    row_spec = pl.BlockSpec((None, 1, block_q), row_map)
+    return pl.pallas_call(
+        functools.partial(_attn_bwd_kernel, causal=causal, scale=scale),
+        grid=(bh, nk, nq),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        out_specs=[pl.BlockSpec((None, t, d), lambda h, i, j: (h, 0, 0)),
+                   kv_spec, kv_spec],
+        out_shape=[jax.ShapeDtypeStruct((bh, t, d), x.dtype)
+                   for x in (qb, kb, vb)],
+        scratch_shapes=[pltpu.VMEM((nq, d, block_q), jnp.float32),
+                        pltpu.VMEM((block_k, d), jnp.float32),
+                        pltpu.VMEM((block_k, d), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_bwd_vmem_bytes(t, d, block_q, block_k,
+                                             qb.dtype)),
+        interpret=interpret,
+        name="attention_bwd",
+    )(qb, kb, vb, dob, lse, delta)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def _flash(q, k, v, causal, block_q, block_k, interpret):
-    return _flash_forward(q, k, v, causal, block_q, block_k, interpret)
+    out = _flash_forward(_to_bh(q), _to_bh(k), _to_bh(v), causal, block_q,
+                         block_k, interpret)
+    return _from_bh(out, q.shape[0])
 
 
 def _flash_fwd_rule(q, k, v, causal, block_q, block_k, interpret):
-    return _flash_forward(q, k, v, causal, block_q, block_k,
-                          interpret), (q, k, v)
+    qb, kb, vb = _to_bh(q), _to_bh(k), _to_bh(v)
+    ob, lse = _flash_forward(qb, kb, vb, causal, block_q, block_k, interpret,
+                             save_lse=True)
+    return _from_bh(ob, q.shape[0]), (qb, kb, vb, ob, lse)
 
 
 def _flash_bwd_rule(causal, block_q, block_k, interpret, res, g):
-    # There is no backward kernel: the cotangents come from re-running
-    # plain attention on the saved q/k/v, so only one layer's (T, T)
-    # scores exist at a time and the forward saves no score matrix.
-    from bigdl_tpu.nn.attention import dot_product_attention
-
-    _, vjp = jax.vjp(
-        functools.partial(dot_product_attention, causal=causal), *res)
-    return vjp(g)
+    t = g.shape[1]
+    # the tiles the caller gave the forward hold for the backward as far
+    # as they fit it (tests pass small ones); the kernel's own are its own
+    block_q, block_k = (min(b, _bwd_tile(t)) for b in (block_q, block_k))
+    grads = _flash_backward(*res, _to_bh(g), causal, block_q, block_k,
+                            interpret)
+    return tuple(_from_bh(x, g.shape[0]) for x in grads)
 
 
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
@@ -238,8 +433,12 @@ def flash_attention(q, k, v, causal: bool = True,
     the dtype they come in and accumulates in fp32; the softmax state is
     fp32, and the weights are rounded to v's dtype before ``p @ v`` as
     ``nn.attention.dot_product_attention`` rounds them.
-    Differentiable: the forward is the kernel, the backward recomputes
-    through ``dot_product_attention``.
+
+    Differentiable, and the backward is a kernel too (``attention_bwd``):
+    where the call is differentiated the forward also saves each row's
+    log-sum-exp, and dq, dk and dv come from q, k, v, the output, that and
+    the cotangent tile by tile, in fp32 until the one rounding before each
+    product.  The plain call saves nothing.
     """
     t = q.shape[1]
     block_q = min(block_q or _tile(t), t)

@@ -178,7 +178,8 @@ def path_taken(kernels, *names):
     return "+".join(n for n in names if n in kernels) or "xla"
 
 
-ATTENTION_KERNELS = ("flash_attention", "flash_decode_attention",
+ATTENTION_KERNELS = ("flash_attention", "attention_bwd",
+                     "flash_decode_attention",
                      "flash_paged_decode_attention")
 
 
@@ -294,8 +295,7 @@ def phase_train_lm(cfg, seed, dev, run_dir, log):
     assert_on(params, dev.platform, "trained LM parameters")
     if checksum(params) == before:
         raise RuntimeError("LM parameters did not change")
-    # a backward kernel would show under a name of its own: the flash
-    # kernel's backward is a plain-attention recompute (custom_vjp)
+    # the flash kernel's backward shows under its own name, attention_bwd
     held, names = log.kernels()
     return {"batch": batch, "seq": seq, "losses": losses,
             "step_wall_seconds": walls, "pallas_kernels": held,

@@ -51,19 +51,22 @@ def one_chip(topo):
     compilation_cache.reset_cache()
 
 
-def _compile(one_chip, fn, *shapes):
-    """Compile ``fn`` for the described chip; returns the number of Pallas
-    kernels (``tpu_custom_call``) in the compiled program."""
+def _compiled_text(one_chip, fn, *shapes):
+    """``fn`` compiled for the described chip, as text."""
     args = [None if s is None
             else jax.ShapeDtypeStruct(s[0], s[1], sharding=one_chip)
             for s in shapes]
-    text = jax.jit(fn).lower(*args).compile().as_text()
-    return text.count("tpu_custom_call")
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _compile(one_chip, fn, *shapes):
+    """Compile ``fn`` for the described chip; returns the number of Pallas
+    kernels (``tpu_custom_call``) in the compiled program."""
+    return _compiled_text(one_chip, fn, *shapes).count("tpu_custom_call")
 
 
 def _flash_grad(q, k, v):
-    # value AND grad: the backward recomputes through plain attention, so
-    # only the returned value keeps the forward kernel in the program
+    # the forward kernel (writing its log-sum-exp) and attention_bwd
     return jax.value_and_grad(
         lambda *a: flash_attention(*a).astype(f32).sum(),
         argnums=(0, 1, 2))(q, k, v)
@@ -130,6 +133,11 @@ CASES = {
     # the LFM2 cell: one call of the attention layer takes a row's 8 KV
     # heads as 8 pairs of 4 query heads at 4096 positions
     "flash-grad-lfm2": (_flash_grad, _qkv(8, 4096, 64, bf16, h=4), None),
+    # the backward at the other shapes the forward accepts: fp32 operands,
+    # one block under 128, and a head's whole dq resident at 8192 x 128
+    "flash-grad-fp32": (_flash_grad, _qkv(2, 1024, 64, f32), None),
+    "flash-grad-short": (_flash_grad, _qkv(2, 64, 64, bf16, h=4), None),
+    "flash-grad-long": (_flash_grad, _qkv(1, 8192, 128, bf16, h=2), None),
     # its experts: 2048 -> 1792 (w1, w3) and 1792 -> 2048 (w2), 8 groups,
     # 65,536 rows at worst; forward and both backward products
     "grouped-up": (grouped_matmul, _grouped(2048, 1792), None),
@@ -148,6 +156,17 @@ def test_kernel_compiles_for_v5e(one_chip, case):
     if gate is not None:
         assert kv_blocks_fit(*gate), "the auto gate must admit this shape"
     assert _compile(one_chip, fn, *shapes) >= 1
+
+
+@pytest.mark.parametrize("case", ["flash-grad-cell", "flash-grad-lfm2"])
+def test_gradient_is_two_kernels(one_chip, case):
+    """The differentiated call is the forward kernel and ``attention_bwd``
+    and nothing of XLA's over ``(T, T)`` scores."""
+    fn, shapes, _ = CASES[case]
+    text = _compiled_text(one_chip, fn, *shapes)
+    assert text.count("tpu_custom_call") == 2
+    t = shapes[0][0][1]
+    assert f"{t},{t}]" not in text
 
 
 def test_forward_outgrows_the_gate(one_chip):
