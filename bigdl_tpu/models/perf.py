@@ -32,7 +32,7 @@ MODELS = {
 }
 
 
-# token models (the BASELINE.md "SimpleRNN LM sample throughput" row and
+# token models (the reference's "SimpleRNN LM sample throughput" row and
 # the transformer flagship): (module, ctor, ctor args/kwargs, vocab, seq_len)
 TOKEN_MODELS = {
     "simplernn": ("bigdl_tpu.models.rnn", "SimpleRNN",

@@ -21,7 +21,7 @@ Four layers over the one shared driver loop:
   ``block_until_ready``-fenced per-step measurement (the only basis
   MFU math may use) and triangulated trust verdicts
   (``trusted`` / ``suspect:async_dispatch`` / ``invalid:*``) stamped
-  on bench records and telemetry streams (``profiling.py``).
+  on telemetry streams (``profiling.py``).
 - ``MemoryLedger`` -- per-subsystem device-byte attribution (params /
   fp32 twin / KV block pool / staged deploy buffers) reconciled
   against ``device_memory_stats()`` (leaks surface as a growing
@@ -58,8 +58,7 @@ from bigdl_tpu.observability.telemetry import (StepTelemetry,
                                                device_memory_stats,
                                                peak_flops)
 from bigdl_tpu.observability.tracing import (HeadSampler, RequestTrace,
-                                             TraceContext,
-                                             tracing_manifest)
+                                             TraceContext)
 from bigdl_tpu.observability.watchdogs import (LossSpikeWatchdog,
                                                MemoryWatchdog,
                                                NonFiniteWatchdog,
@@ -76,7 +75,7 @@ __all__ = [
     "BlockingStepTimer", "TimingAuditor",
     "Counter", "Gauge", "Histogram", "MetricsRegistry",
     "MetricsExporter", "SloObjective", "SloTracker",
-    "TraceContext", "HeadSampler", "RequestTrace", "tracing_manifest",
+    "TraceContext", "HeadSampler", "RequestTrace",
     "read_trace_events",
     "MemoryLedger", "tree_bytes", "is_oom_error",
 ]

@@ -1201,7 +1201,7 @@ class SloTracker:
             self.observe(obj.name, values)
 
     def observe(self, name, values, t=None):
-        """Feed samples directly (bench drills, tests); evaluates the
+        """Feed samples directly (drills, tests); evaluates the
         objective's alerts after ingestion."""
         obj = next((o for o in self.objectives if o.name == name), None)
         if obj is None:

@@ -1,15 +1,14 @@
 """Trusted timing: blocking step timers and MFU triangulation.
 
 The measurement layer must be unable to lie before any step-time claim
-can land (ROADMAP item 1: BENCH_r02 published a 2.74 "MFU" that was an
+can land (a round-2 record published a 2.74 "MFU" that was an
 async-dispatch artifact -- the host clocked dispatches, not execution --
 and was judged down 20x).  Two pieces enforce that here:
 
 - ``BlockingStepTimer`` -- serial-dependency, ``block_until_ready``-
   fenced per-step timing.  ``step_blocked_s`` (the fenced time from
   just before dispatch to the step's outputs being READY on device) is
-  the ONLY number the MFU math in bench.py and tools/obs_report.py
-  publishes.  The fence defeats async dispatch and pipelining, so it is
+  the ONLY number the MFU math in tools/obs_report.py publishes.  The fence defeats async dispatch and pipelining, so it is
   a measurement mode, not a throughput mode.
 
 - ``TimingAuditor`` -- triangulates three INDEPENDENT estimates of the
@@ -25,7 +24,7 @@ and was judged down 20x).  Two pieces enforce that here:
                              the device's own busy time per step, or
                              shorter than the serial dispatch-chain time
                              -- pipelining leaked through the fence
-                             (exactly the BENCH_r02 failure)
+                             (exactly the round-2 failure)
   ``invalid:off_tpu``        the run never reached the accelerator (CPU
                              fallback); MFU is not chip-meaningful
   ``invalid:impossible``     the published MFU is outside (0, 1] -- the
@@ -33,20 +32,17 @@ and was judged down 20x).  Two pieces enforce that here:
                              broken, not the chip fast
   =========================  ============================================
 
-Every step-time BENCH record (the ResNet MFU measurements -- the
-host-side A/B micro-benches measure ratios, not device step time, and
-carry no verdict) carries the verdict top-level (``"trust"``) with the
-full audit under ``extra["timing_audit"]``; training runs under
-``set_blocking_timing(True)`` record a ``kind: "timing_audit"``
-telemetry event that obs_report's Profiling section surfaces.
+Training runs under ``set_blocking_timing(True)`` record a
+``kind: "timing_audit"`` telemetry event that obs_report's Profiling
+section surfaces.
 
 No top-level jax import: ``tools/obs_report.py`` (which must run
 anywhere the artifacts were copied) can load this module standalone,
 and ``BlockingStepTimer`` imports jax lazily only when fencing.
 
-Audit an existing artifact from the command line::
+Audit a record file from the command line::
 
-    python -m bigdl_tpu.observability.profiling BENCH_r06.json
+    python -m bigdl_tpu.observability.profiling record.json
 """
 
 import json
@@ -62,7 +58,7 @@ INVALID_IMPOSSIBLE = "invalid:impossible"
 def percentile(sorted_vals, q):
     """Nearest-rank percentile over a pre-sorted list -- THE one
     definition: ``tools/obs_report.py`` aliases this function (by
-    spec-load, no package import), so a bench record and its run
+    spec-load, no package import), so a timer's summary and its run
     report can never disagree on a p50."""
     if not sorted_vals:
         return None
@@ -273,8 +269,7 @@ class TimingAuditor:
         }
 
     def audit_record(self, record):
-        """Audit a BENCH-style record dict (the gate every perf PR's
-        BENCH_*.json passes through).  Reads the published timing fields
+        """Audit a record dict.  Reads the published timing fields
         from ``record["extra"]`` (or ``record`` itself when no extra
         nesting): ``platform``, ``sec_per_step_blocked`` (falling back
         to ``sec_per_step``), ``sec_per_step_chained``,
@@ -299,12 +294,12 @@ class TimingAuditor:
 
 
 def main(argv=None):
-    """Audit a BENCH_*.json artifact: print the TimingAuditor verdict."""
+    """Audit a record file: print the TimingAuditor verdict."""
     import argparse
 
     ap = argparse.ArgumentParser(
-        description="stamp a trust verdict on a BENCH record")
-    ap.add_argument("record", help="path to a BENCH_*.json file")
+        description="stamp a trust verdict on a timing record")
+    ap.add_argument("record", help="path to a record's JSON file")
     ap.add_argument("--tolerance", type=float, default=0.10)
     args = ap.parse_args(argv)
     with open(args.record) as f:

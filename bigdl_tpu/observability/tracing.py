@@ -68,14 +68,6 @@ def default_sample_rate():
         return _DEFAULT_RATE
 
 
-def tracing_manifest(rate=None):
-    """The tracing-config block bench records stamp into ``extra`` so
-    ``tools/perf_gate.py`` can refuse numbers measured with
-    always-sample tracing enabled."""
-    r = default_sample_rate() if rate is None else float(rate)
-    return {"sample_rate": r, "always_sample": r >= 1.0}
-
-
 class TraceContext:
     """trace_id / span_id / parent_id / sampled -- one span's identity.
 

@@ -869,8 +869,7 @@ class DistriOptimizer(BaseOptimizer):
 
         # the flat plane's per-step wire footprint (both collectives),
         # stamped on every step event: wire_bytes / compression_ratio
-        # feed the obs_report "Communication" section and the
-        # BENCH_QCOMM A/B
+        # feed the obs_report "Communication" section
         comm_fields = (uncompressed_wire_summary(flat_space.padded_size)
                        if spec is None
                        else spec.wire_summary(flat_space.padded_size))

@@ -194,11 +194,10 @@ class BaseOptimizer:
         ``jax.block_until_ready`` and stamp ``step_blocked_s`` -- the
         fenced dispatch-to-outputs-ready time -- on every step event.
         ``step_blocked_s`` is the ONLY number the MFU math in
-        ``tools/obs_report.py`` and ``bench.py`` publishes; un-fenced
-        wall clocks measure dispatch, not execution (the BENCH_r02
-        2.74-"MFU" async-dispatch artifact).  The fence defeats the
+        ``tools/obs_report.py`` publishes; un-fenced wall clocks
+        measure dispatch, not execution.  The fence defeats the
         async pipelining ``set_sync_every`` exists to exploit, so this
-        is a MEASUREMENT mode for bench legs and timing audits, not a
+        is a MEASUREMENT mode for timing audits, not a
         production throughput default.  At the end of the run a
         ``kind: "timing_audit"`` event records the ``TimingAuditor``
         trust verdict for the run's blocked timing."""

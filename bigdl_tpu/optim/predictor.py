@@ -191,8 +191,7 @@ class PredictionService:
     path with a ``ServingEngine``: concurrent requests coalesce into one
     padded, bucketed device batch per dispatch tick (``max_batch_size``
     / ``max_wait_ms``), optionally sharded over ``mesh``'s data axis --
-    the high-throughput path (``BENCH_SERVE=1 python bench.py`` A/Bs
-    the two).  NOTE: with coalescing, ``num_threads`` no longer bounds
+    the high-throughput path.  NOTE: with coalescing, ``num_threads`` no longer bounds
     in-flight requests -- admission control moves to the engine's
     bounded queue (``queue_capacity``, default 1024, back-pressuring
     ``submit``), because queued requests are cheap host-side rows, not

@@ -36,7 +36,7 @@ from bigdl_tpu.utils.errors import (CheckpointCorruptionError,
 log = logging.getLogger("bigdl_tpu.optim")
 
 #: restart causes a recovery event may carry (the schema pin in
-#: tests/test_bench_contract.py holds this closed set)
+#: tests/test_recovery.py holds this closed set)
 RECOVERY_CAUSES = ("exception", "watchdog_halt", "process_death")
 
 #: keys every ``kind: "recovery"`` telemetry event carries

@@ -762,8 +762,8 @@ class ServingEngine:
         ``bucket``, evaluated synchronously outside the queue.  Within
         one bucket shape XLA's reduction order is fixed and eval-mode
         rows are independent, so this is bit-exact to the same request
-        served in a coalesced tick of the same bucket (the bench's
-        identical-outputs witness)."""
+        served in a coalesced tick of the same bucket (the
+        identical-outputs witness of the tests)."""
         x = self._form_batch([feature], bucket)
         y = self._backend.eval(x, tick=0)
         return jax.tree.map(lambda a: np.asarray(a)[0], y)

@@ -47,7 +47,7 @@ prefix blocks shared across requests, long prompts prefilled in
 fixed-size chunks interleaved with decode ticks, and temperature /
 top-k / top-p sampling drawn inside the decode step
 (serving/sampling.py).  The contiguous scheduler stays as the greedy
-A/B baseline the bench compares against (docs/performance.md, "Paged
+baseline the paged one is compared against (docs/performance.md, "Paged
 KV cache").
 """
 
@@ -325,8 +325,8 @@ class GenerateScheduler:
                                             self._cache_dtype)
 
     def cache_bytes(self) -> int:
-        """Device bytes the KV cache actually holds (the bench's
-        peak-cache-bytes comparison reads this on both schedulers)."""
+        """Device bytes the KV cache actually holds (the same reading
+        on both schedulers)."""
         return int(sum(leaf.size * leaf.dtype.itemsize
                        for leaf in jax.tree.leaves(self._cache)))
 
@@ -1025,8 +1025,8 @@ class PagedGenerateScheduler(GenerateScheduler):
         self.max_blocks_per_seq = -(-eff_max // self.block_size)
         #: pool size; the default matches the contiguous pool's token
         #: capacity (slots x max_len) -- pass something smaller to
-        #: actually cap memory (the bench does; prefix sharing means a
-        #: smaller pool still holds the same traffic)
+        #: actually cap memory (prefix sharing means a smaller pool
+        #: still holds the same traffic)
         self.num_blocks = int(num_blocks) if num_blocks is not None \
             else int(slots) * self.max_blocks_per_seq
         if prefill_chunk is None:
@@ -1508,7 +1508,7 @@ class SpeculativeScheduler(PagedGenerateScheduler):
     def cache_bytes(self) -> int:
         """Verifier pool + drafter pool -- the speculative price is
         BOTH pools resident, and hiding the drafter's share would
-        falsify the bench's peak-bytes comparison."""
+        falsify a comparison of peak bytes."""
         return super().cache_bytes() + int(sum(
             leaf.size * leaf.dtype.itemsize
             for leaf in jax.tree.leaves(self._dcache)))

@@ -131,7 +131,7 @@ class BlockAllocator:
                     "sequences": len(self._seqs),
                     "kv_dtype": self.kv_dtype,
                     # allocator-reported bytes (ROADMAP item 3's rule:
-                    # obs_report and the bench cite these, never
+                    # obs_report cites these, never
                     # hand-computed dtype math); None until the pool
                     # owner measured the device tree
                     "bytes_per_block": pb,

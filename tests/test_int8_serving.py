@@ -3,7 +3,7 @@ quantizer (`nn.quantize_model` over Sequential / Graph / TransformerLM in
 both param layouts), the `ServingEngine(quantize=...)` path on all three
 device layouts, the fp32-vs-int8 accuracy-delta gate riding the
 `param_refresh` audit path, the serving-precision telemetry stamp, and
-the `BENCH_SERVE` fp32-vs-int8 A/B smoke."""
+both precisions under the same concurrent clients."""
 
 import json
 
@@ -421,46 +421,42 @@ class TestServingPrecisionTelemetry:
 
 
 # --------------------------------------------------------------------------- #
-# BENCH_SERVE fp32-vs-int8 A/B (ISSUE-11 satellite: tier-1 smoke; the
-# full-size A/B stays in the slow tier).
+# fp32 and int8 engines under the same concurrent clients.
 # --------------------------------------------------------------------------- #
 
-class TestServeInt8BenchSmoke:
-    def test_fast_smoke(self, tmp_path):
-        """Tiny-model, one-bucket smoke of the precision A/B: record
-        shapes, the accuracy gate passing, and zero steady-state
-        recompiles on BOTH legs."""
-        import bench
+class TestInt8UnderConcurrentClients:
+    def test_same_requests_through_both_precisions(self, tmp_path):
+        """Four clients, twelve requests, one bucket, through an fp32
+        and an int8 engine over the same MLP: no compile
+        after ``precompile()`` on either, the accuracy gate passes, the
+        run report states the precision, the int8 tree is under a third
+        of the fp32 bytes and its outputs track the fp32 engine's."""
+        from concurrent.futures import ThreadPoolExecutor
 
-        rec_rps, rec_bytes = bench.run_serve_quant_bench(
-            concurrency=4, per_client=3, hidden=32, max_batch=4,
-            max_wait_ms=5.0, out_dir=str(tmp_path))
-        assert rec_rps["metric"] == "serving_int8_rps_ratio"
-        assert rec_rps["value"] > 0
-        x = rec_rps["extra"]
-        assert x["fp32"]["recompiles_after_precompile"] == 0
-        assert x["int8"]["recompiles_after_precompile"] == 0
-        assert x["fp32"]["p99_ms"] > 0 and x["int8"]["p99_ms"] > 0
-        assert x["int8"]["serving_report"]["quantized"] is True
-        assert x["fp32"]["serving_report"]["quantized"] is False
-        assert x["int8"]["accuracy_gate"]["ok"] is True
-        assert x["logit_max_rel_delta"] < 0.1
-        assert rec_bytes["metric"] == "serving_int8_model_bytes_ratio"
-        assert rec_bytes["value"] > 3.0
-        assert rec_bytes["extra"]["model_bytes_int8"] \
-            < rec_bytes["extra"]["model_bytes_fp32"]
-
-    @pytest.mark.slow
-    def test_full_ab_default_config(self):
-        """The full-size A/B at the default offered load: the ~4x bytes
-        contract (>= 3.5x floor) and a sane rps ratio, gate passing."""
-        import bench
-
-        rec_rps, rec_bytes = bench.run_serve_quant_bench()
-        assert rec_bytes["value"] >= 3.5
-        assert rec_rps["extra"]["int8"]["recompiles_after_precompile"] == 0
-        assert rec_rps["extra"]["fp32"]["recompiles_after_precompile"] == 0
-        assert rec_rps["extra"]["int8"]["accuracy_gate"]["ok"] is True
-        # no promised rps floor off-TPU, but the ratio must be a real,
-        # finite measurement in a sane band
-        assert 0.2 < rec_rps["value"] < 5.0
+        m = _mlp(hidden=64)
+        xs = _xs(256)
+        outs, model_bytes_of = {}, {}
+        for name, kw in (("fp32", {}),
+                         ("int8", {"quantize": True, "accuracy_gate": {
+                             "features": xs[:64],
+                             "min_top1_agreement": 0.98}})):
+            run_dir = tmp_path / name
+            tel = StepTelemetry(str(run_dir), run_name="serve",
+                                trace=False)
+            with ServingEngine(m, max_batch_size=4, max_wait_ms=5.0,
+                               telemetry=tel, **kw) as eng:
+                eng.precompile()
+                before = backend_compile_count()
+                with ThreadPoolExecutor(4) as clients:
+                    outs[name] = list(clients.map(eng.predict, xs[:12]))
+                assert backend_compile_count() - before == 0
+                model_bytes_of[name] = eng.serving_model_bytes()
+                if name == "int8":
+                    assert eng._gate_detail["ok"] is True
+            tel.close()
+            report = _obs_report().build_report(str(run_dir))
+            assert report["serving"]["quantized"] is (name == "int8")
+        assert model_bytes_of["int8"] * 3 < model_bytes_of["fp32"]
+        delta = max(np.abs(q - f).max()
+                    for q, f in zip(outs["int8"], outs["fp32"]))
+        assert delta / max(np.abs(f).max() for f in outs["fp32"]) < 0.1

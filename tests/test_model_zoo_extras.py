@@ -90,7 +90,7 @@ class TestCliMains:
 
     @pytest.mark.slow
     def test_perf_driver_token_models(self):
-        """The LM rows (BASELINE.md SimpleRNN throughput; transformer
+        """The LM rows (the reference's SimpleRNN throughput; transformer
         flagship) run through the same fused-step perf harness.  Slow
         tier (~27s of compiles); test_perf_driver pins the harness."""
         from bigdl_tpu.models import perf

@@ -393,7 +393,7 @@ class TestInt8ErrorFeedback:
 
 
 def _fit_distri(compression, run_dir=None, steps=6, health_every=None,
-                ckpt=None, ckpt_every=3, resume=False, seed=0):
+                ckpt=None, ckpt_every=3, resume=False, seed=0, model=None):
     from bigdl_tpu.observability import StepTelemetry
     from bigdl_tpu.utils.engine import Engine
 
@@ -405,7 +405,7 @@ def _fit_distri(compression, run_dir=None, steps=6, health_every=None,
     y = rng.integers(0, 5, n).astype("int32")
     from bigdl_tpu.dataset import SampleToMiniBatch, array_dataset
     ds = array_dataset(x, y) >> SampleToMiniBatch(batch)
-    model = _mlp()
+    model = model or _mlp()
     opt = optim.DistriOptimizer(model, ds, nn.CrossEntropyCriterion(),
                                 optim.SGD(learning_rate=0.1),
                                 grad_compression=compression)
@@ -639,36 +639,26 @@ class TestDriverWiring:
             CompressionSpec(wire="int8", error_feedback=True))
         assert CompressionSpec.parse(opt.grad_compression).quantized
 
-
-class TestQcommBenchSmoke:
-    def test_fast_smoke(self, tmp_path):
-        """Tier-1 smoke of the BENCH_QCOMM leg: record shape + the
-        wire-byte arithmetic (the 3.5x floor is exact accounting, so
-        it holds even in the tiny configuration)."""
-        import bench
-
-        # hidden=128 (~19k params): big enough that the int8 layout's
-        # block-rounding padding is amortized and the raw cross-leg
-        # byte ratio clears the floor, small enough for tier-1
-        rec = bench.run_qcomm_bench(steps=3, batch=16, hidden=128,
-                                    out_dir=str(tmp_path))
-        assert rec["metric"] == "qcomm_grad_wire_byte_reduction"
-        assert rec["value"] >= 3.5
-        assert rec["vs_baseline"] >= 1.0
-        legs = rec["extra"]["legs"]
-        assert set(legs) == {"fp32", "bf16", "int8_ef"}
-        for leg in legs.values():
-            assert np.isfinite(leg["loss_last"])
-            assert leg["sec_per_step_p50"] > 0
-        assert legs["fp32"]["compression_ratio"] == 1.0
-        assert legs["bf16"]["grad_compression_ratio"] == 2.0
-
-    @pytest.mark.slow
-    def test_full_sweep(self):
-        """The full A/B at the documented defaults (slow tier)."""
-        import bench
-
-        rec = bench.run_qcomm_bench()
-        assert rec["value"] >= 3.5
-        for leg in rec["extra"]["legs"].values():
-            assert np.isfinite(leg["loss_last"])
+    def test_raw_wire_bytes_across_legs(self, tmp_path):
+        """fp32, bf16 and int8 + error feedback on a model of about 19k
+        parameters (large enough that the int8 layout's rounding of
+        chunks to whole blocks is amortized): the RAW gradient bytes of
+        the fp32 leg's events over the int8 leg's are at least 3.5,
+        bf16 is exactly 2x, and each leg's loss is finite."""
+        legs = {"fp32": None, "bf16": "bf16",
+                "int8_ef": CompressionSpec(wire="int8", block_size=256,
+                                           error_feedback=True)}
+        event = {}
+        for name, compression in legs.items():
+            model = (nn.Sequential().add(nn.Linear(12, 128)).add(nn.ReLU())
+                     .add(nn.Linear(128, 128)).add(nn.ReLU())
+                     .add(nn.Linear(128, 5)))
+            run_dir = str(tmp_path / name)
+            opt = _fit_distri(compression, run_dir=run_dir, steps=3,
+                              model=model)
+            assert np.isfinite(opt.driver_state["loss"])
+            event[name] = [e for e in _events(run_dir)
+                           if e["kind"] == "step"][-1]
+        assert (event["fp32"]["grad_wire_bytes"]
+                / event["int8_ef"]["grad_wire_bytes"]) >= 3.5
+        assert event["bf16"]["grad_compression_ratio"] == 2.0

@@ -714,6 +714,54 @@ class TestRecoveryReporting:
         json.dumps(mod._json_safe(rep), allow_nan=False)   # strict JSON
 
 
+class TestRecoveryEventContract:
+    """ISSUE-8 pin: the ``kind: "recovery"`` telemetry event schema the
+    RunSupervisor emits (docs/robustness.md) -- obs_report's Recovery
+    section and any external consumer parse exactly these keys."""
+
+    def test_recovery_event_schema(self):
+        from bigdl_tpu.optim.recovery import (RECOVERY_CAUSES,
+                                              RECOVERY_EVENT_KEYS)
+
+        events = []
+
+        class Sink:                    # minimal telemetry duck type
+            def record(self, kind, **fields):
+                events.append({"kind": kind, **fields})
+
+        class Dummy:
+            checkpoint_path = None
+            sharded_checkpoint_path = None
+            driver_state = {"neval": 7}
+
+            def __init__(self, fail):
+                self.fail = fail
+
+            def optimize(self):
+                if self.fail:
+                    raise RuntimeError("preempted")
+
+        sup = RunSupervisor(max_restarts=1, backoff_base_s=0.5,
+                            telemetry=Sink(), sleep=lambda s: None)
+        sup.run(lambda attempt: Dummy(fail=(attempt == 0)))
+        assert len(events) == 1
+        ev = events[0]
+        assert ev["kind"] == "recovery"
+        # the closed key set, all present even when unknown (None)
+        assert set(RECOVERY_EVENT_KEYS) <= set(ev)
+        assert ev["cause"] in RECOVERY_CAUSES
+        assert ev["restart"] == 1
+        assert ev["at_step"] == 7
+        assert ev["backoff_s"] == 0.5
+        assert ev["snapshot"] is None and ev["steps_replayed"] is None
+        json.dumps(ev)                 # JSONL-ready
+
+    def test_recovery_is_durable_kind(self):
+        from bigdl_tpu.observability.telemetry import DURABLE_KINDS
+
+        assert "recovery" in DURABLE_KINDS
+
+
 # --------------------------------------------------------------------------- #
 # Slow tier: the SIGKILL acceptance drill (ISSUE 8 acceptance criteria).
 # --------------------------------------------------------------------------- #

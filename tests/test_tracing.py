@@ -43,8 +43,7 @@ from bigdl_tpu.observability.metrics import MetricsRegistry
 from bigdl_tpu.observability.tracing import (TRACE_SAMPLE_ENV,
                                              HeadSampler, RequestTrace,
                                              TraceContext,
-                                             default_sample_rate,
-                                             tracing_manifest)
+                                             default_sample_rate)
 from bigdl_tpu.serving import (FleetOverloadedError,
                                FleetUnavailableError, InProcessReplica,
                                ServingEngine, ServingFleet)
@@ -202,11 +201,6 @@ class TestHeadSampler:
         assert default_sample_rate() == 0.01    # fall back, don't crash
         monkeypatch.delenv(TRACE_SAMPLE_ENV)
         assert default_sample_rate() == 0.01
-
-    def test_tracing_manifest_flags_always_sample(self):
-        assert tracing_manifest(1.0) == {"sample_rate": 1.0,
-                                         "always_sample": True}
-        assert tracing_manifest(0.05)["always_sample"] is False
 
 
 class TestRequestTrace:

@@ -4,8 +4,8 @@ codec and its restricted pickle fallback, multiplexed
 ``WireClient``/``WirePool`` semantics (including eviction + re-dial
 after a SIGKILL'd peer), blockwise-int8 weight distribution through a
 real ``stage_tree`` round trip, the fleet's ``wire`` telemetry events
--> metrics bridge -> obs_report rendering, and the ``BENCH_WIRE``
-smoke."""
+-> metrics bridge -> obs_report rendering, and the pooled binary
+wire against the in-process engine."""
 
 import base64
 import importlib.util
@@ -586,25 +586,62 @@ class TestWireObservability:
 
 
 # --------------------------------------------------------------------------- #
-# BENCH_WIRE smoke: both legs gate-clean on a tiny config.
+# The binary wire against the engine it fronts, under concurrent clients.
 # --------------------------------------------------------------------------- #
 
-class TestWireBenchSmoke:
-    def test_run_wire_bench_smoke(self):
-        spec = importlib.util.spec_from_file_location(
-            "_bench_wire", os.path.join(REPO, "bench.py"))
-        bench = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(bench)
-        rec_rps, rec_bytes = bench.run_wire_bench(
-            concurrency=2, per_client=4, hidden=128)
-        assert rec_rps["metric"] == "fleet_wire_rps_ratio"
-        assert rec_rps["extra"]["recompiles_after_precompile"] == 0
-        assert rec_rps["extra"]["pickle_fallbacks"] == 0
-        assert rec_rps["extra"]["outputs_bit_identical"] is True
-        assert rec_rps["value"] > 0
-        assert rec_bytes["metric"] == "fleet_wire_bytes_ratio"
-        # the bytes ratio is exact anywhere: int8 staging must undercut
-        # 0.35x the fp32 bytes (vs_baseline >= 1 iff it does)
-        assert rec_bytes["value"] >= 1 / 0.35
-        assert rec_bytes["vs_baseline"] >= 1.0
-        assert rec_bytes["extra"]["int8_max_abs_err"] < 0.1
+class TestBinaryWireAgainstInProcessEngine:
+    def test_pooled_predict_and_staged_weight_bytes(self):
+        """Two clients share a ``WirePool`` of two connections: every
+        answer is, bit for bit, what the engine answers in process at
+        some rung of its batch ladder (a tick's bucket depends on
+        timing, the bits on the bucket), requests sent one at a time
+        equal the in-process ``predict``, no array rode pickle, nothing
+        compiled; and the serving tree staged as blockwise int8 costs at
+        most 0.35x its fp32 bytes, both measured on the wire."""
+        from concurrent.futures import ThreadPoolExecutor
+
+        from bigdl_tpu.observability.watchdogs import backend_compile_count
+
+        RNG.set_seed(0)
+        model = (nn.Sequential().add(nn.Linear(16, 128)).add(nn.ReLU())
+                 .add(nn.Linear(128, 128)).add(nn.ReLU())
+                 .add(nn.Linear(128, 10)))
+        model.build(jax.ShapeDtypeStruct((2, 16), jnp.float32))
+        xs = np.random.default_rng(0).standard_normal((8, 16)) \
+            .astype("float32")
+        eng = ServingEngine(model, max_batch_size=2, max_wait_ms=1.0)
+        srv = ReplicaServer(eng, port=0, transport="binary").start()
+        pool = WirePool("127.0.0.1", srv.port, size=2)
+        cli = WireClient("127.0.0.1", srv.port)
+        try:
+            eng.precompile()
+            before = backend_compile_count()
+
+            def over_the_wire(x):
+                return np.asarray(pool.request("predict", feature=x))
+
+            with ThreadPoolExecutor(2) as clients:
+                got = list(clients.map(over_the_wire, xs))
+            for x, y in zip(xs, got):
+                assert any(np.array_equal(y, eng.predict_at(x, b))
+                           for b in eng.ladder)
+            for x in xs[:4]:
+                np.testing.assert_array_equal(over_the_wire(x),
+                                              eng.predict(x))
+            assert pool.stats()["pickle_fallbacks"] == 0
+            assert backend_compile_count() - before == 0
+
+            params = eng.model.parameters()[0]
+            staged = {}
+            for wire, tree in (("fp32", params),
+                               ("int8", quantize_tree_for_wire(params))):
+                token, staged[wire], _ = cli.request_ex(
+                    "stage_tree", rpc_timeout=120.0, params=tree,
+                    weight_wire=wire)
+                cli.request_ex("release", token=token)
+            assert staged["int8"] <= 0.35 * staged["fp32"]
+        finally:
+            cli.close()
+            pool.close()
+            srv.close()
+            eng.close()

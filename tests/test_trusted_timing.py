@@ -1,7 +1,6 @@
 """Trusted timing (ISSUE 6): BlockingStepTimer, TimingAuditor
 triangulation + trust verdicts, the driver-loop blocking mode across
-drivers, the obs_report Profiling section schema, and the bench probe's
-honest outcome recording.
+drivers, and the obs_report Profiling section schema.
 
 The tier-1 acceptance pins live here: a deliberately async-dispatch-
 mistimed synthetic record MUST be flagged ``suspect:async_dispatch``,
@@ -129,9 +128,9 @@ class TestTimingAuditor:
 
 
 class TestAuditRecord:
-    """The record-level gate every perf PR's BENCH_*.json passes
-    through, incl. the tier-1 acceptance pin: a deliberately
-    async-dispatch-mistimed synthetic record flags suspect."""
+    """``audit_record`` over a record dict, incl. the tier-1 acceptance
+    pin: a deliberately async-dispatch-mistimed synthetic record flags
+    suspect."""
 
     def _record(self, **extra):
         base = {
@@ -181,7 +180,7 @@ class TestAuditRecord:
 
     def test_cli_audits_a_record_file(self, tmp_path, capsys):
         from bigdl_tpu.observability import profiling
-        path = tmp_path / "BENCH_x.json"
+        path = tmp_path / "record.json"
         path.write_text(json.dumps(self._record(
             sec_per_step_blocked=0.02, sec_per_step_chained=0.02)))
         rc = profiling.main([str(path)])
@@ -339,8 +338,8 @@ class TestObsReportProfiling:
         return str(tmp_path)
 
     def test_json_schema_pin(self, run_dir, capsys):
-        """The machine-readable profiling-section contract CI and bench
-        assert on: these keys may grow but must not move or vanish."""
+        """The machine-readable profiling-section contract: these keys
+        may grow but must not move or vanish."""
         obs = _load_by_path("_t_obs_report2", "tools/obs_report.py")
         assert obs.main([run_dir, "--format", "json"]) == 0
         rep = json.loads(capsys.readouterr().out)   # strict JSON
@@ -403,71 +402,3 @@ class TestObsReportProfiling:
         assert rep["steps"]["mfu_basis"] == "wall_s"
         assert "not publishable" in obs.format_report(rep)
 
-
-# --------------------------------------------------------------------------- #
-# Bench probe: fast, cancellable, honestly recorded
-# --------------------------------------------------------------------------- #
-
-class TestBenchProbe:
-    def _probe(self, spawn, probe_timeout=60, attempts=3):
-        import bench
-
-        failures = []
-        info, left = bench._probe_device(
-            lambda want, stage, minimum=30: want, probe_timeout,
-            attempts, failures, spawn=spawn)
-        return info, left, failures
-
-    def test_tpu_probe_keeps_attempts(self):
-        info, left, failures = self._probe(
-            lambda env, t: ({"probe": "tpu"}, None))
-        assert info["probe_result"] == "tpu"
-        assert info["probe_sec"] is not None
-        assert left == 3 and not failures
-
-    def test_cpu_probe_skips_attempts(self):
-        info, left, failures = self._probe(
-            lambda env, t: ({"probe": "cpu"}, None))
-        assert info["probe_result"] == "cpu"
-        assert left == 0
-        assert any("not tpu" in f for f in failures)
-
-    def test_timeout_probe_skips_attempts(self):
-        info, left, failures = self._probe(
-            lambda env, t: (None, "timeout after 60s; stderr tail: "))
-        assert info["probe_result"] == "timeout"
-        assert left == 0
-        assert any("never answered" in f for f in failures)
-
-    def test_transient_error_keeps_retry_budget(self):
-        # round-1's failure story: fast transient init errors must keep
-        # the full retry budget
-        info, left, failures = self._probe(
-            lambda env, t: (None, "rc=1; stderr tail: connection reset"))
-        assert info["probe_result"] == "error"
-        assert left == 3
-        assert any("connection reset" in f for f in failures)
-
-    def test_no_budget_skips_probe(self):
-        import bench
-
-        failures = []
-        info, left = bench._probe_device(
-            lambda want, stage, minimum=30: None, 60, 3, failures,
-            spawn=lambda env, t: pytest.fail("must not spawn"))
-        assert info == {"probe_sec": None,
-                        "probe_result": "skipped:budget"}
-        assert left == 3
-
-    def test_probe_child_spawn_env(self):
-        """The real probe spawns with BENCH_PROBE=1 and the configured
-        timeout -- the child prints its platform and exits."""
-        seen = {}
-
-        def spawn(env, t):
-            seen.update(env=env, timeout=t)
-            return {"probe": "tpu"}, None
-
-        self._probe(spawn, probe_timeout=42)
-        assert seen["env"] == {"BENCH_PROBE": "1"}
-        assert seen["timeout"] == 42

@@ -29,8 +29,8 @@ into one report (with a per-attempt summary) and the supervisor's
 covers the whole supervised run instead of one report per attempt.
 
 ``--format json`` emits the same dict the text renderer consumes, with
-non-finite floats mapped to null (strictly valid JSON), so CI and
-bench.py can assert on health/occupancy numbers.  The reader tolerates
+non-finite floats mapped to null (strictly valid JSON), so scripts
+can assert on health/occupancy numbers.  The reader tolerates
 a truncated final JSONL line / undecodable bytes from a crashed run.
 A run dir whose artifacts carry ZERO events worth reporting (no steps,
 no serving/recovery/health/validation/memory) exits nonzero: a hollow
@@ -64,8 +64,8 @@ load_device_planes = _xplane.load_device_planes
 
 # same mechanism for observability/profiling.py (it has no top-level jax
 # import by design): its nearest-rank percentile is THE one definition,
-# shared with BlockingStepTimer's summaries and bench.py's serve
-# percentiles, so a bench record and its run report can never disagree
+# shared with BlockingStepTimer's summaries, so a timer's summary and
+# its run report can never disagree
 _pspec = importlib.util.spec_from_file_location(
     "_obs_profiling",
     os.path.join(REPO, "bigdl_tpu", "observability", "profiling.py"))
@@ -328,8 +328,7 @@ def _serving_section(other, header=None):
     if gen:
         toks = sum(int(e.get("tokens", 0) or 0) for e in gen)
         # the rendered figure is "tok/s WHILE DECODING": decode ticks
-        # only, so prefill-heavy runs don't dilute the number an
-        # operator compares against the bench's per-leg decode rate
+        # only, so prefill-heavy runs don't dilute the decode rate
         dec = [e for e in gen if e["tick_kind"] == "decode"]
         dtoks = sum(int(e.get("tokens", 0) or 0) for e in dec)
         dwall = sum(e.get("wall_s", 0.0) for e in dec

@@ -16,13 +16,12 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 def _bench(compiled, args, steps=8, chain_idx=2):
     """Dispatch-N-then-fetch-a-VALUE timing: a result value cannot exist
-    before its execution completes (docs/performance.md, round-3 timing
-    investigation).  Each dispatch's input batch is perturbed by
-    ``0 * (a scalar of the previous output)`` -- a structural data
-    dependency chaining step i+1 onto step i, so the final value fetch
-    proves ALL N executed serially even if the transport overlapped
-    independent dispatches (same guarantee as bench.py's donated chain;
-    the extra elementwise add costs ~0.2 ms against a >15 ms step)."""
+    before its execution completes.  Each dispatch's input batch is
+    perturbed by ``0 * (a scalar of the previous output)`` -- a
+    structural data dependency chaining step i+1 onto step i, so the
+    final value fetch proves ALL N executed serially even if the
+    transport overlapped independent dispatches (the extra elementwise
+    add costs ~0.2 ms against a >15 ms step)."""
     import jax
 
     args = list(args)
