@@ -71,9 +71,11 @@ class MultiHeadAttention(Module):
         self.seq_mode = seq_mode
         #: "auto": the Pallas flash kernel (ops/flash_attention.py) on TPU
         #: when T is block-aligned (the forward streams K/V a block at a
-        #: time, so no length is too long for it; the two decode kernels
-        #: keep a head's whole K/V in VMEM and also ask ``kv_blocks_fit``);
-        #: plain attention otherwise.  "interpret" forces the kernel in
+        #: time, so no length is too long for it; the contiguous decode
+        #: kernel keeps a head's whole K/V in VMEM and also asks
+        #: ``kv_blocks_fit``; the paged one streams a slot's blocks and
+        #: asks only that a block be whole tiles); plain attention
+        #: otherwise.  "interpret" forces the kernel in
         #: interpreter mode (CPU tests).
         self.use_flash = use_flash
 
@@ -90,10 +92,10 @@ class MultiHeadAttention(Module):
             return t % 8 == 0
         return t % 128 == 0
 
-    def _kv_fit(self, rows, dtype, quantized=False):
+    def _kv_fit(self, rows, dtype):
         from bigdl_tpu.ops.flash_attention import kv_blocks_fit
 
-        return kv_blocks_fit(rows, self.head_dim, dtype, quantized)
+        return kv_blocks_fit(rows, self.head_dim, dtype)
 
     def _flash_ok(self, t):
         if self.use_flash == "never" or self.seq_axis_name is not None:
@@ -244,7 +246,7 @@ class MultiHeadAttention(Module):
     def init_paged_cache(self, num_blocks: int, block_size: int,
                          dtype=jnp.float32):
         """Per-layer K/V BLOCK POOL for paged decode: fixed-shape
-        ``(num_blocks, block_size, heads, head_dim)`` zero tensors that
+        ``(num_blocks, block_size, heads * head_dim)`` zero tensors that
         ``_apply_paged`` reads and writes THROUGH per-sequence block
         tables (serving/paging.py).  Unlike ``init_cache`` the leading
         axis is physical blocks, not slots: memory scales with tokens
@@ -252,18 +254,25 @@ class MultiHeadAttention(Module):
         caller includes the trash block in ``num_blocks`` (by
         convention the last id).
 
+        Heads and head_dim share the last axis so that a block is one
+        contiguous piece of device memory.  A TPU array's last two axes
+        are tiled (8 x 128 fp32), and the compiler stores a
+        ``(..., heads, 64)`` array with the BLOCK axis on the lanes
+        rather than pad 64 to 128: a block's values then lie 512 bytes
+        apart, nothing can fetch one, and every step re-lays the whole
+        pool out and back (PERF.md section 6, PR 32).
+
         ``dtype=jnp.int8`` selects the QUANTIZED block layout: int8
-        K/V payloads plus fp32 absmax scales -- one scale per (position,
-        head) ``head_dim`` vector, i.e. the ops/quantization.py
-        blockwise format with the quantization block = ``head_dim``.
-        The scale leaves keep the payload's 4-D ``(blocks, block_size,
-        heads, 1)`` rank so every pool consumer that tree-maps by rank
+        K/V payloads plus fp32 absmax scales ``(num_blocks, block_size,
+        heads)`` -- one scale per (position, head) ``head_dim`` vector,
+        i.e. the ops/quantization.py blockwise format with the
+        quantization block = ``head_dim``.  The scale leaves keep the
+        payload's rank so every pool consumer that tree-maps by rank
         (block copies, donation, byte accounting) handles both layouts
         with one code path."""
-        shape = (int(num_blocks), int(block_size), self.num_heads,
-                 self.head_dim)
+        shape = (int(num_blocks), int(block_size), self.hidden_size)
         if jnp.dtype(dtype) == jnp.dtype(jnp.int8):
-            sshape = shape[:-1] + (1,)
+            sshape = shape[:-1] + (self.num_heads,)
             return {"k": jnp.zeros(shape, jnp.int8),
                     "v": jnp.zeros(shape, jnp.int8),
                     "k_scale": jnp.zeros(sshape, jnp.float32),
@@ -271,42 +280,36 @@ class MultiHeadAttention(Module):
         return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
     def _paged_quant(self, x):
-        """fp K/V vectors ``(..., heads, head_dim)`` -> (int8 payload,
-        fp32 scales ``(..., heads, 1)``) through the blockwise wire
+        """fp K/V rows ``(rows, heads * head_dim)`` -> (int8 payload,
+        fp32 scales ``(rows, heads)``) through the blockwise wire
         kernel (one absmax scale per head_dim vector; non-finite
         vectors drop to exact zero, same contract as the wire path)."""
         from bigdl_tpu.ops.quantization import quantize_blockwise
 
         q8, sc = quantize_blockwise(x.reshape(-1), self.head_dim,
                                     scale_dtype=jnp.float32)
-        return q8.reshape(x.shape), sc.reshape(x.shape[:-1] + (1,))
+        return q8.reshape(x.shape), sc.reshape(x.shape[:-1]
+                                               + (self.num_heads,))
 
     def _paged_dequant(self, q8, sc, dt):
         """Inverse of ``_paged_quant`` over gathered context blocks:
-        ``(..., heads, head_dim)`` int8 + ``(..., heads, 1)`` scales ->
+        ``(..., heads * head_dim)`` int8 + ``(..., heads)`` scales ->
         ``dt`` values."""
         from bigdl_tpu.ops.quantization import dequantize_blockwise
 
-        lead = q8.shape[:-2]
-        flat = q8.reshape(lead + (q8.shape[-2] * q8.shape[-1],))
-        out = dequantize_blockwise(flat, sc.reshape(lead + (-1,)),
-                                   self.head_dim)
-        return out.reshape(q8.shape).astype(dt)
+        return dequantize_blockwise(q8, sc, self.head_dim).astype(dt)
 
-    def _flash_paged_ok(self, num_blocks, block_size, dtype, quantized):
+    def _flash_paged_ok(self, block_size, dtype):
+        """Whether decode goes through ``flash_paged_decode_attention``:
+        in ``auto`` on a TPU, when a block is whole tiles of the pool's
+        dtype -- 8 rows of fp32, 16 of bf16, 32 of int8, by 128 lanes."""
         if self.use_flash == "never" or self.seq_axis_name is not None:
             return False
         if self.use_flash in ("always", "interpret"):
             return True
-        # on real TPU the paged kernel walks the pool in block_size
-        # strides; tiny blocks (the useful CPU/bench sizes) are far
-        # below the 128-lane tile, so auto mode only takes the kernel
-        # when blocks themselves tile -- and when each head's whole pool
-        # plane, which the kernel keeps in VMEM, fits there
-        if block_size % 128:
-            return False
-        return _on_tpu() and self._kv_fit(num_blocks * block_size, dtype,
-                                          quantized)
+        tile_rows = 32 // jnp.dtype(dtype).itemsize
+        return _on_tpu() and block_size % tile_rows == 0 \
+            and self.hidden_size % 128 == 0
 
     def _apply_paged(self, params, input, pool, tables, pos, lengths):
         """Incremental attention against a paged K/V pool.  Returns
@@ -338,19 +341,20 @@ class MultiHeadAttention(Module):
         quant = "k_scale" in pool      # int8 payload + fp32 scale leaves
         bs = pool["k"].shape[1]
         max_blocks = tables.shape[1]
+        ctx = max_blocks * bs
         trash = pool["k"].shape[0] - 1
         tables = jnp.asarray(tables, jnp.int32)
         pos = jnp.asarray(pos, jnp.int32)
         qkv = self._project_qkv(params, input)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        shape = (n, t, self.num_heads, self.head_dim)
-        q, k, v = q.reshape(shape), k.reshape(shape), v.reshape(shape)
+        q, k, v = jnp.split(qkv, 3, axis=-1)                # (n, t, d) each
+        heads = (n, t, self.num_heads, self.head_dim)
 
         def scatter(phys, off, kf, vf):
-            """Write one batch of K/V rows through the table: quantize
-            first on an int8 pool (payload + scales land at the same
-            (block, offset) address, so the table indirection, COW block
-            copies and prefix sharing are format-blind)."""
+            """Write one batch of K/V rows ``(rows, d)`` through the
+            table: quantize first on an int8 pool (payload + scales land
+            at the same (block, offset) address, so the table
+            indirection, COW block copies and prefix sharing are
+            format-blind)."""
             if quant:
                 kq, ksc = self._paged_quant(kf)
                 vq, vsc = self._paged_quant(vf)
@@ -362,16 +366,23 @@ class MultiHeadAttention(Module):
                     "v": pool["v"].at[phys, off].set(vf.astype(cdt))}
 
         def gather_ctx(new_pool, name):
-            """The row's full mapped context from the pool, dequantized
-            to the compute dtype on an int8 pool."""
-            ctx = max_blocks * bs
+            """The row's full mapped context from the pool ``(n, ctx,
+            heads, head_dim)``, dequantized to the compute dtype on an
+            int8 pool."""
             raw = jnp.take(new_pool[name], tables, axis=0).reshape(
-                n, ctx, self.num_heads, self.head_dim)
+                n, ctx, d)
             if quant:
                 sc = jnp.take(new_pool[name + "_scale"], tables,
-                              axis=0).reshape(n, ctx, self.num_heads, 1)
-                return self._paged_dequant(raw, sc, dt)
-            return raw.astype(dt)
+                              axis=0).reshape(n, ctx, self.num_heads)
+                raw = self._paged_dequant(raw, sc, dt)
+            return raw.astype(dt).reshape(n, ctx, self.num_heads,
+                                          self.head_dim)
+
+        def gathered_attention(new_pool, mask):
+            return dot_product_attention(q.reshape(heads),
+                                         gather_ctx(new_pool, "k"),
+                                         gather_ctx(new_pool, "v"),
+                                         mask=mask)
 
         if lengths is not None:                           # chunk prefill
             lengths = jnp.asarray(lengths, jnp.int32)
@@ -382,50 +393,32 @@ class MultiHeadAttention(Module):
             phys = jnp.take_along_axis(tables, logical, axis=1)
             phys = jnp.where(valid, phys, trash)
             off = gpos % bs
-            flat = (n * t,)
-            new_pool = scatter(phys.reshape(flat), off.reshape(flat),
-                               k.reshape(flat + shape[2:]),
-                               v.reshape(flat + shape[2:]))
-            ctx = max_blocks * bs
-            ctx_k = gather_ctx(new_pool, "k")
-            ctx_v = gather_ctx(new_pool, "v")
+            new_pool = scatter(phys.reshape(n * t), off.reshape(n * t),
+                               k.reshape(n * t, d), v.reshape(n * t, d))
             # (N, 1, Tc, ctx): key at logical position kp is visible to
             # the chunk token at absolute position gpos iff kp <= gpos
             mask = (jnp.arange(ctx, dtype=jnp.int32)[None, None, :]
                     <= gpos[:, :, None])[:, None]
-            y = dot_product_attention(q, ctx_k, ctx_v, mask=mask)
+            y = gathered_attention(new_pool, mask)
         else:                                             # one-token step
             if t != 1:
                 raise ValueError(
                     f"paged decode steps take one token per row, got T={t}")
             phys = jnp.take_along_axis(
                 tables, (pos // bs)[:, None], axis=1)[:, 0]
-            off = pos % bs
-            new_pool = scatter(phys, off, k[:, 0], v[:, 0])
-            if self._flash_paged_ok(pool["k"].shape[0], bs,
-                                    jnp.int8 if quant else dt, quant):
+            new_pool = scatter(phys, pos % bs, k[:, 0], v[:, 0])
+            if self._flash_paged_ok(bs, cdt):
                 from bigdl_tpu.ops.flash_attention import \
                     flash_paged_decode_attention
 
-                if quant:
-                    y = flash_paged_decode_attention(
-                        q, new_pool["k"], new_pool["v"], tables, pos,
-                        k_scale=new_pool["k_scale"],
-                        v_scale=new_pool["v_scale"],
-                        interpret=self.use_flash == "interpret")
-                else:
-                    y = flash_paged_decode_attention(
-                        q, new_pool["k"].astype(dt),
-                        new_pool["v"].astype(dt), tables, pos,
-                        interpret=self.use_flash == "interpret")
-                y = y.astype(dt)
+                y = flash_paged_decode_attention(
+                    q.reshape(heads), new_pool["k"], new_pool["v"], tables,
+                    pos, new_pool.get("k_scale"), new_pool.get("v_scale"),
+                    interpret=self.use_flash == "interpret").astype(dt)
             else:
-                ctx = max_blocks * bs
-                ctx_k = gather_ctx(new_pool, "k")
-                ctx_v = gather_ctx(new_pool, "v")
                 mask = (jnp.arange(ctx, dtype=jnp.int32)[None, :]
                         <= pos[:, None])[:, None, None, :]
-                y = dot_product_attention(q, ctx_k, ctx_v, mask=mask)
+                y = gathered_attention(new_pool, mask)
         y = y.reshape(n, t, d)
         return self._project_out(params, y, dt), new_pool
 

@@ -34,10 +34,18 @@ head's whole dq (``T x D`` fp32) stays in VMEM across both axes, the one
 part that grows with T and what ``_bwd_vmem_bytes`` asks the compiler for.
 The same blocks are skipped and masked as in the forward.
 
-The two decode kernels keep one head's whole K and V (or pool plane)
-resident in VMEM, so what the TPU compiler accepts of them is bounded by
-bytes, not only by tile alignment: ``kv_blocks_fit`` is that bound, and
-their ``auto`` gates in nn/attention.py ask it before selecting a kernel.
+``flash_decode_attention`` (the contiguous cache's decode kernel) keeps one
+head's whole K and V resident in VMEM, so what the TPU compiler accepts of
+it is bounded by bytes, not only by tile alignment: ``kv_blocks_fit`` is
+that bound, and its ``auto`` gate in nn/attention.py asks it before
+selecting the kernel.
+
+``flash_paged_decode_attention`` (the paged pool's decode kernel) leaves
+the pool in HBM as it is stored, ``(NB, bs, H * D)``, and fetches a slot's
+blocks through its block table by its own DMAs, a step's worth at a time
+into a double buffer, up to the slot's frontier and no further: VMEM holds
+two steps whatever the pool's size, so no gate bounds it by bytes; its
+gate asks only that a block be whole tiles of the pool's dtype.
 """
 
 import functools
@@ -49,7 +57,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-#: VMEM the decode kernels' resident K/V blocks may take.  The compiler's
+#: VMEM the contiguous decode kernel's resident K/V blocks may take.  The compiler's
 #: default scoped limit on a v5e is 16 MiB (``flash_decode_attention`` over
 #: a cache of 8192 fp32 positions is refused there with "size 16.00M and
 #: limit 16.00M exceeded ... by 1.0K"); the rest is left to the q/o rows
@@ -65,18 +73,11 @@ def _vmem_block_bytes(rows: int, cols: int, dtype) -> int:
     return -(-rows // sub) * sub * -(-cols // 128) * 128 * item
 
 
-def kv_blocks_fit(rows: int, head_dim: int, dtype,
-                  quantized: bool = False) -> bool:
-    """Whether a decode kernel of this file compiles with ``rows`` K/V
-    positions per head resident: the cache length for
-    ``flash_decode_attention``, the whole pool plane
-    (``num_blocks * block_size``) for ``flash_paged_decode_attention``.
-    K and V are each double-buffered by the pipeline; an int8 pool adds
-    two fp32 scale columns, which pad to full 128-lane rows."""
-    need = 4 * _vmem_block_bytes(rows, head_dim, dtype)
-    if quantized:
-        need += 4 * _vmem_block_bytes(rows, 1, jnp.float32)
-    return need <= _VMEM_KV_BUDGET
+def kv_blocks_fit(rows: int, head_dim: int, dtype) -> bool:
+    """Whether ``flash_decode_attention`` compiles with ``rows`` K/V
+    positions per head resident (the cache length).  K and V are each
+    double-buffered by the pipeline."""
+    return 4 * _vmem_block_bytes(rows, head_dim, dtype) <= _VMEM_KV_BUDGET
 
 
 #: What a masked score is set to: finite, so that no ``inf - inf`` can
@@ -448,8 +449,8 @@ def flash_attention(q, k, v, causal: bool = True,
 
 
 def _online_softmax_step(q, kblk, vblk, kpos, p, carry):
-    """One K/V block of the q_len=1 online softmax shared by the two
-    decode kernels: ``q (1, d)``, ``kblk/vblk (n, d)`` fp32, ``kpos (1,
+    """One K/V block of ``_decode_kernel``'s q_len=1 online softmax:
+    ``q (1, d)``, ``kblk/vblk (n, d)`` fp32, ``kpos (1,
     n)`` the block's logical positions, ``p`` the row's frontier."""
     acc, m, l = carry
     s = jax.lax.dot_general(q, kblk, _CONTRACT_LAST,
@@ -541,46 +542,161 @@ def flash_decode_attention(q, k, v, pos, block_k: int = 128,
     return out.transpose(0, 2, 1, 3)
 
 
-def _paged_decode_kernel(pos_ref, table_ref, q_ref, k_ref, v_ref, *rest,
-                         block_size: int, scale: float, quantized: bool):
-    """Paged decode step: like ``_decode_kernel`` but the K/V blocks
-    are INDIRECT -- loop iteration ``j`` covers logical positions
-    ``[j*bs, (j+1)*bs)``, whose K/V physically live at pool block
-    ``table[j]``; the ``pl.ds`` slice start is the table entry, read
-    from SMEM (``pos_ref (B,)`` and ``table_ref (B, max_blocks)`` are
-    scalar-prefetched whole).  The trip count is still the dynamic
-    frontier count ``ceil((pos + 1) / bs)``, so a short sequence in a
-    big pool reads only the blocks it has actually mapped.
+def _split3(x):
+    """An fp32 array as three bf16 arrays whose sum is ``x`` to the last
+    bit (8 + 8 + 8 significant bits; each residual is exact in fp32).
+    Against a 0/1 matrix the MXU then sums fp32 values at fp32 accuracy in
+    three bf16 passes, where ``precision=highest`` takes six."""
+    hi = x.astype(jnp.bfloat16)
+    r = x - hi.astype(jnp.float32)
+    mid = r.astype(jnp.bfloat16)
+    lo = (r - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    return hi, mid, lo
 
-    ``quantized=True`` adds two scale refs (per-position-per-head fp32
-    absmax scales, one per K/V ``head_dim`` vector): each int8 block
-    dequantizes IN-KERNEL -- payload * scale right after the VMEM load,
-    so the fp32 K/V context the XLA fallback would materialise in HBM
-    never exists and the pool traffic stays at int8 width."""
+
+def _paged_decode_kernel(pos_ref, table_ref, q_ref, k_hbm, v_hbm, *rest,
+                         block_size: int, blocks_per_step: int,
+                         max_blocks: int, head_dim: int, scale: float,
+                         quantized: bool):
+    """One decode slot a grid step.  The pool stays in HBM in the layout it
+    is stored in, seen as ``(NB, bs, H * D)``: the slot's blocks come
+    through its row of the table ``blocks_per_step`` at a time, each block
+    one DMA of all its heads, into the other half of a double buffer while
+    this half is computed on -- and the first step of the NEXT slot while
+    this slot's last one is, so no slot waits for its first block.  Only
+    blocks up to the frontier ``pos // bs`` are fetched and only steps up
+    to it run; rows past ``pos`` (the rest of the frontier block, and what
+    an earlier step left in the buffer) are masked.
+
+    The lanes of a K or V row hold ``(head, d)``.  The per-head sums over
+    ``d`` and the spreading of a head's weight back over its ``d`` lanes
+    are products with one 0/1 matrix ``seg (H, H * D)`` on the MXU, exact
+    in fp32 (``_split3``); scores and softmax state are ``(H, rows)`` and
+    ``(H, 1)``, positions on the lanes; the rest is fp32 on the VPU.  An
+    int8 pool's scales come gathered as ``(steps, H, rows)`` a slot and
+    multiply there: K's the scores, V's the softmax weights -- the
+    payload goes from int8 to fp32 and is never multiplied out."""
+    bs, g, d = block_size, blocks_per_step, head_dim
     if quantized:
-        ks_ref, vs_ref, o_ref = rest
-    else:
-        (o_ref,) = rest
-    d = q_ref.shape[-1]
-    bs = block_size
-    i = pl.program_id(0)
+        ks_ref, vs_ref, *rest = rest
+    o_ref, k_buf, v_buf, sem, half_ref, m_ref, l_ref, acc_ref = rest
+    i, n = pl.program_id(0), pl.num_programs(0)
+    rows, width = g * bs, q_ref.shape[-1]
+    heads = width // d
+
+    def frontier(slot):
+        return jnp.clip(pos_ref[slot] // bs, 0, max_blocks - 1)
+
+    def copies(slot, step, half):
+        """The DMAs of one step of one slot, each with whether its block
+        is live (at or before the slot's frontier)."""
+        last = frontier(slot)
+        for j in range(g):
+            blk = step * g + j
+            phys = table_ref[slot * max_blocks + jnp.minimum(blk, last)]
+            for a, (pool, buf) in enumerate(((k_hbm, k_buf),
+                                             (v_hbm, v_buf))):
+                yield blk <= last, pltpu.make_async_copy(
+                    pool.at[phys], buf.at[half, pl.ds(j * bs, bs)],
+                    sem.at[half, a])
+
+    def start(slot, step, half):
+        for live, dma in copies(slot, step, half):
+            pl.when(live)(dma.start)
+
+    def wait(slot, step, half):
+        for live, dma in copies(slot, step, half):
+            pl.when(live)(dma.wait)
+
+    @pl.when(i == 0)
+    def _():
+        half_ref[0] = 0
+        start(0, 0, 0)
+
+    m_ref[:] = jnp.full_like(m_ref, _MASKED)
+    l_ref[:] = jnp.zeros_like(l_ref)
+    acc_ref[:] = jnp.zeros_like(acc_ref)
     p = pos_ref[i]
-    q = q_ref[:].astype(jnp.float32) * scale          # (1, d)
-    nk = (p + bs) // bs                               # mapped, visible blocks
+    steps = frontier(i) // g + 1
+    first_half = half_ref[0]
+    q = q_ref[:].astype(jnp.float32) * scale              # (1, H * D)
+    seg = jax.lax.broadcasted_iota(jnp.int32, (heads, width), 1) // d \
+        == jax.lax.broadcasted_iota(jnp.int32, (heads, width), 0)
+    seg16 = seg.astype(jnp.bfloat16)
 
-    def body(j, carry):
-        start = pl.multiple_of(table_ref[i, j] * bs, bs)  # physical block
-        kblk = k_ref[pl.ds(start, bs), :].astype(jnp.float32)
-        vblk = v_ref[pl.ds(start, bs), :].astype(jnp.float32)
+    def spread(x):
+        # (H, 1) -> (1, H * D): each head's value on its own lanes
+        return jnp.sum(jnp.where(seg, x, 0.0), axis=0, keepdims=True)
+
+    def with_seg(lhs, rhs, dims):
+        return jax.lax.dot_general(lhs, rhs, dims,
+                                   precision=jax.lax.Precision.DEFAULT,
+                                   preferred_element_type=jnp.float32)
+
+    def head_sums(x):
+        # (rows, H * D) -> (H, rows): each head's sum over its d lanes
+        return sum(with_seg(seg16, part, _CONTRACT_LAST)
+                   for part in _split3(x))
+
+    def over_lanes(w):
+        # (H, rows) -> (rows, H * D): a head's weight on each of its lanes
+        return sum(with_seg(part, seg16, _CONTRACT_FIRST)
+                   for part in _split3(w))
+
+    def body(step, _):
+        half = (first_half + step) % 2
+
+        @pl.when(step + 1 < steps)
+        def _():
+            start(i, step + 1, 1 - half)
+
+        @pl.when(jnp.logical_and(step + 1 == steps, i + 1 < n))
+        def _():
+            start(i + 1, 0, 1 - half)
+
+        wait(i, step, half)
+        k = k_buf[half].astype(jnp.float32)                # (rows, H * D)
+        v = v_buf[half].astype(jnp.float32)
+        s = head_sums(k * q)                               # (H, rows)
         if quantized:
-            # (bs, 1) scale columns broadcast over head_dim
-            kblk = kblk * ks_ref[pl.ds(start, bs), :]
-            vblk = vblk * vs_ref[pl.ds(start, bs), :]
-        kpos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
-        return _online_softmax_step(q, kblk, vblk, kpos, p, carry)
+            s = s * ks_ref[step]
+        seen = step * rows + jax.lax.broadcasted_iota(
+            jnp.int32, (1, rows), 1) <= p
+        s = jnp.where(seen, s, _MASKED)
+        m = m_ref[:]
+        new_m = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        w = jnp.exp(s - new_m)                             # (H, rows)
+        corr = jnp.exp(m - new_m)
+        m_ref[:] = new_m
+        l_ref[:] = l_ref[:] * corr + jnp.sum(w, axis=1, keepdims=True)
+        if quantized:
+            w = w * vs_ref[step]
+        # a row never fetched holds whatever the buffer held: its weight
+        # is 0, and 0 * nan must not reach the sum
+        fetched = step * rows + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, 1), 0) <= p
+        pv = jnp.where(fetched, over_lanes(w) * v, 0.0)
+        acc_ref[:] = acc_ref[:] * spread(corr) + jnp.sum(
+            pv, axis=0, keepdims=True)
+        return _
 
-    acc, m, l = jax.lax.fori_loop(0, nk, body, _online_softmax_init(d))
-    o_ref[:] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+    jax.lax.fori_loop(0, steps, body, None)
+    half_ref[0] = (first_half + steps) % 2
+    o_ref[:] = (acc_ref[:] / spread(l_ref[:])).astype(o_ref.dtype)
+
+
+#: fp32 bytes of K (and as many of V) that one step of the paged decode
+#: kernel computes on: 256 rows at 16 heads of 64.  At the serving cell's
+#: shape and lengths (PERF.md section 6, PR 32; ms a call) 32 rows a step
+#: read 0.34, 64 0.25, 128 0.19, 256 0.17, 512 0.21, the DMAs alone 0.13:
+#: fewer, larger steps until a slot's last step is mostly rows past its end.
+_PAGED_STEP_BYTES = 2 ** 20
+
+
+def _paged_blocks_per_step(block_size: int, width: int,
+                           max_blocks: int) -> int:
+    rows = _PAGED_STEP_BYTES // (4 * width)
+    return max(1, min(max_blocks, rows // block_size))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -588,64 +704,86 @@ def flash_paged_decode_attention(q, k_pool, v_pool, tables, pos,
                                  k_scale=None, v_scale=None,
                                  interpret: bool = False):
     """Single-token decode attention through a PAGED K/V pool:
-    ``q (B, 1, H, D)`` against pools ``k_pool, v_pool (NB, bs, H, D)``
+    ``q (B, 1, H, D)`` against pools ``k_pool, v_pool (NB, bs, H * D)``
     addressed by per-row block tables ``tables (B, max_blocks)`` with
     frontier positions ``pos (B,)`` -> ``(B, 1, H, D)``.
 
-    The paged sibling of :func:`flash_decode_attention`: the same
-    one-program-per-(batch, head) online softmax, but K/V blocks are
-    fetched by table lookup instead of contiguous stride, so the
-    gather that the XLA fallback materialises (``(B, max_blocks*bs,
-    H, D)`` per layer per step) never exists -- each program streams
-    exactly the ``ceil((pos+1)/bs)`` blocks its row has mapped.
+    The pool is read where it lies, in the shape ``init_paged_cache``
+    stores it in (heads and head_dim on one axis: the TPU compiler lays a
+    ``(NB, bs, H, 64)`` array out with the block axis on the lanes, and
+    no block of it can be fetched): the kernel fetches a row's blocks
+    through its table by its own DMAs and does work in proportion to
+    ``pos`` -- the gather that the XLA path materialises
+    (``(B, max_blocks * bs, H, D)`` a layer a step, whatever the lengths)
+    never exists, and nothing of the pool is copied or transposed before
+    the call.  VMEM holds two steps' blocks, whatever the pool's size.
 
-    ``k_scale``/``v_scale`` (both or neither, ``(NB, bs, H, 1)`` fp32)
-    select the INT8 pool layout: payloads are int8 and each block
-    dequantizes in-kernel against its per-position-per-head scale
-    column, so HBM<->VMEM traffic stays at the narrow width end to end.
-    ``interpret=True`` runs on CPU for tests.  On a TPU each head's
-    whole pool plane ``(NB*bs, D)`` is one VMEM block, so only a pool
-    that ``kv_blocks_fit`` admits compiles, and ``bs`` must tile (auto
-    mode gates on both, MultiHeadAttention._flash_paged_ok).
+    ``k_scale``/``v_scale`` (both or neither, ``(NB, bs, H)`` fp32)
+    select the INT8 pool layout: payloads are int8 and become fp32 in the
+    kernel, so the pool's traffic stays at the narrow width; the scales,
+    a sixteenth of the payload at D=64 and too narrow for a DMA of their
+    own (Mosaic pads their 16 lanes to 128 in HBM and refuses the slice),
+    are gathered by XLA, all ``max_blocks`` of a row.
+    ``interpret=True`` runs on the CPU for tests.  On a TPU ``bs`` must be
+    a multiple of the pool dtype's sublane tile (8 fp32, 16 bf16, 32 int8)
+    and ``H * D`` of 128 (``MultiHeadAttention._flash_paged_ok``).
     """
     b, t1, h, d = q.shape
-    nb, bs = k_pool.shape[0], k_pool.shape[1]
+    bs = k_pool.shape[1]
+    max_blocks = tables.shape[1]
     assert t1 == 1, f"decode takes one query token per row, got {t1}"
+    assert k_pool.shape[2] == h * d, (k_pool.shape, q.shape)
     quantized = k_scale is not None
     assert (v_scale is not None) == quantized, \
         "pass both k_scale and v_scale or neither"
-    scale = 1.0 / math.sqrt(d)
+    tables = jnp.asarray(tables, jnp.int32)
+    width = h * d
+    g = _paged_blocks_per_step(bs, width, max_blocks)
+    steps = -(-max_blocks // g)
 
-    # per-head pool planes (H, NB*bs, D): physical block i occupies rows
-    # [i*bs, (i+1)*bs) so the kernel's pl.ds(bid*bs, bs) lands on it
-    def plane(x):
-        return x.transpose(2, 0, 1, 3).reshape(h, nb * bs, x.shape[-1])
+    def slot_block(*shape):
+        return pl.BlockSpec((None,) + shape,
+                            lambda i, pos, tables: (i,) + (0,) * len(shape))
 
-    def plane_spec(cols):
-        return pl.BlockSpec((None, nb * bs, cols),
-                            lambda i, j, pos, tables: (j, 0, 0))
+    def gathered(scales):
+        # (NB, bs, H) -> (B, steps, H, rows): a step's positions on the
+        # lanes, as its scores have them
+        x = jnp.take(scales.astype(jnp.float32), tables,
+                     axis=0).reshape(b, max_blocks * bs, h)
+        x = jnp.pad(x, ((0, 0), (0, (steps * g - max_blocks) * bs), (0, 0)))
+        return x.reshape(b, steps, g * bs, h).transpose(0, 1, 3, 2)
 
-    row = pl.BlockSpec((None, None, 1, d),
-                       lambda i, j, pos, tables: (i, j, 0, 0))
-    in_specs = [row, plane_spec(d), plane_spec(d)]
-    args = [q.transpose(0, 2, 1, 3), plane(k_pool), plane(v_pool)]
+    in_specs = [slot_block(1, width)] + \
+        [pl.BlockSpec(memory_space=pltpu.HBM)] * 2
+    args = [q.reshape(b, 1, width), k_pool, v_pool]
     if quantized:
-        # fp32 scale planes (H, NB*bs, 1) ride beside the int8 payload
-        in_specs += [plane_spec(1), plane_spec(1)]
-        args += [plane(k_scale.astype(jnp.float32)),
-                 plane(v_scale.astype(jnp.float32))]
+        in_specs += [slot_block(steps, h, g * bs)] * 2
+        args += [gathered(k_scale), gathered(v_scale)]
 
     out = pl.pallas_call(
-        functools.partial(_paged_decode_kernel, block_size=bs, scale=scale,
+        functools.partial(_paged_decode_kernel, block_size=bs,
+                          blocks_per_step=g, max_blocks=max_blocks,
+                          head_dim=d, scale=1.0 / math.sqrt(d),
                           quantized=quantized),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(b, h),
+            grid=(b,),
             in_specs=in_specs,
-            out_specs=row),
-        out_shape=jax.ShapeDtypeStruct((b, h, 1, d),
-                                       jnp.float32 if quantized else q.dtype),
+            out_specs=slot_block(1, width),
+            scratch_shapes=[
+                pltpu.VMEM((2, g * bs, width), k_pool.dtype),
+                pltpu.VMEM((2, g * bs, width), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((1,), jnp.int32),
+                pltpu.VMEM((h, 1), jnp.float32),
+                pltpu.VMEM((h, 1), jnp.float32),
+                pltpu.VMEM((1, width), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct(
+            (b, 1, width), jnp.float32 if quantized else q.dtype),
+        # slots run in order: each starts the next one's first fetch
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="flash_paged_decode_attention",
-    )(jnp.asarray(pos, jnp.int32), jnp.asarray(tables, jnp.int32), *args)
-    return out.transpose(0, 2, 1, 3)
+    )(jnp.asarray(pos, jnp.int32), tables.reshape(-1), *args)
+    return out.reshape(b, 1, h, d)

@@ -929,10 +929,10 @@ def paged_generate_steps(model, cache_dtype=jnp.float32):
 
     def copy_block(pool, src, dst):
         def cp(leaf):
-            # pool leaves are (NB, bs, H, Dh); the scan-stacked layout
-            # adds a leading layer axis -- block axis sits at ndim - 4
-            # either way (same convention as _scatter_rows)
-            if leaf.ndim == 4:
+            # pool leaves are (NB, bs, H * Dh), scales (NB, bs, H); the
+            # scan-stacked layout adds a leading layer axis -- the block
+            # axis sits at ndim - 3 either way
+            if leaf.ndim == 3:
                 return leaf.at[dst].set(leaf[src])
             return leaf.at[:, dst].set(leaf[:, src])
         return jax.tree.map(cp, pool)

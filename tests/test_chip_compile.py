@@ -99,14 +99,16 @@ def _decode(b, t, d, dt, h=16):
             ((b,), i32)]
 
 
-def _paged(b, nb, bs, d, quantized, h=16, mb=16):
-    pool = ((nb, bs, h, d), i8 if quantized else f32)
-    scale = ((nb, bs, h, 1), f32) if quantized else None
+def _paged(b, nb, bs, d, dtype, h=16, mb=64):
+    """The pool as the engine stores it: ``(NB, bs, H * D)``, an int8
+    one with ``(NB, bs, H)`` fp32 scales."""
+    pool = ((nb, bs, h * d), dtype)
+    scale = ((nb, bs, h), f32) if dtype == i8 else None
     return [((b, 1, h, d), f32), pool, pool, ((b, mb), i32), ((b,), i32),
             scale, scale]
 
 
-# (kernel, shapes, what the auto gate is asked: rows, head_dim, dtype, int8)
+# (kernel, shapes, what the auto gate is asked: rows, head_dim, dtype)
 CASES = {
     # train_lm's sequence, bf16 compute.  The forward kernel streams K/V
     # a block at a time: no gate bounds it by bytes (gate None)
@@ -119,17 +121,21 @@ CASES = {
     # serve_lm's contiguous cache: 8 slots + trash row, 2048 long, fp32;
     # and the longest fp32 cache the gate admits
     "decode-medium": (flash_decode_attention, _decode(9, 2048, 64, f32),
-                      (2048, 64, f32, False)),
+                      (2048, 64, f32)),
     "decode-large-longest": (flash_decode_attention,
                              _decode(9, 6144, 96, f32),
-                             (6144, 96, f32, False)),
-    # the largest pools whose per-head plane the gate admits
-    "paged-medium-fp32": (flash_paged_decode_attention,
-                          _paged(8, 48, 128, 64, False),
-                          (48 * 128, 64, f32, False)),
+                             (6144, 96, f32)),
+    # the paged decode kernel streams a slot's blocks through a double
+    # buffer: no gate bounds it by bytes.  The benchmark's serving cell
+    # (32 slots, 1280 blocks of 16 and the trash block, 16 heads of 64,
+    # 64 table entries, fp32), the same tokens in a bf16 pool, and the
+    # large model's heads of 96 in an int8 pool of 32-row blocks
+    "paged-cell-fp32": (flash_paged_decode_attention,
+                        _paged(32, 1281, 16, 64, f32), None),
+    "paged-cell-bf16": (flash_paged_decode_attention,
+                        _paged(32, 1281, 16, 64, bf16), None),
     "paged-large-int8": (flash_paged_decode_attention,
-                         _paged(8, 38, 128, 96, True),
-                         (38 * 128, 96, i8, True)),
+                         _paged(8, 641, 32, 96, i8, mb=32), None),
     # the LFM2 cell: one call of the attention layer takes a row's 8 KV
     # heads as 8 pairs of 4 query heads at 4096 positions
     "flash-grad-lfm2": (_flash_grad, _qkv(8, 4096, 64, bf16, h=4), None),
@@ -185,6 +191,66 @@ def test_gate_refuses_what_the_compiler_refuses(one_chip):
     assert not kv_blocks_fit(8192, 64, f32)
     with pytest.raises(Exception, match="vmem"):
         _compile(one_chip, flash_decode_attention, *_decode(9, 8192, 64, f32))
+
+
+@pytest.mark.parametrize("block_size,dtype,hidden,admitted", [
+    (16, f32, 1024, True),        # the serving cell
+    (8, f32, 1024, True), (4, f32, 1024, False),
+    (16, bf16, 1024, True), (8, bf16, 1024, False),
+    (32, i8, 1536, True), (16, i8, 1024, False),
+    (16, f32, 1088, False),       # 17 heads of 64: not whole lanes
+])
+def test_paged_gate_reads_tile_and_width(monkeypatch, block_size, dtype,
+                                         hidden, admitted):
+    """``auto`` on a TPU takes the paged decode kernel when a block is
+    whole tiles of the pool's dtype and ``H * D`` whole lanes; the pool's
+    size is nothing to it."""
+    import bigdl_tpu.nn.attention as attention
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    attn = attention.MultiHeadAttention(hidden, hidden // 64, causal=True)
+    assert attn._flash_paged_ok(block_size, dtype) is admitted
+    monkeypatch.setattr(attention, "_on_tpu", lambda: False)
+    assert not attn._flash_paged_ok(block_size, dtype)
+
+
+def test_decode_step_is_one_kernel_a_layer(one_chip, monkeypatch):
+    """The engine's ``jit_decode`` at the serving cell's widths and pool
+    (two layers, a small vocabulary), compiled for the described chip
+    with the gate seeing a TPU: one Pallas kernel a layer, no gathered
+    ``(slots, max_blocks * bs, H, D)`` context in any shape, and the pool
+    leaves neither copied nor transposed on their way to the kernel."""
+    import re
+
+    import bigdl_tpu.nn.attention as attention
+    from bigdl_tpu.serving.generation import paged_generate_steps
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    slots, blocks, bs, entries, layers = 32, 1280, 16, 64, 2
+    model = attention.TransformerLM(512, 1024, 16, layers, max_len=1024)
+    model.build(jax.ShapeDtypeStruct((2, 16), i32))
+    assert model.blocks[0].attn._flash_paged_ok(bs, f32)
+
+    def described(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    def per_slot(dtype):
+        return jax.ShapeDtypeStruct((slots,), dtype, sharding=one_chip)
+
+    pool = jax.eval_shape(lambda: model.init_paged_cache(blocks, bs, f32))
+    decode = paged_generate_steps(model, f32)[1]
+    text = decode.lower(
+        jax.tree.map(described, model.parameters()[0]),
+        jax.tree.map(described, pool), per_slot(i32), per_slot(i32),
+        jax.ShapeDtypeStruct((slots, entries), i32, sharding=one_chip),
+        per_slot(f32), per_slot(i32), per_slot(f32),
+        per_slot(i32)).compile().as_text()
+    assert text.count("tpu_custom_call") == layers
+    assert text.count("flash_paged_decode_attention") >= layers
+    ctx = entries * bs
+    assert not re.search(rf"\[{slots},({ctx}|{entries},{bs}),", text)
+    leaf = rf"f32\[{blocks + 1},{bs},1024\]"
+    assert not re.search(leaf + r"\S* (copy|transpose)\(", text)
 
 
 def test_lm_gradient_through_flash_matches_plain():

@@ -413,33 +413,136 @@ class TestPagedServing:
         assert "bigdl_serving_prefix_hit_tokens_total" in text
 
 
+def _paged_kernel_case(case):
+    """``(geometry, tables, pos)`` of one case of the kernel's test.  The
+    last physical block is the trash block, as in the engine."""
+    b, h, d, nb, bs, mb = 3, 4, 16, 11, 4, 6
+    trash = nb - 1
+    # deliberately NON-contiguous, per-row-distinct tables
+    tables = [[7, 2, 9, trash, trash, trash],
+              [1, 8, 3, 5, trash, trash],
+              [4, trash, trash, trash, trash, trash]]
+    pos = [9, 14, 2]
+    if case == "pos-zero":
+        pos = [0, 0, 0]
+    elif case == "block-last-row":
+        pos = [11, 7, 3]
+    elif case == "block-first-row":
+        pos = [8, 12, 0]
+    elif case == "table-end":
+        tables = [[7, 2, 9, 0, 6, 5], [1, 8, 3, 5, 9, 2], [4, 0, 1, 2, 3, 6]]
+        pos = [mb * bs - 1, mb * bs - 1, mb * bs - 2]
+    elif case == "trash-row":
+        # an empty slot (or one mid-prefill): every entry the trash block
+        tables[1] = [trash] * mb
+        pos = [9, 0, 2]
+    elif case in ("cell-geometry", "int8-cell-geometry"):
+        # the serving cell's in small: blocks of 16 (32 for int8, its
+        # tile), H * D = 128, 64 table entries, several steps a row, one
+        # row full, one empty, two ending beside a step's edge
+        bs = 32 if case.startswith("int8") else 16
+        b, h, d, mb = 5, 4, 32, 64
+        lens = [mb * bs, 1, 8 * bs + 1, 8 * bs, 300]
+        nb = sum(-(-n // bs) for n in lens) + 3
+        trash = nb - 1
+        order = iter(np.random.default_rng(1).permutation(nb - 1))
+        tables = [[next(order) if j * bs < n and i != 1 else trash
+                   for j in range(mb)] for i, n in enumerate(lens)]
+        pos = [n - 1 for n in lens]
+    return (b, h, d, nb, bs, mb), np.asarray(tables, np.int32), \
+        np.asarray(pos, np.int32)
+
+
 class TestFlashPagedKernel:
-    def test_interpret_matches_gather_reference(self):
+    @pytest.mark.parametrize("case", [
+        "scattered-tables", "pos-zero", "block-last-row", "block-first-row",
+        "table-end", "trash-row", "cell-geometry", "int8-scattered-tables",
+        "int8-block-last-row", "int8-cell-geometry"])
+    def test_interpret_matches_gather_reference(self, case):
+        """The kernel in interpreter mode against gather-and-mask, on the
+        pool as the engine stores it (``(NB, bs, H * D)``; int8 payloads
+        with ``(NB, bs, H)`` scales)."""
         from bigdl_tpu.ops.flash_attention import \
             flash_paged_decode_attention
 
+        quant = case.startswith("int8")
+        (b, h, d, nb, bs, mb), tables, pos = _paged_kernel_case(
+            case[5:] if quant and "cell" not in case else case)
         rng = np.random.default_rng(0)
-        b, h, d, nb, bs, mb = 3, 4, 16, 10, 4, 6
         q = jnp.asarray(rng.normal(size=(b, 1, h, d)), jnp.float32)
-        kp = jnp.asarray(rng.normal(size=(nb, bs, h, d)), jnp.float32)
-        vp = jnp.asarray(rng.normal(size=(nb, bs, h, d)), jnp.float32)
-        # deliberately NON-contiguous, per-row-distinct tables
-        tables = jnp.asarray([[7, 2, 9, 0, 0, 0],
-                              [1, 8, 3, 5, 0, 0],
-                              [4, 0, 0, 0, 0, 0]], jnp.int32)
-        pos = jnp.asarray([9, 14, 2], jnp.int32)
-        out = flash_paged_decode_attention(q, kp, vp, tables, pos,
+        pools, scales = [], []
+        for _ in "kv":
+            if quant:
+                pools.append(jnp.asarray(
+                    rng.integers(-127, 128, (nb, bs, h * d)), jnp.int8))
+                scales.append(jnp.asarray(
+                    rng.uniform(0.005, 0.02, (nb, bs, h)), jnp.float32))
+            else:
+                pools.append(jnp.asarray(
+                    rng.normal(size=(nb, bs, h * d)), jnp.float32))
+        out = flash_paged_decode_attention(q, *pools, tables, pos, *scales,
                                            interpret=True)
+
         # reference: gather the mapped context and mask beyond pos
-        k = jnp.take(kp, tables, axis=0).reshape(b, mb * bs, h, d)
-        v = jnp.take(vp, tables, axis=0).reshape(b, mb * bs, h, d)
-        logits = jnp.einsum("bihd,bkhd->bhik", q, k) / np.sqrt(d)
+        def ctx(i):
+            x = jnp.take(pools[i], tables, axis=0).astype(jnp.float32)
+            x = x.reshape(b, mb * bs, h, d)
+            if quant:
+                x = x * jnp.take(scales[i], tables, axis=0).reshape(
+                    b, mb * bs, h, 1)
+            return x
+
+        logits = jnp.einsum("bihd,bkhd->bhik", q, ctx(0),
+                            precision="highest") / np.sqrt(d)
         mask = (jnp.arange(mb * bs)[None, :]
                 <= pos[:, None])[:, None, None, :]
         w = jax.nn.softmax(jnp.where(mask, logits, -jnp.inf), axis=-1)
-        ref = jnp.einsum("bhik,bkhd->bihd", w, v)
+        ref = jnp.einsum("bhik,bkhd->bihd", w, ctx(1), precision="highest")
+        assert out.shape == ref.shape
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=2e-5)
+
+    def test_rows_never_fetched_cannot_poison_a_slot(self):
+        """A short slot after a long one computes on a buffer that still
+        holds the long one's rows: whatever they are (here not finite),
+        nothing of them reaches the short slot's output."""
+        from bigdl_tpu.ops.flash_attention import \
+            flash_paged_decode_attention
+
+        (b, h, d, nb, bs, mb), tables, pos = _paged_kernel_case(
+            "cell-geometry")
+        rng = np.random.default_rng(3)
+        q = jnp.asarray(rng.normal(size=(b, 1, h, d)), jnp.float32)
+        k = np.asarray(rng.normal(size=(nb, bs, h * d)), np.float32)
+        v = np.asarray(rng.normal(size=(nb, bs, h * d)), np.float32)
+        clean = flash_paged_decode_attention(q, k, v, tables, pos,
+                                             interpret=True)
+        # slot 0 is full and runs first; poison blocks only it maps, past
+        # where slot 1 (one token, in the trash block) will look
+        for blk in tables[0, 1:]:
+            k[blk], v[blk] = np.nan, np.inf
+        out = flash_paged_decode_attention(q, k, v, tables, pos,
+                                           interpret=True)
+        assert not np.isfinite(np.asarray(out[0])).all()
+        np.testing.assert_array_equal(np.asarray(out[1:]),
+                                      np.asarray(clean[1:]))
+
+    def test_engine_tokens_equal_with_and_without_the_kernel(self):
+        """A finished engine run through the kernel (interpreter mode)
+        gives the greedy tokens of the XLA gather path, on prompts and
+        outputs that cross block boundaries."""
+        streams = {}
+        prompts = [[3, 1, 4, 1, 5, 9, 2], [7, 8, 9], [4] * 13]
+        for mode in ("never", "interpret"):
+            m = _lm(layers=2, max_len=64, scan=True)
+            for block in m.blocks:
+                block.attn.use_flash = mode
+            with ServingEngine(m, decode_slots=3, decode_max_len=48,
+                               kv_cache="paged", kv_block_size=4) as eng:
+                futs = [eng.generate(p, max_new_tokens=7) for p in prompts]
+                streams[mode] = [f.result(120) for f in futs]
+        assert streams["interpret"] == streams["never"]
+        assert all(len(s) == 7 for s in streams["never"])
 
 
 class TestSamplingWire:

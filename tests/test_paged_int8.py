@@ -60,11 +60,12 @@ class TestInt8PoolLayout:
         h, d = 4, 8                              # hidden 32, 4 heads
         for name in ("k", "v"):
             assert layer[name].dtype == jnp.int8
-            assert layer[name].shape == (nb + 1, bs, h, d)
+            assert layer[name].shape == (nb + 1, bs, h * d)
             # one fp32 absmax per (position, head) head_dim vector,
-            # kept 4-D so copy_block treats it like any pool leaf
+            # of the payload's rank so copy_block treats it like any
+            # pool leaf
             assert layer[name + "_scale"].dtype == jnp.float32
-            assert layer[name + "_scale"].shape == (nb + 1, bs, h, 1)
+            assert layer[name + "_scale"].shape == (nb + 1, bs, h)
         # head_dim 8: fp32 32 B/vector vs int8 8 B + 4 B scale -> 8/3x
         ratio = _pool_bytes(fp) / _pool_bytes(q8)
         assert abs(ratio - 32 / 12) < 1e-6
