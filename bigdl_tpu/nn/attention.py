@@ -270,14 +270,24 @@ class MultiHeadAttention(Module):
         payload's rank so every pool consumer that tree-maps by rank
         (block copies, donation, byte accounting) handles both layouts
         with one code path."""
-        shape = (int(num_blocks), int(block_size), self.hidden_size)
+        from bigdl_tpu.nn.generation_state import allocate
+
+        return allocate(self.state_spec(dtype), int(num_blocks) - 1,
+                        block_size)
+
+    def state_spec(self, dtype=jnp.float32):
+        """What this layer keeps between the steps of paged generation
+        (nn/generation_state.py): K and V a token, in blocks; an int8 pool
+        adds their scales."""
+        from bigdl_tpu.nn.generation_state import BLOCK, StateSpec
+
         if jnp.dtype(dtype) == jnp.dtype(jnp.int8):
-            sshape = shape[:-1] + (self.num_heads,)
-            return {"k": jnp.zeros(shape, jnp.int8),
-                    "v": jnp.zeros(shape, jnp.int8),
-                    "k_scale": jnp.zeros(sshape, jnp.float32),
-                    "v_scale": jnp.zeros(sshape, jnp.float32)}
-        return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+            payload = StateSpec(BLOCK, (self.hidden_size,), jnp.int8)
+            scale = StateSpec(BLOCK, (self.num_heads,), jnp.float32)
+            return {"k": payload, "v": payload,
+                    "k_scale": scale, "v_scale": scale}
+        leaf = StateSpec(BLOCK, (self.hidden_size,), dtype)
+        return {"k": leaf, "v": leaf}
 
     def _paged_quant(self, x):
         """fp K/V rows ``(rows, heads * head_dim)`` -> (int8 payload,
@@ -786,6 +796,16 @@ class TransformerLM(Container):
         return self._layer_caches(
             lambda b: b.init_paged_cache(int(num_blocks) + 1, block_size,
                                          dtype))
+
+    def paged_state_spec(self, dtype=jnp.float32):
+        """The kinds of the pool's leaves, in the pool's layout: every
+        layer keeps K and V a token, in blocks, and nothing a sequence
+        (under ``scan_layers`` a leaf of the one ``"blocks"`` entry stands
+        for all layers: the stacked leaf has the layer axis in front)."""
+        spec = self.blocks[0].attn.state_spec(dtype)
+        if self.scan_layers:
+            return {"blocks": spec}
+        return {f"block{i}": spec for i in range(len(self.blocks))}
 
     def apply_paged(self, params, input, pool, tables, *, pos,
                     lengths=None):

@@ -1,0 +1,235 @@
+"""Multi-head latent attention (MLA, as published for DeepSeek-V2) without
+a query latent, with a head-wise output gate: keys and values of every
+head are read off ONE low-rank latent a token, and that latent, not the
+heads' keys and values, is what generation caches.
+
+For a token ``x`` at position ``p``::
+
+    q          = RMSNorm_head(W_q x)          (H, nope + rope); RoPE on the last ``rope``
+    [c, k_r]   = W_kva x                      (rank), (rope)
+    c, k_r     = RMSNorm(c), RoPE_p(RMSNorm(k_r))
+    [k_n, v]_h = W_kvb c                      (H, nope + v_dim)
+    k_h        = [k_n_h, k_r]                 k_r shared by all heads
+    o_h        = softmax(q_h . k_h / sqrt(nope + rope)) v      causal
+    out        = W_o (sigmoid(W_gate x)_h * o_h)
+
+The cache row of a token is ``[c, k_r]`` (``rank + rope`` values, a
+``"block"`` leaf of ``nn/generation_state.py``).  Two paths that must
+agree: EXPANDED (the full forward and a prefill chunk: ``W_kvb`` applied
+to every context token, then plain attention over heads) and ABSORBED
+(decode: ``W_kvb`` folded into the query and the output, attention over
+the latent rows themselves, no per-head key or value ever formed).
+"""
+
+import jax
+import jax.numpy as jnp
+
+from bigdl_tpu.nn.generation_state import BLOCK, StateSpec
+from bigdl_tpu.nn.initialization import Xavier
+from bigdl_tpu.nn.module import Module, child_rng
+
+#: context tokens a prefill chunk expands at a time
+CONTEXT_BLOCK = 1024
+
+
+def rotary_at(x, positions, theta: float):
+    """Rotary positions on ``x (N, T, H, Dh)`` at ``positions (N, T)``,
+    rotate-half convention; float32 inside, ``x``'s dtype out."""
+    dh = x.shape[-1]
+    inv_freq = theta ** (-jnp.arange(0, dh, 2, dtype=jnp.float32) / dh)
+    angles = positions.astype(jnp.float32)[..., None] * inv_freq
+    cos = jnp.cos(angles)[:, :, None, :]
+    sin = jnp.sin(angles)[:, :, None, :]
+    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)
+
+
+def _rms(x, weight, eps):
+    sq = jnp.mean(jnp.square(x.astype(jnp.float32)), -1, keepdims=True)
+    return x * jax.lax.rsqrt(sq + eps).astype(x.dtype) * weight.astype(x.dtype)
+
+
+class LatentAttention(Module):
+    """``(N, T, D) -> (N, T, D)``, causal."""
+
+    def __init__(self, hidden_size: int, num_heads: int, kv_rank: int = 512,
+                 nope_dim: int = 128, rope_dim: int = 64, v_dim: int = 128,
+                 rope_theta: float = 10000.0, norm_eps: float = 1e-6,
+                 name=None):
+        super().__init__(name)
+        self.hidden_size = hidden_size
+        self.num_heads = num_heads
+        self.kv_rank, self.nope_dim = kv_rank, nope_dim
+        self.rope_dim, self.v_dim = rope_dim, v_dim
+        self.rope_theta = float(rope_theta)
+        self.norm_eps = norm_eps
+        self.scale = (nope_dim + rope_dim) ** -0.5
+
+    def setup(self, rng, input_spec):
+        d, h = self.hidden_size, self.num_heads
+        r, qd = self.kv_rank, self.nope_dim + self.rope_dim
+        kvd = self.nope_dim + self.v_dim
+        init = Xavier()
+        return {
+            "q_weight": init.init(child_rng(rng, 0), (h * qd, d), d, h * qd),
+            "q_norm": jnp.ones((qd,), jnp.float32),
+            "kva_weight": init.init(child_rng(rng, 1),
+                                    (r + self.rope_dim, d), d, r),
+            "kv_norm": jnp.ones((r,), jnp.float32),
+            "kr_norm": jnp.ones((self.rope_dim,), jnp.float32),
+            "kvb_weight": init.init(child_rng(rng, 2), (h * kvd, r), r,
+                                    h * kvd),
+            "gate_weight": init.init(child_rng(rng, 3), (h, d), d, h),
+            "out_weight": init.init(child_rng(rng, 4), (d, h * self.v_dim),
+                                    h * self.v_dim, d),
+        }, ()
+
+    # ----- generation state ------------------------------------------------- #
+    def state_spec(self, dtype):
+        return {"latent": StateSpec(BLOCK, (self.kv_rank + self.rope_dim,),
+                                    dtype)}
+
+    # ----- the layer's parts ------------------------------------------------ #
+    def _query_and_latent(self, params, x, positions):
+        """``q (N, T, H, nope + rope)`` with its rotary part turned, and the
+        cache rows ``[c, RoPE(k_r)] (N, T, rank + rope)``."""
+        n, t, _ = x.shape
+        dt = x.dtype
+        h, rope = self.num_heads, self.rope_dim
+        q = (x @ params["q_weight"].astype(dt).T).reshape(n, t, h, -1)
+        q = _rms(q, params["q_norm"], self.norm_eps)
+        q = jnp.concatenate(
+            [q[..., :-rope],
+             rotary_at(q[..., -rope:], positions, self.rope_theta)], -1)
+        kva = x @ params["kva_weight"].astype(dt).T
+        c = _rms(kva[..., :self.kv_rank], params["kv_norm"], self.norm_eps)
+        k_r = _rms(kva[..., self.kv_rank:], params["kr_norm"], self.norm_eps)
+        k_r = rotary_at(k_r[:, :, None, :], positions, self.rope_theta)[:, :, 0]
+        return q, jnp.concatenate([c, k_r], -1)
+
+    def _expand(self, params, rows):
+        """Cache rows ``(..., rank + rope)`` -> ``(k (..., H, nope + rope),
+        v (..., H, v_dim))``."""
+        h = self.num_heads
+        c, k_r = rows[..., :self.kv_rank], rows[..., self.kv_rank:]
+        kv = (c @ params["kvb_weight"].astype(c.dtype).T).reshape(
+            c.shape[:-1] + (h, self.nope_dim + self.v_dim))
+        k_r = jnp.broadcast_to(k_r[..., None, :],
+                               c.shape[:-1] + (h, self.rope_dim))
+        return (jnp.concatenate([kv[..., :self.nope_dim], k_r], -1),
+                kv[..., self.nope_dim:])
+
+    def _output(self, params, o, x):
+        """``o (N, T, H, v_dim)`` -> ``(N, T, D)``, gated head by head."""
+        n, t = o.shape[:2]
+        dt = x.dtype
+        gate = jax.nn.sigmoid(x @ params["gate_weight"].astype(dt).T)
+        y = (o.astype(dt) * gate[..., None]).reshape(n, t, -1)
+        return y @ params["out_weight"].astype(dt).T
+
+    # ----- forward (expanded) ------------------------------------------------ #
+    def apply(self, params, state, input, *, training=False, rng=None):
+        from bigdl_tpu.nn.attention import dot_product_attention
+
+        n, t, _ = input.shape
+        positions = jnp.broadcast_to(jnp.arange(t)[None], (n, t))
+        q, rows = self._query_and_latent(params, input, positions)
+        k, v = self._expand(params, rows)
+        o = dot_product_attention(q, k, v, causal=True, scale=self.scale)
+        return self._output(params, o, input), state
+
+    # ----- generation ------------------------------------------------------- #
+    def apply_paged(self, params, input, pool, tables, pos, lengths=None):
+        """A chunk (``lengths`` given; expanded) or one token a row
+        (absorbed) against the block leaf ``pool["latent"]``; see
+        ``MultiHeadAttention._apply_paged`` for the table contract.
+        Returns ``(out, new pool)``."""
+        n, t, _ = input.shape
+        leaf = pool["latent"]
+        bs, max_blocks = leaf.shape[1], tables.shape[1]
+        trash = leaf.shape[0] - 1
+        gpos = pos[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
+        q, rows = self._query_and_latent(params, input, gpos)
+        logical = jnp.clip(gpos // bs, 0, max_blocks - 1)
+        phys = jnp.take_along_axis(tables, logical, axis=1)
+        if lengths is not None:
+            valid = jnp.arange(t, dtype=jnp.int32)[None, :] < lengths[:, None]
+            phys = jnp.where(valid, phys, trash)
+        leaf = leaf.at[phys.reshape(-1), (gpos % bs).reshape(-1)].set(
+            rows.reshape(n * t, -1).astype(leaf.dtype))
+        if lengths is not None:
+            o = self._chunk_attention(params, q, leaf, tables, gpos)
+        else:
+            o = self._absorbed_attention(params, q[:, 0], leaf, tables,
+                                         pos)[:, None]
+        return self._output(params, o, input), {"latent": leaf}
+
+    def _absorbed_attention(self, params, q, leaf, tables, pos):
+        """``q (N, H, nope + rope)`` at positions ``pos`` over each row's
+        mapped latent rows; ``(N, H, v_dim)``."""
+        n, h = q.shape[:2]
+        dt = q.dtype
+        r, nope = self.kv_rank, self.nope_dim
+        w = params["kvb_weight"].astype(dt).reshape(h, nope + self.v_dim, r)
+        ctx = jnp.take(leaf, tables, axis=0, mode="clip").reshape(
+            n, -1, leaf.shape[-1]).astype(dt)
+        q_abs = jnp.concatenate(
+            [jnp.einsum("nhd,hdr->nhr", q[..., :nope], w[:, :nope]),
+             q[..., nope:]], -1)
+        scores = jnp.einsum("nhr,ncr->nhc", q_abs, ctx).astype(jnp.float32)
+        seen = jnp.arange(ctx.shape[1])[None, None, :] <= pos[:, None, None]
+        p = jax.nn.softmax(
+            jnp.where(seen, scores * self.scale, -jnp.inf), axis=-1)
+        o_lat = jnp.einsum("nhc,ncr->nhr", p.astype(dt), ctx[..., :r])
+        return jnp.einsum("nhr,hdr->nhd", o_lat, w[:, nope:])
+
+    def _chunk_attention(self, params, q, leaf, tables, gpos):
+        """``q (N, T, H, nope + rope)`` at positions ``gpos (N, T)`` over
+        each row's mapped context, expanded ``CONTEXT_BLOCK`` tokens at a
+        time with a running softmax.  Every block of the table is gone
+        through, the ones past a row's last query masked whole (no branch
+        on a value read on the device: the whole of it is a millisecond or
+        two beside the chunk's recurrence).  One row after the other
+        (``lax.map``): a row's scores are ``(H, T, CONTEXT_BLOCK)``
+        float32.  ``(N, T, H, v_dim)``."""
+        n, t, h, _ = q.shape
+        dt = q.dtype
+        bs, max_blocks = leaf.shape[1], tables.shape[1]
+        per = max(1, min(CONTEXT_BLOCK // bs, max_blocks))
+        steps = -(-max_blocks // per)
+        tables = jnp.pad(tables, ((0, 0), (0, steps * per - max_blocks)),
+                         constant_values=leaf.shape[0] - 1)
+        f32 = jnp.float32
+
+        def one_row(args):
+            q_row, table, at = args                # (T, H, d), (steps*per,), (T,)
+            q_row = q_row * jnp.asarray(self.scale, dt)
+
+            def block(carry, j):
+                m, l, acc = carry
+                ids = jax.lax.dynamic_slice_in_dim(table, j * per, per)
+                rows = jnp.take(leaf, ids, axis=0, mode="clip") \
+                    .reshape(per * bs, -1).astype(dt)
+                k, v = self._expand(params, rows)
+                s = jnp.einsum("qhd,khd->hqk", q_row, k).astype(f32)
+                kpos = j * per * bs + jnp.arange(per * bs)
+                s = jnp.where(kpos[None, None, :] <= at[None, :, None],
+                              s, -jnp.inf)
+                m_new = jnp.maximum(m, s.max(-1))
+                # a block whose keys all lie ahead of a query leaves that
+                # query's maximum at -inf: exp(-inf - -inf) is NaN
+                safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+                p = jnp.exp(s - safe[..., None])
+                fix = jnp.exp(jnp.where(jnp.isfinite(m), m - safe, -jnp.inf))
+                l = l * fix + p.sum(-1)
+                acc = acc * fix[..., None] + jnp.einsum(
+                    "hqk,khd->hqd", p.astype(dt), v).astype(f32)
+                return (m_new, l, acc), None
+
+            init = (jnp.full((h, t), -jnp.inf, f32), jnp.zeros((h, t), f32),
+                    jnp.zeros((h, t, self.v_dim), f32))
+            (_, l, acc), _ = jax.lax.scan(block, init, jnp.arange(steps))
+            return jnp.moveaxis(acc / l[..., None], 0, 1)   # (T, H, v)
+
+        return jax.lax.map(one_row, (q, tables, gpos))

@@ -331,6 +331,13 @@ class Module:
             self._grads = jax.tree.map(jnp.zeros_like, self._params)
         return self._params, self._grads
 
+    def weights(self) -> Params:
+        """The weights alone.  ``parameters()`` also hands out the facade's
+        gradient tree and allocates it, zeros as large as the weights, the
+        first time it is asked: a path that only reads weights (serving)
+        asks here and holds no such tree."""
+        return self._params
+
     def set_parameters(self, params: Params):
         self._params = params
 

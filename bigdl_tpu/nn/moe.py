@@ -148,7 +148,14 @@ class DroplessMoE(Module):
     (``s = sigmoid(u W_g)``), chooses the top ``k`` of ``s + b`` (``b``,
     the selection bias, chooses and does not weigh; it gets no gradient:
     a balancing rule outside the loss moves it) and weighs the chosen
-    with ``s`` over ``(their sum + 1e-6)``.  Of the ``num_experts`` this
+    with ``s`` over ``(their sum + 1e-6)``.  With ``n_group > 1`` the
+    choice is group-limited: the experts lie in ``n_group`` equal groups,
+    a group's score is the sum of its two best ``s + b``, only the best
+    ``topk_group`` groups stay, and the top ``k`` are taken among their
+    experts.  ``shared_width > 0`` adds one gated MLP of that width that
+    every token passes through, whatever the router says (every share of
+    an expert-parallel layer computes it alike: count it once).  Of the
+    ``num_experts`` this
     layer HOLDS ``experts_held = (first, count)``: the assignments to held
     experts are sorted by expert into a buffer sized for the worst case,
     three grouped products (``ops/grouped_matmul.py``) run over the rows
@@ -161,6 +168,9 @@ class DroplessMoE(Module):
     assignments to experts held here, those of the busiest held expert,
     and ``rows_here`` over the experts held (whole rows).  The trainer's
     loop puts them into a ``moe_load`` span (``state_spans``).
+    ``generate()`` is the same layer for the steps of generation: tiles
+    sized for the few rows an expert gets there, and a fifth count, the
+    held experts that got a row at all.
     """
 
     #: leaves that the train step keeps in float32 under a compute dtype
@@ -173,9 +183,13 @@ class DroplessMoE(Module):
     def __init__(self, hidden_size: int, expert_width: int, num_experts: int,
                  k: int, experts_held=None, norm_topk_prob: bool = True,
                  routed_scaling_factor: float = 1.0,
-                 use_kernel: str = "auto", name=None):
+                 use_kernel: str = "auto", n_group: int = 1,
+                 topk_group: int = 1, shared_width: int = 0, name=None):
         super().__init__(name)
         assert use_kernel in ("auto", "never", "interpret")
+        assert num_experts % n_group == 0 and 1 <= topk_group <= n_group
+        self.n_group, self.topk_group = n_group, topk_group
+        self.shared_width = shared_width
         self.hidden_size = hidden_size
         self.expert_width = expert_width
         self.num_experts = num_experts
@@ -197,13 +211,20 @@ class DroplessMoE(Module):
                                         fan_in, fan_out)
                               for i in range(held)])
 
-        return {
+        params = {
             "router_weight": init.init(child_rng(rng, 0), (e, d), d, e),
             "router_bias": jnp.zeros((e,), jnp.float32),
             "w1": stack(1000, (d, f), d, f),       # (held, D, F)
             "w3": stack(2000, (d, f), d, f),
             "w2": stack(3000, (f, d), f, d),       # (held, F, D)
-        }, {"moe_load": jnp.zeros((4,), jnp.int32)}
+        }
+        if self.shared_width:
+            fs = self.shared_width
+            params["shared"] = {
+                "w1": init.init(child_rng(rng, 4000), (fs, d), d, fs),
+                "w3": init.init(child_rng(rng, 4001), (fs, d), d, fs),
+                "w2": init.init(child_rng(rng, 4002), (d, fs), fs, d)}
+        return params, {"moe_load": jnp.zeros((4,), jnp.int32)}
 
     def route(self, params, x):
         """``(expert ids (T, k), weights (T, k))`` in float32."""
@@ -213,18 +234,34 @@ class DroplessMoE(Module):
         scores = jax.nn.sigmoid(logits)
         chosen = scores + jax.lax.stop_gradient(
             params["router_bias"].astype(jnp.float32))
+        if self.n_group > 1:
+            grouped = chosen.reshape(chosen.shape[0], self.n_group, -1)
+            best_two = jax.lax.top_k(grouped, 2)[0].sum(-1)
+            _, keep = jax.lax.top_k(best_two, self.topk_group)
+            kept = jnp.zeros(best_two.shape, bool).at[
+                jnp.arange(keep.shape[0])[:, None], keep].set(True)
+            chosen = jnp.where(kept[..., None], grouped,
+                               -jnp.inf).reshape(chosen.shape)
         _, idx = jax.lax.top_k(chosen, self.k)
         w = jnp.take_along_axis(scores, idx, axis=-1)
         if self.norm_topk_prob:
             w = w / (w.sum(-1, keepdims=True) + 1e-6)
         return idx, w * self.routed_scaling_factor
 
-    def _products(self, on_tpu):
+    def _products(self, on_tpu, rows_an_expert=None):
+        """``(rows a tile, the grouped product)``.  A tile is
+        ``ops.grouped_matmul.BLOCK_ROWS`` on the chip; generation, where
+        an expert gets ``rows_an_expert`` rows on average and every
+        expert with a row costs a whole tile, takes the power of two
+        next above that, from 16 (one bf16 tile) up."""
         from bigdl_tpu.ops import grouped_matmul as gm
 
         kernel = self.use_kernel == "interpret" or (
             self.use_kernel == "auto" and on_tpu)
         block = gm.BLOCK_ROWS if on_tpu else 8
+        if on_tpu and rows_an_expert is not None:
+            block = min(block, max(16, 1 << max(
+                0, int(rows_an_expert) - 1).bit_length()))
         if kernel:
             interpret = self.use_kernel == "interpret"
             return block, lambda a, w, sizes: gm.grouped_matmul(
@@ -233,17 +270,51 @@ class DroplessMoE(Module):
             a, w, sizes, block)
 
     def apply(self, params, state, input, *, training=False, rng=None):
+        out, sizes, assigned = self._run(params, input)
+        held = self.experts_held[1]
+        load = jnp.stack([jnp.int32(assigned), sizes.sum(), sizes.max(),
+                          sizes.sum() // held])
+        return out.reshape(input.shape), {"moe_load": load}
+
+    #: what ``generate()`` counts, in order
+    generate_counts = ("rows_routed", "rows_here", "rows_busiest_expert",
+                       "rows_mean_expert", "experts_touched")
+
+    def generate(self, params, input, live=None):
+        """The layer inside a step of generation: ``(out, int32[5])``, the
+        counts ``generate_counts`` names.  The router reads ``input`` as it
+        comes (a float32 residual stream's norm keeps its choice from
+        flipping on a rounding); the experts multiply in the dtype their
+        weights are stored in, and ``out`` is in that dtype.  ``live (N,
+        T)`` bool says which tokens are real: a padding token or a row
+        that is not live is routed to no expert (it gets the shared
+        expert's part alone, and nobody reads it) and is not counted."""
+        n, t, _ = input.shape
+        out, sizes, assigned = self._run(
+            params, input, rows_an_expert=-(-n * t * self.k
+                                            // self.num_experts),
+            dt=params["w1"].dtype, live=live)
+        held = self.experts_held[1]
+        return out.reshape(input.shape), jnp.stack([
+            jnp.asarray(assigned, jnp.int32), sizes.sum(), sizes.max(),
+            sizes.sum() // held, (sizes > 0).sum().astype(jnp.int32)])
+
+    def _run(self, params, input, rows_an_expert=None, dt=None, live=None):
+        """``(out (tokens, D), rows of every held expert (held,),
+        assignments)``."""
         from bigdl_tpu.nn.attention import _on_tpu
         from bigdl_tpu.ops.grouped_matmul import buffer_rows, group_layout
 
         n, t, d = input.shape
         tokens, k = n * t, self.k
         first, held = self.experts_held
-        block, product = self._products(_on_tpu())
+        block, product = self._products(_on_tpu(), rows_an_expert)
         x = input.reshape(tokens, d)
-        dt = x.dtype
         with jax.named_scope("moe_router"):
             idx, weights = self.route(params, x)
+        if dt is not None:
+            x = x.astype(dt)
+        dt = x.dtype
         with jax.named_scope("moe_dispatch"):
             # assignments (token-major) sorted by held expert, each
             # expert's rows from a multiple of the tile; those of absent
@@ -252,7 +323,10 @@ class DroplessMoE(Module):
             assigned = tokens * k
             rows = buffer_rows(assigned, held, block)
             local = idx.reshape(assigned) - first
-            group = jnp.where((local >= 0) & (local < held), local, held)
+            here = (local >= 0) & (local < held)
+            if live is not None:
+                here &= jnp.repeat(live.reshape(tokens), k)
+            group = jnp.where(here, local, held)
             member = (group[None] == jnp.arange(held + 1)[:, None])
             count = jnp.cumsum(member.astype(jnp.int32), axis=1)
             sizes_all = count[:, -1]
@@ -282,9 +356,15 @@ class DroplessMoE(Module):
             picked = _take_rows(ys, slot_of, assignment_at[:, None])
             out = (picked.reshape(tokens, k, d)
                    * weights[..., None].astype(dt)).sum(1)
-        load = jnp.stack([jnp.int32(assigned), sizes.sum(), sizes.max(),
-                          sizes.sum() // held])
-        return out.reshape(n, t, d), {"moe_load": load}
+        if self.shared_width:
+            with jax.named_scope("moe_shared"):
+                p = params["shared"]
+                out = out + (jax.nn.silu(x @ p["w1"].astype(dt).T)
+                             * (x @ p["w3"].astype(dt).T)) \
+                    @ p["w2"].astype(dt).T
+        if live is not None:
+            assigned = live.sum().astype(jnp.int32) * k
+        return out, sizes, assigned
 
 
 class MoETransformerBlock(Module):

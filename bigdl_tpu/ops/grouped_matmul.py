@@ -46,6 +46,18 @@ BLOCK_ROWS = 512
 #: of a weight tile is resident, double-buffered (about 15 MiB at 2048 x
 #: 1792 in bf16, over the compiler's default 16 MiB scoped limit)
 _VMEM_LIMIT = 64 * 2 ** 20
+#: ... and asked for only by tiles that need it: the limit is a claim on
+#: VMEM from the scoped region's start upward, whatever the kernel uses,
+#: and the compiler keeps small values of the surrounding program there
+#: (generation's tiles of 16 to 256 rows need 3 to 6 MiB; training's 12.5 and over)
+_VMEM_DEFAULT_FITS = 10 * 2 ** 20
+
+
+def _vmem_limit(*tiles, itemsize):
+    """None (the compiler's default) where the double-buffered ``tiles``
+    fit under it, ``_VMEM_LIMIT`` where they do not."""
+    need = 2 * itemsize * sum(int(np.prod(t)) for t in tiles)
+    return None if need <= _VMEM_DEFAULT_FITS else _VMEM_LIMIT
 
 
 def buffer_rows(rows, groups, block_rows):
@@ -124,7 +136,9 @@ def _grouped_matmul_forward(lhs, rhs, group_sizes, block_rows, transpose_rhs,
         ),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
-            vmem_limit_bytes=_VMEM_LIMIT),
+            vmem_limit_bytes=_vmem_limit(
+                (block_rows, k), (k, tn), (block_rows, tn),
+                itemsize=lhs.dtype.itemsize)),
         interpret=interpret,
         name="grouped_matmul",
     )(tile_group, lhs, rhs)

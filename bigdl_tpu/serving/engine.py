@@ -102,16 +102,16 @@ class _LocalEval:
         pass
 
     def capture(self):
-        return (self.model.parameters()[0], self.model.state())
+        return (self.model.weights(), self.model.state())
 
     def eval(self, x, tick=0, weights=None):
         if weights is not None:
             return self.step(weights[0], weights[1], x)
-        params, mstate = self.model.parameters()[0], self.model.state()
+        params, mstate = self.model.weights(), self.model.state()
         return self.step(params, mstate, x)
 
     def precompile(self, sample_spec, buckets):
-        params, mstate = self.model.parameters()[0], self.model.state()
+        params, mstate = self.model.weights(), self.model.state()
         return self.step.precompile(params, mstate, sample_spec, buckets)
 
 
@@ -138,7 +138,7 @@ class _ShardedEval:
         self.refresh_params()
 
     def refresh_params(self):
-        self.install(self.stage(self.model.parameters()[0],
+        self.install(self.stage(self.model.weights(),
                                 self.model.state()))
 
     def stage(self, params, mstate):
@@ -188,7 +188,7 @@ class _RoundRobinEval:
 
     def refresh_params(self):
         # per-device replicas (the "clone pool"), remade on demand
-        self.install(self.stage(self.model.parameters()[0],
+        self.install(self.stage(self.model.weights(),
                                 self.model.state()))
 
     def stage(self, params, mstate):
@@ -369,7 +369,7 @@ class ServingEngine:
         # serving the old weights (docs/robustness.md).  The contract is
         # always the FP32 tree -- a quantized engine still swaps fp32
         # checkpoints in, quantizing them itself at staging time.
-        self._params_spec = _tree_spec(model.parameters()[0])
+        self._params_spec = _tree_spec(model.weights())
         self._mstate_spec = _tree_spec(model.state())
         self._quantized = bool(quantize)
         self._qselect = quantize if callable(quantize) else None
@@ -477,7 +477,8 @@ class ServingEngine:
         # first generate() after precompile must not pay compiles,
         # whether or not decode_slots was spelled out).
         if decode_slots is None:
-            decode_slots = 8 if hasattr(model, "init_cache") else 0
+            decode_slots = 8 if hasattr(model, "init_cache") \
+                or hasattr(model, "init_paged_cache") else 0
         self.decode_slots = int(decode_slots)
         self.decode_max_len = decode_max_len
         self._prompt_ladder = prompt_ladder
@@ -522,7 +523,7 @@ class ServingEngine:
             # the INITIAL quantization must clear the same bar a later
             # hot-swap would: a model this quantizer damages beyond
             # tolerance never starts serving int8 at all
-            ok, detail = self._check_accuracy(model.parameters()[0],
+            ok, detail = self._check_accuracy(model.weights(),
                                               model.state())
             self._gate_detail = detail
             if not ok:
@@ -841,9 +842,9 @@ class ServingEngine:
         # engines, so their first generate() after "precompile" still
         # paid every generation compile (tests/test_paged.py pins this)
         gen_compiles = 0
-        if self.decode_slots > 0 \
-                and hasattr(self._qmodel if self._quantized
-                            else self.model, "init_cache"):
+        served = self._qmodel if self._quantized else self.model
+        if self.decode_slots > 0 and (hasattr(served, "init_cache")
+                                      or hasattr(served, "init_paged_cache")):
             gen_compiles = self._generation().precompile()
         if self.length_ladder is None:
             return self._backend.precompile(spec, buckets) + gen_compiles
@@ -1076,7 +1077,7 @@ class ServingEngine:
         from bigdl_tpu.nn.quantized import model_bytes
 
         src = self._qmodel if self._quantized else self.model
-        return model_bytes(src.parameters()[0])
+        return model_bytes(src.weights())
 
     # ----- device-memory ledger (observability/memory.py) -------------------- #
     def memory_ledger(self, registry=None):
@@ -1097,7 +1098,7 @@ class ServingEngine:
                 # refresh_params source -- real bytes, own them
                 def fp32_bytes():
                     from bigdl_tpu.nn.quantized import model_bytes
-                    return model_bytes(self.model.parameters()[0])
+                    return model_bytes(self.model.weights())
                 led.register("params_fp32", fp32_bytes)
             led.register("kv_cache", self._kv_cache_bytes)
             if self.telemetry is not None:
@@ -1263,7 +1264,7 @@ class ServingEngine:
             info["version"] = self._version_info["version"]
             info["digest"] = self._version_info["digest"]
         if self._quantized:
-            info["model_bytes_fp32"] = model_bytes(self.model.parameters()[0])
+            info["model_bytes_fp32"] = model_bytes(self.model.weights())
         if self._gate_detail is not None:
             info["accuracy_gate"] = self._gate_detail
         try:
@@ -1341,14 +1342,14 @@ class ServingEngine:
         or a re-stage."""
         from bigdl_tpu.nn.quantized import model_bytes
 
-        qparams = self._qmodel.parameters()[0] if self._quantized else None
+        qparams = self._qmodel.weights() if self._quantized else None
         serve_tree = qparams if qparams is not None \
-            else self.model.parameters()[0]
+            else self.model.weights()
         # the CURRENT model state rides the handle: a rollback must
         # restore it too, or a stateful model (BatchNorm running
         # stats) would serve previous params mixed with the rejected
         # candidate's state -- not the bit-for-bit re-serve promised
-        return {"params": self.model.parameters()[0],
+        return {"params": self.model.weights(),
                 "mstate": self.model.state(), "qparams": qparams,
                 "staged": self._backend.capture(),
                 "model_bytes": model_bytes(serve_tree),
@@ -1616,7 +1617,7 @@ class ServingEngine:
                     "weights -- is the source checkpoint half-written "
                     "or from a different model?")
         else:
-            params = self.model.parameters()[0]
+            params = self.model.weights()
             reason = _spec_mismatch(self._params_spec, _tree_spec(params),
                                     "params")
             if reason is not None:

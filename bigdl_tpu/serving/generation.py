@@ -65,6 +65,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bigdl_tpu.nn.generation_state import (BLOCK, COUNTER, SLOT,
+                                           kinds as state_kinds)
 from bigdl_tpu.observability.spans import now_ns, record_span, span, to_ns
 from bigdl_tpu.serving.buckets import BucketLadder
 
@@ -174,6 +176,10 @@ class GenerateFuture(Future):
         #: prompt positions served straight from the prefix cache
         #: (paged scheduler only; 0 means every position was computed)
         self.prefix_hit_tokens = 0
+        #: device bytes of per-slot state the request held (a model with
+        #: such leaves; its ``request`` span then also says how many rows
+        #: it wrote to the per-token cache), or None
+        self._state_bytes = None
         self._stream: "queue.Queue" = queue.Queue()
         #: set by GenerateScheduler._abandon on a CLAIMED request: the
         #: dispatcher evicts the sequence at the next tick boundary
@@ -244,10 +250,6 @@ class GenerateScheduler:
                  queue_capacity: int = 1024, cache_dtype=jnp.float32,
                  telemetry=None, params_fn=None, admission_check=None,
                  exhausted_hook=None, name: str = "generate"):
-        if not hasattr(model, "init_cache"):
-            raise TypeError(
-                f"{type(model).__name__} has no init_cache(): generation "
-                f"needs a KV-cache decode mode (TransformerLM has one)")
         if slots < 1:
             raise ValueError(f"need at least 1 decode slot, got {slots}")
         self.model = model
@@ -268,7 +270,7 @@ class GenerateScheduler:
         #: this at its MemoryLedger's forensic dump so the first
         #: exhaustion leaves a durable memory_dump event
         self._exhausted_hook = exhausted_hook
-        self._params = params_fn or (lambda: model.parameters()[0])
+        self._params = params_fn or (lambda: model.weights())
         # prompt lengths round up this ladder (rung = the padded prefill
         # T); a COPY like the engine's batch ladder, so growth stays ours
         self.prompt_ladder = prompt_ladder.copy() \
@@ -314,6 +316,11 @@ class GenerateScheduler:
     def _setup_steps(self):
         """Compile the step pair and allocate the device cache; the
         paged subclass overrides this with the pool + allocator."""
+        if not hasattr(self.model, "init_cache"):
+            raise TypeError(
+                f"{type(self.model).__name__} has no init_cache(): "
+                f"generation needs a KV-cache decode mode (TransformerLM "
+                f"has one)")
         self._prefill_fn, self._decode_fn = generate_steps(
             self.model, self._cache_dtype)
         self._cache = self.model.init_cache(self.slots + 1, self.max_len,
@@ -466,6 +473,14 @@ class GenerateScheduler:
                     else:
                         f._stream.put(None)
                 self._sweep_abandoned()
+                if self._cache is None and claimed:
+                    # a failed tick lost the pool and could not rebuild it
+                    try:
+                        self._reset_pool()
+                    except Exception as lost:
+                        for _p, f in claimed:
+                            self._fail_request(f, lost)
+                        claimed = []
                 placed = self._admit(claimed) if claimed else None
                 adm.set(requests=len(claimed))
             tick.set(queue_depth=qdepth, **self._occupancy(claimed))
@@ -610,7 +625,24 @@ class GenerateScheduler:
             self._release_slot(i, slot)
         with self._lock:
             self._free.extend(extra_free)
-        self._reset_pool()
+        try:
+            self._reset_pool()
+        except Exception as lost:
+            # the pool cannot be rebuilt (the device has no room left, or
+            # is gone): nothing can be decoded until it can, so nobody
+            # may be left waiting on it.  ``_iterate`` tries again before
+            # it admits anything
+            log.exception("the generation pool could not be rebuilt")
+            self._release_cache()
+            with self._lock:
+                waiting = [f for _p, f in self._pending]
+                self._pending.clear()
+                self._not_full.notify_all()
+            for f in waiting:
+                if f.set_running_or_notify_cancel():
+                    self._fail_request(f, lost)
+                else:
+                    f._stream.put(None)
         for f in failed:
             if not f.done():
                 self._fail_request(f, e)
@@ -712,7 +744,10 @@ class GenerateScheduler:
             queue_wait_ns=int(fut.queue_wait_s * 1e9),
             prefill_ns=int(max(0.0, first - admit) * 1e9),
             decode_ns=int(max(0.0, now - first) * 1e9),
-            finish_reason=fut.finish_reason)
+            finish_reason=fut.finish_reason,
+            **({} if fut._state_bytes is None else
+               {"state_bytes": fut._state_bytes,
+                "latent_tokens": fut.prompt_len + n_tokens}))
 
     def _record_request_trace(self, fut, n_tokens):
         """Completion span for one traced generation -- the decode-side
@@ -871,6 +906,17 @@ class GenerateScheduler:
         return False
 
 
+def _state_kinds(model, cache_dtype):
+    """The kinds of the model's generation-state leaves, as a tree like
+    the pool's (nn/generation_state.py): every model with a paged cache
+    declares them (``paged_state_spec``)."""
+    return state_kinds(model.paged_state_spec(cache_dtype))
+
+
+def _has_slot_leaves(kinds):
+    return SLOT in jax.tree.leaves(kinds)
+
+
 def paged_generate_steps(model, cache_dtype=jnp.float32):
     """The jitted step triple for PAGED generation, compiled once per
     (model, cache dtype) and cached on the instance like
@@ -895,6 +941,15 @@ def paged_generate_steps(model, cache_dtype=jnp.float32):
     RNG folds on (seed, token position) -- a request replays
     identically however it was chunked or slotted.  All three steps
     donate the pool.
+
+    A model whose generation state has per-SLOT leaves
+    (``paged_state_spec``, nn/generation_state.py) is also told which slot
+    each row is: both steps take one more argument, ``slots (B,)`` or
+    ``(S,)``, the trash slot for a row that is padding or not live.  A
+    model without such leaves is never handed it, and compiles to the
+    programs it always had.  A model that sets ``paged_logits_at`` is
+    asked by the chunk step for the logits of one position a row
+    (``apply_paged(..., logits_at=)``), with or without slot leaves.
     """
     from bigdl_tpu.serving.sampling import sample_tokens
 
@@ -904,12 +959,22 @@ def paged_generate_steps(model, cache_dtype=jnp.float32):
     if fns is not None:
         return fns
 
+    kinds = _state_kinds(model, cache_dtype)
+
     def chunk_prefill(params, pool, tokens, start, lengths, tables,
-                      temperature, top_k, top_p, seed):
+                      temperature, top_k, top_p, seed, slots=None):
         tc = tokens.shape[1]
+        last = lambda: jnp.clip(lengths.astype(jnp.int32) - 1, 0, tc - 1)
+        kw = {} if slots is None else {"slots": slots}
+        if getattr(model, "paged_logits_at", False):
+            # only the last valid position's logits are wanted: a model
+            # that says so can be asked for one position a row (a chunk
+            # of 512 tokens then forms no 512 x vocabulary logits)
+            kw["logits_at"] = last()
         logits, new = model.apply_paged(params, tokens, pool, tables,
-                                        pos=start, lengths=lengths)
-        idx = jnp.clip(lengths.astype(jnp.int32) - 1, 0, tc - 1)
+                                        pos=start, lengths=lengths, **kw)
+        idx = jnp.zeros_like(kw["logits_at"]) if "logits_at" in kw \
+            else last()
         row = jnp.take_along_axis(
             logits, idx[:, None, None], axis=1)[:, 0]
         # the sampled token OCCUPIES position start + lengths; folding
@@ -920,22 +985,26 @@ def paged_generate_steps(model, cache_dtype=jnp.float32):
         return first, new
 
     def decode(params, pool, tokens, pos, tables, temperature, top_k,
-               top_p, seed):
+               top_p, seed, slots=None):
+        kw = {} if slots is None else {"slots": slots}
         logits, new = model.apply_paged(params, tokens[:, None], pool,
-                                        tables, pos=pos)
+                                        tables, pos=pos, **kw)
         nxt = sample_tokens(logits[:, 0], temperature, top_k, top_p,
                             seed, pos + 1)
         return nxt, new
 
     def copy_block(pool, src, dst):
-        def cp(leaf):
-            # pool leaves are (NB, bs, H * Dh), scales (NB, bs, H); the
+        def cp(leaf, kind):
+            # block leaves are (NB, bs, H * Dh), scales (NB, bs, H); the
             # scan-stacked layout adds a leading layer axis -- the block
-            # axis sits at ndim - 3 either way
+            # axis sits at ndim - 3 either way.  Per-slot leaves and
+            # counters hold nothing of a block.
+            if kind != BLOCK:
+                return leaf
             if leaf.ndim == 3:
                 return leaf.at[dst].set(leaf[src])
             return leaf.at[:, dst].set(leaf[:, src])
-        return jax.tree.map(cp, pool)
+        return jax.tree.map(cp, pool, kinds)
 
     fns = (jax.jit(chunk_prefill, donate_argnums=(1,)),
            jax.jit(decode, donate_argnums=(1,)),
@@ -1053,9 +1122,32 @@ class PagedGenerateScheduler(GenerateScheduler):
     def _setup_steps(self):
         self._chunk_fn, self._decode_fn, self._copy_fn = \
             paged_generate_steps(self.model, self._cache_dtype)
+        #: the kinds of the pool's leaves (nn/generation_state.py)
+        self._kinds = _state_kinds(self.model, self._cache_dtype)
+        #: per-SLOT leaves beside the blocks: rows are then told their
+        #: slot, a slot is reset by its sequence's first chunk, and no
+        #: prompt is served from the prefix cache (a recurrent state
+        #: cannot be rebuilt from the blocks a prefix shares)
+        self._slot_state = _has_slot_leaves(self._kinds)
+        self._build_pool()
+
+    def _build_pool(self):
+        kw = {"slots": self.slots} if self._slot_state else {}
         self._cache = self.model.init_paged_cache(
-            self.num_blocks, self.block_size, self._cache_dtype)
+            self.num_blocks, self.block_size, self._cache_dtype, **kw)
         self._alloc = self._make_alloc()
+
+    def _leaves_of(self, kind):
+        """The pool's leaves of one kind."""
+        return [leaf for leaf, k in zip(jax.tree.leaves(self._cache),
+                                        jax.tree.leaves(self._kinds))
+                if k == kind]
+
+    def slot_state_bytes(self) -> int:
+        """Device bytes of per-slot state ONE sequence holds."""
+        return int(sum(leaf.size * leaf.dtype.itemsize
+                       for leaf in self._leaves_of(SLOT))) \
+            // (self.slots + 1)
 
     def kv_dtype(self) -> str:
         """Short storage-dtype name of the paged pool ("fp32"/"int8"),
@@ -1074,18 +1166,17 @@ class PagedGenerateScheduler(GenerateScheduler):
         from bigdl_tpu.serving.paging import BlockAllocator
 
         pool_bytes = sum(leaf.size * leaf.dtype.itemsize
-                         for leaf in jax.tree.leaves(self._cache))
+                         for leaf in self._leaves_of(BLOCK))
         return BlockAllocator(
             self.num_blocks, self.block_size, kv_dtype=self.kv_dtype(),
-            bytes_per_block=int(pool_bytes) // (self.num_blocks + 1))
+            bytes_per_block=int(pool_bytes) // (self.num_blocks + 1),
+            share_prefixes=not self._slot_state)
 
     def _reset_pool(self):
         # a failed donating tick killed the device pool, so every
         # cached prefix block's CONTENT is gone too: fresh allocator,
         # empty registry (the base already released live sequences)
-        self._cache = self.model.init_paged_cache(
-            self.num_blocks, self.block_size, self._cache_dtype)
-        self._alloc = self._make_alloc()
+        self._build_pool()
 
     def flush_prefix_cache(self):
         """Invalidate cached prefix blocks (engine weight swaps call
@@ -1121,7 +1212,7 @@ class PagedGenerateScheduler(GenerateScheduler):
         nxt, dummy = self._decode_fn(
             params, dummy, np.zeros((s,), np.int32),
             np.zeros((s,), np.int32), np.full((s, mb), trash, np.int32),
-            *knobs(s))
+            *knobs(s), *self._slot_rows(s))
         jax.block_until_ready(nxt)
         tc = self.prefill_chunk
         for b in self.batch_ladder:
@@ -1129,7 +1220,8 @@ class PagedGenerateScheduler(GenerateScheduler):
             first, dummy = self._chunk_fn(
                 params, dummy, np.zeros((b, tc), np.int32),
                 np.zeros((b,), np.int32), np.ones((b,), np.int32),
-                np.full((b, mb), trash, np.int32), *knobs(b))
+                np.full((b, mb), trash, np.int32), *knobs(b),
+                *self._slot_rows(b))
             jax.block_until_ready(first)
         dummy = self._copy_fn(dummy, np.int32(0), np.int32(0))
         jax.block_until_ready(jax.tree.leaves(dummy)[0])
@@ -1168,6 +1260,7 @@ class PagedGenerateScheduler(GenerateScheduler):
         t_admit = time.perf_counter()
         for p, f in reqs:
             f._t_admit = t_admit     # queue wait ends at slot admission
+        admitted = 0
         for p, f in reqs:
             sp = f.sampling
             seed = 0
@@ -1199,10 +1292,46 @@ class PagedGenerateScheduler(GenerateScheduler):
             self._hit_tokens_delta += cached
             self._prompt_tokens_delta += int(p.size)
             self._slots[idx] = _PagedSlot(f, p, seq, cached, seed)
+            admitted += 1
+            if self._slot_state:
+                f._state_bytes = self.slot_state_bytes()
+        if self._slot_state and admitted:
+            # the slots' leaves are zeroed on the device by each
+            # sequence's first chunk (it starts at position 0)
+            now = now_ns()
+            record_span("state_reset", now, now, nest=True, slots=admitted)
 
     def _sampling_rows(self, n):
         return (np.zeros((n,), np.float32), np.zeros((n,), np.int32),
                 np.ones((n,), np.float32), np.zeros((n,), np.int32))
+
+    def _slot_rows(self, n):
+        """The steps' ``slots`` argument for ``n`` rows, every row on the
+        trash slot; nothing for a model without per-slot state."""
+        return (np.full((n,), self.slots, np.int32),) \
+            if self._slot_state else ()
+
+    def _fetch(self, tokens):
+        """The tick's host sync: its tokens and, with them, whatever the
+        step counted (``counter`` leaves) -- one fetch, no sync of its
+        own."""
+        counted = self._leaves_of(COUNTER)
+        if not counted:
+            return np.asarray(tokens), None
+        tokens, counted = jax.device_get((tokens, counted))
+        return np.asarray(tokens), counted
+
+    def _record_counts(self, counted):
+        """What the step counted, as spans under the open ``tick``: one a
+        counter leaf, named as the model names its counters
+        (``tick_counters``: span name -> attribute names)."""
+        if counted is None:
+            return
+        now = now_ns()
+        for (name, attrs), leaf in zip(self.model.tick_counters.items(),
+                                       counted):
+            record_span(name, now, now, nest=True,
+                        **{k: int(v) for k, v in zip(attrs, leaf)})
 
     @staticmethod
     def _fill_sampling(arrs, r, slot):
@@ -1246,11 +1375,14 @@ class PagedGenerateScheduler(GenerateScheduler):
             lens = np.zeros((bucket,), np.int32)
             tables = np.full((bucket, mb), self._alloc.trash, np.int32)
             knobs = self._sampling_rows(bucket)
+            slot_ids = self._slot_rows(bucket)
             for r, (i, s) in enumerate(rows):
                 chunk = s.prompt[s.consumed:s.consumed + tc]
                 tokens[r, :chunk.size] = chunk
                 start[r] = s.consumed
                 lens[r] = chunk.size
+                if slot_ids:
+                    slot_ids[0][r] = i
                 self._cow_guard(s, s.consumed, s.consumed + chunk.size - 1)
                 tables[r] = self._alloc.table_row(s.seq, mb)
                 self._fill_sampling(knobs, r, s)
@@ -1260,14 +1392,15 @@ class PagedGenerateScheduler(GenerateScheduler):
                 with span("launch"):
                     first, self._cache = self._chunk_fn(
                         self._params(), self._cache, tokens, start, lens,
-                        tables, *knobs)
+                        tables, *knobs, *slot_ids)
                 with span("fetch"):
-                    first = np.asarray(first)        # host sync
+                    first, counted = self._fetch(first)     # host sync
                 self._mirror_chunk(tokens, start, lens, tables, knobs)
         except Exception as e:
             log.exception("chunk prefill tick failed (%d prompts)", n)
             self._tick_failed(e, [], [])
             return
+        self._record_counts(counted)
         done_lat = []
         emitted = 0
         with span("deliver") as dlv:
@@ -1303,10 +1436,13 @@ class PagedGenerateScheduler(GenerateScheduler):
             pos = np.zeros((s_n,), np.int32)
             tables = np.full((s_n, mb), self._alloc.trash, np.int32)
             knobs = self._sampling_rows(s_n)
+            slot_ids = self._slot_rows(s_n)
             for i, s in active:
                 self._cow_guard(s, s.pos, s.pos)
                 tokens[i] = s.last
                 pos[i] = s.pos
+                if slot_ids:
+                    slot_ids[0][i] = i
                 tables[i] = self._alloc.table_row(s.seq, mb)
                 self._fill_sampling(knobs, i, s)
         try:
@@ -1315,13 +1451,14 @@ class PagedGenerateScheduler(GenerateScheduler):
                 with span("launch"):
                     nxt, self._cache = self._decode_fn(
                         self._params(), self._cache, tokens, pos, tables,
-                        *knobs)
+                        *knobs, *slot_ids)
                 with span("fetch"):
-                    nxt = np.asarray(nxt)            # host sync
+                    nxt, counted = self._fetch(nxt)         # host sync
         except Exception as e:
             log.exception("decode tick failed (%d slots)", len(active))
             self._tick_failed(e, [], [])
             return
+        self._record_counts(counted)
         done_lat = []
         with span("deliver", tokens=len(active)) as dlv:
             for i, s in active:
@@ -1465,10 +1602,19 @@ class SpeculativeScheduler(PagedGenerateScheduler):
                 f"{type(draft_model).__name__} has no init_paged_cache():"
                 f" the drafter must run the same paged decode mode as "
                 f"the verifier")
+        for m in (model, draft_model):
+            if _has_slot_leaves(
+                    _state_kinds(m, kw.get("cache_dtype", jnp.float32))):
+                raise TypeError(
+                    f"{type(m).__name__} keeps per-slot generation state "
+                    f"(a recurrent state or a convolution's tail): a "
+                    f"rejected draft cannot be rolled back out of it, so "
+                    f"speculative decoding is not offered for it; serve it "
+                    f"with speculative=0")
         self.spec_k = int(spec_k)
         self.draft_model = draft_model
         self._dparams = draft_params_fn or \
-            (lambda: draft_model.parameters()[0])
+            (lambda: draft_model.weights())
         self._spec_rounds = 0
         self._spec_drafted = 0
         self._spec_accepted = 0

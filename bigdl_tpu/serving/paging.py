@@ -82,7 +82,8 @@ class BlockAllocator:
     serve new prompts)."""
 
     def __init__(self, num_blocks: int, block_size: int,
-                 kv_dtype: str = "fp32", bytes_per_block=None):
+                 kv_dtype: str = "fp32", bytes_per_block=None,
+                 share_prefixes: bool = True):
         if num_blocks < 1 or block_size < 1:
             raise ValueError(
                 f"need num_blocks >= 1 and block_size >= 1, got "
@@ -102,6 +103,12 @@ class BlockAllocator:
         #: jax to measure it itself.  None until a pool owner sets it.
         self.bytes_per_block = None if bytes_per_block is None \
             else int(bytes_per_block)
+        #: False for a pool whose model also keeps per-SEQUENCE state (a
+        #: recurrent state, a convolution's tail): the blocks of a shared
+        #: prefix hold only the per-token part of what the prefix left
+        #: behind, so no block is hashed, registered or matched, and
+        #: every prompt is computed from its first token
+        self.share_prefixes = bool(share_prefixes)
         self.trash = self.num_blocks
         self._lock = threading.Lock()
         self._free = collections.deque(range(self.num_blocks))
@@ -202,6 +209,8 @@ class BlockAllocator:
         prompt = [int(t) for t in prompt]
         matchable = max(0, (len(prompt) - 1) // bs)   # full blocks only,
         #                                               last token excluded
+        if not self.share_prefixes:
+            matchable = 0
         with self._lock:
             if seq_id in self._seqs:
                 raise ValueError(f"sequence {seq_id!r} already admitted")

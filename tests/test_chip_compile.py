@@ -18,6 +18,7 @@ from jax.sharding import SingleDeviceSharding
 
 from bigdl_tpu.ops.cross_entropy import fused_softmax_cross_entropy
 from bigdl_tpu.ops.grouped_matmul import buffer_rows, grouped_matmul
+from bigdl_tpu.ops.kda import kda_decode_step
 from bigdl_tpu.ops.flash_attention import (flash_attention,
                                            flash_decode_attention,
                                            flash_paged_decode_attention,
@@ -90,6 +91,19 @@ def _grouped(k, n, rows=65536, groups=8):
     return [((m, k), bf16), ((groups, k, n), bf16), ((groups,), i32)]
 
 
+def _grouped_small(lhs, rhs, sizes):
+    # a decode tick's tiles: 16 rows, one bf16 tile
+    return grouped_matmul(lhs, rhs, sizes, block_rows=16)
+
+
+def _kda(slots, h=32, d=128):
+    """The delta-rule decode step of the Ling cell: every slot's state and
+    the trash slot's, one token a slot."""
+    vec = ((slots, h, d), f32)
+    return [((slots + 1, h, d, d), f32), ((slots,), i32), vec, vec, vec, vec,
+            ((slots, h), f32)]
+
+
 def _qkv(b, t, d, dt, h=16):
     return [((b, t, h, d), dt)] * 3
 
@@ -149,6 +163,16 @@ CASES = {
     "grouped-up": (grouped_matmul, _grouped(2048, 1792), None),
     "grouped-grad-up": (_grouped_grad, _grouped(2048, 1792), None),
     "grouped-grad-down": (_grouped_grad, _grouped(1792, 2048), None),
+    # the Ling serving cell: the delta-rule decode step over 32 slots of 32
+    # heads of 128 x 128, and its experts at decode rows (2560 -> 768 and
+    # back, 128 held, 256 assignments at worst, tiles of 16 rows)
+    "kda-decode-cell": (kda_decode_step, _kda(32), None),
+    "grouped-decode-up": (_grouped_small, [
+        ((buffer_rows(256, 128, 16), 2560), bf16), ((128, 2560, 768), bf16),
+        ((128,), i32)], None),
+    "grouped-decode-down": (_grouped_small, [
+        ((buffer_rows(256, 128, 16), 768), bf16), ((128, 768, 2560), bf16),
+        ((128,), i32)], None),
     # train_lm's head: 2 sequences of 2048 tokens, vocab 32000
     "ce-forward": (fused_softmax_cross_entropy,
                    [((4096, 32000), f32), ((4096,), i32)], None),

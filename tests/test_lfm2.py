@@ -237,6 +237,26 @@ def session(seed=3, mix=None):
     return s
 
 
+@pytest.mark.parametrize("tiles,asked", [
+    # training (512 rows of LFM2's 2048 x 896 and 1792 x 1024 weight tiles)
+    (((512, 2048), (2048, 896), (512, 896)), True),
+    (((512, 1792), (1792, 1024), (512, 1024)), True),
+    # generation (16 to 256 rows of Ling's 2560 x 256 and 768 x 512 tiles)
+    (((16, 2560), (2560, 256), (16, 256)), False),
+    (((256, 2560), (2560, 256), (256, 256)), False),
+    (((256, 768), (768, 512), (256, 512)), False)])
+def test_grouped_matmul_claims_vmem_only_for_tiles_that_need_it(tiles,
+                                                                asked):
+    """A limit over the compiler's default re-lays the VMEM of the whole
+    program around the call (the chunk step of ``models/ling.py`` never
+    returned on the chip under it, PERF.md section 6, PR 33): only tiles
+    that do not fit under the default ask for it."""
+    from bigdl_tpu.ops import grouped_matmul as gm
+
+    limit = gm._vmem_limit(*tiles, itemsize=2)
+    assert limit == (gm._VMEM_LIMIT if asked else None)
+
+
 def test_whole_model_loss_and_gradients_against_reference():
     """The rehearsal sizes (a conv layer with the dense FFN, an attention
     layer and a conv layer with experts), float32: loss to 1e-5, every

@@ -262,6 +262,13 @@ EXAMPLES = {
     "GatedMLP": (lambda: nn.GatedMLP(8, 12), lambda: _r(2, 5, 8)),
     "DroplessMoE": (lambda: nn.DroplessMoE(8, 4, 4, 2, experts_held=(1, 2)),
                     lambda: _r(2, 5, 8)),
+    "KimiDeltaAttention": (
+        lambda: nn.KimiDeltaAttention(8, 2, head_dim=4, use_kernel="never"),
+        lambda: _r(2, 5, 8)),
+    "LatentAttention": (
+        lambda: nn.LatentAttention(8, 2, kv_rank=4, nope_dim=4, rope_dim=2,
+                                   v_dim=4),
+        lambda: _r(2, 5, 8)),
     "TransformerBlock": (lambda: nn.TransformerBlock(8, 2),
                          lambda: _r(2, 5, 8)),
     "TransformerLM": (lambda: nn.TransformerLM(11, 8, 2, 2, max_len=6),
