@@ -254,6 +254,11 @@ class MultiHeadAttention(Module):
         caller includes the trash block in ``num_blocks`` (by
         convention the last id).
 
+        Block ``b`` of this layer is ``leaf[b]``.  A ``scan_layers``
+        model stacks its layers' leaves (``TransformerLM.
+        init_paged_cache``) and block ``b`` of layer ``l`` is then
+        ``leaf[l, b]``; ``_apply_paged`` takes either.
+
         Heads and head_dim share the last axis so that a block is one
         contiguous piece of device memory.  A TPU array's last two axes
         are tiled (8 x 128 fp32), and the compiler stores a
@@ -321,12 +326,27 @@ class MultiHeadAttention(Module):
         return _on_tpu() and block_size % tile_rows == 0 \
             and self.hidden_size % 128 == 0
 
-    def _apply_paged(self, params, input, pool, tables, pos, lengths):
+    def _apply_paged(self, params, input, pool, tables, pos, lengths,
+                     layer=None):
         """Incremental attention against a paged K/V pool.  Returns
         ``(y, new_pool)``.  ``tables`` maps each row's LOGICAL block
         index to a physical pool block, padded with the trash block id
         (the pool's last block), so the compiled step never sees how
         long any sequence really is.
+
+        Where a block lies is told by what is handed in.  With
+        ``layer=None`` ``pool`` is this layer's own leaves ``(NB, bs,
+        H * D)`` and block ``b`` is ``leaf[b]`` (the unrolled layout).
+        With ``layer`` an int32 scalar, traced inside the layer loop,
+        ``pool`` is the layer-STACKED leaves ``(L, NB, bs, H * D)`` of a
+        ``scan_layers`` model and block ``b`` is ``leaf[layer, b]``: rows
+        are written ``.at[layer, block, offset]``, a row's context is one
+        gather over ``(layer, tables)`` and the decode kernel indexes
+        ``(layer, block)`` itself.  ``leaf[layer]`` is never formed (it
+        would be a copy of a layer's whole pool, 84 MB in the serving
+        cell), so the loop that carries the leaf updates it in place and
+        no program copies the pool.  ``new_pool`` has the shape of
+        ``pool`` either way.
 
         Two shapes, mirroring ``_apply_cached``:
 
@@ -349,10 +369,11 @@ class MultiHeadAttention(Module):
         dt = input.dtype
         cdt = pool["k"].dtype
         quant = "k_scale" in pool      # int8 payload + fp32 scale leaves
-        bs = pool["k"].shape[1]
+        at = () if layer is None else (layer,)
+        bs = pool["k"].shape[-2]
         max_blocks = tables.shape[1]
         ctx = max_blocks * bs
-        trash = pool["k"].shape[0] - 1
+        trash = pool["k"].shape[-3] - 1
         tables = jnp.asarray(tables, jnp.int32)
         pos = jnp.asarray(pos, jnp.int32)
         qkv = self._project_qkv(params, input)
@@ -368,22 +389,29 @@ class MultiHeadAttention(Module):
             if quant:
                 kq, ksc = self._paged_quant(kf)
                 vq, vsc = self._paged_quant(vf)
-                return {"k": pool["k"].at[phys, off].set(kq),
-                        "v": pool["v"].at[phys, off].set(vq),
-                        "k_scale": pool["k_scale"].at[phys, off].set(ksc),
-                        "v_scale": pool["v_scale"].at[phys, off].set(vsc)}
-            return {"k": pool["k"].at[phys, off].set(kf.astype(cdt)),
-                    "v": pool["v"].at[phys, off].set(vf.astype(cdt))}
+                rows = {"k": kq, "v": vq, "k_scale": ksc, "v_scale": vsc}
+            else:
+                rows = {"k": kf.astype(cdt), "v": vf.astype(cdt)}
+            return {name: pool[name].at[at + (phys, off)].set(x)
+                    for name, x in rows.items()}
+
+        def blocks_of(leaf):
+            """``leaf``'s blocks through the tables ``(n, max_blocks,
+            bs, width)``: one gather, over ``(layer, tables)`` on a
+            stacked leaf (``jnp.take`` with its fill mode is what a
+            single layer's leaf has always lowered to)."""
+            if layer is None:
+                return jnp.take(leaf, tables, axis=0)
+            return leaf.at[layer, tables].get(mode="fill")
 
         def gather_ctx(new_pool, name):
             """The row's full mapped context from the pool ``(n, ctx,
             heads, head_dim)``, dequantized to the compute dtype on an
             int8 pool."""
-            raw = jnp.take(new_pool[name], tables, axis=0).reshape(
-                n, ctx, d)
+            raw = blocks_of(new_pool[name]).reshape(n, ctx, d)
             if quant:
-                sc = jnp.take(new_pool[name + "_scale"], tables,
-                              axis=0).reshape(n, ctx, self.num_heads)
+                sc = blocks_of(new_pool[name + "_scale"]).reshape(
+                    n, ctx, self.num_heads)
                 raw = self._paged_dequant(raw, sc, dt)
             return raw.astype(dt).reshape(n, ctx, self.num_heads,
                                           self.head_dim)
@@ -424,6 +452,7 @@ class MultiHeadAttention(Module):
                 y = flash_paged_decode_attention(
                     q.reshape(heads), new_pool["k"], new_pool["v"], tables,
                     pos, new_pool.get("k_scale"), new_pool.get("v_scale"),
+                    layer=layer,
                     interpret=self.use_flash == "interpret").astype(dt)
             else:
                 mask = (jnp.arange(ctx, dtype=jnp.int32)[None, :]
@@ -624,12 +653,15 @@ class TransformerBlock(Container):
         """This block's paged K/V pool (the attention sublayer's)."""
         return self.attn.init_paged_cache(num_blocks, block_size, dtype)
 
-    def apply_paged(self, params, input, pool, tables, pos, lengths=None):
+    def apply_paged(self, params, input, pool, tables, pos, lengths=None,
+                    layer=None):
         """Paged prefill-chunk/decode through this block; returns
-        ``(out, new_pool)`` (see MultiHeadAttention._apply_paged)."""
+        ``(out, new_pool)``.  ``pool`` is this block's own leaves, or
+        with ``layer`` the layer-stacked leaves this block is layer
+        ``layer`` of (see MultiHeadAttention._apply_paged)."""
         h, _ = self.ln1.apply(params["ln1"], (), input)
         a, new_pool = self.attn._apply_paged(params["attn"], h, pool,
-                                             tables, pos, lengths)
+                                             tables, pos, lengths, layer)
         x = input + a
         h, _ = self.ln2.apply(params["ln2"], (), x)
         h, _ = self.fc1.apply(params["fc1"], (), h)
@@ -792,7 +824,14 @@ class TransformerLM(Container):
         ``scan_layers``, mirroring ``init_cache``).  ``num_blocks`` is
         the allocator's pool size; every layer gets ONE EXTRA block on
         top -- the TRASH block, id ``num_blocks`` -- that padded table
-        entries and inactive rows write into (serving/paging.py)."""
+        entries and inactive rows write into (serving/paging.py).
+
+        Where a block lies: unrolled, block ``b`` of layer ``i`` is
+        ``pool["block{i}"][leaf][b]``, a leaf ``(NB + 1, bs, H * D)`` a
+        layer; under ``scan_layers`` it is ``pool["blocks"][leaf][i, b]``,
+        ONE leaf ``(L, NB + 1, bs, H * D)`` for all layers.  Either way
+        ``apply_paged`` writes and reads the leaves where they lie and,
+        the pool donated, no program copies it."""
         return self._layer_caches(
             lambda b: b.init_paged_cache(int(num_blocks) + 1, block_size,
                                          dtype))
@@ -817,7 +856,18 @@ class TransformerLM(Container):
         them through its padded block-table row -- the shapes the
         executable sees never depend on sequence length, block
         residency, or how a prompt was chunked.  Returns ``(logits,
-        new_pool)``."""
+        new_pool)``.
+
+        Unrolled, each layer is handed its own leaves and returns them
+        updated.  Under ``scan_layers`` the stacked leaves ride the layer
+        loop's CARRY, whole, beside the activations, and the loop scans
+        over the stacked params and the layer index: every access
+        addresses ``(layer, block)`` on the stacked leaf
+        (``MultiHeadAttention._apply_paged``), so with the pool donated
+        the compiled program updates it in place -- no layer's leaf is
+        sliced out, restacked or copied, and the program's temporaries
+        hold nothing the size of the pool
+        (tests/test_chip_compile.py)."""
         if self.seq_axis_name is not None:
             raise ValueError("cached decode runs on a replicated model; "
                              "sequence-parallel serving is not a thing "
@@ -836,13 +886,19 @@ class TransformerLM(Container):
         if self.scan_layers:
             inner = self.blocks[0]
 
-            def body(h, sliced):
-                p, c = sliced
-                y, nc = inner.apply_paged(p, h, c, tables, pos, lengths)
-                return y, nc
+            def body(carry, sliced):
+                # the stacked pool rides in the carry, whole: as xs/ys
+                # the loop would slice a layer's leaf out, update the
+                # slice, restack it and copy the result (PERF.md
+                # section 6, PR 34)
+                h, stacked = carry
+                p, layer = sliced
+                return inner.apply_paged(p, h, stacked, tables, pos,
+                                         lengths, layer), None
 
-            x, stacked = jax.lax.scan(
-                body, x, (params["blocks"], pool["blocks"]))
+            layers = jnp.arange(len(self.blocks), dtype=jnp.int32)
+            (x, stacked), _ = jax.lax.scan(
+                body, (x, pool["blocks"]), (params["blocks"], layers))
             new_pool = {"blocks": stacked}
         else:
             new_pool = {}

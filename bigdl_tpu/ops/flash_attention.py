@@ -41,11 +41,13 @@ that bound, and its ``auto`` gate in nn/attention.py asks it before
 selecting the kernel.
 
 ``flash_paged_decode_attention`` (the paged pool's decode kernel) leaves
-the pool in HBM as it is stored, ``(NB, bs, H * D)``, and fetches a slot's
-blocks through its block table by its own DMAs, a step's worth at a time
-into a double buffer, up to the slot's frontier and no further: VMEM holds
-two steps whatever the pool's size, so no gate bounds it by bytes; its
-gate asks only that a block be whole tiles of the pool's dtype.
+the pool in HBM as it is stored, ``(NB, bs, H * D)`` a layer or
+``(L, NB, bs, H * D)`` with the layers stacked and the layer an argument,
+and fetches a slot's blocks through its block table by its own DMAs, a
+step's worth at a time into a double buffer, up to the slot's frontier and
+no further: VMEM holds two steps whatever the pool's size, so no gate
+bounds it by bytes; its gate asks only that a block be whole tiles of the
+pool's dtype.
 """
 
 import functools
@@ -554,12 +556,13 @@ def _split3(x):
     return hi, mid, lo
 
 
-def _paged_decode_kernel(pos_ref, table_ref, q_ref, k_hbm, v_hbm, *rest,
-                         block_size: int, blocks_per_step: int,
+def _paged_decode_kernel(pos_ref, table_ref, layer_ref, q_ref, k_hbm, v_hbm,
+                         *rest, block_size: int, blocks_per_step: int,
                          max_blocks: int, head_dim: int, scale: float,
                          quantized: bool):
     """One decode slot a grid step.  The pool stays in HBM in the layout it
-    is stored in, seen as ``(NB, bs, H * D)``: the slot's blocks come
+    is stored in, seen as ``(L, NB, bs, H * D)``: a block lies at
+    ``(layer_ref[0], table entry)``, and the slot's blocks come
     through its row of the table ``blocks_per_step`` at a time, each block
     one DMA of all its heads, into the other half of a double buffer while
     this half is computed on -- and the first step of the NEXT slot while
@@ -590,14 +593,14 @@ def _paged_decode_kernel(pos_ref, table_ref, q_ref, k_hbm, v_hbm, *rest,
     def copies(slot, step, half):
         """The DMAs of one step of one slot, each with whether its block
         is live (at or before the slot's frontier)."""
-        last = frontier(slot)
+        last, layer = frontier(slot), layer_ref[0]
         for j in range(g):
             blk = step * g + j
             phys = table_ref[slot * max_blocks + jnp.minimum(blk, last)]
             for a, (pool, buf) in enumerate(((k_hbm, k_buf),
                                              (v_hbm, v_buf))):
                 yield blk <= last, pltpu.make_async_copy(
-                    pool.at[phys], buf.at[half, pl.ds(j * bs, bs)],
+                    pool.at[layer, phys], buf.at[half, pl.ds(j * bs, bs)],
                     sem.at[half, a])
 
     def start(slot, step, half):
@@ -701,12 +704,21 @@ def _paged_blocks_per_step(block_size: int, width: int,
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def flash_paged_decode_attention(q, k_pool, v_pool, tables, pos,
-                                 k_scale=None, v_scale=None,
+                                 k_scale=None, v_scale=None, layer=None,
                                  interpret: bool = False):
     """Single-token decode attention through a PAGED K/V pool:
-    ``q (B, 1, H, D)`` against pools ``k_pool, v_pool (NB, bs, H * D)``
-    addressed by per-row block tables ``tables (B, max_blocks)`` with
-    frontier positions ``pos (B,)`` -> ``(B, 1, H, D)``.
+    ``q (B, 1, H, D)`` against pools ``k_pool, v_pool`` addressed by
+    per-row block tables ``tables (B, max_blocks)`` with frontier positions
+    ``pos (B,)`` -> ``(B, 1, H, D)``.
+
+    Where a block lies is told by the pool's shape.  One layer's leaf is
+    ``(NB, bs, H * D)`` and block ``b`` is ``pool[b]`` (the unrolled
+    layout).  The layer-stacked leaf of a ``scan_layers`` model is
+    ``(L, NB, bs, H * D)``, ``layer`` (an int32 scalar, traced inside the
+    layer loop) says which layer is asked for, and block ``b`` is
+    ``pool[layer, b]``: the whole leaf is handed over and no layer of it is
+    sliced out, so the loop that carries it never copies it.  The first is
+    the second with one layer.
 
     The pool is read where it lies, in the shape ``init_paged_cache``
     stores it in (heads and head_dim on one axis: the TPU compiler lays a
@@ -718,38 +730,48 @@ def flash_paged_decode_attention(q, k_pool, v_pool, tables, pos,
     never exists, and nothing of the pool is copied or transposed before
     the call.  VMEM holds two steps' blocks, whatever the pool's size.
 
-    ``k_scale``/``v_scale`` (both or neither, ``(NB, bs, H)`` fp32)
-    select the INT8 pool layout: payloads are int8 and become fp32 in the
-    kernel, so the pool's traffic stays at the narrow width; the scales,
-    a sixteenth of the payload at D=64 and too narrow for a DMA of their
-    own (Mosaic pads their 16 lanes to 128 in HBM and refuses the slice),
-    are gathered by XLA, all ``max_blocks`` of a row.
+    ``k_scale``/``v_scale`` (both or neither, fp32 ``(NB, bs, H)``, or
+    ``(L, NB, bs, H)`` beside a stacked pool) select the INT8 pool layout:
+    payloads are int8 and become fp32 in the kernel, so the pool's traffic
+    stays at the narrow width; the scales, a sixteenth of the payload at
+    D=64 and too narrow for a DMA of their own (Mosaic pads their 16 lanes
+    to 128 in HBM and refuses the slice), are gathered by XLA, all
+    ``max_blocks`` of a row, by one gather over ``(layer, tables)``.
     ``interpret=True`` runs on the CPU for tests.  On a TPU ``bs`` must be
     a multiple of the pool dtype's sublane tile (8 fp32, 16 bf16, 32 int8)
     and ``H * D`` of 128 (``MultiHeadAttention._flash_paged_ok``).
     """
     b, t1, h, d = q.shape
-    bs = k_pool.shape[1]
     max_blocks = tables.shape[1]
     assert t1 == 1, f"decode takes one query token per row, got {t1}"
-    assert k_pool.shape[2] == h * d, (k_pool.shape, q.shape)
     quantized = k_scale is not None
     assert (v_scale is not None) == quantized, \
         "pass both k_scale and v_scale or neither"
+    if k_pool.ndim == 3:
+        assert layer is None, "a single layer's leaf has no layer to ask for"
+        layer = 0
+        k_pool, v_pool = k_pool[None], v_pool[None]
+        if quantized:
+            k_scale, v_scale = k_scale[None], v_scale[None]
+    assert layer is not None, "a stacked pool needs the layer"
+    assert k_pool.shape[3] == h * d, (k_pool.shape, q.shape)
+    layer = jnp.asarray(layer, jnp.int32)
+    bs = k_pool.shape[2]
     tables = jnp.asarray(tables, jnp.int32)
     width = h * d
     g = _paged_blocks_per_step(bs, width, max_blocks)
     steps = -(-max_blocks // g)
 
     def slot_block(*shape):
-        return pl.BlockSpec((None,) + shape,
-                            lambda i, pos, tables: (i,) + (0,) * len(shape))
+        return pl.BlockSpec(
+            (None,) + shape,
+            lambda i, pos, tables, layer: (i,) + (0,) * len(shape))
 
     def gathered(scales):
-        # (NB, bs, H) -> (B, steps, H, rows): a step's positions on the
+        # (L, NB, bs, H) -> (B, steps, H, rows): a step's positions on the
         # lanes, as its scores have them
-        x = jnp.take(scales.astype(jnp.float32), tables,
-                     axis=0).reshape(b, max_blocks * bs, h)
+        x = scales.at[layer, tables].get(mode="fill").astype(
+            jnp.float32).reshape(b, max_blocks * bs, h)
         x = jnp.pad(x, ((0, 0), (0, (steps * g - max_blocks) * bs), (0, 0)))
         return x.reshape(b, steps, g * bs, h).transpose(0, 1, 3, 2)
 
@@ -766,7 +788,7 @@ def flash_paged_decode_attention(q, k_pool, v_pool, tables, pos,
                           head_dim=d, scale=1.0 / math.sqrt(d),
                           quantized=quantized),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(b,),
             in_specs=in_specs,
             out_specs=slot_block(1, width),
@@ -785,5 +807,6 @@ def flash_paged_decode_attention(q, k_pool, v_pool, tables, pos,
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="flash_paged_decode_attention",
-    )(jnp.asarray(pos, jnp.int32), tables.reshape(-1), *args)
+    )(jnp.asarray(pos, jnp.int32), tables.reshape(-1), layer.reshape(1),
+      *args)
     return out.reshape(b, 1, h, d)

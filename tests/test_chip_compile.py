@@ -113,13 +113,15 @@ def _decode(b, t, d, dt, h=16):
             ((b,), i32)]
 
 
-def _paged(b, nb, bs, d, dtype, h=16, mb=64):
+def _paged(b, nb, bs, d, dtype, h=16, mb=64, layers=None):
     """The pool as the engine stores it: ``(NB, bs, H * D)``, an int8
-    one with ``(NB, bs, H)`` fp32 scales."""
-    pool = ((nb, bs, h * d), dtype)
-    scale = ((nb, bs, h), f32) if dtype == i8 else None
+    one with ``(NB, bs, H)`` fp32 scales; with ``layers`` the stacked leaf
+    of a ``scan_layers`` model, ``(L, NB, bs, H * D)``, and the layer."""
+    stack = () if layers is None else (layers,)
+    pool = (stack + (nb, bs, h * d), dtype)
+    scale = (stack + (nb, bs, h), f32) if dtype == i8 else None
     return [((b, 1, h, d), f32), pool, pool, ((b, mb), i32), ((b,), i32),
-            scale, scale]
+            scale, scale, None if layers is None else ((), i32)]
 
 
 # (kernel, shapes, what the auto gate is asked: rows, head_dim, dtype)
@@ -150,6 +152,15 @@ CASES = {
                         _paged(32, 1281, 16, 64, bf16), None),
     "paged-large-int8": (flash_paged_decode_attention,
                          _paged(8, 641, 32, 96, i8, mb=32), None),
+    # the cell's pool as its scan_layers model holds it, all 24 layers in
+    # one leaf (3.75 GiB each of K and V) and the layer an argument; and
+    # an int8 one with its stacked scales
+    "paged-cell-stacked-fp32": (flash_paged_decode_attention,
+                                _paged(32, 1281, 16, 64, f32, layers=24),
+                                None),
+    "paged-stacked-int8": (flash_paged_decode_attention,
+                           _paged(8, 641, 32, 96, i8, mb=32, layers=4),
+                           None),
     # the LFM2 cell: one call of the attention layer takes a row's 8 KV
     # heads as 8 pairs of 4 query heads at 4096 positions
     "flash-grad-lfm2": (_flash_grad, _qkv(8, 4096, 64, bf16, h=4), None),
@@ -238,6 +249,46 @@ def test_paged_gate_reads_tile_and_width(monkeypatch, block_size, dtype,
     assert not attn._flash_paged_ok(block_size, dtype)
 
 
+def _cell_paged_programs(one_chip, monkeypatch, scan_layers):
+    """The engine's paged programs at the serving cell's widths and pool
+    (32 slots, 1280 blocks of 16 and the trash block, 64 table entries;
+    two layers, a small vocabulary), lowered for the described chip with
+    the gate seeing a TPU: ``{"decode": ..., "chunk": ...}`` (the chunk
+    program at two rows of 64 tokens) and the pool's bytes."""
+    import bigdl_tpu.nn.attention as attention
+    from bigdl_tpu.serving.generation import paged_generate_steps
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    slots, rows, blocks, bs, entries = 32, 2, 1280, 16, 64
+    model = attention.TransformerLM(512, 1024, 16, 2, max_len=1024,
+                                    scan_layers=scan_layers)
+    model.build(jax.ShapeDtypeStruct((2, 16), i32))
+    assert model.blocks[0].attn._flash_paged_ok(bs, f32)
+
+    def described(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    def arg(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def knobs(n):
+        return arg(f32, n), arg(i32, n), arg(f32, n), arg(i32, n)
+
+    pool = jax.eval_shape(lambda: model.init_paged_cache(blocks, bs, f32))
+    pool_bytes = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(pool))
+    pool = jax.tree.map(described, pool)
+    weights = jax.tree.map(described, model.parameters()[0])
+    chunk, decode, _ = paged_generate_steps(model, f32)
+    return {
+        "decode": decode.lower(
+            weights, pool, arg(i32, slots), arg(i32, slots),
+            arg(i32, slots, entries), *knobs(slots)),
+        "chunk": chunk.lower(
+            weights, pool, arg(i32, rows, 64), arg(i32, rows),
+            arg(i32, rows), arg(i32, rows, entries), *knobs(rows)),
+    }, pool_bytes
+
+
 def test_decode_step_is_one_kernel_a_layer(one_chip, monkeypatch):
     """The engine's ``jit_decode`` at the serving cell's widths and pool
     (two layers, a small vocabulary), compiled for the described chip
@@ -246,35 +297,37 @@ def test_decode_step_is_one_kernel_a_layer(one_chip, monkeypatch):
     leaves neither copied nor transposed on their way to the kernel."""
     import re
 
-    import bigdl_tpu.nn.attention as attention
-    from bigdl_tpu.serving.generation import paged_generate_steps
+    programs, _ = _cell_paged_programs(one_chip, monkeypatch, False)
+    text = programs["decode"].compile().as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert text.count("flash_paged_decode_attention") >= 2
+    assert not re.search(r"\[32,(1024|64,16),", text)
+    assert not re.search(r"f32\[1281,16,1024\]\S* (copy|transpose)\(", text)
 
-    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
-    slots, blocks, bs, entries, layers = 32, 1280, 16, 64, 2
-    model = attention.TransformerLM(512, 1024, 16, layers, max_len=1024)
-    model.build(jax.ShapeDtypeStruct((2, 16), i32))
-    assert model.blocks[0].attn._flash_paged_ok(bs, f32)
 
-    def described(x):
-        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_scan_layout_leaves_the_pool_where_it_lies(one_chip, monkeypatch,
+                                                   program):
+    """The same two programs of a ``scan_layers`` model: the stacked pool
+    rides the layer loop's carry and is updated in place.  No copy, slice
+    or update-slice, fused or not, has the stacked leaf or one layer's
+    leaf as its result, the loop body holds the one Pallas kernel, and the
+    program's temporaries are a small part of the pool (they held a second
+    pool while it went through the loop as ``xs`` and ``ys``: 0.49 and
+    0.51 GiB beside a pool of 0.31)."""
+    import re
 
-    def per_slot(dtype):
-        return jax.ShapeDtypeStruct((slots,), dtype, sharding=one_chip)
-
-    pool = jax.eval_shape(lambda: model.init_paged_cache(blocks, bs, f32))
-    decode = paged_generate_steps(model, f32)[1]
-    text = decode.lower(
-        jax.tree.map(described, model.parameters()[0]),
-        jax.tree.map(described, pool), per_slot(i32), per_slot(i32),
-        jax.ShapeDtypeStruct((slots, entries), i32, sharding=one_chip),
-        per_slot(f32), per_slot(i32), per_slot(f32),
-        per_slot(i32)).compile().as_text()
-    assert text.count("tpu_custom_call") == layers
-    assert text.count("flash_paged_decode_attention") >= layers
-    ctx = entries * bs
-    assert not re.search(rf"\[{slots},({ctx}|{entries},{bs}),", text)
-    leaf = rf"f32\[{blocks + 1},{bs},1024\]"
-    assert not re.search(leaf + r"\S* (copy|transpose)\(", text)
+    programs, pool_bytes = _cell_paged_programs(one_chip, monkeypatch, True)
+    compiled = programs[program].compile()
+    text = compiled.as_text()
+    leaf = r"f32\[(2,)?1281,16,1024\]"
+    moved = r"copy|dynamic-slice|dynamic-update-slice"
+    assert re.search(leaf + r"\S* fusion\(", text), "the scatter is there"
+    assert not re.search(leaf + rf"\S* ({moved})\(", text)
+    assert not re.search(rf"%\S*({moved})\S* = " + leaf, text)
+    assert " while(" in text
+    assert text.count("tpu_custom_call") == (program == "decode")
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes / 8
 
 
 def test_lm_gradient_through_flash_matches_plain():

@@ -429,10 +429,13 @@ def test_spans_of_a_served_request(toy):
 #: below, taken at the parent of the PR that brought the state-spec seam
 #: (d154f15) with the installed JAX (0.9.0) under this suite's settings
 #: (tests/conftest.py: matmul precision ``highest``): unrolled, then
-#: scan-stacked
+#: scan-stacked.  The scan layout's decode and chunk programs are those of
+#: the PR that put the stacked pool in the layer loop's carry (PR 34, taken
+#: on its tree, parent c6c266d; they were 7960c747552fad7c and
+#: 79dd0cc04c68a5c2); its block copy and the unrolled three are as before
 LOWERED_BEFORE = {
     False: ["c287b3308b6e8195", "c409795aa41b88c0", "79004a5f4eb5c2d9"],
-    True: ["7960c747552fad7c", "79dd0cc04c68a5c2", "0751efbb7a7d66ff"],
+    True: ["8888f3e56b3e264c", "ceb64c0210814d8c", "0751efbb7a7d66ff"],
 }
 
 

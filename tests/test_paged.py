@@ -502,6 +502,42 @@ class TestFlashPagedKernel:
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=2e-5)
 
+    @pytest.mark.parametrize("layer", [0, 1, 2])
+    @pytest.mark.parametrize("dtype", ["fp32", "bf16", "int8"])
+    def test_stacked_pool_with_layer_equals_the_layer_alone(self, dtype,
+                                                            layer):
+        """The layer-stacked leaf ``(L, NB, bs, H * D)`` with ``layer=l``,
+        traced as it is inside the layer loop, gives what the call on
+        ``pool[l]`` gives, bit for bit: first, middle and last layer, the
+        int8 pool with its stacked scales."""
+        from bigdl_tpu.ops.flash_attention import \
+            flash_paged_decode_attention
+
+        (b, h, d, nb, bs, mb), tables, pos = _paged_kernel_case(
+            "scattered-tables")
+        rng = np.random.default_rng(4)
+        q = jnp.asarray(rng.normal(size=(b, 1, h, d)), jnp.float32)
+        shape = (3, nb, bs, h * d)
+        if dtype == "int8":
+            k, v = (jnp.asarray(rng.integers(-127, 128, shape), jnp.int8)
+                    for _ in "kv")
+            scales = [jnp.asarray(rng.uniform(0.005, 0.02, shape[:3] + (h,)),
+                                  jnp.float32) for _ in "kv"]
+        else:
+            k, v = (jnp.asarray(rng.normal(size=shape), jnp.float32).astype(
+                jnp.bfloat16 if dtype == "bf16" else jnp.float32)
+                for _ in "kv")
+            scales = []
+        stacked = jax.jit(lambda l: flash_paged_decode_attention(
+            q, k, v, tables, pos, *scales, layer=l, interpret=True))(
+                jnp.int32(layer))
+        alone = flash_paged_decode_attention(
+            q, k[layer], v[layer], tables, pos,
+            *(s[layer] for s in scales), interpret=True)
+        assert np.isfinite(np.asarray(alone)).all()
+        np.testing.assert_array_equal(np.asarray(stacked),
+                                      np.asarray(alone))
+
     def test_rows_never_fetched_cannot_poison_a_slot(self):
         """A short slot after a long one computes on a buffer that still
         holds the long one's rows: whatever they are (here not finite),
@@ -543,6 +579,60 @@ class TestFlashPagedKernel:
                 streams[mode] = [f.result(120) for f in futs]
         assert streams["interpret"] == streams["never"]
         assert all(len(s) == 7 for s in streams["never"])
+
+
+class TestStackedPoolStaysInPlace:
+    """The ``scan_layers`` layout carries the stacked pool through the layer
+    loop and addresses ``(layer, block)`` on it; the unrolled layout hands
+    each layer its own leaf.  Same weights, same steps: the same logits
+    and, layer for layer, the same pool."""
+
+    @pytest.mark.parametrize("cache", ["fp32", "int8"])
+    @pytest.mark.parametrize("flash", ["interpret", "never"])
+    def test_scan_layout_equals_unrolled(self, flash, cache):
+        from bigdl_tpu.nn.attention import stack_block_params
+
+        layers, nb, bs = 3, 9, 4
+        dtype = jnp.int8 if cache == "int8" else jnp.float32
+        models = {scan: _lm(layers=layers, max_len=32, scan=scan)
+                  for scan in (False, True)}
+        for m in models.values():
+            for block in m.blocks:
+                block.attn.use_flash = flash
+        params = {False: models[False].parameters()[0]}
+        params[True] = stack_block_params(params[False])
+        rng = np.random.default_rng(6)
+        toks = rng.integers(0, VOCAB, size=(2, 8)).astype(np.int32)
+        tables = jnp.asarray([[5, 0, 7, nb], [2, 6, 3, nb]], jnp.int32)
+        start = jnp.asarray([0, 0], jnp.int32)
+        lengths = jnp.asarray([6, 3], jnp.int32)
+        got = {}
+        for scan, m in models.items():
+            step = jax.jit(lambda p, x, c, pos, n=None, _m=m: _m.apply_paged(
+                p, x, c, tables, pos=pos, lengths=n))
+            pool = m.init_paged_cache(nb, bs, dtype=dtype)
+            # one chunk of ragged rows, a second that reads the first
+            # back, then decode across a block boundary
+            lg1, pool = step(params[scan], toks[:, :6], pool, start, lengths)
+            lg2, pool = step(params[scan], toks[:, 4:8], pool, lengths,
+                             jnp.asarray([2, 4], jnp.int32))
+            out = [lg1, lg2]
+            pos = np.asarray([8, 7], np.int32)
+            for t in range(3):
+                lg, pool = step(params[scan], toks[:, t:t + 1], pool,
+                                jnp.asarray(pos + t))
+                out.append(lg)
+            got[scan] = (out, pool)
+        for a, b in zip(got[False][0], got[True][0]):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        stacked = got[True][1]["blocks"]
+        assert stacked["k"].shape == (layers, nb + 1, bs, 32)
+        for i in range(layers):
+            for name, leaf in got[False][1][f"block{i}"].items():
+                np.testing.assert_array_equal(
+                    np.asarray(stacked[name][i]), np.asarray(leaf),
+                    err_msg=f"layer {i} leaf {name}")
+        assert np.asarray(stacked["k"][:, :nb]).any()
 
 
 class TestSamplingWire:
