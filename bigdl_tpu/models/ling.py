@@ -36,7 +36,7 @@ import jax
 import jax.numpy as jnp
 
 from bigdl_tpu.nn.gated import GatedMLP
-from bigdl_tpu.nn.generation_state import (COUNTER, StateSpec, allocate)
+from bigdl_tpu.nn.generation_state import COUNTER, StateSpec, allocate
 from bigdl_tpu.nn.latent_attention import LatentAttention
 from bigdl_tpu.nn.linear_attention import KimiDeltaAttention
 from bigdl_tpu.nn.module import Container, child_rng
@@ -48,9 +48,14 @@ FULL_PRECISION = ("weight", "router_weight", "router_bias", "A_log",
                   "dt_bias", "o_norm", "q_norm", "kv_norm", "kr_norm")
 
 
-class Ling(Container):
-    """Decoder-only hybrid LM: ``(N, T)`` token ids -> ``(N, T, V)``
-    float32 logits."""
+class ServedLM(Container):
+    """What the served language models of this package share
+    (``Ling`` here, ``models/kanana.py``): pre-norm residual blocks of a
+    token mixer and a gated MLP or a dropless mixture, a float32 residual
+    stream, matrices stored and multiplied in ``self.dtype``, an untied
+    head, and the generation-state plumbing.  A subclass sets
+    ``vocab_size``, ``hidden_size``, ``max_len``, ``dtype`` and
+    ``norm_f``, and builds its layers with ``_new_layer``."""
 
     #: the ``counter`` leaves of the generation state, in the pool's
     #: order: the span a tick records for each, and its attributes
@@ -58,6 +63,99 @@ class Ling(Container):
     #: ``apply_paged`` takes ``logits_at``: a chunk's step asks for the
     #: logits of the one position a row it samples from
     paged_logits_at = True
+
+    def _new_layer(self, op, ffn, norm_eps):
+        """A layer's four modules, registered."""
+        layer = {"op_norm": RMSNorm(self.hidden_size, norm_eps), "op": op,
+                 "ffn_norm": RMSNorm(self.hidden_size, norm_eps), "ffn": ffn}
+        for m in layer.values():
+            self.add(m)
+        return layer
+
+    def _setup_layer(self, layer, rng, spec):
+        return {k: m.setup(child_rng(rng, j), spec)[0]
+                for j, (k, m) in enumerate(layer.items())}
+
+    def _setup_tables(self, rng, input_spec):
+        """``(embed, head and norm_f, the layers' input spec)``."""
+        d = self.hidden_size
+        spec = jax.ShapeDtypeStruct(tuple(input_spec.shape) + (d,),
+                                    jnp.float32)
+        table = lambda i: 0.02 * jax.random.normal(
+            child_rng(rng, i), (self.vocab_size, d), jnp.float32)
+        params = {"embed": table(0), "head": table(98)}
+        params["norm_f"], _ = self.norm_f.setup(child_rng(rng, 99), spec)
+        return params, spec
+
+    def _stored(self, params):
+        """Every leaf in the dtype it is kept in."""
+        def stored(path, leaf):
+            keep = getattr(path[-1], "key", None) in FULL_PRECISION
+            return leaf if keep else leaf.astype(self.dtype)
+
+        return jax.tree_util.tree_map_with_path(stored, params)
+
+    # The residual stream is float32 (it is small beside the weights, and
+    # rounding it to bfloat16 after every layer is what moves a router's
+    # choice); every matrix is multiplied in ``dtype``.
+
+    def _embed(self, params, input):
+        return jnp.take(params["embed"], input.astype(jnp.int32),
+                        axis=0).astype(jnp.float32)
+
+    def _ffn(self, layer, p, x, live=None):
+        """``(x + FFN(RMSNorm(x)), the expert layer's counts or None)``;
+        tokens that are not ``live (N, T)`` go to no routed expert."""
+        h, _ = layer["ffn_norm"].apply(p["ffn_norm"], (), x)
+        if isinstance(layer["ffn"], DroplessMoE):
+            h, load = layer["ffn"].generate(p["ffn"], h, live)
+        else:
+            h, _ = layer["ffn"].apply(p["ffn"], (), h.astype(self.dtype))
+            load = None
+        return x + h.astype(jnp.float32), load
+
+    def _forward_layer(self, layer, p, x):
+        """A layer of the full forward."""
+        h, _ = layer["op_norm"].apply(p["op_norm"], (), x)
+        h, _ = layer["op"].apply(p["op"], (), h.astype(self.dtype))
+        return self._ffn(layer, p, x + h.astype(jnp.float32))[0]
+
+    def _paged_layer(self, block, p, x, pool, by, pos, lengths, live, **kw):
+        """A layer (``block``: its four modules) of a step of paged
+        generation: ``(x, the layer's new state, the expert layer's counts
+        or None)``; ``kw`` goes to the mixer (the ``layer`` of a stacked
+        leaf)."""
+        h, _ = block["op_norm"].apply(p["op_norm"], (), x)
+        h, new = block["op"].apply_paged(p["op"], h.astype(self.dtype), pool,
+                                         by, pos, lengths, **kw)
+        x, load = self._ffn(block, p, x + h.astype(jnp.float32), live)
+        return x, new, load
+
+    def _logits(self, params, x, logits_at=None):
+        """Float32 logits, of the one position ``logits_at (N,)`` a row
+        where given: ``(N, 1, V)``."""
+        if logits_at is not None:
+            x = jnp.take_along_axis(x, logits_at[:, None, None], axis=1)
+        x, _ = self.norm_f.apply(params["norm_f"], (), x)
+        return jnp.einsum("ntd,vd->ntv", x.astype(self.dtype),
+                          params["head"].astype(self.dtype),
+                          preferred_element_type=jnp.float32)
+
+    @staticmethod
+    def _check_cache_dtype(dtype, who):
+        if jnp.dtype(dtype) == jnp.dtype(jnp.int8):
+            raise NotImplementedError(
+                f"{who} keeps its latent cache in the model's dtype; an int8 "
+                "block layout exists for per-head K and V only")
+
+    #: the counter leaf of a model with expert layers
+    moe_load_spec = StateSpec(COUNTER, (len(DroplessMoE.generate_counts),),
+                              jnp.int32)
+
+
+class Ling(ServedLM):
+    """Decoder-only hybrid LM: ``(N, T)`` token ids -> ``(N, T, V)``
+    float32 logits."""
 
     def __init__(self, vocab_size: int, hidden_size: int,
                  layer_types: Sequence[str], num_dense_layers: int,
@@ -97,67 +195,22 @@ class Ling(Container):
                                   experts_held, True, routed_scaling_factor,
                                   use_kernel, n_group, topk_group,
                                   shared_width)
-            layer = {"op_norm": RMSNorm(hidden_size, norm_eps), "op": op,
-                     "ffn_norm": RMSNorm(hidden_size, norm_eps), "ffn": ffn}
-            self.layers.append(layer)
-            for m in layer.values():
-                self.add(m)
+            self.layers.append(self._new_layer(op, ffn, norm_eps))
         self.norm_f = RMSNorm(hidden_size, norm_eps)
         self.add(self.norm_f)
 
     def setup(self, rng, input_spec):
-        d = self.hidden_size
-        spec = jax.ShapeDtypeStruct(tuple(input_spec.shape) + (d,),
-                                    jnp.float32)
-        table = lambda i: 0.02 * jax.random.normal(
-            child_rng(rng, i), (self.vocab_size, d), jnp.float32)
-        params = {"embed": table(0), "head": table(98)}
+        params, spec = self._setup_tables(rng, input_spec)
         for i, layer in enumerate(self.layers):
-            params[f"layer{i}"] = {
-                k: m.setup(child_rng(child_rng(rng, 1 + i), j), spec)[0]
-                for j, (k, m) in enumerate(layer.items())}
-        params["norm_f"], _ = self.norm_f.setup(child_rng(rng, 99), spec)
-
-        def stored(path, leaf):
-            keep = getattr(path[-1], "key", None) in FULL_PRECISION
-            return leaf if keep else leaf.astype(self.dtype)
-
-        return jax.tree_util.tree_map_with_path(stored, params), ()
-
-    # ----- the parts both paths share --------------------------------------- #
-    # The residual stream is float32 (it is small beside the weights, and
-    # rounding it to bfloat16 after every layer is what moves a router's
-    # choice); every matrix is multiplied in ``dtype``.
-
-    def _embed(self, params, input):
-        return jnp.take(params["embed"], input.astype(jnp.int32),
-                        axis=0).astype(jnp.float32)
-
-    def _ffn(self, layer, p, x, live=None):
-        """``(x + FFN(RMSNorm(x)), the expert layer's counts or None)``;
-        tokens that are not ``live (N, T)`` go to no routed expert."""
-        h, _ = layer["ffn_norm"].apply(p["ffn_norm"], (), x)
-        if isinstance(layer["ffn"], DroplessMoE):
-            h, load = layer["ffn"].generate(p["ffn"], h, live)
-        else:
-            h, _ = layer["ffn"].apply(p["ffn"], (), h.astype(self.dtype))
-            load = None
-        return x + h.astype(jnp.float32), load
-
-    def _logits(self, params, x):
-        x, _ = self.norm_f.apply(params["norm_f"], (), x)
-        return jnp.einsum("ntd,vd->ntv", x.astype(self.dtype),
-                          params["head"].astype(self.dtype),
-                          preferred_element_type=jnp.float32)
+            params[f"layer{i}"] = self._setup_layer(
+                layer, child_rng(rng, 1 + i), spec)
+        return self._stored(params), ()
 
     # ----- full forward ----------------------------------------------------- #
     def apply(self, params, state, input, *, training=False, rng=None):
         x = self._embed(params, input)
         for i, layer in enumerate(self.layers):
-            p = params[f"layer{i}"]
-            h, _ = layer["op_norm"].apply(p["op_norm"], (), x)
-            h, _ = layer["op"].apply(p["op"], (), h.astype(self.dtype))
-            x, _ = self._ffn(layer, p, x + h.astype(jnp.float32))
+            x = self._forward_layer(layer, params[f"layer{i}"], x)
         return self._logits(params, x), state
 
     # ----- paged generation -------------------------------------------------- #
@@ -166,16 +219,12 @@ class Ling(Container):
         the engine's word for a cache that is not quantized, means this
         model's own ``dtype`` for the per-token rows and the convolution's
         tail; the recurrent state is float32 whatever is asked."""
-        if jnp.dtype(dtype) == jnp.dtype(jnp.int8):
-            raise NotImplementedError(
-                "Ling keeps its latent cache in the model's dtype; an int8 "
-                "block layout exists for per-head K and V only")
+        self._check_cache_dtype(dtype, "Ling")
         spec = {f"layer{i}": layer["op"].state_spec(self.dtype)
                 for i, layer in enumerate(self.layers)}
         if any(isinstance(layer["ffn"], DroplessMoE)
                for layer in self.layers):
-            spec["moe_load"] = StateSpec(
-                COUNTER, (len(DroplessMoE.generate_counts),), jnp.int32)
+            spec["moe_load"] = self.moe_load_spec
         return spec
 
     def init_paged_cache(self, num_blocks: int, block_size: int,
@@ -210,16 +259,12 @@ class Ling(Container):
             live &= jnp.arange(input.shape[1])[None, :] < lengths[:, None]
         new_pool, loads = {}, []
         for i, layer in enumerate(self.layers):
-            p, key = params[f"layer{i}"], f"layer{i}"
-            h, _ = layer["op_norm"].apply(p["op_norm"], (), x)
+            key = f"layer{i}"
             by = slots if self.layer_types[i] == "kda" else tables
-            h, new_pool[key] = layer["op"].apply_paged(
-                p["op"], h.astype(self.dtype), pool[key], by, pos, lengths)
-            x, load = self._ffn(layer, p, x + h.astype(jnp.float32), live)
+            x, new_pool[key], load = self._paged_layer(
+                layer, params[key], x, pool[key], by, pos, lengths, live)
             if load is not None:
                 loads.append(load)
         if loads:
             new_pool["moe_load"] = sum(loads)
-        if logits_at is not None:
-            x = jnp.take_along_axis(x, logits_at[:, None, None], axis=1)
-        return self._logits(params, x), new_pool
+        return self._logits(params, x, logits_at), new_pool

@@ -48,6 +48,15 @@ step's worth at a time into a double buffer, up to the slot's frontier and
 no further: VMEM holds two steps whatever the pool's size, so no gate
 bounds it by bytes; its gate asks only that a block be whole tiles of the
 pool's dtype.
+
+``latent_paged_decode_attention`` (the latent paged pool's decode kernel)
+fetches the same way from a leaf of ONE shared row a token, ``(NB, bs, W)``
+or ``(L, NB, bs, W)``: every absorbed head scores against the whole row and
+sums its first ``rank`` columns, so a slot's rows are fetched once for all
+heads and both products are MXU matmuls in the leaf's dtype; a slot whose
+table starts on the trash block is skipped.  ``W`` must be whole tiles of
+128 columns (the chip stores a row so whatever its logical width, and a
+DMA of part of a tile is refused).
 """
 
 import functools
@@ -810,3 +819,165 @@ def flash_paged_decode_attention(q, k_pool, v_pool, tables, pos,
     )(jnp.asarray(pos, jnp.int32), tables.reshape(-1), layer.reshape(1),
       *args)
     return out.reshape(b, 1, h, d)
+
+
+#: latent rows one step of the latent paged decode kernel computes on
+_LATENT_STEP_ROWS = 512
+
+
+def _latent_decode_kernel(pos_ref, table_ref, layer_ref, q_ref, pool_hbm,
+                          o_ref, buf, sem, chain_ref, m_ref, l_ref, acc_ref,
+                          *, block_size: int, blocks_per_step: int,
+                          max_blocks: int, rank: int, scale: float,
+                          trash: int):
+    """One decode slot a grid step, ``_paged_decode_kernel``'s way of
+    fetching (the pool in HBM as ``(L, NB, bs, W)``, a block at
+    ``(layer_ref[0], table entry)``, a step's blocks by one DMA each into
+    the other half of a double buffer, the next slot's first step started
+    by this slot's last) over ONE shared row a token: the scores are
+    ``q (H, W) . rows (R, W)`` and the output ``p (H, R) . rows[:, :rank]``,
+    both on the MXU in the pool's dtype with float32 sums, the running
+    softmax ``(H, 1)`` in float32.  A slot whose table starts on the trash
+    block is not live: nothing of it is fetched or computed and its output
+    is nought.  ``chain_ref``: which half the next step lands in, and
+    whether the slot before started this slot's first step."""
+    bs, g = block_size, blocks_per_step
+    i, n = pl.program_id(0), pl.num_programs(0)
+    rows = g * bs
+
+    def is_live(slot):
+        return table_ref[slot * max_blocks] != trash
+
+    def frontier(slot):
+        return jnp.clip(pos_ref[slot] // bs, 0, max_blocks - 1)
+
+    def copies(slot, step, half):
+        last, layer, alive = frontier(slot), layer_ref[0], is_live(slot)
+        for j in range(g):
+            blk = step * g + j
+            phys = table_ref[slot * max_blocks + jnp.minimum(blk, last)]
+            yield jnp.logical_and(alive, blk <= last), pltpu.make_async_copy(
+                pool_hbm.at[layer, phys], buf.at[half, pl.ds(j * bs, bs)],
+                sem.at[half])
+
+    def start(slot, step, half):
+        for fetch, dma in copies(slot, step, half):
+            pl.when(fetch)(dma.start)
+
+    def wait(slot, step, half):
+        for fetch, dma in copies(slot, step, half):
+            pl.when(fetch)(dma.wait)
+
+    @pl.when(i == 0)
+    def _():
+        chain_ref[0] = 0
+        chain_ref[1] = 0
+
+    first_half = chain_ref[0]
+
+    @pl.when(chain_ref[1] == 0)
+    def _():
+        start(i, 0, first_half)
+
+    m_ref[:] = jnp.full_like(m_ref, _MASKED)
+    l_ref[:] = jnp.zeros_like(l_ref)
+    acc_ref[:] = jnp.zeros_like(acc_ref)
+    p = pos_ref[i]
+    steps = jnp.where(is_live(i), frontier(i) // g + 1, 0)
+    q = q_ref[:]                                           # (H, W)
+    precision = _operand_precision(q.dtype)
+
+    def body(step, _):
+        half = (first_half + step) % 2
+
+        @pl.when(step + 1 < steps)
+        def _():
+            start(i, step + 1, 1 - half)
+
+        @pl.when(jnp.logical_and(step + 1 == steps, i + 1 < n))
+        def _():
+            start(i + 1, 0, 1 - half)
+
+        wait(i, step, half)
+        # a row never fetched holds whatever the buffer held: its weight
+        # is 0, and 0 * nan must not reach the sum
+        fetched = step * rows + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, 1), 0) <= p
+        ctx = jnp.where(fetched, buf[half], 0)             # (R, W)
+        s = jax.lax.dot_general(q, ctx, _CONTRACT_LAST, precision=precision,
+                                preferred_element_type=jnp.float32) * scale
+        seen = step * rows + jax.lax.broadcasted_iota(
+            jnp.int32, (1, rows), 1) <= p
+        s = jnp.where(seen, s, _MASKED)                    # (H, R)
+        m = m_ref[:]
+        new_m = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        w = jnp.exp(s - new_m)
+        corr = jnp.exp(m - new_m)
+        m_ref[:] = new_m
+        l_ref[:] = l_ref[:] * corr + jnp.sum(w, axis=1, keepdims=True)
+        acc_ref[:] = acc_ref[:] * corr + jnp.dot(
+            w.astype(ctx.dtype), ctx[:, :rank], precision=precision,
+            preferred_element_type=jnp.float32)
+        return _
+
+    jax.lax.fori_loop(0, steps, body, None)
+    chain_ref[0] = (first_half + steps) % 2
+    # a slot that ran a step started its successor's first one
+    chain_ref[1] = jnp.where(steps > 0, 1, 0)
+    o_ref[:] = (acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("rank", "scale", "interpret"))
+def latent_paged_decode_attention(q, pool, tables, pos, layer=None, *,
+                                  rank: int, scale: float,
+                                  interpret: bool = False):
+    """Single-token ABSORBED latent attention through a paged pool of
+    latent rows: ``q (B, H, W)`` (``W_kvb``'s key half already folded into
+    its first ``rank`` columns, the rotary part behind them) against the
+    block leaf ``pool`` -- ``(NB, bs, W)`` a layer, or ``(L, NB, bs, W)``
+    with ``layer`` an int32 scalar, as ``flash_paged_decode_attention``
+    takes them -- through ``tables (B, max_blocks)`` up to ``pos (B,)``;
+    ``(B, H, rank)``: the softmax-weighted sum of each row's first
+    ``rank`` columns, which the caller takes through ``W_kvb``'s value
+    half.  Every head reads the same row a token, so a slot's rows are
+    fetched ONCE for all heads, only the blocks up to its frontier, and
+    the work follows its length.  A row whose table starts on the trash
+    block (the pool's last) is not live and comes back nought."""
+    b, h, width = q.shape
+    if pool.ndim == 3:
+        assert layer is None, "a single layer's leaf has no layer to ask for"
+        layer, pool = 0, pool[None]
+    assert layer is not None, "a stacked pool needs the layer"
+    assert pool.shape[3] == width, (pool.shape, q.shape)
+    bs, max_blocks = pool.shape[2], tables.shape[1]
+    g = max(1, min(max_blocks, _LATENT_STEP_ROWS // bs))
+
+    def slot_block(cols):
+        return pl.BlockSpec((None, h, cols),
+                            lambda i, pos, tables, layer: (i, 0, 0))
+
+    return pl.pallas_call(
+        functools.partial(_latent_decode_kernel, block_size=bs,
+                          blocks_per_step=g, max_blocks=max_blocks,
+                          rank=rank, scale=scale, trash=pool.shape[1] - 1),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(b,),
+            in_specs=[slot_block(width),
+                      pl.BlockSpec(memory_space=pltpu.HBM)],
+            out_specs=slot_block(rank),
+            scratch_shapes=[
+                pltpu.VMEM((2, g * bs, width), pool.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((2,), jnp.int32),
+                pltpu.VMEM((h, 1), jnp.float32),
+                pltpu.VMEM((h, 1), jnp.float32),
+                pltpu.VMEM((h, rank), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((b, h, rank), q.dtype),
+        # slots run in order: each starts the next one's first fetch
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="latent_paged_decode_attention",
+    )(jnp.asarray(pos, jnp.int32), jnp.asarray(tables, jnp.int32).reshape(-1),
+      jnp.asarray(layer, jnp.int32).reshape(1), q.astype(pool.dtype), pool)

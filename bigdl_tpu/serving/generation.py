@@ -1386,7 +1386,10 @@ class PagedGenerateScheduler(GenerateScheduler):
                 self._cow_guard(s, s.consumed, s.consumed + chunk.size - 1)
                 tables[r] = self._alloc.table_row(s.seq, mb)
                 self._fill_sampling(knobs, r, s)
-            prep.set(bucket=int(bucket), prompt_tokens=int(lens.sum()))
+            # context_tokens: what the rows already hold, which the chunk's
+            # attention reads beside its own tokens
+            prep.set(bucket=int(bucket), prompt_tokens=int(lens.sum()),
+                     context_tokens=int(start.sum()))
         try:
             with span("generate_prefill", tick=self._tick, records=n):
                 with span("launch"):
@@ -1445,6 +1448,9 @@ class PagedGenerateScheduler(GenerateScheduler):
                     slot_ids[0][i] = i
                 tables[i] = self._alloc.table_row(s.seq, mb)
                 self._fill_sampling(knobs, i, s)
+            # the cache rows this tick's attention reads: every live
+            # slot's positions up to and with the one it writes
+            prep.set(context_tokens=int(pos.sum()) + len(active))
         try:
             with span("generate_decode", tick=self._tick,
                       records=len(active)):

@@ -22,7 +22,8 @@ from bigdl_tpu.ops.kda import kda_decode_step
 from bigdl_tpu.ops.flash_attention import (flash_attention,
                                            flash_decode_attention,
                                            flash_paged_decode_attention,
-                                           kv_blocks_fit)
+                                           kv_blocks_fit,
+                                           latent_paged_decode_attention)
 
 f32, bf16, i8, i32 = jnp.float32, jnp.bfloat16, jnp.int8, jnp.int32
 
@@ -124,6 +125,21 @@ def _paged(b, nb, bs, d, dtype, h=16, mb=64, layers=None):
             scale, scale, None if layers is None else ((), i32)]
 
 
+def _latent(q, pool, tables, pos, layer=None):
+    return latent_paged_decode_attention(q, pool, tables, pos, layer,
+                                         rank=512, scale=192 ** -0.5)
+
+
+def _latent_pool(layers=None, width=640):
+    """The kanana cell's decode step: 32 slots of 32 absorbed heads over
+    rows of 576 values stored in 640 columns, blocks of 16, 1024 table
+    entries; a layer's own leaf, or 15 layers stacked and the layer."""
+    stack = () if layers is None else (layers,)
+    return [((32, 32, width), bf16), (stack + (2049, 16, width), bf16),
+            ((32, 1024), i32), ((32,), i32)] \
+        + ([] if layers is None else [((), i32)])
+
+
 # (kernel, shapes, what the auto gate is asked: rows, head_dim, dtype)
 CASES = {
     # train_lm's sequence, bf16 compute.  The forward kernel streams K/V
@@ -184,6 +200,10 @@ CASES = {
     "grouped-decode-down": (_grouped_small, [
         ((buffer_rows(256, 128, 16), 768), bf16), ((128, 768, 2560), bf16),
         ((128,), i32)], None),
+    # the kanana serving cell: the latent decode kernel over a layer's own
+    # leaf (the leading dense layer) and over the scanned layers' stacked one
+    "latent-decode-cell": (_latent, _latent_pool(), None),
+    "latent-decode-cell-stacked": (_latent, _latent_pool(15), None),
     # train_lm's head: 2 sequences of 2048 tokens, vocab 32000
     "ce-forward": (fused_softmax_cross_entropy,
                    [((4096, 32000), f32), ((4096,), i32)], None),
@@ -327,6 +347,76 @@ def test_scan_layout_leaves_the_pool_where_it_lies(one_chip, monkeypatch,
     assert not re.search(rf"%\S*({moved})\S* = " + leaf, text)
     assert " while(" in text
     assert text.count("tpu_custom_call") == (program == "decode")
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes / 8
+
+
+def test_latent_rows_are_fetched_in_whole_tiles(one_chip):
+    """Why the latent leaf says 640 columns for 576 values: the chip
+    stores a row in tiles of 128 columns whatever the leaf says, and the
+    kernel's DMA of a block 576 wide is refused ("must be aligned to
+    tiling (128)"); ``LatentAttention._kernel_ok`` keeps such a leaf
+    (Ling's) on the gather."""
+    with pytest.raises(Exception, match="aligned to tiling"):
+        _compile(one_chip, _latent, *_latent_pool(width=576))
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_latent_scan_leaves_the_pool_where_it_lies(one_chip, monkeypatch,
+                                                   program):
+    """The engine's paged programs of ``models/kanana.py`` at the cell's
+    widths (three layers, two of them scanned; 8192 blocks of 16; a small
+    vocabulary): the stacked latent leaf rides the layer loop's carry
+    beside the counts and is updated in place.  No copy, slice or
+    update-slice has the stacked leaf or a layer's leaf as its result,
+    the decode program holds no gather of a whole table (no ``(32,
+    1024, 16, 640)`` or ``(32, 16384, 640)`` in any shape) and one latent
+    kernel a layer, the chunk program's loop over context blocks is a
+    ``while`` inside the layer loop, and the temporaries are a small part
+    of the pool."""
+    import re
+
+    import bigdl_tpu.nn.attention as attention
+    from bigdl_tpu.models.kanana import Kanana
+    from bigdl_tpu.serving.generation import paged_generate_steps
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    model = Kanana(512, 2048, 3, 1, 6144, 768, 32, 128, 6, (0, 16), 1536,
+                   2.448, max_len=16384, dtype=bf16)
+    weights, _ = jax.eval_shape(
+        lambda k: model.setup(k, jax.ShapeDtypeStruct((1, 16), i32)),
+        jax.random.key(0))
+
+    def described(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    def arg(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def knobs(n):
+        return arg(f32, n), arg(i32, n), arg(f32, n), arg(i32, n)
+
+    pool = jax.eval_shape(lambda: model.init_paged_cache(8192, 16))
+    pool_bytes = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(pool))
+    pool, weights = jax.tree.map(described, (pool, weights))
+    chunk, decode, _ = paged_generate_steps(model, f32)
+    if program == "decode":
+        lowered = decode.lower(weights, pool, arg(i32, 32), arg(i32, 32),
+                               arg(i32, 32, 1024), *knobs(32))
+    else:
+        lowered = chunk.lower(weights, pool, arg(i32, 2, 512), arg(i32, 2),
+                              arg(i32, 2), arg(i32, 2, 1024), *knobs(2))
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    leaf = r"bf16\[(2,)?2049,16,640\]"
+    moved = r"copy|dynamic-slice|dynamic-update-slice"
+    assert not re.search(leaf + rf"\S* ({moved})\(", text)
+    assert not re.search(rf"%\S*({moved})\S* = " + leaf, text)
+    assert not re.search(r"\[(32|2),(1024,16|16384),640\]", text)
+    # the layer loop, and in the chunk program a row's loop over context
+    # blocks inside it and inside the leading layer (and the rows' own)
+    assert text.count(" while(") >= (1 if program == "decode" else 3)
+    kernels = text.count("latent_paged_decode_attention")
+    assert (kernels >= 2) == (program == "decode")
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes / 8
 
 

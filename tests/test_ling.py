@@ -461,6 +461,38 @@ def test_transformer_lm_paged_programs_lower_as_before(scan):
     assert not has_slot_state(model.paged_state_spec())
 
 
+#: the same for Ling's own three programs at the toy sizes below, taken at
+#: the parent of the PR that told ``nn.LatentAttention`` what it is (gate,
+#: q/k norms, rotary convention and row width as arguments whose defaults
+#: are Ling's; a4f0e79): decode and block copy are the parent's; the chunk
+#: program is that PR's, whose loop over context blocks ends at the row's
+#: own last position (it was a70d51d148ac7d7b with every block gone
+#: through)
+LING_LOWERED_BEFORE = ["130a0ad7bce524fd", "02502564ac740ab7",
+                       "a57c305d0332a8f5"]
+
+
+def test_ling_paged_programs_lower_as_before(toy):
+    _, cfg, _, model = toy
+    params = model.weights()
+    pool = model.init_paged_cache(8, 16, jnp.float32, slots=4)
+    model.__dict__.pop("_compiled_paged_steps", None)
+    chunk, decode, copy = paged_generate_steps(model, jnp.float32)
+    knobs = lambda n: (np.zeros((n,), np.float32), np.zeros((n,), np.int32),
+                       np.ones((n,), np.float32), np.zeros((n,), np.int32))
+    z = lambda *shape: np.zeros(shape, np.int32)
+    texts = [
+        decode.lower(params, pool, z(4), z(4), np.full((4, 4), 8, np.int32),
+                     *knobs(4), np.full((4,), 4, np.int32)).as_text(),
+        chunk.lower(params, pool, z(2, 16), z(2), np.ones((2,), np.int32),
+                    np.full((2, 4), 8, np.int32), *knobs(2),
+                    np.full((2,), 4, np.int32)).as_text(),
+        copy.lower(pool, np.int32(0), np.int32(1)).as_text()]
+    got = [hashlib.sha256(t.encode()).hexdigest()[:16] for t in texts]
+    assert got == LING_LOWERED_BEFORE
+    assert has_slot_state(model.paged_state_spec())
+
+
 # ------------------------------------------- a pool that is lost ----- #
 
 def test_a_failed_rebuild_fails_the_waiting_requests(toy, monkeypatch):
