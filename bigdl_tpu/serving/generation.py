@@ -1387,9 +1387,11 @@ class PagedGenerateScheduler(GenerateScheduler):
                 tables[r] = self._alloc.table_row(s.seq, mb)
                 self._fill_sampling(knobs, r, s)
             # context_tokens: what the rows already hold, which the chunk's
-            # attention reads beside its own tokens
+            # attention reads beside its own tokens; rows_sampling: the rows
+            # with a temperature (one is enough for the sampler to sort)
             prep.set(bucket=int(bucket), prompt_tokens=int(lens.sum()),
-                     context_tokens=int(start.sum()))
+                     context_tokens=int(start.sum()),
+                     rows_sampling=int((knobs[0] > 0).sum()))
         try:
             with span("generate_prefill", tick=self._tick, records=n):
                 with span("launch"):
@@ -1450,7 +1452,8 @@ class PagedGenerateScheduler(GenerateScheduler):
                 self._fill_sampling(knobs, i, s)
             # the cache rows this tick's attention reads: every live
             # slot's positions up to and with the one it writes
-            prep.set(context_tokens=int(pos.sum()) + len(active))
+            prep.set(context_tokens=int(pos.sum()) + len(active),
+                     rows_sampling=int((knobs[0] > 0).sum()))
         try:
             with span("generate_decode", tick=self._tick,
                       records=len(active)):

@@ -20,13 +20,27 @@ Instead sampling runs inside the jitted step:
   logits (argmax(logits/T + gumbel) samples the softmax exactly), which
   needs no normalization and no host sync.
 
-``temperature <= 0`` means greedy -- the whole masking/gumbel result is
-discarded for those rows, so the default path is bit-identical to the
-old argmax.
+``temperature <= 0`` means greedy, and the work follows what the tick's
+rows ask for, decided on the device from the ``temperature`` array:
+
+- a tick whose rows are ALL greedy runs one argmax over the logits and
+  nothing else -- no sort, no gather, no softmax, no draw;
+- a tick with at least one sampling row runs the sampled branch over
+  every row: ONE two-operand sort gives the ranked logits and their ids
+  together (the keys of the sort ARE the ranked logits, so nothing is
+  gathered back over the vocabulary), then the cuts, the Gumbel draw and
+  a one-element-a-row gather of the picked id; its greedy rows still
+  take the argmax.
+
+The branch is a real conditional (``lax.cond`` on a runtime value), so
+one executable still serves both kinds of tick; it stays one as long as
+``sample_tokens`` is never put under ``vmap``, which would turn the
+conditional into a select that runs both sides.
 """
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 
 class SamplingParams:
@@ -73,28 +87,20 @@ class SamplingParams:
 GREEDY = SamplingParams()
 
 
-def sample_tokens(logits, temperature, top_k, top_p, seed, position):
-    """Draw one token per row from ``logits`` -- traceable, fixed-shape.
+def _greedy(logits, *_knobs):
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
-    logits       (rows, vocab) float
-    temperature  (rows,) float; <= 0 selects greedy for that row
-    top_k        (rows,) int32; <= 0 disables
-    top_p        (rows,) float in [0, 1]
-    seed         (rows,) int32/uint32 per-request RNG seed
-    position     (rows,) int32 sequence position of the token being
-                 drawn -- the fold-in counter, so the draw is a pure
-                 function of (seed, position)
 
-    Returns (rows,) int32 token ids.
-    """
+def _sampled(logits, temperature, top_k, top_p, seed, position):
     vocab = logits.shape[-1]
-    logits = logits.astype(jnp.float32)
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-
     # Work in sorted order (descending): top-k is a rank cut and top-p a
-    # cumulative-mass cut over the same sort.
-    order = jnp.argsort(-logits, axis=-1)
-    ranked = jnp.take_along_axis(logits, order, axis=-1)
+    # cumulative-mass cut over the same sort.  The sort carries the ids
+    # along (what ``argsort`` does, stably) and its keys are the ranked
+    # logits, negated twice, which is exact.
+    neg, order = lax.sort(
+        (-logits, lax.broadcasted_iota(jnp.int32, logits.shape, 1)),
+        dimension=-1, num_keys=1, is_stable=True)
+    ranked = -neg
     temp = jnp.maximum(temperature, 1e-6).astype(jnp.float32)[:, None]
     scaled = ranked / temp
 
@@ -120,7 +126,27 @@ def sample_tokens(logits, temperature, top_k, top_p, seed, position):
     gumbel = jax.vmap(lambda key, row: jax.random.gumbel(
         key, row.shape, dtype=row.dtype))(keys, masked)
     pick = jnp.argmax(masked + gumbel, axis=-1)
-    sampled = jnp.take_along_axis(
+    drawn = jnp.take_along_axis(
         order, pick[:, None], axis=-1)[:, 0].astype(jnp.int32)
+    return jnp.where(temperature > 0.0, drawn, _greedy(logits))
 
-    return jnp.where(temperature > 0.0, sampled, greedy)
+
+def sample_tokens(logits, temperature, top_k, top_p, seed, position):
+    """Draw one token per row from ``logits`` -- traceable, fixed-shape.
+
+    logits       (rows, vocab) float
+    temperature  (rows,) float; <= 0 selects greedy for that row
+    top_k        (rows,) int32; <= 0 disables
+    top_p        (rows,) float in [0, 1]
+    seed         (rows,) int32/uint32 per-request RNG seed
+    position     (rows,) int32 sequence position of the token being
+                 drawn -- the fold-in counter, so the draw is a pure
+                 function of (seed, position)
+
+    Returns (rows,) int32 token ids.
+    """
+    # both branches are module-level functions of the same operands, so a
+    # bare call traces them once a shape and not once a call
+    return lax.cond(jnp.any(temperature > 0.0), _sampled, _greedy,
+                    logits.astype(jnp.float32), temperature, top_k, top_p,
+                    seed, position)

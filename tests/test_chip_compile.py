@@ -350,6 +350,75 @@ def test_scan_layout_leaves_the_pool_where_it_lies(one_chip, monkeypatch,
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes / 8
 
 
+def _computations(text):
+    """Compiled HLO text as ``{computation name: its lines}`` and the
+    entry computation's name."""
+    import re
+
+    comps, entry, lines = {}, None, None
+    for line in text.splitlines():
+        head = re.match(r"(ENTRY )?%(\S+) \(.*\) -> .* \{$", line)
+        if head:
+            lines = comps[head.group(2)] = []
+            entry = head.group(2) if head.group(1) else entry
+        elif lines is not None:
+            lines.append(line)
+    return comps, entry
+
+
+def _reached(comps, root, but=()):
+    """The lines of ``root`` and of every computation it calls (fusions,
+    loop bodies, comparators, branches), not going into ``but``."""
+    import re
+
+    seen, todo, lines = set(but), [root], []
+    while todo:
+        name = todo.pop()
+        if name in seen:
+            continue
+        seen.add(name)
+        lines += comps[name]
+        todo += [c for line in comps[name]
+                 for c in re.findall(r"%([\w.\-]+)", line) if c in comps]
+    return lines
+
+
+@pytest.mark.parametrize("scan_layers", [False, True],
+                         ids=["unrolled", "scan"])
+@pytest.mark.parametrize("program,rows", [("decode", 32), ("chunk", 2)])
+def test_sampler_sorts_only_in_its_sampled_branch(one_chip, monkeypatch,
+                                                  program, rows,
+                                                  scan_layers):
+    """The sampler of the cell's two programs is a conditional on the
+    chip (not a select that runs both sides): the vocabulary's sort lies
+    in the sampled branch and nowhere else, the greedy branch and the
+    program around the conditional hold neither a sort nor an
+    element-wise gather with a ``(rows, vocab)`` result, and the sampled
+    branch gathers nothing of that size either: its ranked logits are the
+    sort's own keys."""
+    import re
+
+    programs, _ = _cell_paged_programs(one_chip, monkeypatch, scan_layers)
+    comps, entry = _computations(programs[program].compile().as_text())
+    everything = _reached(comps, entry)
+    (cond,) = [line for line in everything if " conditional(" in line]
+    assert re.search(rf"\(s32\[{rows}\]\S*\) conditional\(", cond)
+    greedy, sampled = re.search(
+        r"branch_computations=\{%([\w.\-]+), %([\w.\-]+)\}", cond).groups()
+    # an element at a time: the chunk program's pick of each row's last
+    # position out of ``(rows, tokens, vocab)`` is a gather of whole rows
+    wide_gather = (rf"= \S*\[{rows},512\]\S* gather\(.*"
+                   r"slice_sizes=\{1(,1)*\}")
+    outside = _reached(comps, entry, but=(greedy, sampled)) \
+        + _reached(comps, greedy)
+    inside = _reached(comps, sampled)
+    assert len(outside) + len(inside) == len(everything)
+    assert not [line for line in outside
+                if " sort(" in line or re.search(wide_gather, line)]
+    assert sum(" sort(" in line for line in inside) == 1
+    assert not [line for line in inside if re.search(wide_gather, line)]
+
+
 def test_latent_rows_are_fetched_in_whole_tiles(one_chip):
     """Why the latent leaf says 640 columns for 576 values: the chip
     stores a row in tiles of 128 columns whatever the leaf says, and the

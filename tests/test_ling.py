@@ -432,10 +432,16 @@ def test_spans_of_a_served_request(toy):
 #: scan-stacked.  The scan layout's decode and chunk programs are those of
 #: the PR that put the stacked pool in the layer loop's carry (PR 34, taken
 #: on its tree, parent c6c266d; they were 7960c747552fad7c and
-#: 79dd0cc04c68a5c2); its block copy and the unrolled three are as before
+#: 79dd0cc04c68a5c2); its block copy and the unrolled three are as before.
+#: Both layouts' decode and chunk programs end in the sampler, and are
+#: those of the PR that made it a conditional with one two-operand sort
+#: (PR 36, taken on its tree, parent 360d345; with SSA names struck out
+#: the text differs from the parent's in the sampler's lines alone; they
+#: were c287b3308b6e8195, c409795aa41b88c0 and 8888f3e56b3e264c,
+#: ceb64c0210814d8c)
 LOWERED_BEFORE = {
-    False: ["c287b3308b6e8195", "c409795aa41b88c0", "79004a5f4eb5c2d9"],
-    True: ["8888f3e56b3e264c", "ceb64c0210814d8c", "0751efbb7a7d66ff"],
+    False: ["a3141351fc1c862e", "936449bb236d178d", "79004a5f4eb5c2d9"],
+    True: ["045214b27ac0ad1e", "fec5f04665d3c1a5", "0751efbb7a7d66ff"],
 }
 
 
@@ -467,8 +473,9 @@ def test_transformer_lm_paged_programs_lower_as_before(scan):
 #: are Ling's; a4f0e79): decode and block copy are the parent's; the chunk
 #: program is that PR's, whose loop over context blocks ends at the row's
 #: own last position (it was a70d51d148ac7d7b with every block gone
-#: through)
-LING_LOWERED_BEFORE = ["130a0ad7bce524fd", "02502564ac740ab7",
+#: through); decode and chunk end in the sampler and are PR 36's, as above
+#: (they were 130a0ad7bce524fd and 02502564ac740ab7)
+LING_LOWERED_BEFORE = ["6132cd16f3dcb8cd", "4c2f2c1945bcbe41",
                        "a57c305d0332a8f5"]
 
 

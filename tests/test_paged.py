@@ -38,6 +38,7 @@ from bigdl_tpu.observability.watchdogs import backend_compile_count
 from bigdl_tpu.serving import (BlockAllocator, BlockPoolExhausted,
                                InProcessReplica, SamplingParams,
                                ServingEngine, ServingFleet)
+from bigdl_tpu.serving.sampling import sample_tokens
 
 VOCAB = 50
 
@@ -164,6 +165,51 @@ class TestBlockAllocator:
         assert a.begin_sequence("s2", prompt, 9) == 0
 
 
+def _reference_sample_tokens(logits, temperature, top_k, top_p, seed,
+                             position):
+    """``sample_tokens`` as it was before the work followed the rows:
+    ``argsort``, the ranked logits gathered back, every row drawn and the
+    greedy rows' draws thrown away.  Kept as the reference the function is
+    held to, token for token."""
+    vocab = logits.shape[-1]
+    logits = logits.astype(jnp.float32)
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    order = jnp.argsort(-logits, axis=-1)
+    ranked = jnp.take_along_axis(logits, order, axis=-1)
+    temp = jnp.maximum(temperature, 1e-6).astype(jnp.float32)[:, None]
+    scaled = ranked / temp
+    rank = jnp.arange(vocab, dtype=jnp.int32)[None, :]
+    k = jnp.where(top_k > 0, top_k, vocab).astype(jnp.int32)[:, None]
+    keep = rank < k
+    probs = jax.nn.softmax(scaled, axis=-1)
+    mass_before = jnp.cumsum(probs, axis=-1) - probs
+    keep = keep & (mass_before < top_p[:, None])
+    keep = keep.at[:, 0].set(True)
+    masked = jnp.where(keep, scaled, -jnp.inf)
+    keys = jax.vmap(
+        lambda s, p: jax.random.fold_in(
+            jax.random.PRNGKey(s.astype(jnp.uint32)), p))(
+        seed, position.astype(jnp.uint32))
+    gumbel = jax.vmap(lambda key, row: jax.random.gumbel(
+        key, row.shape, dtype=row.dtype))(keys, masked)
+    pick = jnp.argmax(masked + gumbel, axis=-1)
+    sampled = jnp.take_along_axis(
+        order, pick[:, None], axis=-1)[:, 0].astype(jnp.int32)
+    return jnp.where(temperature > 0.0, sampled, greedy)
+
+
+# one jitted handle each for the module: a compile a shape, not a case
+_JIT_NEW, _JIT_OLD = jax.jit(sample_tokens), jax.jit(_reference_sample_tokens)
+
+
+#: rows of a tick, by what they ask for: the temperature of each of 6 rows
+_TICKS = {
+    "greedy": [0.0] * 6,
+    "sampling": [0.7, 1.0, 1.3, 0.2, 2.0, 0.7],
+    "mixed": [0.0, 0.0, 0.0, 0.9, 0.0, 0.0],
+}
+
+
 class TestSampleTokens:
     """The in-jit draw: greedy degenerations are exact, randomness is a
     pure function of (seed, position)."""
@@ -219,6 +265,40 @@ class TestSampleTokens:
                 jnp.ones((1,), jnp.float32), jnp.asarray([3], jnp.int32),
                 jnp.asarray([p], jnp.int32))[0])
             assert tok in top2
+
+    @pytest.mark.parametrize("jitted", [True, False],
+                             ids=["jit", "bare"])
+    @pytest.mark.parametrize("vocab", [16, 1031])
+    @pytest.mark.parametrize("top_p", [0.0, 0.3, 1.0])
+    @pytest.mark.parametrize("top_k", [0, 1, 5])
+    @pytest.mark.parametrize("tied", [False, True], ids=["distinct", "tied"])
+    @pytest.mark.parametrize("tick", sorted(_TICKS))
+    def test_token_for_token_the_reference(self, tick, tied, top_k, top_p,
+                                        vocab, jitted):
+        """Greedy, sampling and mixed ticks give, token for token, what
+        the function gave when it sorted, gathered and drew for every row
+        (``_reference_sample_tokens``): the two-operand sort ranks ties as
+        ``argsort`` does, and the branch changes no row's token."""
+        rows = len(_TICKS[tick])
+        logits = np.random.default_rng(vocab + top_k).normal(
+            size=(rows, vocab)).astype(np.float32) * 3.0
+        if tied:
+            # equal values all through a row, and two at its maximum
+            logits = np.round(logits, 1)
+            logits[:, vocab // 2] = logits.max(axis=-1)
+        args = (jnp.asarray(logits),
+                jnp.asarray(_TICKS[tick], jnp.float32),
+                jnp.full((rows,), top_k, jnp.int32),
+                jnp.full((rows,), top_p, jnp.float32),
+                jnp.arange(rows, dtype=jnp.int32) * 7919 + 11,
+                jnp.asarray([0, 3, 500, 1, 2, 1023], jnp.int32))
+        new, old = (_JIT_NEW, _JIT_OLD) if jitted \
+            else (sample_tokens, _reference_sample_tokens)
+        got, want = new(*args), old(*args)
+        assert got.dtype == want.dtype == jnp.int32
+        assert np.array_equal(np.asarray(got), np.asarray(want))
+        if tick == "greedy":
+            assert np.array_equal(np.asarray(got), np.argmax(logits, axis=-1))
 
     def test_params_validation(self):
         with pytest.raises(ValueError):
@@ -368,6 +448,31 @@ class TestPagedServing:
                                  temperature=0.9, top_p=0.8, seed=5)]
             [f.result(60) for f in futs]
             assert backend_compile_count() - before == 0
+
+    @pytest.mark.parametrize("sampling", [0, 2])
+    def test_prep_spans_count_the_sampling_rows(self, sampling):
+        """``rows_sampling`` on the paged scheduler's prep spans: the rows
+        of the tick with a temperature, any one of which sends the tick's
+        sampler down its sampled branch; 0 on every tick of a greedy
+        batch."""
+        from bigdl_tpu.observability.spans import recorder
+
+        m = _lm(layers=2, max_len=48)
+        before = len(recorder().snapshot())
+        with ServingEngine(m, decode_slots=3, decode_max_len=40,
+                           kv_block_size=4) as eng:
+            futs = [eng.generate([1 + i, 2, 3], max_new_tokens=4,
+                                 **({"temperature": 0.9, "seed": i}
+                                    if i < sampling else {}))
+                    for i in range(3)]
+            [f.result(60) for f in futs]
+        recs = recorder().snapshot()[before:]
+        for name in ("prefill_prep", "decode_prep"):
+            attrs = [r.attrs for r in recs if r.name == name]
+            assert attrs
+            assert all(0 <= a["rows_sampling"] <= min(sampling, a["rows"])
+                       for a in attrs)
+            assert max(a["rows_sampling"] for a in attrs) == sampling
 
     def test_auto_engine_precompile_warms_generation(self):
         """The satellite fix: an AUTO-mode engine (decode_slots unset)
