@@ -111,13 +111,19 @@ class Kanana(ServedLM):
         """``carry = body(carry, a layer's parameters, its index)`` over the
         expert layers: one ``lax.scan``, or the same steps unrolled."""
         layers = jnp.arange(self.num_expert_layers, dtype=jnp.int32)
+        # ``moe_weights`` names what hands a layer its parameters: the
+        # slices out of the stacked leaves (the expert weights are most of
+        # their bytes), which inside a scan are the scan's own; what a
+        # layer does with them names itself further in
         if self.scan_layers:
-            return jax.lax.scan(
-                lambda c, sliced: (body(c, *sliced), None), carry,
-                (params["layers"], layers))[0]
+            with jax.named_scope("moe_weights"):
+                return jax.lax.scan(
+                    lambda c, sliced: (body(c, *sliced), None), carry,
+                    (params["layers"], layers))[0]
         for i in range(self.num_expert_layers):
-            carry = body(carry, jax.tree.map(lambda a: a[i], params["layers"]),
-                         layers[i])
+            with jax.named_scope("moe_weights"):
+                p = jax.tree.map(lambda a: a[i], params["layers"])
+            carry = body(carry, p, layers[i])
         return carry
 
     # ----- full forward ----------------------------------------------------- #

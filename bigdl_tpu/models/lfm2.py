@@ -105,18 +105,22 @@ class LFM2(Container):
 
     def _layer(self, layer, p, x):
         """One layer; returns ``(y, the ffn's state)``."""
-        with jax.named_scope("operator"):
+        mixer = "state_mixer" if isinstance(layer["op"], GatedShortConv) \
+            else "attention"
+        with jax.named_scope("operator"), jax.named_scope(mixer):
             h, _ = layer["op_norm"].apply(p["op_norm"], (), x)
             h, _ = layer["op"].apply(p["op"], (), h, training=True)
             x = x + h
-        with jax.named_scope("ffn"):
+        ffn = "moe" if isinstance(layer["ffn"], DroplessMoE) else "mlp"
+        with jax.named_scope("ffn"), jax.named_scope(ffn):
             h, _ = layer["ffn_norm"].apply(p["ffn_norm"], (), x)
             h, st = layer["ffn"].apply(p["ffn"], (), h, training=True)
-        return x + h, st
+            return x + h, st
 
     def apply(self, params, state, input, *, training=False, rng=None):
         embed = params["embed"]
-        x = jnp.take(embed, input.astype(jnp.int32), axis=0)
+        with jax.named_scope("embed"):
+            x = jnp.take(embed, input.astype(jnp.int32), axis=0)
         loads = []
         for i, layer in enumerate(self.layers):
             run = lambda p, x, _layer=layer: self._layer(_layer, p, x)
@@ -125,6 +129,7 @@ class LFM2(Container):
             x, st = run(params[f"layer{i}"], x)
             if st != ():
                 loads.append(st["moe_load"])
-        x, _ = self.norm_f.apply(params["norm_f"], (), x)
         new_state = {"moe_load": sum(loads)} if loads else {}
-        return x @ embed.astype(x.dtype).T, new_state
+        with jax.named_scope("head"):
+            x, _ = self.norm_f.apply(params["norm_f"], (), x)
+            return x @ embed.astype(x.dtype).T, new_state

@@ -100,46 +100,61 @@ class ServedLM(Container):
     # choice); every matrix is multiplied in ``dtype``.
 
     def _embed(self, params, input):
-        return jnp.take(params["embed"], input.astype(jnp.int32),
-                        axis=0).astype(jnp.float32)
+        with jax.named_scope("embed"):
+            return jnp.take(params["embed"], input.astype(jnp.int32),
+                            axis=0).astype(jnp.float32)
 
     def _ffn(self, layer, p, x, live=None):
         """``(x + FFN(RMSNorm(x)), the expert layer's counts or None)``;
         tokens that are not ``live (N, T)`` go to no routed expert."""
-        h, _ = layer["ffn_norm"].apply(p["ffn_norm"], (), x)
-        if isinstance(layer["ffn"], DroplessMoE):
-            h, load = layer["ffn"].generate(p["ffn"], h, live)
-        else:
-            h, _ = layer["ffn"].apply(p["ffn"], (), h.astype(self.dtype))
-            load = None
-        return x + h.astype(jnp.float32), load
+        experts = isinstance(layer["ffn"], DroplessMoE)
+        with jax.named_scope("moe" if experts else "mlp"):
+            h, _ = layer["ffn_norm"].apply(p["ffn_norm"], (), x)
+            if experts:
+                h, load = layer["ffn"].generate(p["ffn"], h, live)
+            else:
+                h, _ = layer["ffn"].apply(p["ffn"], (), h.astype(self.dtype))
+                load = None
+            return x + h.astype(jnp.float32), load
+
+    @staticmethod
+    def _mixer_scope(op):
+        """The scope of a layer's token mixer with its norm and residual:
+        ``state_mixer`` for a delta-rule layer, ``attention`` otherwise."""
+        return jax.named_scope("state_mixer" if isinstance(
+            op, KimiDeltaAttention) else "attention")
 
     def _forward_layer(self, layer, p, x):
         """A layer of the full forward."""
-        h, _ = layer["op_norm"].apply(p["op_norm"], (), x)
-        h, _ = layer["op"].apply(p["op"], (), h.astype(self.dtype))
-        return self._ffn(layer, p, x + h.astype(jnp.float32))[0]
+        with self._mixer_scope(layer["op"]):
+            h, _ = layer["op_norm"].apply(p["op_norm"], (), x)
+            h, _ = layer["op"].apply(p["op"], (), h.astype(self.dtype))
+            x = x + h.astype(jnp.float32)
+        return self._ffn(layer, p, x)[0]
 
     def _paged_layer(self, block, p, x, pool, by, pos, lengths, live, **kw):
         """A layer (``block``: its four modules) of a step of paged
         generation: ``(x, the layer's new state, the expert layer's counts
         or None)``; ``kw`` goes to the mixer (the ``layer`` of a stacked
         leaf)."""
-        h, _ = block["op_norm"].apply(p["op_norm"], (), x)
-        h, new = block["op"].apply_paged(p["op"], h.astype(self.dtype), pool,
-                                         by, pos, lengths, **kw)
-        x, load = self._ffn(block, p, x + h.astype(jnp.float32), live)
+        with self._mixer_scope(block["op"]):
+            h, _ = block["op_norm"].apply(p["op_norm"], (), x)
+            h, new = block["op"].apply_paged(p["op"], h.astype(self.dtype),
+                                             pool, by, pos, lengths, **kw)
+            x = x + h.astype(jnp.float32)
+        x, load = self._ffn(block, p, x, live)
         return x, new, load
 
     def _logits(self, params, x, logits_at=None):
         """Float32 logits, of the one position ``logits_at (N,)`` a row
         where given: ``(N, 1, V)``."""
-        if logits_at is not None:
-            x = jnp.take_along_axis(x, logits_at[:, None, None], axis=1)
-        x, _ = self.norm_f.apply(params["norm_f"], (), x)
-        return jnp.einsum("ntd,vd->ntv", x.astype(self.dtype),
-                          params["head"].astype(self.dtype),
-                          preferred_element_type=jnp.float32)
+        with jax.named_scope("head"):
+            if logits_at is not None:
+                x = jnp.take_along_axis(x, logits_at[:, None, None], axis=1)
+            x, _ = self.norm_f.apply(params["norm_f"], (), x)
+            return jnp.einsum("ntd,vd->ntv", x.astype(self.dtype),
+                              params["head"].astype(self.dtype),
+                              preferred_element_type=jnp.float32)
 
     @staticmethod
     def _check_cache_dtype(dtype, who):

@@ -659,39 +659,39 @@ class TransformerBlock(Container):
         ``(out, new_pool)``.  ``pool`` is this block's own leaves, or
         with ``layer`` the layer-stacked leaves this block is layer
         ``layer`` of (see MultiHeadAttention._apply_paged)."""
-        h, _ = self.ln1.apply(params["ln1"], (), input)
-        a, new_pool = self.attn._apply_paged(params["attn"], h, pool,
-                                             tables, pos, lengths, layer)
-        x = input + a
-        h, _ = self.ln2.apply(params["ln2"], (), x)
-        h, _ = self.fc1.apply(params["fc1"], (), h)
-        h = jax.nn.gelu(h)
-        h, _ = self.fc2.apply(params["fc2"], (), h)
-        return x + h, new_pool
+        with jax.named_scope("attention"):
+            h, _ = self.ln1.apply(params["ln1"], (), input)
+            a, new_pool = self.attn._apply_paged(params["attn"], h, pool,
+                                                 tables, pos, lengths, layer)
+            x = input + a
+        return self._mlp(params, x), new_pool
+
+    def _mlp(self, params, x):
+        """The MLP half: ``x + MLP(LN(x))``."""
+        with jax.named_scope("mlp"):
+            h, _ = self.ln2.apply(params["ln2"], (), x)
+            h, _ = self.fc1.apply(params["fc1"], (), h)
+            h = jax.nn.gelu(h)
+            h, _ = self.fc2.apply(params["fc2"], (), h)
+            return x + h
 
     def apply(self, params, state, input, *, training=False, rng=None,
               cache=None, pos=None):
         if cache is not None:
             # cached prefill/decode: eval-mode block, returns
             # (out, new_cache) like MultiHeadAttention's cached apply
+            with jax.named_scope("attention"):
+                h, _ = self.ln1.apply(params["ln1"], (), input)
+                a, new_cache = self.attn.apply(params["attn"], (), h,
+                                               cache=cache, pos=pos)
+                x = input + a
+            return self._mlp(params, x), new_cache
+        with jax.named_scope("attention"):
             h, _ = self.ln1.apply(params["ln1"], (), input)
-            a, new_cache = self.attn.apply(params["attn"], (), h,
-                                           cache=cache, pos=pos)
+            a, _ = self.attn.apply(params["attn"], (), h, training=training,
+                                   rng=child_rng(rng, 0))
             x = input + a
-            h, _ = self.ln2.apply(params["ln2"], (), x)
-            h, _ = self.fc1.apply(params["fc1"], (), h)
-            h = jax.nn.gelu(h)
-            h, _ = self.fc2.apply(params["fc2"], (), h)
-            return x + h, new_cache
-        h, _ = self.ln1.apply(params["ln1"], (), input)
-        a, _ = self.attn.apply(params["attn"], (), h, training=training,
-                               rng=child_rng(rng, 0))
-        x = input + a
-        h, _ = self.ln2.apply(params["ln2"], (), x)
-        h, _ = self.fc1.apply(params["fc1"], (), h)
-        h = jax.nn.gelu(h)
-        h, _ = self.fc2.apply(params["fc2"], (), h)
-        return x + h, state
+        return self._mlp(params, x), state
 
 
 class TransformerLM(Container):
@@ -874,15 +874,16 @@ class TransformerLM(Container):
                              "(shard the BATCH axis instead)")
         t = input.shape[1]
         pos = jnp.asarray(pos, jnp.int32)
-        x = jnp.take(params["wte"], input.astype(jnp.int32), axis=0)
-        if lengths is not None:
-            # absolute position of each chunk token; jnp.take clips, so
-            # padding tokens past max_len just reuse the last wpe row
-            # (they write to trash and are never read)
-            gpos = pos[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
-            x = x + jnp.take(params["wpe"], gpos, axis=0)
-        else:
-            x = x + jnp.take(params["wpe"], pos, axis=0)[:, None, :]
+        with jax.named_scope("embed"):
+            x = jnp.take(params["wte"], input.astype(jnp.int32), axis=0)
+            if lengths is not None:
+                # absolute position of each chunk token; jnp.take clips,
+                # so padding tokens past max_len just reuse the last wpe
+                # row (they write to trash and are never read)
+                gpos = pos[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
+                x = x + jnp.take(params["wpe"], gpos, axis=0)
+            else:
+                x = x + jnp.take(params["wpe"], pos, axis=0)[:, None, :]
         if self.scan_layers:
             inner = self.blocks[0]
 
@@ -907,8 +908,13 @@ class TransformerLM(Container):
                                       pool[f"block{i}"], tables, pos,
                                       lengths)
                 new_pool[f"block{i}"] = nc
-        x, _ = self.ln_f.apply(params["ln_f"], (), x)
-        return x @ params["head"].astype(x.dtype).T, new_pool
+        return self._head(params, x), new_pool
+
+    def _head(self, params, x):
+        """Final norm and the logits of every position."""
+        with jax.named_scope("head"):
+            x, _ = self.ln_f.apply(params["ln_f"], (), x)
+            return x @ params["head"].astype(x.dtype).T
 
     def _apply_cached(self, params, input, cache, pos):
         """Prefill (``pos=None``: whole padded prompt, K/V written at
@@ -924,14 +930,15 @@ class TransformerLM(Container):
                              "sequence-parallel serving is not a thing "
                              "(shard the BATCH axis instead)")
         t = input.shape[1]
-        x = jnp.take(params["wte"], input.astype(jnp.int32), axis=0)
-        if pos is None:
-            x = x + params["wpe"][:t][None]
-        else:
-            pos = jnp.asarray(pos, jnp.int32)
-            # jnp.take clips out-of-range rows; an inactive slot's
-            # clamped position writes only into its own dead cache row
-            x = x + jnp.take(params["wpe"], pos, axis=0)[:, None, :]
+        with jax.named_scope("embed"):
+            x = jnp.take(params["wte"], input.astype(jnp.int32), axis=0)
+            if pos is None:
+                x = x + params["wpe"][:t][None]
+            else:
+                pos = jnp.asarray(pos, jnp.int32)
+                # jnp.take clips out-of-range rows; an inactive slot's
+                # clamped position writes only into its own dead cache row
+                x = x + jnp.take(params["wpe"], pos, axis=0)[:, None, :]
         if self.scan_layers:
             inner = self.blocks[0]
 
@@ -949,23 +956,23 @@ class TransformerLM(Container):
                 x, nc = b.apply(params[f"block{i}"], (), x,
                                 cache=cache[f"block{i}"], pos=pos)
                 new_cache[f"block{i}"] = nc
-        x, _ = self.ln_f.apply(params["ln_f"], (), x)
-        return x @ params["head"].astype(x.dtype).T, new_cache
+        return self._head(params, x), new_cache
 
     def apply(self, params, state, input, *, training=False, rng=None,
               cache=None, pos=None):
         if cache is not None:
             return self._apply_cached(params, input, cache, pos)
         t = input.shape[1]
-        x = jnp.take(params["wte"], input.astype(jnp.int32), axis=0)
-        if self.seq_axis_name is not None:
-            # inside shard_map the block holds T_local tokens; use global
-            # positions derived from the device's ring index
-            offset = jax.lax.axis_index(self.seq_axis_name) * t
-            pos = offset + jnp.arange(t)
-            x = x + jnp.take(params["wpe"], pos, axis=0)[None]
-        else:
-            x = x + params["wpe"][:t][None]
+        with jax.named_scope("embed"):
+            x = jnp.take(params["wte"], input.astype(jnp.int32), axis=0)
+            if self.seq_axis_name is not None:
+                # inside shard_map the block holds T_local tokens; use
+                # global positions derived from the device's ring index
+                offset = jax.lax.axis_index(self.seq_axis_name) * t
+                pos = offset + jnp.arange(t)
+                x = x + jnp.take(params["wpe"], pos, axis=0)[None]
+            else:
+                x = x + params["wpe"][:t][None]
         if self.scan_layers:
             # one scanned block body; layer i draws fold_in(rng, i), the
             # same per-block key derivation as the unrolled loop below
@@ -987,9 +994,7 @@ class TransformerLM(Container):
                 else:
                     x, _ = b.apply(params[f"block{i}"], (), x,
                                    training=training, rng=key)
-        x, _ = self.ln_f.apply(params["ln_f"], (), x)
-        logits = x @ params["head"].astype(x.dtype).T
-        return logits, state
+        return self._head(params, x), state
 
 
 #: matches the unrolled per-block param keys ("block0".."block{N-1}")

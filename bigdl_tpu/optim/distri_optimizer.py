@@ -218,7 +218,9 @@ def make_distri_train_step(model, criterion, optim_method, flat_space,
         pchunk = flat_space.chunk(params_flat, jax.lax.axis_index(axis))
         if mchunk is not None:
             gchunk = gchunk * mchunk
-        new_pchunk, new_opt_state = optim_method.update(gchunk, opt_state, pchunk)
+        with jax.named_scope("optimizer"):
+            new_pchunk, new_opt_state = optim_method.update(
+                gchunk, opt_state, pchunk)
         if freeze_mask_flat is not None:
             # restore frozen positions so weight decay cannot leak in
             new_pchunk = mchunk * new_pchunk + (1.0 - mchunk) * pchunk

@@ -106,36 +106,40 @@ def make_train_step(
             cp = _cast_params(p, compute_dtype, keep_fp32)
             x = _cast_tree(input, compute_dtype)
             out, new_mstate = model.apply(cp, mstate, x, training=True, rng=rng)
-            out32 = _cast_tree(out, jnp.float32)
-            data_loss = criterion.apply(out32, target)
-            total = data_loss
-            if use_reg:
-                # per-layer wRegularizer/bRegularizer terms on the fp32
-                # master params: gradients pick them up via autodiff, but
-                # the REPORTED loss stays the bare criterion value like the
-                # reference (accGradParameters touches gradients only)
-                total = total + regularization_loss(model, p)
+            with jax.named_scope("loss"):
+                out32 = _cast_tree(out, jnp.float32)
+                data_loss = criterion.apply(out32, target)
+                total = data_loss
+                if use_reg:
+                    # per-layer wRegularizer/bRegularizer terms on the fp32
+                    # master params: gradients pick them up via autodiff,
+                    # but the REPORTED loss stays the bare criterion value
+                    # like the reference (accGradParameters touches
+                    # gradients only)
+                    total = total + regularization_loss(model, p)
             return total, (data_loss, new_mstate)
 
         (_, (loss, new_mstate)), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(params)
-        grads = _cast_tree(grads, jnp.float32)
-        if grad_transform is not None:
-            grads = grad_transform(grads)
-        if freeze_mask is not None:
-            grads = jax.tree.map(
-                lambda g, keep: g if keep else jnp.zeros_like(g),
-                grads, freeze_mask)
-        raw_grads = grads             # pre-clip: clip hides explosions
-        if clip_value is not None:
-            grads = clip_by_value(grads, *clip_value)
-        if clip_norm is not None:
-            grads = clip_by_global_norm(grads, clip_norm)
-        new_params, new_opt_state = optim_method.update(grads, opt_state, params)
-        if freeze_mask is not None:
-            new_params = jax.tree.map(
-                lambda n, o, keep: n if keep else o,
-                new_params, params, freeze_mask)
+        with jax.named_scope("optimizer"):
+            grads = _cast_tree(grads, jnp.float32)
+            if grad_transform is not None:
+                grads = grad_transform(grads)
+            if freeze_mask is not None:
+                grads = jax.tree.map(
+                    lambda g, keep: g if keep else jnp.zeros_like(g),
+                    grads, freeze_mask)
+            raw_grads = grads             # pre-clip: clip hides explosions
+            if clip_value is not None:
+                grads = clip_by_value(grads, *clip_value)
+            if clip_norm is not None:
+                grads = clip_by_global_norm(grads, clip_norm)
+            new_params, new_opt_state = optim_method.update(
+                grads, opt_state, params)
+            if freeze_mask is not None:
+                new_params = jax.tree.map(
+                    lambda n, o, keep: n if keep else o,
+                    new_params, params, freeze_mask)
         if sample is None:
             return new_params, new_mstate, new_opt_state, loss
         from bigdl_tpu.observability.health import (empty_health_stats,

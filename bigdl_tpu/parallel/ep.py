@@ -95,8 +95,10 @@ def make_ep_train_step(model, criterion, optim_method, mesh,
 
         (loss, task), grads = jax.value_and_grad(loss_fn, has_aux=True)(
             params)
-        grads = _cast_tree(grads, jnp.float32)
-        new_params, new_opt = optim_method.update(grads, opt_state, params)
+        with jax.named_scope("optimizer"):
+            grads = _cast_tree(grads, jnp.float32)
+            new_params, new_opt = optim_method.update(grads, opt_state,
+                                                      params)
         return new_params, new_opt, task
 
     def compile_for(params):

@@ -252,7 +252,9 @@ def make_pp_train_step(model, criterion, optim_method, mesh,
 
     def step(pp_params, opt_state, x, y, rng):
         loss, grads = jax.value_and_grad(loss_fn)(pp_params, x, y, rng)
-        new_params, new_opt = optim_method.update(grads, opt_state, pp_params)
+        with jax.named_scope("optimizer"):
+            new_params, new_opt = optim_method.update(grads, opt_state,
+                                                      pp_params)
         return new_params, new_opt, loss
 
     return jax.jit(step, donate_argnums=(0, 1))
@@ -457,8 +459,9 @@ def make_pp_1f1b_train_step(model, criterion, optim_method, mesh,
         xm = x.reshape(n_microbatches, n // n_microbatches, t)
         ym = y.reshape(n_microbatches, n // n_microbatches, t)
         loss, grads = smapped(pp_params, xm, ym, rng)
-        new_params, new_opt = optim_method.update(grads, opt_state,
-                                                  pp_params)
+        with jax.named_scope("optimizer"):
+            new_params, new_opt = optim_method.update(grads, opt_state,
+                                                      pp_params)
         return new_params, new_opt, loss
 
     return jax.jit(step, donate_argnums=(0, 1))

@@ -235,9 +235,10 @@ def make_het_pp_train_step(model, criterion, optim_method, mesh,
     def step(stage_params_list, opt_state, x, y, rng):
         loss, grads = jax.value_and_grad(loss_fn)(stage_params_list, x, y,
                                                   rng)
-        grads = _cast_tree(grads, jnp.float32)
-        new_params, new_opt = optim_method.update(grads, opt_state,
-                                                  stage_params_list)
+        with jax.named_scope("optimizer"):
+            grads = _cast_tree(grads, jnp.float32)
+            new_params, new_opt = optim_method.update(grads, opt_state,
+                                                      stage_params_list)
         return new_params, new_opt, loss
 
     return jax.jit(step, donate_argnums=(0, 1)), init_stage_params

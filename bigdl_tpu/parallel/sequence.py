@@ -53,7 +53,9 @@ def make_sp_train_step(model, criterion, optim_method, mesh,
         # equal token counts per shard -> grad of the global mean loss is the
         # mean of shard grads
         grads = jax.tree.map(lambda g: lax.pmean(g, axes), grads)
-        new_params, new_opt = optim_method.update(grads, opt_state, params)
+        with jax.named_scope("optimizer"):
+            new_params, new_opt = optim_method.update(grads, opt_state,
+                                                      params)
         return new_params, new_opt, lax.pmean(loss, axes)
 
     batch_spec = P(data_axis, seq_axis)

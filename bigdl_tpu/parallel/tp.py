@@ -77,8 +77,10 @@ def make_tp_train_step(model, criterion, optim_method, mesh,
             return criterion.apply(out.astype(jnp.float32), y)
 
         loss, grads = jax.value_and_grad(loss_fn)(params)
-        grads = _cast_tree(grads, jnp.float32)
-        new_params, new_opt = optim_method.update(grads, opt_state, params)
+        with jax.named_scope("optimizer"):
+            grads = _cast_tree(grads, jnp.float32)
+            new_params, new_opt = optim_method.update(grads, opt_state,
+                                                      params)
         return new_params, new_opt, loss
 
     def compile_for(params):

@@ -973,15 +973,16 @@ def paged_generate_steps(model, cache_dtype=jnp.float32):
             kw["logits_at"] = last()
         logits, new = model.apply_paged(params, tokens, pool, tables,
                                         pos=start, lengths=lengths, **kw)
-        idx = jnp.zeros_like(kw["logits_at"]) if "logits_at" in kw \
-            else last()
-        row = jnp.take_along_axis(
-            logits, idx[:, None, None], axis=1)[:, 0]
-        # the sampled token OCCUPIES position start + lengths; folding
-        # the RNG on that position makes the draw independent of how
-        # the prompt was chunked
-        first = sample_tokens(row, temperature, top_k, top_p, seed,
-                              start + lengths)
+        with jax.named_scope("sampler"):
+            idx = jnp.zeros_like(kw["logits_at"]) if "logits_at" in kw \
+                else last()
+            row = jnp.take_along_axis(
+                logits, idx[:, None, None], axis=1)[:, 0]
+            # the sampled token OCCUPIES position start + lengths; folding
+            # the RNG on that position makes the draw independent of how
+            # the prompt was chunked
+            first = sample_tokens(row, temperature, top_k, top_p, seed,
+                                  start + lengths)
         return first, new
 
     def decode(params, pool, tokens, pos, tables, temperature, top_k,
@@ -989,8 +990,9 @@ def paged_generate_steps(model, cache_dtype=jnp.float32):
         kw = {} if slots is None else {"slots": slots}
         logits, new = model.apply_paged(params, tokens[:, None], pool,
                                         tables, pos=pos, **kw)
-        nxt = sample_tokens(logits[:, 0], temperature, top_k, top_p,
-                            seed, pos + 1)
+        with jax.named_scope("sampler"):
+            nxt = sample_tokens(logits[:, 0], temperature, top_k, top_p,
+                                seed, pos + 1)
         return nxt, new
 
     def copy_block(pool, src, dst):
@@ -1550,16 +1552,17 @@ def speculative_verify_step(model, cache_dtype, k: int):
         logits, new = model.apply_paged(
             params, tokens, pool, tables, pos=pos,
             lengths=jnp.full_like(pos, k1))
-        flat = logits.reshape((-1, logits.shape[-1]))
-        positions = (pos[:, None] + 1
-                     + jnp.arange(k1, dtype=jnp.int32)[None, :])
 
         def rep(a):
             return jnp.repeat(a, k1)
 
-        sampled = sample_tokens(flat, rep(temperature), rep(top_k),
-                                rep(top_p), rep(seed),
-                                positions.reshape(-1))
+        with jax.named_scope("sampler"):
+            flat = logits.reshape((-1, logits.shape[-1]))
+            positions = (pos[:, None] + 1
+                         + jnp.arange(k1, dtype=jnp.int32)[None, :])
+            sampled = sample_tokens(flat, rep(temperature), rep(top_k),
+                                    rep(top_p), rep(seed),
+                                    positions.reshape(-1))
         return sampled.reshape(tokens.shape), new
 
     fn = jax.jit(verify, donate_argnums=(1,))
