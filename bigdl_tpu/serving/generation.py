@@ -59,7 +59,7 @@ import queue
 import threading
 import time
 from concurrent.futures import Future, TimeoutError as FutureTimeoutError
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -391,6 +391,11 @@ class GenerateScheduler:
     def _active(self):
         return [(i, s) for i, s in enumerate(self._slots) if s is not None]
 
+    def _busy(self):
+        """Work the dispatcher still owes: what ``_loop``, ``drain`` and
+        ``close`` wait for besides the queue."""
+        return bool(self._active())
+
     def stats(self):
         with self._lock:
             active = len(self._active())
@@ -433,14 +438,14 @@ class GenerateScheduler:
         while True:
             with self._lock:
                 if self._running and not self._pending \
-                        and not self._active():
+                        and not self._busy():
                     with span("dispatcher_idle"):
                         while self._running and not self._pending \
-                                and not self._active():
+                                and not self._busy():
                             self._idle.notify_all()
                             self._work.wait()
                 if not self._running and not self._pending \
-                        and not self._active():
+                        and not self._busy():
                     self._idle.notify_all()
                     return
             with span("tick", tick=self._tick,
@@ -496,7 +501,7 @@ class GenerateScheduler:
             with self._lock:
                 self._in_flight -= len(admit)
                 if not self._pending and not self._in_flight \
-                        and not self._active():
+                        and not self._busy():
                     self._idle.notify_all()
 
     def _admit(self, reqs):
@@ -871,7 +876,7 @@ class GenerateScheduler:
             else time.perf_counter() + timeout
         with self._lock:
             self._work.notify_all()
-            while self._pending or self._in_flight or self._active():
+            while self._pending or self._in_flight or self._busy():
                 remaining = None if deadline is None \
                     else deadline - time.perf_counter()
                 if remaining is not None and remaining <= 0:
@@ -1020,11 +1025,21 @@ class _PagedSlot:
     ``consumed < len(prompt)`` the slot is PREFILLING: chunk ticks
     advance ``consumed`` (which starts at the prefix-cache hit length,
     not 0).  The final chunk samples the first token and flips the
-    slot to decoding, after which the fields mean exactly what
-    ``_Slot``'s do."""
+    slot to decoding, after which ``tokens`` and ``last`` mean exactly
+    what ``_Slot``'s do.
+
+    The dispatcher launches a tick before it has fetched the one
+    before, so the slot is kept in two tenses.  ``consumed``, ``pos``
+    and ``launched`` (tokens whose computation has been launched) say
+    what the device has been ASKED for and move when a tick is
+    launched; ``tokens`` and ``last`` say what has come back.
+    ``riding`` counts the launched ticks that carry the slot's row and
+    have not been fetched; ``ended`` marks a request that finished (EOS,
+    abandoned) while one still did: its slot and blocks are given back
+    when ``riding`` falls to nought."""
 
     __slots__ = ("fut", "prompt", "seq", "consumed", "tokens", "last",
-                 "pos", "seed")
+                 "pos", "seed", "launched", "riding", "ended")
 
     def __init__(self, fut, prompt, seq, consumed, seed):
         self.fut = fut
@@ -1035,10 +1050,55 @@ class _PagedSlot:
         self.last = None
         self.pos = None
         self.seed = int(seed)
+        self.launched = 0
+        self.riding = 0
+        self.ended = False
 
     @property
     def prefilling(self):
         return self.consumed < self.prompt.size
+
+    @property
+    def decoding(self):
+        """Wants a row in the next decode tick: its prompt is on the
+        device (or on its way) and a token of its budget is still to be
+        asked for.  A row that ends by length leaves here, exactly; one
+        that may end on ``eos_id`` is found out a tick late."""
+        return not self.ended and not self.prefilling \
+            and self.launched < self.fut.max_new_tokens
+
+
+class _Launched(NamedTuple):
+    """A device call that has been launched and not fetched: what its
+    fetch, deliver and telemetry need once the tokens are asked for."""
+
+    kind: str                 # "prefill" | "decode"
+    tick: int
+    #: ``(slot index, slot, at)``, ``at`` the row's index in ``out`` or
+    #: None where the call gives the row no token (a chunk that does not
+    #: end its prompt)
+    rows: list
+    out: jax.Array            # the tokens, on the device
+    counted: Optional[list]   # copies of the counter leaves
+    start_ns: int             # of the call's prep span
+    qdepth: int
+    execs_before: Optional[int]
+    event: dict               # the tick event's own fields
+
+
+@jax.jit
+def _feed_tokens(host, src, prev):
+    """A decode tick's input tokens when some rows' newest token is still
+    on the device: row ``i`` takes ``prev[src[i]]`` (the unfetched call's
+    output) where ``src[i] >= 0``, else the host's ``host[i]``."""
+    return jnp.where(src >= 0, prev[jnp.maximum(src, 0)], host)
+
+
+@jax.jit
+def _copy_leaves(leaves):
+    """Copies that outlive the pool they were read from: the next call
+    donates the pool, counters and all."""
+    return [jnp.copy(leaf) for leaf in leaves]
 
 
 class PagedGenerateScheduler(GenerateScheduler):
@@ -1063,11 +1123,28 @@ class PagedGenerateScheduler(GenerateScheduler):
       chunk's latency per token, never head-of-line-blocks them.
     - Decode ticks sample in-jit per the request's ``SamplingParams``
       (greedy by default, bit-identical to the contiguous argmax).
+    - The dispatcher LAUNCHES AHEAD, one deep: tick k+1 is prepared and
+      launched before tick k's tokens are fetched, so the device's queue
+      is never empty while there is work and the host's share of a call
+      (arguments, dispatch, the transfer back, delivery) runs beside a
+      program and not between two.  At most one call is launched and
+      not fetched (``_inflight``).  A chunk tick needs nothing of the
+      tick before it; a decode tick needs each row's newest token, and
+      takes it on the device from the unfetched call's output
+      (``_feed_tokens``).  Who rides tick k+1 is known without tick k's
+      tokens for a row that ends by length; a row that may end on
+      ``eos_id`` rides regardless, and if tick k ended it, its row of
+      tick k+1 is computed and thrown away (``rows_wasted``).  A slot
+      whose request ends while a launched tick still carries its row
+      keeps its blocks and its per-slot state until that tick has been
+      fetched.  Rows are independent and program order on the device is
+      launch order, so the tokens are those of one tick at a time.
 
     The executable set stays closed and warmable: ONE decode shape,
-    one chunk shape per admission-batch rung, one block-copy -- zero
-    steady-state recompiles across mixed lengths, chunked prefill and
-    sampled decoding (the acceptance contract, tests/test_paged.py).
+    one chunk shape per admission-batch rung, one block-copy, and the
+    token feed at each rung's length -- zero steady-state recompiles
+    across mixed lengths, chunked prefill and sampled decoding (the
+    acceptance contract, tests/test_paged.py).
     """
 
     supports_sampling = True
@@ -1113,6 +1190,10 @@ class PagedGenerateScheduler(GenerateScheduler):
         self._hit_tokens_delta = 0
         self._prompt_tokens_delta = 0
         self._seq_counter = 0
+        #: the call that has been launched and not fetched, or None
+        self._inflight = None
+        self._launched_ahead = 0
+        self._rows_wasted = 0
         super().__init__(model, slots=slots, max_len=max_len,
                          prompt_ladder=prompt_ladder,
                          queue_capacity=queue_capacity,
@@ -1132,6 +1213,12 @@ class PagedGenerateScheduler(GenerateScheduler):
         #: cannot be rebuilt from the blocks a prefix shares)
         self._slot_state = _has_slot_leaves(self._kinds)
         self._build_pool()
+        # the two small programs of launching ahead, at every shape a
+        # tick can ask for: they are nobody's rung to warm
+        host = np.zeros((self.slots,), np.int32)
+        for n in self.batch_ladder:
+            _feed_tokens(host, host - 1, np.zeros((int(n),), np.int32))
+        self._counters()
 
     def _build_pool(self):
         kw = {"slots": self.slots} if self._slot_state else {}
@@ -1191,6 +1278,8 @@ class PagedGenerateScheduler(GenerateScheduler):
         st["kv"] = self._alloc.stats()
         st["block_size"] = self.block_size
         st["prefill_chunk"] = self.prefill_chunk
+        st["launched_ahead"] = self._launched_ahead
+        st["rows_wasted"] = self._rows_wasted
         return st
 
     # ----- warmup ----------------------------------------------------------- #
@@ -1230,11 +1319,27 @@ class PagedGenerateScheduler(GenerateScheduler):
         return backend_compile_count() - before
 
     # ----- dispatcher ticks -------------------------------------------------- #
+    def _busy(self):
+        return self._inflight is not None or super()._busy()
+
     def _release_slot(self, index, slot):
-        seq = getattr(slot, "seq", None)
-        if seq is not None:
-            self._alloc.free_sequence(seq)
+        if slot.riding:
+            # a launched tick still writes this row's blocks and state:
+            # ``_complete`` gives them back once it has been fetched
+            slot.ended = True
+            return
+        self._alloc.free_sequence(slot.seq)
         super()._release_slot(index, slot)
+
+    def _tick_failed(self, e, futs, extra_free):
+        """The call in flight was launched on the pool that is lost, or is
+        the one that failed: it is dropped untouched, and nothing rides
+        any more, so every slot goes back now.  The riders of both calls
+        are the active slots, and the base fails each once."""
+        self._inflight = None
+        for _i, slot in self._active():
+            slot.riding = 0
+        super()._tick_failed(e, futs, extra_free)
 
     def _kv_extra(self):
         st = self._alloc.stats()
@@ -1313,12 +1418,17 @@ class PagedGenerateScheduler(GenerateScheduler):
         return (np.full((n,), self.slots, np.int32),) \
             if self._slot_state else ()
 
-    def _fetch(self, tokens):
-        """The tick's host sync: its tokens and, with them, whatever the
-        step counted (``counter`` leaves) -- one fetch, no sync of its
-        own."""
+    def _counters(self):
+        """The pool's counter leaves as arrays of their own, or None: what
+        the call just launched counted, taken out before the next call
+        donates the pool."""
         counted = self._leaves_of(COUNTER)
-        if not counted:
+        return _copy_leaves(counted) if counted else None
+
+    def _fetch(self, tokens, counted):
+        """A launched call's host sync: its tokens and, with them, what it
+        counted -- one fetch, no sync of its own."""
+        if counted is None:
             return np.asarray(tokens), None
         tokens, counted = jax.device_get((tokens, counted))
         return np.asarray(tokens), counted
@@ -1347,9 +1457,9 @@ class PagedGenerateScheduler(GenerateScheduler):
         seed[r] = slot.seed
 
     def _occupancy(self, claimed):
-        active = self._active()
-        prefilling = sum(s.prefilling for _i, s in active)
-        return {"slots_decoding": len(active) - prefilling,
+        live = [s for _i, s in self._active() if not s.ended]
+        prefilling = sum(s.prefilling for s in live)
+        return {"slots_decoding": len(live) - prefilling,
                 "slots_prefilling": prefilling,
                 "blocks_free": self._alloc.stats()["blocks_free"]}
 
@@ -1357,17 +1467,44 @@ class PagedGenerateScheduler(GenerateScheduler):
         """One dispatcher iteration of device work: at most ONE prefill
         chunk per currently-prefilling sequence, then one decode tick
         over every decoding slot -- the interleave that keeps chunked
-        prefill from starving live streams."""
-        if any(s.prefilling for _i, s in self._active()):
+        prefill from starving live streams.  Each is launched before
+        the call ahead of it is fetched; with nothing to launch, what
+        is in flight is fetched."""
+        idle = True
+        if any(s.prefilling and not s.ended for _i, s in self._active()):
+            idle = False
             self._run_chunk_tick(qdepth)
-        if any(not s.prefilling for _i, s in self._active()):
+        # asked again: a chunk just launched may have ended a prompt
+        if any(s.decoding for _i, s in self._active()):
+            idle = False
             self._run_decode_tick(qdepth)
+        if idle:
+            self._land(None)
 
     def _run_chunk_tick(self, qdepth):
+        self._land(self._launch_chunk(qdepth))
+
+    def _run_decode_tick(self, qdepth):
+        self._land(self._launch_decode(qdepth))
+
+    def _land(self, launched):
+        """``launched`` (a call just launched, or None) becomes the call
+        in flight, and the one that was is fetched, delivered and
+        recorded while the device runs the new one."""
+        ahead, self._inflight = self._inflight, launched
+        if ahead is not None:
+            self._complete(ahead)
+
+    def _launch_chunk(self, qdepth):
+        """Prepare and launch one chunk of every prefilling row; None
+        where the launch failed (``_tick_failed`` has run)."""
         execs_before = self._compiles()
-        rows = [(i, s) for i, s in self._active() if s.prefilling]
+        ahead = self._inflight
+        rows = [(i, s) for i, s in self._active()
+                if s.prefilling and not s.ended]
         n = len(rows)
-        with span("prefill_prep", rows=n, slots_total=self.slots) as prep:
+        with span("prefill_prep", rows=n, slots_total=self.slots,
+                  ahead=int(ahead is not None)) as prep:
             bucket = self.batch_ladder.bucket_for(n) \
                 or self.batch_ladder.add(n)
             tc = self.prefill_chunk
@@ -1394,97 +1531,140 @@ class PagedGenerateScheduler(GenerateScheduler):
             prep.set(bucket=int(bucket), prompt_tokens=int(lens.sum()),
                      context_tokens=int(start.sum()),
                      rows_sampling=int((knobs[0] > 0).sum()))
+        tick = self._tick + (ahead is not None)
         try:
-            with span("generate_prefill", tick=self._tick, records=n):
+            with span("generate_prefill", tick=tick, records=n):
                 with span("launch"):
                     first, self._cache = self._chunk_fn(
                         self._params(), self._cache, tokens, start, lens,
                         tables, *knobs, *slot_ids)
-                with span("fetch"):
-                    first, counted = self._fetch(first)     # host sync
+                    counted = self._counters()
                 self._mirror_chunk(tokens, start, lens, tables, knobs)
         except Exception as e:
             log.exception("chunk prefill tick failed (%d prompts)", n)
             self._tick_failed(e, [], [])
-            return
-        self._record_counts(counted)
-        done_lat = []
-        emitted = 0
-        with span("deliver") as dlv:
-            for r, (i, s) in enumerate(rows):
-                s.fut._chunks += 1
-                s.consumed += int(lens[r])
-                # full prompt blocks now hold real K/V: register their
-                # hashes so later admissions can share them
-                self._alloc.commit_full_blocks(s.seq, s.consumed)
-                if not s.prefilling:                 # prompt complete
-                    s.last = int(first[r])
-                    s.tokens = [s.last]
-                    s.pos = int(s.prompt.size)
-                    emitted += 1
-                    self._deliver(i, s, done_lat)
-            dlv.set(tokens=emitted, finished=len(done_lat))
-        self._tick += 1
-        self._record_tick("prefill", prep.start_ns, dlv.end_ns, records=n,
-                          tokens=emitted, bucket=int(bucket),
-                          prompt_bucket=tc, qdepth=qdepth,
-                          execs_before=execs_before, latencies=done_lat,
-                          riders=[s.fut for _i, s in rows],
-                          extra=self._kv_extra())
+            return None
+        riding = []
+        for r, (i, s) in enumerate(rows):
+            s.consumed += int(lens[r])
+            s.riding += 1
+            # full prompt blocks hold real K/V before any later program
+            # reads them (launch order is program order): register their
+            # hashes so later admissions can share them
+            self._alloc.commit_full_blocks(s.seq, s.consumed)
+            if s.prefilling:
+                riding.append((i, s, None))
+            else:                                    # prompt complete
+                s.pos = int(s.prompt.size)
+                s.launched = 1
+                riding.append((i, s, r))
+        self._launched_ahead += ahead is not None
+        return _Launched("prefill", tick, riding, first, counted,
+                         prep.start_ns, qdepth, execs_before,
+                         dict(records=n, bucket=int(bucket),
+                              prompt_bucket=tc))
 
-    def _run_decode_tick(self, qdepth):
+    def _launch_decode(self, qdepth):
+        """Prepare and launch one token of every decoding row; None where
+        the launch failed.  A row whose newest token is in the unfetched
+        call's output is fed it on the device."""
         execs_before = self._compiles()
         s_n = self.slots
-        active = [(i, s) for i, s in self._active() if not s.prefilling]
-        with span("decode_prep", rows=len(active),
-                  slots_total=s_n) as prep:
+        ahead = self._inflight
+        unfetched = {} if ahead is None else \
+            {i: at for i, _s, at in ahead.rows if at is not None}
+        active = [(i, s) for i, s in self._active() if s.decoding]
+        with span("decode_prep", rows=len(active), slots_total=s_n,
+                  ahead=int(ahead is not None)) as prep:
             mb = self.max_blocks_per_seq
             tokens = np.zeros((s_n,), np.int32)
+            src = np.full((s_n,), -1, np.int32)
             pos = np.zeros((s_n,), np.int32)
             tables = np.full((s_n, mb), self._alloc.trash, np.int32)
             knobs = self._sampling_rows(s_n)
             slot_ids = self._slot_rows(s_n)
             for i, s in active:
                 self._cow_guard(s, s.pos, s.pos)
-                tokens[i] = s.last
+                if i in unfetched:
+                    src[i] = unfetched[i]
+                else:
+                    tokens[i] = s.last
                 pos[i] = s.pos
                 if slot_ids:
                     slot_ids[0][i] = i
                 tables[i] = self._alloc.table_row(s.seq, mb)
                 self._fill_sampling(knobs, i, s)
+            rows_ahead = int((src >= 0).sum())
             # the cache rows this tick's attention reads: every live
             # slot's positions up to and with the one it writes
             prep.set(context_tokens=int(pos.sum()) + len(active),
-                     rows_sampling=int((knobs[0] > 0).sum()))
+                     rows_sampling=int((knobs[0] > 0).sum()),
+                     rows_ahead=rows_ahead)
+        tick = self._tick + (ahead is not None)
         try:
-            with span("generate_decode", tick=self._tick,
-                      records=len(active)):
+            with span("generate_decode", tick=tick, records=len(active)):
                 with span("launch"):
+                    if rows_ahead:
+                        tokens = _feed_tokens(tokens, src, ahead.out)
                     nxt, self._cache = self._decode_fn(
                         self._params(), self._cache, tokens, pos, tables,
                         *knobs, *slot_ids)
-                with span("fetch"):
-                    nxt, counted = self._fetch(nxt)         # host sync
+                    counted = self._counters()
         except Exception as e:
             log.exception("decode tick failed (%d slots)", len(active))
+            self._tick_failed(e, [], [])
+            return None
+        for _i, s in active:
+            s.pos += 1
+            s.launched += 1
+            s.riding += 1
+        self._launched_ahead += ahead is not None
+        return _Launched("decode", tick, [(i, s, i) for i, s in active],
+                         nxt, counted, prep.start_ns, qdepth, execs_before,
+                         dict(records=0, slots_before=len(active)))
+
+    def _complete(self, call):
+        """Fetch a launched call's tokens, deliver them and record the
+        tick.  A failure surfaces here, one call late, when the next call
+        is already launched on a pool that is lost with this one."""
+        try:
+            with span("generate_" + call.kind, tick=call.tick,
+                      records=len(call.rows)):
+                with span("fetch"):
+                    out, counted = self._fetch(call.out, call.counted)
+        except Exception as e:
+            log.exception("%s tick failed (%d rows)", call.kind,
+                          len(call.rows))
             self._tick_failed(e, [], [])
             return
         self._record_counts(counted)
         done_lat = []
-        with span("deliver", tokens=len(active)) as dlv:
-            for i, s in active:
-                s.pos += 1
-                s.last = int(nxt[i])
-                s.tokens.append(s.last)
-                self._deliver(i, s, done_lat)
-            dlv.set(finished=len(done_lat))
+        emitted = wasted = 0
+        with span("deliver") as dlv:
+            for i, s, at in call.rows:
+                s.riding -= 1
+                if s.ended:
+                    # its request ended while this row was on the device
+                    wasted += 1
+                    self._release_slot(i, s)
+                    continue
+                if call.kind == "prefill":
+                    s.fut._chunks += 1
+                if at is not None:
+                    s.last = int(out[at])
+                    s.tokens.append(s.last)
+                    emitted += 1
+                    self._deliver(i, s, done_lat)
+            dlv.set(tokens=emitted, finished=len(done_lat),
+                    rows_wasted=wasted)
         self._tick += 1
-        self._record_tick("decode", prep.start_ns, dlv.end_ns, records=0,
-                          tokens=len(active), qdepth=qdepth,
-                          execs_before=execs_before, latencies=done_lat,
-                          slots_before=len(active),
-                          riders=[s.fut for _i, s in active],
-                          extra=self._kv_extra())
+        self._rows_wasted += wasted
+        self._record_tick(call.kind, call.start_ns, dlv.end_ns,
+                          tokens=emitted, qdepth=call.qdepth,
+                          execs_before=call.execs_before,
+                          latencies=done_lat,
+                          riders=[s.fut for _i, s, _at in call.rows],
+                          extra=self._kv_extra(), **call.event)
 
     def _mirror_chunk(self, tokens, start, lens, tables, knobs):
         """Hook for a twin cache that must see every prompt chunk:
@@ -1754,7 +1934,12 @@ class SpeculativeScheduler(PagedGenerateScheduler):
         3. Accept the longest matching draft prefix + the verifier's
            next token; stream them through the normal ``_deliver``
            path (EOS / token budget truncate the run mid-emission).
+
+        The next round needs this one's accepted counts on the host, so
+        nothing is launched ahead of a round: the chunk call that may be
+        in flight is fetched first.
         """
+        self._land(None)
         t0_ns = now_ns()
         execs_before = self._compiles()
         s_n = self.slots
@@ -1765,6 +1950,8 @@ class SpeculativeScheduler(PagedGenerateScheduler):
         tables = np.full((s_n, mb), self._alloc.trash, np.int32)
         knobs = self._sampling_rows(s_n)
         active = [(i, s) for i, s in self._active() if not s.prefilling]
+        if not active:         # the chunk just fetched ended them all
+            return
         for i, s in active:
             # COW the WHOLE write span up front, clamped to the
             # sequence's reserved range (overshoot writes go to trash

@@ -285,6 +285,7 @@ class TestServingSpans:
                    for t in ticks)
         kids = _children([r for r in recs if r.thread == thread])
         prefills = decodes = 0
+        launched, fetched = {}, {}
         for t in ticks:
             names = [c.name for c in kids[t.span_id]]
             assert names[0] == "admit"
@@ -292,10 +293,40 @@ class TestServingSpans:
                     "slots_prefilling", "slots_total"} <= set(t.attrs)
             assert ("blocks_free" in t.attrs) == (kv_cache == "paged")
             assert t.attrs["slots_total"] == 2
+            rest = kids[t.span_id][1:]
+            if kv_cache == "paged":
+                # the paged dispatcher launches a call before it fetches
+                # the one ahead of it: pairs of (prep, generate_* > launch)
+                # and of (generate_* > fetch, deliver), in any order
+                assert len(rest) % 2 == 0
+                for a, b in zip(*[iter(rest)] * 2):
+                    gen = b if a.name.endswith("_prep") else a
+                    kind = gen.name.split("_")[1]
+                    assert gen.name in ("generate_prefill",
+                                        "generate_decode")
+                    assert {"tick", "records"} <= set(gen.attrs)
+                    did = [c.name for c in kids[gen.span_id]
+                           if c.name != "compile"]
+                    key = (kind, gen.attrs["tick"])
+                    if gen is b:
+                        assert a.name == kind + "_prep" \
+                            and did == ["launch"]
+                        assert {"rows", "slots_total", "ahead"} \
+                            <= set(a.attrs)
+                        assert key not in launched
+                        launched[key] = a
+                    else:
+                        assert b.name == "deliver" and did == ["fetch"]
+                        assert {"tokens", "finished", "rows_wasted"} \
+                            <= set(b.attrs)
+                        assert key in launched and key not in fetched
+                        fetched[key] = b
+                    assert t.start_ns <= a.start_ns <= a.end_ns \
+                        <= b.start_ns <= b.end_ns <= t.end_ns
+                continue
             # after admission: groups of (prep, generate_*, deliver)
-            rest = names[1:]
             assert len(rest) % 3 == 0
-            for prep, gen, dlv in zip(*[iter(kids[t.span_id][1:])] * 3):
+            for prep, gen, dlv in zip(*[iter(rest)] * 3):
                 kind = prep.name.split("_")[0]
                 assert prep.name in ("prefill_prep", "decode_prep")
                 assert gen.name == "generate_" + kind
@@ -313,6 +344,23 @@ class TestServingSpans:
                     assert prep.attrs["rows"] == dlv.attrs["tokens"]
                 assert t.start_ns <= prep.start_ns <= gen.start_ns \
                     <= gen.end_ns <= dlv.end_ns <= t.end_ns
+        if kv_cache == "paged":
+            # every call launched was fetched, one call later at most, and
+            # delivered a token for each of its rows that was still wanted
+            assert sorted(launched) == sorted(fetched)
+            ticks_of = sorted(n for _kind, n in launched)
+            assert ticks_of == list(range(ticks_of[0], ticks_of[-1] + 1))
+            for (kind, n), prep in launched.items():
+                dlv = fetched[kind, n]
+                if kind == "prefill":
+                    prefills += 1
+                    assert {"bucket", "prompt_tokens"} <= set(prep.attrs)
+                else:
+                    decodes += 1
+                    assert {"rows_ahead"} <= set(prep.attrs)
+                    assert prep.attrs["rows"] == dlv.attrs["tokens"] \
+                        + dlv.attrs["rows_wasted"]
+            assert any(p.attrs["ahead"] for p in launched.values())
         assert prefills >= 2 and decodes >= 4
         assert sum(kids[t.span_id][0].attrs["requests"]
                    for t in ticks) == 3
