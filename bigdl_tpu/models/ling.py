@@ -36,7 +36,8 @@ import jax
 import jax.numpy as jnp
 
 from bigdl_tpu.nn.gated import GatedMLP
-from bigdl_tpu.nn.generation_state import COUNTER, StateSpec, allocate
+from bigdl_tpu.nn.generation_state import (COUNTER, StateSpec, allocate,
+                                           has_slot_state)
 from bigdl_tpu.nn.latent_attention import LatentAttention
 from bigdl_tpu.nn.linear_attention import KimiDeltaAttention
 from bigdl_tpu.nn.module import Container, child_rng
@@ -45,17 +46,41 @@ from bigdl_tpu.nn.normalization import RMSNorm
 
 #: leaves that stay float32 whatever ``dtype`` is
 FULL_PRECISION = ("weight", "router_weight", "router_bias", "A_log",
-                  "dt_bias", "o_norm", "q_norm", "kv_norm", "kr_norm")
+                  "dt_bias", "D", "o_norm", "q_norm", "kv_norm", "kr_norm")
 
 
 class ServedLM(Container):
     """What the served language models of this package share
-    (``Ling`` here, ``models/kanana.py``): pre-norm residual blocks of a
-    token mixer and a gated MLP or a dropless mixture, a float32 residual
-    stream, matrices stored and multiplied in ``self.dtype``, an untied
-    head, and the generation-state plumbing.  A subclass sets
-    ``vocab_size``, ``hidden_size``, ``max_len``, ``dtype`` and
-    ``norm_f``, and builds its layers with ``_new_layer``."""
+    (``Ling`` here, ``models/kanana.py``, ``models/granite.py``): pre-norm
+    residual blocks of a token mixer and a gated MLP or a dropless
+    mixture, a float32 residual stream, matrices stored and multiplied in
+    ``self.dtype``, a head, and the generation-state plumbing.  A subclass
+    sets ``vocab_size``, ``hidden_size``, ``max_len``, ``dtype`` and
+    ``norm_f``, and builds its layers with ``_new_layer``.
+
+    The head is untied (a ``head`` table beside ``embed``) unless the
+    subclass sets ``tied_head``: the logits are then taken against
+    ``embed`` itself and the tree holds no ``head``.  The four multipliers
+    of the Granite family are class attributes that read 1 here, and a
+    multiplier of 1 adds no operation to a program.
+
+    What a mixer keeps between the steps of generation is what its
+    ``state_spec`` declares (``nn/generation_state.py``): per-token
+    ``block`` leaves (K and V, a latent row) or per-sequence ``slot``
+    leaves: a delta-rule or state-space layer's float32 recurrent
+    ``state`` and ``conv``, the tail of its short convolution's input.
+    ``_paged_layer`` hands a mixer its leaves and the rows' block tables
+    or slot ids and takes the new leaves back; a model that scans like
+    layers hands the stacked leaf and the ``layer``."""
+
+    #: logits against ``embed`` itself; no ``head`` leaf
+    tied_head = False
+    #: ``x0 = embedding_multiplier * embed[ids]``; every branch enters the
+    #: residual stream times ``residual_multiplier``; the logits are
+    #: divided by ``logits_scaling``
+    embedding_multiplier = 1.0
+    residual_multiplier = 1.0
+    logits_scaling = 1.0
 
     #: the ``counter`` leaves of the generation state, in the pool's
     #: order: the span a tick records for each, and its attributes
@@ -83,7 +108,9 @@ class ServedLM(Container):
                                     jnp.float32)
         table = lambda i: 0.02 * jax.random.normal(
             child_rng(rng, i), (self.vocab_size, d), jnp.float32)
-        params = {"embed": table(0), "head": table(98)}
+        params = {"embed": table(0)}
+        if not self.tied_head:
+            params["head"] = table(98)
         params["norm_f"], _ = self.norm_f.setup(child_rng(rng, 99), spec)
         return params, spec
 
@@ -101,8 +128,16 @@ class ServedLM(Container):
 
     def _embed(self, params, input):
         with jax.named_scope("embed"):
-            return jnp.take(params["embed"], input.astype(jnp.int32),
-                            axis=0).astype(jnp.float32)
+            x = jnp.take(params["embed"], input.astype(jnp.int32),
+                         axis=0).astype(jnp.float32)
+            return x if self.embedding_multiplier == 1 \
+                else self.embedding_multiplier * x
+
+    def _residual(self, x, branch):
+        """The stream with a branch's output added, float32."""
+        branch = branch.astype(jnp.float32)
+        return x + (branch if self.residual_multiplier == 1
+                    else self.residual_multiplier * branch)
 
     def _ffn(self, layer, p, x, live=None):
         """``(x + FFN(RMSNorm(x)), the expert layer's counts or None)``;
@@ -115,21 +150,22 @@ class ServedLM(Container):
             else:
                 h, _ = layer["ffn"].apply(p["ffn"], (), h.astype(self.dtype))
                 load = None
-            return x + h.astype(jnp.float32), load
+            return self._residual(x, h), load
 
     @staticmethod
     def _mixer_scope(op):
         """The scope of a layer's token mixer with its norm and residual:
-        ``state_mixer`` for a delta-rule layer, ``attention`` otherwise."""
-        return jax.named_scope("state_mixer" if isinstance(
-            op, KimiDeltaAttention) else "attention")
+        ``state_mixer`` for a delta-rule or state-space layer (a state a
+        sequence, nothing a token), ``attention`` otherwise."""
+        return jax.named_scope("state_mixer" if has_slot_state(
+            op.state_spec(jnp.float32)) else "attention")
 
     def _forward_layer(self, layer, p, x):
         """A layer of the full forward."""
         with self._mixer_scope(layer["op"]):
             h, _ = layer["op_norm"].apply(p["op_norm"], (), x)
             h, _ = layer["op"].apply(p["op"], (), h.astype(self.dtype))
-            x = x + h.astype(jnp.float32)
+            x = self._residual(x, h)
         return self._ffn(layer, p, x)[0]
 
     def _paged_layer(self, block, p, x, pool, by, pos, lengths, live, **kw):
@@ -141,7 +177,7 @@ class ServedLM(Container):
             h, _ = block["op_norm"].apply(p["op_norm"], (), x)
             h, new = block["op"].apply_paged(p["op"], h.astype(self.dtype),
                                              pool, by, pos, lengths, **kw)
-            x = x + h.astype(jnp.float32)
+            x = self._residual(x, h)
         x, load = self._ffn(block, p, x, live)
         return x, new, load
 
@@ -152,9 +188,12 @@ class ServedLM(Container):
             if logits_at is not None:
                 x = jnp.take_along_axis(x, logits_at[:, None, None], axis=1)
             x, _ = self.norm_f.apply(params["norm_f"], (), x)
-            return jnp.einsum("ntd,vd->ntv", x.astype(self.dtype),
-                              params["head"].astype(self.dtype),
-                              preferred_element_type=jnp.float32)
+            table = params["embed" if self.tied_head else "head"]
+            logits = jnp.einsum("ntd,vd->ntv", x.astype(self.dtype),
+                                table.astype(self.dtype),
+                                preferred_element_type=jnp.float32)
+            return logits if self.logits_scaling == 1 \
+                else logits / self.logits_scaling
 
     @staticmethod
     def _check_cache_dtype(dtype, who):

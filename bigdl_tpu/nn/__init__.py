@@ -39,6 +39,7 @@ from bigdl_tpu.nn.moe import DroplessMoE
 from bigdl_tpu.nn.generation_state import StateSpec
 from bigdl_tpu.nn.latent_attention import LatentAttention
 from bigdl_tpu.nn.linear_attention import KimiDeltaAttention
+from bigdl_tpu.nn.state_space import Mamba2Mixer
 from bigdl_tpu.nn.activations import (
     ReLU, Tanh, Sigmoid, SoftMax, SoftMin, LogSoftMax, HardTanh, Clamp,
     ReLU6, ELU, SoftPlus, SoftSign, LeakyReLU, Threshold, HardSigmoid,

@@ -49,11 +49,23 @@ def _on_tpu():
 
 
 class MultiHeadAttention(Module):
-    """Self-attention with fused qkv projection (one big MXU matmul)."""
+    """Self-attention with fused qkv projection (one big MXU matmul).
+
+    ``num_kv_heads`` (default: ``num_heads``) makes it GROUPED-QUERY
+    attention: query head ``g`` reads key/value head ``g // groups``,
+    ``groups = num_heads // num_kv_heads``; K and V are projected, cached
+    and paged at ``num_kv_heads * head_dim`` (``kv_width``) and the paged
+    decode kernel fetches a block once for a group's query heads.
+    ``bias=False`` leaves the two biases out of the parameter tree;
+    ``scale`` replaces the softmax scale ``head_dim ** -0.5`` (the
+    queries are multiplied by the ratio once, after their projection, so
+    every path and kernel below sees the usual scale)."""
 
     def __init__(self, hidden_size: int, num_heads: int, causal: bool = False,
                  dropout: float = 0.0, seq_axis_name: Optional[str] = None,
-                 seq_mode: str = "ring", use_flash: str = "auto", name=None):
+                 seq_mode: str = "ring", use_flash: str = "auto", name=None,
+                 num_kv_heads: Optional[int] = None, bias: bool = True,
+                 scale: Optional[float] = None):
         super().__init__(name)
         assert hidden_size % num_heads == 0
         assert seq_mode in ("ring", "ulysses")
@@ -61,6 +73,18 @@ class MultiHeadAttention(Module):
         self.hidden_size = hidden_size
         self.num_heads = num_heads
         self.head_dim = hidden_size // num_heads
+        self.num_kv_heads = num_heads if num_kv_heads is None \
+            else int(num_kv_heads)
+        assert num_heads % self.num_kv_heads == 0, \
+            (num_heads, self.num_kv_heads)
+        self.groups = num_heads // self.num_kv_heads
+        #: what a token's K (and V) row holds: the width the caches and
+        #: the paged pool are stored at
+        self.kv_width = self.num_kv_heads * self.head_dim
+        assert self.groups == 1 or seq_axis_name is None, \
+            "grouped-query attention has no sequence-parallel path"
+        self.bias = bool(bias)
+        self.scale = None if scale is None else float(scale)
         self.causal = causal
         self.dropout = dropout
         #: when set, apply() is assumed to run inside shard_map with the
@@ -106,13 +130,36 @@ class MultiHeadAttention(Module):
 
     def setup(self, rng, input_spec):
         d = self.hidden_size
+        rows = d + 2 * self.kv_width
         init = Xavier()
-        return {
-            "qkv_weight": init.init(child_rng(rng, 0), (3 * d, d), d, d),
-            "qkv_bias": jnp.zeros((3 * d,), jnp.float32),
+        params = {
+            "qkv_weight": init.init(child_rng(rng, 0), (rows, d), d, d),
+            "qkv_bias": jnp.zeros((rows,), jnp.float32),
             "out_weight": init.init(child_rng(rng, 1), (d, d), d, d),
             "out_bias": jnp.zeros((d,), jnp.float32),
-        }, ()
+        }
+        if not self.bias:
+            del params["qkv_bias"], params["out_bias"]
+        return params, ()
+
+    def _with_bias(self, y, params, name):
+        return y + params[name].astype(y.dtype) if name in params else y
+
+    def _split_qkv(self, qkv):
+        """The fused projection's ``(q (.., H Dh), k, v (.., Hkv Dh))``,
+        the queries brought to the softmax scale asked for."""
+        q, k, v = jnp.split(
+            qkv, [self.hidden_size, self.hidden_size + self.kv_width],
+            axis=-1)
+        if self.scale is not None:
+            q = q * jnp.asarray(self.scale * math.sqrt(self.head_dim),
+                                q.dtype)
+        return q, k, v
+
+    def _grouped(self, x):
+        """K or V ``(..., Hkv, Dh)`` as the query heads read it ``(..., H,
+        Dh)``: head ``g`` is KV head ``g // groups``."""
+        return x if self.groups == 1 else jnp.repeat(x, self.groups, axis=-2)
 
     def _project_qkv(self, params, input):
         """Fused qkv projection; ONE implementation for the full-sequence
@@ -126,21 +173,22 @@ class MultiHeadAttention(Module):
             # dtype (softmax in fp32 as always)
             from bigdl_tpu.nn.quantized import int8_matmul
 
-            return (int8_matmul(input, params["qkv_weight_q"],
-                                params["qkv_scale"])
-                    + params["qkv_bias"]).astype(dt)
-        return input @ params["qkv_weight"].astype(dt).T \
-            + params["qkv_bias"].astype(dt)
+            return self._with_bias(
+                int8_matmul(input, params["qkv_weight_q"],
+                            params["qkv_scale"]), params,
+                "qkv_bias").astype(dt)
+        return self._with_bias(input @ params["qkv_weight"].astype(dt).T,
+                               params, "qkv_bias")
 
     def _project_out(self, params, y, dt):
         if "out_weight_q" in params:
             from bigdl_tpu.nn.quantized import int8_matmul
 
-            return (int8_matmul(y, params["out_weight_q"],
-                                params["out_scale"])
-                    + params["out_bias"]).astype(dt)
-        return y @ params["out_weight"].astype(dt).T \
-            + params["out_bias"].astype(dt)
+            return self._with_bias(
+                int8_matmul(y, params["out_weight_q"], params["out_scale"]),
+                params, "out_bias").astype(dt)
+        return self._with_bias(y @ params["out_weight"].astype(dt).T,
+                               params, "out_bias")
 
     # ----- KV-cache decode mode -------------------------------------------- #
     def init_cache(self, batch: int, max_len: int, dtype=jnp.float32):
@@ -150,7 +198,7 @@ class MultiHeadAttention(Module):
         shapes are the whole point -- every decode step reuses ONE
         compiled executable regardless of how many tokens are live
         (docs/performance.md, "Generation serving")."""
-        shape = (batch, int(max_len), self.num_heads, self.head_dim)
+        shape = (batch, int(max_len), self.num_kv_heads, self.head_dim)
         return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
     def _flash_decode_ok(self, max_len, dtype=jnp.float32):
@@ -189,10 +237,10 @@ class MultiHeadAttention(Module):
         """
         n, t, d = input.shape
         dt = input.dtype
-        qkv = self._project_qkv(params, input)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        shape = (n, t, self.num_heads, self.head_dim)
-        q, k, v = q.reshape(shape), k.reshape(shape), v.reshape(shape)
+        q, k, v = self._split_qkv(self._project_qkv(params, input))
+        kv_shape = (n, t, self.num_kv_heads, self.head_dim)
+        q = q.reshape(n, t, self.num_heads, self.head_dim)
+        k, v = k.reshape(kv_shape), v.reshape(kv_shape)
         cdt = cache["k"].dtype
         if pos is None:                                   # prefill
             max_len = cache["k"].shape[1]
@@ -209,10 +257,13 @@ class MultiHeadAttention(Module):
             if self._flash_ok(t) and self._flash_block_ok(t):
                 from bigdl_tpu.ops.flash_attention import flash_attention
 
-                y = flash_attention(q, k, v, causal=self.causal,
+                y = flash_attention(q, self._grouped(k), self._grouped(v),
+                                    causal=self.causal,
                                     interpret=self.use_flash == "interpret")
             else:
-                y = dot_product_attention(q, k, v, causal=self.causal)
+                y = dot_product_attention(q, self._grouped(k),
+                                          self._grouped(v),
+                                          causal=self.causal)
         else:                                             # one-token step
             if t != 1:
                 raise ValueError(
@@ -224,21 +275,20 @@ class MultiHeadAttention(Module):
             new_cache = {"k": write(cache["k"], k.astype(cdt), pos),
                          "v": write(cache["v"], v.astype(cdt), pos)}
             max_len = cache["k"].shape[1]
+            ck = self._grouped(new_cache["k"].astype(dt))
+            cv = self._grouped(new_cache["v"].astype(dt))
             if self._flash_decode_ok(max_len, dt):
                 from bigdl_tpu.ops.flash_attention import \
                     flash_decode_attention
 
                 y = flash_decode_attention(
-                    q, new_cache["k"].astype(dt), new_cache["v"].astype(dt),
-                    pos, interpret=self.use_flash == "interpret")
+                    q, ck, cv, pos, interpret=self.use_flash == "interpret")
             else:
                 # scores (N, H, 1, max_len); the position mask broadcasts
                 # over heads and the single query row
                 mask = (jnp.arange(max_len)[None, :]
                         <= pos[:, None])[:, None, None, :]
-                y = dot_product_attention(q, new_cache["k"].astype(dt),
-                                          new_cache["v"].astype(dt),
-                                          mask=mask)
+                y = dot_product_attention(q, ck, cv, mask=mask)
         y = y.reshape(n, t, d)
         return self._project_out(params, y, dt), new_cache
 
@@ -246,7 +296,7 @@ class MultiHeadAttention(Module):
     def init_paged_cache(self, num_blocks: int, block_size: int,
                          dtype=jnp.float32):
         """Per-layer K/V BLOCK POOL for paged decode: fixed-shape
-        ``(num_blocks, block_size, heads * head_dim)`` zero tensors that
+        ``(num_blocks, block_size, kv_heads * head_dim)`` zero tensors that
         ``_apply_paged`` reads and writes THROUGH per-sequence block
         tables (serving/paging.py).  Unlike ``init_cache`` the leading
         axis is physical blocks, not slots: memory scales with tokens
@@ -259,8 +309,11 @@ class MultiHeadAttention(Module):
         init_paged_cache``) and block ``b`` of layer ``l`` is then
         ``leaf[l, b]``; ``_apply_paged`` takes either.
 
-        Heads and head_dim share the last axis so that a block is one
-        contiguous piece of device memory.  A TPU array's last two axes
+        KV heads and head_dim share the last axis (``kv_width = Hkv * D``:
+        a grouped-query layer's pool is sized by its KV heads, not its
+        query heads) so that a block is one contiguous piece of device
+        memory; the decode kernel wants ``Hkv * D`` a multiple of 128
+        (``_flash_paged_ok``).  A TPU array's last two axes
         are tiled (8 x 128 fp32), and the compiler stores a
         ``(..., heads, 64)`` array with the BLOCK axis on the lanes
         rather than pad 64 to 128: a block's values then lie 512 bytes
@@ -287,11 +340,11 @@ class MultiHeadAttention(Module):
         from bigdl_tpu.nn.generation_state import BLOCK, StateSpec
 
         if jnp.dtype(dtype) == jnp.dtype(jnp.int8):
-            payload = StateSpec(BLOCK, (self.hidden_size,), jnp.int8)
-            scale = StateSpec(BLOCK, (self.num_heads,), jnp.float32)
+            payload = StateSpec(BLOCK, (self.kv_width,), jnp.int8)
+            scale = StateSpec(BLOCK, (self.num_kv_heads,), jnp.float32)
             return {"k": payload, "v": payload,
                     "k_scale": scale, "v_scale": scale}
-        leaf = StateSpec(BLOCK, (self.hidden_size,), dtype)
+        leaf = StateSpec(BLOCK, (self.kv_width,), dtype)
         return {"k": leaf, "v": leaf}
 
     def _paged_quant(self, x):
@@ -304,7 +357,7 @@ class MultiHeadAttention(Module):
         q8, sc = quantize_blockwise(x.reshape(-1), self.head_dim,
                                     scale_dtype=jnp.float32)
         return q8.reshape(x.shape), sc.reshape(x.shape[:-1]
-                                               + (self.num_heads,))
+                                               + (self.num_kv_heads,))
 
     def _paged_dequant(self, q8, sc, dt):
         """Inverse of ``_paged_quant`` over gathered context blocks:
@@ -317,14 +370,22 @@ class MultiHeadAttention(Module):
     def _flash_paged_ok(self, block_size, dtype):
         """Whether decode goes through ``flash_paged_decode_attention``:
         in ``auto`` on a TPU, when a block is whole tiles of the pool's
-        dtype -- 8 rows of fp32, 16 of bf16, 32 of int8, by 128 lanes."""
+        dtype -- 8 rows of fp32, 16 of bf16, 32 of int8 -- and a row of
+        it, ``Hkv * D`` values, whole lanes of 128."""
         if self.use_flash == "never" or self.seq_axis_name is not None:
             return False
         if self.use_flash in ("always", "interpret"):
             return True
         tile_rows = 32 // jnp.dtype(dtype).itemsize
         return _on_tpu() and block_size % tile_rows == 0 \
-            and self.hidden_size % 128 == 0
+            and self.kv_width % 128 == 0
+
+    def apply_paged(self, params, input, pool, tables, pos, lengths=None,
+                    layer=None):
+        """``_apply_paged`` under the name a served model's layers call
+        their mixers by (``models/ling.py`` ``ServedLM._paged_layer``)."""
+        return self._apply_paged(params, input, pool, tables, pos, lengths,
+                                 layer)
 
     def _apply_paged(self, params, input, pool, tables, pos, lengths,
                      layer=None):
@@ -336,10 +397,10 @@ class MultiHeadAttention(Module):
 
         Where a block lies is told by what is handed in.  With
         ``layer=None`` ``pool`` is this layer's own leaves ``(NB, bs,
-        H * D)`` and block ``b`` is ``leaf[b]`` (the unrolled layout).
+        Hkv * D)`` and block ``b`` is ``leaf[b]`` (the unrolled layout).
         With ``layer`` an int32 scalar, traced inside the layer loop,
-        ``pool`` is the layer-STACKED leaves ``(L, NB, bs, H * D)`` of a
-        ``scan_layers`` model and block ``b`` is ``leaf[layer, b]``: rows
+        ``pool`` is the layer-STACKED leaves ``(L, NB, bs, Hkv * D)`` of a
+        layer-scanned model and block ``b`` is ``leaf[layer, b]``: rows
         are written ``.at[layer, block, offset]``, a row's context is one
         gather over ``(layer, tables)`` and the decode kernel indexes
         ``(layer, block)`` itself.  ``leaf[layer]`` is never formed (it
@@ -376,12 +437,13 @@ class MultiHeadAttention(Module):
         trash = pool["k"].shape[-3] - 1
         tables = jnp.asarray(tables, jnp.int32)
         pos = jnp.asarray(pos, jnp.int32)
-        qkv = self._project_qkv(params, input)
-        q, k, v = jnp.split(qkv, 3, axis=-1)                # (n, t, d) each
+        # q (n, t, d), k and v (n, t, kv_width)
+        q, k, v = self._split_qkv(self._project_qkv(params, input))
         heads = (n, t, self.num_heads, self.head_dim)
+        kvw, kv_heads = self.kv_width, self.num_kv_heads
 
         def scatter(phys, off, kf, vf):
-            """Write one batch of K/V rows ``(rows, d)`` through the
+            """Write one batch of K/V rows ``(rows, kv_width)`` through the
             table: quantize first on an int8 pool (payload + scales land
             at the same (block, offset) address, so the table
             indirection, COW block copies and prefix sharing are
@@ -406,21 +468,19 @@ class MultiHeadAttention(Module):
 
         def gather_ctx(new_pool, name):
             """The row's full mapped context from the pool ``(n, ctx,
-            heads, head_dim)``, dequantized to the compute dtype on an
+            kv heads, head_dim)``, dequantized to the compute dtype on an
             int8 pool."""
-            raw = blocks_of(new_pool[name]).reshape(n, ctx, d)
+            raw = blocks_of(new_pool[name]).reshape(n, ctx, kvw)
             if quant:
                 sc = blocks_of(new_pool[name + "_scale"]).reshape(
-                    n, ctx, self.num_heads)
+                    n, ctx, kv_heads)
                 raw = self._paged_dequant(raw, sc, dt)
-            return raw.astype(dt).reshape(n, ctx, self.num_heads,
-                                          self.head_dim)
+            return raw.astype(dt).reshape(n, ctx, kv_heads, self.head_dim)
 
         def gathered_attention(new_pool, mask):
-            return dot_product_attention(q.reshape(heads),
-                                         gather_ctx(new_pool, "k"),
-                                         gather_ctx(new_pool, "v"),
-                                         mask=mask)
+            return dot_product_attention(
+                q.reshape(heads), self._grouped(gather_ctx(new_pool, "k")),
+                self._grouped(gather_ctx(new_pool, "v")), mask=mask)
 
         if lengths is not None:                           # chunk prefill
             lengths = jnp.asarray(lengths, jnp.int32)
@@ -432,7 +492,7 @@ class MultiHeadAttention(Module):
             phys = jnp.where(valid, phys, trash)
             off = gpos % bs
             new_pool = scatter(phys.reshape(n * t), off.reshape(n * t),
-                               k.reshape(n * t, d), v.reshape(n * t, d))
+                               k.reshape(n * t, kvw), v.reshape(n * t, kvw))
             # (N, 1, Tc, ctx): key at logical position kp is visible to
             # the chunk token at absolute position gpos iff kp <= gpos
             mask = (jnp.arange(ctx, dtype=jnp.int32)[None, None, :]
@@ -470,9 +530,12 @@ class MultiHeadAttention(Module):
             return self._apply_cached(params, input, cache, pos)
         n, t, d = input.shape
         dt = input.dtype
-        qkv = self._project_qkv(params, input)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
+        q, k, v = self._split_qkv(self._project_qkv(params, input))
         shape = (n, t, self.num_heads, self.head_dim)
+        if self.groups > 1:
+            kv_shape = (n, t, self.num_kv_heads, self.head_dim)
+            k = self._grouped(k.reshape(kv_shape))
+            v = self._grouped(v.reshape(kv_shape))
         if self.seq_axis_name is not None and self.seq_mode == "ulysses":
             from bigdl_tpu.parallel.ulysses import ulysses_self_attention
 

@@ -6,13 +6,17 @@ the device leaves ``init_paged_cache`` returns, and the serving scheduler
 (``serving/generation.py``) reads the kinds to know what it may do with a
 leaf:
 
-- ``"block"``: state PER TOKEN (K and V rows, a latent row).  The leaf is
+- ``"block"``: state PER TOKEN (K and V rows, ``Hkv * D`` wide: sized by
+  the key/value heads of a grouped-query layer; a latent row).  The leaf is
   ``(num_blocks + 1, block_size) + shape``; a sequence addresses it
   through its block table (``serving/paging.py``), the last block is the
   trash block, a full block may be shared by every sequence with the same
   prefix, and a shared block is copied before it is written.
 - ``"slot"``: state PER SEQUENCE (a recurrent state, a convolution's
-  tail).  The leaf is ``(slots + 1,) + shape``; a row addresses it by its
+  tail: a delta-rule layer's ``(H, d, d)`` and a state-space layer's
+  ``(H, P, N)`` float32 state, the latter stored ``(H / pack, N, pack
+  P)``, ``ops/ssd.py``; each with the last ``taps - 1`` rows of its
+  short convolution's input).  The leaf is ``(slots + 1,) + shape``; a row addresses it by its
   slot id, the last row is the trash slot, a slot is zeroed when its
   sequence's first chunk arrives, and it cannot be rebuilt from blocks: a
   model with such a leaf gets no prefix hit.

@@ -580,11 +580,15 @@ def _paged_decode_kernel(pos_ref, table_ref, layer_ref, q_ref, k_hbm, v_hbm,
     to it run; rows past ``pos`` (the rest of the frontier block, and what
     an earlier step left in the buffer) are masked.
 
-    The lanes of a K or V row hold ``(head, d)``.  The per-head sums over
-    ``d`` and the spreading of a head's weight back over its ``d`` lanes
-    are products with one 0/1 matrix ``seg (H, H * D)`` on the MXU, exact
-    in fp32 (``_split3``); scores and softmax state are ``(H, rows)`` and
-    ``(H, 1)``, positions on the lanes; the rest is fp32 on the VPU.  An
+    The lanes of a K or V row hold ``(kv head, d)``.  The per-head sums
+    over ``d`` and the spreading of a head's weight back over its ``d``
+    lanes are products with one 0/1 matrix ``seg (H, H * D)`` on the MXU,
+    exact in fp32 (``_split3``); scores and softmax state are ``(H, rows)``
+    and ``(H, 1)``, positions on the lanes; the rest is fp32 on the VPU.
+    Grouped queries (``q_ref (G, Hkv * D)``: row ``j`` holds query head
+    ``kv * G + j`` on KV head ``kv``'s lanes) take the block that was
+    fetched once through the same arithmetic ``G`` times, each with its
+    own softmax state; one group is the multi-head kernel as it was.  An
     int8 pool's scales come gathered as ``(steps, H, rows)`` a slot and
     multiply there: K's the scores, V's the softmax weights -- the
     payload goes from int8 to fp32 and is never multiplied out."""
@@ -593,7 +597,7 @@ def _paged_decode_kernel(pos_ref, table_ref, layer_ref, q_ref, k_hbm, v_hbm,
         ks_ref, vs_ref, *rest = rest
     o_ref, k_buf, v_buf, sem, half_ref, m_ref, l_ref, acc_ref = rest
     i, n = pl.program_id(0), pl.num_programs(0)
-    rows, width = g * bs, q_ref.shape[-1]
+    rows, (groups, width) = g * bs, q_ref.shape
     heads = width // d
 
     def frontier(slot):
@@ -631,7 +635,7 @@ def _paged_decode_kernel(pos_ref, table_ref, layer_ref, q_ref, k_hbm, v_hbm,
     p = pos_ref[i]
     steps = frontier(i) // g + 1
     first_half = half_ref[0]
-    q = q_ref[:].astype(jnp.float32) * scale              # (1, H * D)
+    q = q_ref[:].astype(jnp.float32) * scale              # (G, H * D)
     seg = jax.lax.broadcasted_iota(jnp.int32, (heads, width), 1) // d \
         == jax.lax.broadcasted_iota(jnp.int32, (heads, width), 0)
     seg16 = seg.astype(jnp.bfloat16)
@@ -669,32 +673,37 @@ def _paged_decode_kernel(pos_ref, table_ref, layer_ref, q_ref, k_hbm, v_hbm,
         wait(i, step, half)
         k = k_buf[half].astype(jnp.float32)                # (rows, H * D)
         v = v_buf[half].astype(jnp.float32)
-        s = head_sums(k * q)                               # (H, rows)
-        if quantized:
-            s = s * ks_ref[step]
         seen = step * rows + jax.lax.broadcasted_iota(
             jnp.int32, (1, rows), 1) <= p
-        s = jnp.where(seen, s, _MASKED)
-        m = m_ref[:]
-        new_m = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-        w = jnp.exp(s - new_m)                             # (H, rows)
-        corr = jnp.exp(m - new_m)
-        m_ref[:] = new_m
-        l_ref[:] = l_ref[:] * corr + jnp.sum(w, axis=1, keepdims=True)
-        if quantized:
-            w = w * vs_ref[step]
         # a row never fetched holds whatever the buffer held: its weight
         # is 0, and 0 * nan must not reach the sum
         fetched = step * rows + jax.lax.broadcasted_iota(
             jnp.int32, (rows, 1), 0) <= p
-        pv = jnp.where(fetched, over_lanes(w) * v, 0.0)
-        acc_ref[:] = acc_ref[:] * spread(corr) + jnp.sum(
-            pv, axis=0, keepdims=True)
+        for j in range(groups):
+            one, of = slice(j, j + 1), slice(j * heads, (j + 1) * heads)
+            s = head_sums(k * q[one])                      # (H, rows)
+            if quantized:
+                s = s * ks_ref[step]
+            s = jnp.where(seen, s, _MASKED)
+            m = m_ref[of]
+            new_m = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+            w = jnp.exp(s - new_m)                         # (H, rows)
+            corr = jnp.exp(m - new_m)
+            m_ref[of] = new_m
+            l_ref[of] = l_ref[of] * corr + jnp.sum(w, axis=1, keepdims=True)
+            if quantized:
+                w = w * vs_ref[step]
+            pv = jnp.where(fetched, over_lanes(w) * v, 0.0)
+            acc_ref[one] = acc_ref[one] * spread(corr) + jnp.sum(
+                pv, axis=0, keepdims=True)
         return _
 
     jax.lax.fori_loop(0, steps, body, None)
     half_ref[0] = (first_half + steps) % 2
-    o_ref[:] = (acc_ref[:] / spread(l_ref[:])).astype(o_ref.dtype)
+    for j in range(groups):
+        one = slice(j, j + 1)
+        o_ref[one] = (acc_ref[one] / spread(
+            l_ref[j * heads:(j + 1) * heads])).astype(o_ref.dtype)
 
 
 #: fp32 bytes of K (and as many of V) that one step of the paged decode
@@ -720,10 +729,16 @@ def flash_paged_decode_attention(q, k_pool, v_pool, tables, pos,
     per-row block tables ``tables (B, max_blocks)`` with frontier positions
     ``pos (B,)`` -> ``(B, 1, H, D)``.
 
+    The pools hold ``Hkv`` KV heads a row, ``Hkv * D`` wide; ``H = Hkv *
+    G`` and query head ``g`` reads KV head ``g // G`` (grouped-query
+    attention; ``G`` is read off the two widths, and ``G = 1`` is
+    multi-head attention).  A block is fetched once for all ``G`` query
+    heads of its KV heads.
+
     Where a block lies is told by the pool's shape.  One layer's leaf is
-    ``(NB, bs, H * D)`` and block ``b`` is ``pool[b]`` (the unrolled
+    ``(NB, bs, Hkv * D)`` and block ``b`` is ``pool[b]`` (the unrolled
     layout).  The layer-stacked leaf of a ``scan_layers`` model is
-    ``(L, NB, bs, H * D)``, ``layer`` (an int32 scalar, traced inside the
+    ``(L, NB, bs, Hkv * D)``, ``layer`` (an int32 scalar, traced inside the
     layer loop) says which layer is asked for, and block ``b`` is
     ``pool[layer, b]``: the whole leaf is handed over and no layer of it is
     sliced out, so the loop that carries it never copies it.  The first is
@@ -748,7 +763,7 @@ def flash_paged_decode_attention(q, k_pool, v_pool, tables, pos,
     ``max_blocks`` of a row, by one gather over ``(layer, tables)``.
     ``interpret=True`` runs on the CPU for tests.  On a TPU ``bs`` must be
     a multiple of the pool dtype's sublane tile (8 fp32, 16 bf16, 32 int8)
-    and ``H * D`` of 128 (``MultiHeadAttention._flash_paged_ok``).
+    and ``Hkv * D`` of 128 (``MultiHeadAttention._flash_paged_ok``).
     """
     b, t1, h, d = q.shape
     max_blocks = tables.shape[1]
@@ -763,11 +778,15 @@ def flash_paged_decode_attention(q, k_pool, v_pool, tables, pos,
         if quantized:
             k_scale, v_scale = k_scale[None], v_scale[None]
     assert layer is not None, "a stacked pool needs the layer"
-    assert k_pool.shape[3] == h * d, (k_pool.shape, q.shape)
+    width = k_pool.shape[3]
+    assert width % d == 0 and (h * d) % width == 0, \
+        f"a pool row of {width} values is not whole KV heads of {d} that " \
+        f"divide the {h} query heads"
+    kv_heads = width // d
+    groups = h // kv_heads
     layer = jnp.asarray(layer, jnp.int32)
     bs = k_pool.shape[2]
     tables = jnp.asarray(tables, jnp.int32)
-    width = h * d
     g = _paged_blocks_per_step(bs, width, max_blocks)
     steps = -(-max_blocks // g)
 
@@ -780,15 +799,20 @@ def flash_paged_decode_attention(q, k_pool, v_pool, tables, pos,
         # (L, NB, bs, H) -> (B, steps, H, rows): a step's positions on the
         # lanes, as its scores have them
         x = scales.at[layer, tables].get(mode="fill").astype(
-            jnp.float32).reshape(b, max_blocks * bs, h)
+            jnp.float32).reshape(b, max_blocks * bs, kv_heads)
         x = jnp.pad(x, ((0, 0), (0, (steps * g - max_blocks) * bs), (0, 0)))
-        return x.reshape(b, steps, g * bs, h).transpose(0, 1, 3, 2)
+        return x.reshape(b, steps, g * bs, kv_heads).transpose(0, 1, 3, 2)
 
-    in_specs = [slot_block(1, width)] + \
+    # (B, G, Hkv * D): row j holds the query heads kv * G + j, each on its
+    # KV head's lanes (one group: the row as it is)
+    q = q.reshape(b, 1, width) if groups == 1 else \
+        q.reshape(b, kv_heads, groups, d).transpose(0, 2, 1, 3).reshape(
+            b, groups, width)
+    in_specs = [slot_block(groups, width)] + \
         [pl.BlockSpec(memory_space=pltpu.HBM)] * 2
-    args = [q.reshape(b, 1, width), k_pool, v_pool]
+    args = [q, k_pool, v_pool]
     if quantized:
-        in_specs += [slot_block(steps, h, g * bs)] * 2
+        in_specs += [slot_block(steps, kv_heads, g * bs)] * 2
         args += [gathered(k_scale), gathered(v_scale)]
 
     out = pl.pallas_call(
@@ -800,7 +824,7 @@ def flash_paged_decode_attention(q, k_pool, v_pool, tables, pos,
             num_scalar_prefetch=3,
             grid=(b,),
             in_specs=in_specs,
-            out_specs=slot_block(1, width),
+            out_specs=slot_block(groups, width),
             scratch_shapes=[
                 pltpu.VMEM((2, g * bs, width), k_pool.dtype),
                 pltpu.VMEM((2, g * bs, width), v_pool.dtype),
@@ -808,9 +832,9 @@ def flash_paged_decode_attention(q, k_pool, v_pool, tables, pos,
                 pltpu.SMEM((1,), jnp.int32),
                 pltpu.VMEM((h, 1), jnp.float32),
                 pltpu.VMEM((h, 1), jnp.float32),
-                pltpu.VMEM((1, width), jnp.float32)]),
+                pltpu.VMEM((groups, width), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct(
-            (b, 1, width), jnp.float32 if quantized else q.dtype),
+            (b, groups, width), jnp.float32 if quantized else q.dtype),
         # slots run in order: each starts the next one's first fetch
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
@@ -818,6 +842,8 @@ def flash_paged_decode_attention(q, k_pool, v_pool, tables, pos,
         name="flash_paged_decode_attention",
     )(jnp.asarray(pos, jnp.int32), tables.reshape(-1), layer.reshape(1),
       *args)
+    if groups > 1:
+        out = out.reshape(b, groups, kv_heads, d).transpose(0, 2, 1, 3)
     return out.reshape(b, 1, h, d)
 
 

@@ -1528,7 +1528,12 @@ class PagedGenerateScheduler(GenerateScheduler):
             # context_tokens: what the rows already hold, which the chunk's
             # attention reads beside its own tokens; rows_sampling: the rows
             # with a temperature (one is enough for the sampler to sort)
+            # tokens_computed: what the program runs over, padding rows
+            # and padding tokens included (a chunked scan's products are
+            # over whole chunks of it; rows and prompt_tokens are the live
+            # part)
             prep.set(bucket=int(bucket), prompt_tokens=int(lens.sum()),
+                     tokens_computed=int(bucket) * int(tc),
                      context_tokens=int(start.sum()),
                      rows_sampling=int((knobs[0] > 0).sum()))
         tick = self._tick + (ahead is not None)

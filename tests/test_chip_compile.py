@@ -19,6 +19,7 @@ from jax.sharding import SingleDeviceSharding
 from bigdl_tpu.ops.cross_entropy import fused_softmax_cross_entropy
 from bigdl_tpu.ops.grouped_matmul import buffer_rows, grouped_matmul
 from bigdl_tpu.ops.kda import kda_decode_step
+from bigdl_tpu.ops.ssd import ssd_decode_step
 from bigdl_tpu.ops.flash_attention import (flash_attention,
                                            flash_decode_attention,
                                            flash_paged_decode_attention,
@@ -105,6 +106,17 @@ def _kda(slots, h=32, d=128):
             ((slots, h), f32)]
 
 
+def _ssd(slots, layers=None, h=64, p=64, k=128):
+    """The state-space decode step of the granite cell: every slot's state
+    and the trash slot's as ``ops/ssd.py`` stores it, one token a slot; a
+    layer's own leaf, or the Mamba layers stacked and the layer."""
+    stack = () if layers is None else (layers,)
+    return [(stack + (slots + 1, h // 2, k, 2 * p), f32), ((slots,), i32),
+            ((slots, h, p), f32), ((slots, h), f32), ((h,), f32),
+            ((slots, k), f32), ((slots, k), f32), ((h,), f32)] \
+        + ([] if layers is None else [((), i32)])
+
+
 def _qkv(b, t, d, dt, h=16):
     return [((b, t, h, d), dt)] * 3
 
@@ -114,14 +126,16 @@ def _decode(b, t, d, dt, h=16):
             ((b,), i32)]
 
 
-def _paged(b, nb, bs, d, dtype, h=16, mb=64, layers=None):
+def _paged(b, nb, bs, d, dtype, h=16, mb=64, layers=None, groups=1):
     """The pool as the engine stores it: ``(NB, bs, H * D)``, an int8
     one with ``(NB, bs, H)`` fp32 scales; with ``layers`` the stacked leaf
-    of a ``scan_layers`` model, ``(L, NB, bs, H * D)``, and the layer."""
+    of a ``scan_layers`` model, ``(L, NB, bs, H * D)``, and the layer;
+    with ``groups`` that many query heads on each of the ``h`` KV heads."""
     stack = () if layers is None else (layers,)
     pool = (stack + (nb, bs, h * d), dtype)
     scale = (stack + (nb, bs, h), f32) if dtype == i8 else None
-    return [((b, 1, h, d), f32), pool, pool, ((b, mb), i32), ((b,), i32),
+    return [((b, 1, h * groups, d), f32), pool, pool, ((b, mb), i32),
+            ((b,), i32),
             scale, scale, None if layers is None else ((), i32)]
 
 
@@ -200,6 +214,21 @@ CASES = {
     "grouped-decode-down": (_grouped_small, [
         ((buffer_rows(256, 128, 16), 768), bf16), ((128, 768, 2560), bf16),
         ((128,), i32)], None),
+    # the granite serving cell: the state-space decode step over 64 slots
+    # of 64 heads of 64 x 128 (a layer's own leaf, and the 36 Mamba layers
+    # in one leaf of 4.9 GB), and the grouped paged decode, 32 query heads
+    # on 8 KV heads of 64 in the 4 attention layers' stacked bf16 pool
+    "ssd-decode-cell": (ssd_decode_step, _ssd(64), None),
+    "ssd-decode-cell-stacked": (
+        lambda *a: ssd_decode_step(*a[:-1], layer=a[-1]), _ssd(64, 36),
+        None),
+    "paged-grouped-cell-bf16": (
+        flash_paged_decode_attention,
+        _paged(64, 9217, 16, 64, bf16, h=8, mb=144, layers=4, groups=4),
+        None),
+    "paged-grouped-int8": (
+        flash_paged_decode_attention,
+        _paged(8, 641, 32, 64, i8, h=8, mb=32, layers=2, groups=4), None),
     # the kanana serving cell: the latent decode kernel over a layer's own
     # leaf (the leading dense layer) and over the scanned layers' stacked one
     "latent-decode-cell": (_latent, _latent_pool(), None),

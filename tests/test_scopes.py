@@ -60,6 +60,10 @@ PROGRAMS = {
     "kanana.decode": ("kanana-2-30b-a3b.serve.long-prompt",
                       LAYER + ("moe_shared", "moe_weights", "sampler")
                       + EXPERTS),
+    "granite.chunk_prefill": ("granite-4.0-h-micro.serve.short-chat",
+                              LAYER + ("state_mixer", "sampler")),
+    "granite.decode": ("granite-4.0-h-micro.serve.short-chat",
+                       LAYER + ("state_mixer", "sampler")),
 }
 TRAIN_STEPS = [p for p in PROGRAMS if p.endswith("train_step")]
 

@@ -265,6 +265,10 @@ EXAMPLES = {
     "KimiDeltaAttention": (
         lambda: nn.KimiDeltaAttention(8, 2, head_dim=4, use_kernel="never"),
         lambda: _r(2, 5, 8)),
+    "Mamba2Mixer": (
+        lambda: nn.Mamba2Mixer(8, 2, 4, state_dim=4, chunk_size=4,
+                               use_kernel="never"),
+        lambda: _r(2, 5, 8)),
     "LatentAttention": (
         lambda: nn.LatentAttention(8, 2, kv_rank=4, nope_dim=4, rope_dim=2,
                                    v_dim=4),
