@@ -212,36 +212,3 @@ class GraniteHybrid(ServedLM):
             mamba, attention)
         return self._logits(params, x, logits_at), {
             "mamba": {"state": state, "conv": conv}, "attention": kv}
-
-    def apply_paged(self, params, input, pool, tables, *, pos, lengths=None,
-                    slots=None, logits_at=None):
-        """A step of paged generation (``TransformerLM.apply_paged``'s
-        contract) for a model with per-slot state: row ``i`` is slot
-        ``slots[i]``, the trash slot (the slot leaves' last row) if the
-        row is padding or not live.  ``logits_at (N,)`` asks for the logits
-        of one position a row, ``(N, 1, V)``."""
-        if slots is None:
-            raise ValueError("a model with per-slot state has to be told "
-                             "which slot each row is (slots=)")
-        i32 = lambda a: jnp.asarray(a, jnp.int32)
-        tables, pos, slots = i32(tables), i32(pos), i32(slots)
-        n, per = input.shape[0], self.chunk_rows
-        if lengths is None or n <= per or n % per:
-            lengths = None if lengths is None else i32(lengths)
-            return self._paged_step(params, input, pool, tables, pos,
-                                    lengths, slots, logits_at)
-        # the rows of a chunk step share no slot and no block: ``per`` of
-        # them at a time, the pool carried from one group to the next
-        rows = (input, tables, pos, i32(lengths), slots) \
-            + (() if logits_at is None else (i32(logits_at),))
-
-        def group(pool, rows):
-            input, tables, pos, lengths, slots, *at = rows
-            logits, pool = self._paged_step(
-                params, input, pool, tables, pos, lengths, slots,
-                at[0] if at else None)
-            return pool, logits
-
-        pool, logits = jax.lax.scan(group, pool, jax.tree.map(
-            lambda a: a.reshape((n // per, per) + a.shape[1:]), rows))
-        return logits.reshape((n,) + logits.shape[2:]), pool

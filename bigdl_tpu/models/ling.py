@@ -46,7 +46,8 @@ from bigdl_tpu.nn.normalization import RMSNorm
 
 #: leaves that stay float32 whatever ``dtype`` is
 FULL_PRECISION = ("weight", "router_weight", "router_bias", "A_log",
-                  "dt_bias", "D", "o_norm", "q_norm", "kv_norm", "kr_norm")
+                  "dt_bias", "D", "o_norm", "q_norm", "kv_norm", "kr_norm",
+                  "qa_norm")
 
 
 class ServedLM(Container):
@@ -56,7 +57,16 @@ class ServedLM(Container):
     mixture, a float32 residual stream, matrices stored and multiplied in
     ``self.dtype``, a head, and the generation-state plumbing.  A subclass
     sets ``vocab_size``, ``hidden_size``, ``max_len``, ``dtype`` and
-    ``norm_f``, and builds its layers with ``_new_layer``.
+    ``norm_f``, and builds its layers with ``_new_layer``.  The model
+    methods here run ``self.layers`` one after the other (``Ling``,
+    ``models/gigachat.py``); a model that scans like layers
+    (``models/kanana.py``, ``models/granite.py``) gives its own and, for
+    ``apply_paged``, a ``_paged_step``.
+
+    A layer's norms are before its two sublayers; with ``post`` also
+    after each, on the sublayer's output before the residual (``h = x +
+    N_post(F(N_pre(x)))``), and every norm is of the kind ``_new_layer``
+    is given.
 
     The head is untied (a ``head`` table beside ``embed``) unless the
     subclass sets ``tied_head``: the logits are then taken against
@@ -88,11 +98,20 @@ class ServedLM(Container):
     #: ``apply_paged`` takes ``logits_at``: a chunk's step asks for the
     #: logits of the one position a row it samples from
     paged_logits_at = True
+    #: rows of a chunk step that run together: a step of more rows (a
+    #: whole number of such groups) runs them this many at a time inside
+    #: the one program, the pool carried from group to group; None: all
+    chunk_rows = None
 
-    def _new_layer(self, op, ffn, norm_eps):
-        """A layer's four modules, registered."""
-        layer = {"op_norm": RMSNorm(self.hidden_size, norm_eps), "op": op,
-                 "ffn_norm": RMSNorm(self.hidden_size, norm_eps), "ffn": ffn}
+    def _new_layer(self, op, ffn, norm_eps, norm=RMSNorm, post=False):
+        """A layer's modules, registered: its two sublayers with a
+        ``norm`` before each, and with ``post`` one after each too."""
+        d = self.hidden_size
+        layer = {"op_norm": norm(d, norm_eps), "op": op,
+                 "ffn_norm": norm(d, norm_eps), "ffn": ffn}
+        if post:
+            layer.update(op_post_norm=norm(d, norm_eps),
+                         ffn_post_norm=norm(d, norm_eps))
         for m in layer.values():
             self.add(m)
         return layer
@@ -139,6 +158,14 @@ class ServedLM(Container):
         return x + (branch if self.residual_multiplier == 1
                     else self.residual_multiplier * branch)
 
+    @staticmethod
+    def _post_norm(layer, p, key, branch):
+        """A branch's output through the layer's norm ``key`` (float32),
+        where the layer has one after the sublayer."""
+        if key not in layer:
+            return branch
+        return layer[key].apply(p[key], (), branch.astype(jnp.float32))[0]
+
     def _ffn(self, layer, p, x, live=None):
         """``(x + FFN(RMSNorm(x)), the expert layer's counts or None)``;
         tokens that are not ``live (N, T)`` go to no routed expert."""
@@ -150,7 +177,8 @@ class ServedLM(Container):
             else:
                 h, _ = layer["ffn"].apply(p["ffn"], (), h.astype(self.dtype))
                 load = None
-            return self._residual(x, h), load
+            return self._residual(
+                x, self._post_norm(layer, p, "ffn_post_norm", h)), load
 
     @staticmethod
     def _mixer_scope(op):
@@ -165,7 +193,8 @@ class ServedLM(Container):
         with self._mixer_scope(layer["op"]):
             h, _ = layer["op_norm"].apply(p["op_norm"], (), x)
             h, _ = layer["op"].apply(p["op"], (), h.astype(self.dtype))
-            x = self._residual(x, h)
+            x = self._residual(x, self._post_norm(layer, p, "op_post_norm",
+                                                  h))
         return self._ffn(layer, p, x)[0]
 
     def _paged_layer(self, block, p, x, pool, by, pos, lengths, live, **kw):
@@ -177,7 +206,8 @@ class ServedLM(Container):
             h, _ = block["op_norm"].apply(p["op_norm"], (), x)
             h, new = block["op"].apply_paged(p["op"], h.astype(self.dtype),
                                              pool, by, pos, lengths, **kw)
-            x = self._residual(x, h)
+            x = self._residual(x, self._post_norm(block, p, "op_post_norm",
+                                                  h))
         x, load = self._ffn(block, p, x, live)
         return x, new, load
 
@@ -205,6 +235,112 @@ class ServedLM(Container):
     #: the counter leaf of a model with expert layers
     moe_load_spec = StateSpec(COUNTER, (len(DroplessMoE.generate_counts),),
                               jnp.int32)
+
+    # ----- the layers in order (``self.layers``) ---------------------------- #
+    def setup(self, rng, input_spec):
+        params, spec = self._setup_tables(rng, input_spec)
+        for i, layer in enumerate(self.layers):
+            params[f"layer{i}"] = self._setup_layer(
+                layer, child_rng(rng, 1 + i), spec)
+        return self._stored(params), ()
+
+    def apply(self, params, state, input, *, training=False, rng=None):
+        x = self._embed(params, input)
+        for i, layer in enumerate(self.layers):
+            x = self._forward_layer(layer, params[f"layer{i}"], x)
+        return self._logits(params, x), state
+
+    def paged_state_spec(self, dtype=jnp.float32):
+        """Every leaf of the generation state with its kind.  ``float32``,
+        the engine's word for a cache that is not quantized, means this
+        model's own ``dtype`` for the per-token rows and the convolution's
+        tail; the recurrent state is float32 whatever is asked."""
+        self._check_cache_dtype(dtype, type(self).__name__)
+        spec = {f"layer{i}": layer["op"].state_spec(self.dtype)
+                for i, layer in enumerate(self.layers)}
+        if any(isinstance(layer["ffn"], DroplessMoE)
+               for layer in self.layers):
+            spec["moe_load"] = self.moe_load_spec
+        return spec
+
+    def init_paged_cache(self, num_blocks: int, block_size: int,
+                         dtype=jnp.float32, slots: int = 0):
+        return allocate(self.paged_state_spec(dtype), num_blocks, block_size,
+                        slots)
+
+    def apply_paged(self, params, input, pool, tables, *, pos, lengths=None,
+                    slots=None, logits_at=None):
+        """A step of paged generation (``TransformerLM.apply_paged``'s
+        contract) for a model with per-slot state: row ``i`` is slot
+        ``slots[i]``, the trash slot (the slot leaves' last row) if the
+        row is padding or not live.  ``logits_at (N,)`` asks for the logits
+        of one position a row, ``(N, 1, V)``.  The pool's counters
+        (``tick_counters``) come back as this step's counts, summed over
+        the expert layers; over the row groups of ``chunk_rows`` summed
+        too, but for a peak (``DroplessMoE.generate_peaks``), of which the
+        largest group's is kept: a lower bound of the whole step's.  An
+        expert that two groups touch counts in ``experts_touched`` twice,
+        as its weights are read twice."""
+        if slots is None:
+            raise ValueError("a model with per-slot state has to be told "
+                             "which slot each row is (slots=)")
+        i32 = lambda a: jnp.asarray(a, jnp.int32)
+        tables, pos, slots = i32(tables), i32(pos), i32(slots)
+        n, per = input.shape[0], self.chunk_rows
+        if lengths is None or per is None or n <= per or n % per:
+            lengths = None if lengths is None else i32(lengths)
+            return self._paged_step(params, input, pool, tables, pos,
+                                    lengths, slots, logits_at)
+        # the rows of a chunk step share no slot and no block: ``per`` of
+        # them at a time, the pool carried from one group to the next
+        rows = (input, tables, pos, i32(lengths), slots) \
+            + (() if logits_at is None else (i32(logits_at),))
+        counters = [k for k in self.tick_counters if k in pool]
+
+        def group(pool, rows):
+            input, tables, pos, lengths, slots, *at = rows
+            logits, pool = self._paged_step(
+                params, input, pool, tables, pos, lengths, slots,
+                at[0] if at else None)
+            return pool, (logits, [pool[k] for k in counters])
+
+        pool, (logits, counts) = jax.lax.scan(group, pool, jax.tree.map(
+            lambda a: a.reshape((n // per, per) + a.shape[1:]), rows))
+        # a peak (the busiest expert) is the largest group's, not a sum
+        peak = {k: jnp.asarray([a in DroplessMoE.generate_peaks
+                                for a in self.tick_counters[k]])
+                for k in counters}
+        pool.update({k: jnp.where(peak[k], c.max(0), c.sum(0))
+                     for k, c in zip(counters, counts)})
+        return logits.reshape((n,) + logits.shape[2:]), pool
+
+    def _paged_step(self, params, input, pool, tables, pos, lengths, slots,
+                    logits_at):
+        """``apply_paged`` for rows that run together: every layer in
+        order, a mixer with slot state told the rows' slots, one with
+        per-token state their block tables."""
+        x = self._embed(params, input)
+        slotted = [has_slot_state(layer["op"].state_spec(self.dtype))
+                   for layer in self.layers]
+        # a row on the trash slot is padding or not live; so is a chunk's
+        # token past its row's length
+        trash = [pool[f"layer{i}"]["state"].shape[0] - 1
+                 for i, s in enumerate(slotted) if s]
+        live = jnp.broadcast_to(
+            (slots != trash[0])[:, None] if trash else True, input.shape)
+        if lengths is not None:
+            live &= jnp.arange(input.shape[1])[None, :] < lengths[:, None]
+        new_pool, loads = {}, []
+        for i, layer in enumerate(self.layers):
+            key = f"layer{i}"
+            by = slots if slotted[i] else tables
+            x, new_pool[key], load = self._paged_layer(
+                layer, params[key], x, pool[key], by, pos, lengths, live)
+            if load is not None:
+                loads.append(load)
+        if loads:
+            new_pool["moe_load"] = sum(loads)
+        return self._logits(params, x, logits_at), new_pool
 
 
 class Ling(ServedLM):
@@ -252,73 +388,3 @@ class Ling(ServedLM):
             self.layers.append(self._new_layer(op, ffn, norm_eps))
         self.norm_f = RMSNorm(hidden_size, norm_eps)
         self.add(self.norm_f)
-
-    def setup(self, rng, input_spec):
-        params, spec = self._setup_tables(rng, input_spec)
-        for i, layer in enumerate(self.layers):
-            params[f"layer{i}"] = self._setup_layer(
-                layer, child_rng(rng, 1 + i), spec)
-        return self._stored(params), ()
-
-    # ----- full forward ----------------------------------------------------- #
-    def apply(self, params, state, input, *, training=False, rng=None):
-        x = self._embed(params, input)
-        for i, layer in enumerate(self.layers):
-            x = self._forward_layer(layer, params[f"layer{i}"], x)
-        return self._logits(params, x), state
-
-    # ----- paged generation -------------------------------------------------- #
-    def paged_state_spec(self, dtype=jnp.float32):
-        """Every leaf of the generation state with its kind.  ``float32``,
-        the engine's word for a cache that is not quantized, means this
-        model's own ``dtype`` for the per-token rows and the convolution's
-        tail; the recurrent state is float32 whatever is asked."""
-        self._check_cache_dtype(dtype, "Ling")
-        spec = {f"layer{i}": layer["op"].state_spec(self.dtype)
-                for i, layer in enumerate(self.layers)}
-        if any(isinstance(layer["ffn"], DroplessMoE)
-               for layer in self.layers):
-            spec["moe_load"] = self.moe_load_spec
-        return spec
-
-    def init_paged_cache(self, num_blocks: int, block_size: int,
-                         dtype=jnp.float32, slots: int = 0):
-        return allocate(self.paged_state_spec(dtype), num_blocks, block_size,
-                        slots)
-
-    def apply_paged(self, params, input, pool, tables, *, pos, lengths=None,
-                    slots=None, logits_at=None):
-        """A step of paged generation (``TransformerLM.apply_paged``'s
-        contract) for a model with per-slot state: row ``i`` is slot
-        ``slots[i]``, the trash slot (the slot leaves' last row) if the
-        row is padding or not live.  ``logits_at (N,)`` asks for the logits
-        of one position a row, ``(N, 1, V)``.  The pool's ``moe_load`` comes
-        back as this step's counts, summed over the expert layers."""
-        if slots is None:
-            raise ValueError("a model with per-slot state has to be told "
-                             "which slot each row is (slots=)")
-        tables = jnp.asarray(tables, jnp.int32)
-        pos = jnp.asarray(pos, jnp.int32)
-        slots = jnp.asarray(slots, jnp.int32)
-        if lengths is not None:
-            lengths = jnp.asarray(lengths, jnp.int32)
-        x = self._embed(params, input)
-        # a row on the trash slot is padding or not live; so is a chunk's
-        # token past its row's length
-        trash = [pool[f"layer{i}"]["state"].shape[0] - 1
-                 for i, kind in enumerate(self.layer_types) if kind == "kda"]
-        live = jnp.broadcast_to(
-            (slots != trash[0])[:, None] if trash else True, input.shape)
-        if lengths is not None:
-            live &= jnp.arange(input.shape[1])[None, :] < lengths[:, None]
-        new_pool, loads = {}, []
-        for i, layer in enumerate(self.layers):
-            key = f"layer{i}"
-            by = slots if self.layer_types[i] == "kda" else tables
-            x, new_pool[key], load = self._paged_layer(
-                layer, params[key], x, pool[key], by, pos, lengths, live)
-            if load is not None:
-                loads.append(load)
-        if loads:
-            new_pool["moe_load"] = sum(loads)
-        return self._logits(params, x, logits_at), new_pool

@@ -27,7 +27,7 @@ from bigdl_tpu.nn.pooling import (
 )
 from bigdl_tpu.nn.normalization import (
     BatchNormalization, SpatialBatchNormalization, LayerNorm, RMSNorm,
-    Dropout, SpatialCrossMapLRN, Normalize,
+    ZeroCenteredGatedNorm, Dropout, SpatialCrossMapLRN, Normalize,
 )
 from bigdl_tpu.nn.attention import (
     MultiHeadAttention, GroupedQueryAttention, TransformerBlock,
@@ -38,7 +38,7 @@ from bigdl_tpu.nn.gated import GatedMLP, GatedShortConv
 from bigdl_tpu.nn.moe import DroplessMoE
 from bigdl_tpu.nn.generation_state import StateSpec
 from bigdl_tpu.nn.latent_attention import LatentAttention
-from bigdl_tpu.nn.linear_attention import KimiDeltaAttention
+from bigdl_tpu.nn.linear_attention import GatedDeltaNet, KimiDeltaAttention
 from bigdl_tpu.nn.state_space import Mamba2Mixer
 from bigdl_tpu.nn.activations import (
     ReLU, Tanh, Sigmoid, SoftMax, SoftMin, LogSoftMax, HardTanh, Clamp,

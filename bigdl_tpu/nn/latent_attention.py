@@ -1,11 +1,11 @@
-"""Multi-head latent attention (MLA, as published for DeepSeek-V2) without
-a query latent: keys and values of every head are read off ONE low-rank
-latent a token, and that latent, not the heads' keys and values, is what
-generation caches.
+"""Multi-head latent attention (MLA, as published for DeepSeek-V2): keys and
+values of every head are read off ONE low-rank latent a token, and that
+latent, not the heads' keys and values, is what generation caches.
 
 For a token ``x`` at position ``p``::
 
     q          = RMSNorm_head(W_q x)          (H, nope + rope); RoPE on the last ``rope``
+                 or W_qb RMSNorm(W_qa x)      with a query latent of ``q_rank``
     [c, k_r]   = W_kva x                      (rank), (rope)
     c, k_r     = RMSNorm(c), RoPE_p(RMSNorm(k_r))
     [k_n, v]_h = W_kvb c                      (H, nope + v_dim)
@@ -13,12 +13,20 @@ For a token ``x`` at position ``p``::
     o_h        = softmax(q_h . k_h / sqrt(nope + rope)) v      causal
     out        = W_o (sigmoid(W_gate x)_h * o_h)
 
-The layer is told what it is: ``gate`` (the head-wise output gate),
-``qk_norm`` (the norms on ``q`` and ``k_r``; the one on ``c`` is MLA's own
-and always there) and ``rope_interleave`` (the rotary part turns the pairs
-``(x_2i, x_2i+1)`` by angle ``i``, DeepSeek-V3's convention, where
-rotate-half turns ``(x_i, x_i+rope/2)``).  The defaults are Ling's; with
-all three off and the last on it is DeepSeek-V3's layer.  The interleaved
+The layer is told what it is: ``gate`` (the output gate: ``True`` one
+value a head, ``"element"`` one a channel of every head, ``sigmoid(W_gate
+x) * o`` over all ``H v_dim``), ``qk_norm`` (the norms on ``q`` and
+``k_r``; the one on ``c`` is MLA's own and always there), ``q_rank`` (a
+query latent with its RMSNorm), ``rope_interleave`` (the rotary part turns
+the pairs ``(x_2i, x_2i+1)`` by angle ``i``, DeepSeek-V3's convention,
+where rotate-half turns ``(x_i, x_i+rope/2)``) and ``rope_scaling`` (YaRN
+as DeepSeek-V3 applies it: each rotary frequency blended between itself
+and itself over ``factor`` by a ramp between the dimensions that turn
+``beta_fast`` and ``beta_slow`` times over ``original_max_position_
+embeddings`` positions, and the softmax scale times ``mscale(factor,
+mscale_all_dim)^2``, ``mscale(s, m) = 0.1 m ln s + 1``).  The defaults are
+Ling's; with the gate and norms off and interleaved pairs it is
+DeepSeek-V3's layer without its query latent.  The interleaved
 rotation is done as the published code does it: the pairs are pulled
 apart (evens, then odds) and turned as halves, so the rotated columns come
 out in that order, in ``q`` and in the cached ``k_r`` alike, and every
@@ -41,8 +49,11 @@ written ``.at[layer, block, offset]`` and read at ``(layer, block)``, and
 ``leaf[layer]`` is never formed (``MultiHeadAttention._apply_paged``).
 """
 
+import math
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from bigdl_tpu.nn.generation_state import BLOCK, StateSpec
 from bigdl_tpu.nn.initialization import Xavier
@@ -52,11 +63,40 @@ from bigdl_tpu.nn.module import Module, child_rng
 CONTEXT_BLOCK = 1024
 
 
-def rotary_at(x, positions, theta: float, interleave: bool = False):
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, scaling) -> np.ndarray:
+    """DeepSeek-V3's YaRN frequencies for a rotary part ``dim`` wide,
+    ``f (1 - ramp) + f / factor * ramp``: a pair that turns more than
+    ``beta_fast`` times over the original context keeps its frequency
+    ``f``, one that turns fewer than ``beta_slow`` times is interpolated
+    (``f / factor``), and the ramp is linear between the two."""
+    factor = float(scaling["factor"])
+    original = scaling["original_max_position_embeddings"]
+    extra = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+
+    def correction(turns):
+        return dim * math.log(original / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(correction(scaling["beta_fast"])), 0)
+    high = min(math.ceil(correction(scaling["beta_slow"])), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2) - low) / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp
+    return (extra / factor * (1.0 - keep) + extra * keep).astype(np.float32)
+
+
+def rotary_at(x, positions, theta: float, interleave: bool = False,
+              inv_freq=None):
     """Rotary positions on ``x (N, T, H, Dh)`` at ``positions (N, T)``,
     rotate-half convention; float32 inside, ``x``'s dtype out.
     ``interleave``: the pairs are ``(x_2i, x_2i+1)``; they are pulled
-    apart first (evens, then odds) and the result stays in that order."""
+    apart first (evens, then odds) and the result stays in that order.
+    ``inv_freq (Dh / 2,)``: the frequencies, where not ``theta``'s own."""
     dh = x.shape[-1]
     if interleave:
         # by a 0/1 matrix on the MXU (exact: one term a column): strided
@@ -65,7 +105,8 @@ def rotary_at(x, positions, theta: float, interleave: bool = False):
         pick = (jnp.arange(dh)[:, None] == order[None, :]).astype(x.dtype)
         x = jnp.einsum("...d,de->...e", x, pick, precision=(
             "highest" if x.dtype == jnp.float32 else None))
-    inv_freq = theta ** (-jnp.arange(0, dh, 2, dtype=jnp.float32) / dh)
+    if inv_freq is None:
+        inv_freq = theta ** (-jnp.arange(0, dh, 2, dtype=jnp.float32) / dh)
     angles = positions.astype(jnp.float32)[..., None] * inv_freq
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
@@ -93,9 +134,11 @@ class LatentAttention(Module):
                  rope_theta: float = 10000.0, norm_eps: float = 1e-6,
                  gate: bool = True, qk_norm: bool = True,
                  rope_interleave: bool = False, row_align: int = 1,
+                 q_rank: int = 0, rope_scaling=None,
                  use_kernel: str = "auto", name=None):
         super().__init__(name)
         assert use_kernel in ("auto", "never", "interpret")
+        assert gate in (False, True, "element")
         #: the cache row, ``rank + rope`` values, is stored in the next
         #: multiple of this many columns, the rest nought
         self.row_align = row_align
@@ -109,14 +152,40 @@ class LatentAttention(Module):
         self.rope_theta = float(rope_theta)
         self.norm_eps = norm_eps
         self.scale = (nope_dim + rope_dim) ** -0.5
+        self.q_rank = q_rank
+        #: the rotary frequencies where they are not ``rope_theta``'s own
+        self.inv_freq = None
+        if rope_scaling is not None:
+            assert rope_scaling.get("type", "yarn") == "yarn"
+            factor = float(rope_scaling["factor"])
+            # a ratio of the two mscales other than 1 would scale the
+            # rotary part's cos and sin: no configuration here asks for it
+            if rope_scaling.get("mscale", 1) != rope_scaling.get(
+                    "mscale_all_dim", 1):
+                raise NotImplementedError("YaRN with mscale != "
+                                          "mscale_all_dim")
+            self.inv_freq = yarn_inv_freq(rope_dim, self.rope_theta,
+                                          rope_scaling)
+            self.scale *= yarn_mscale(
+                factor, rope_scaling.get("mscale_all_dim", 1)) ** 2
 
     def setup(self, rng, input_spec):
         d, h = self.hidden_size, self.num_heads
         r, qd = self.kv_rank, self.nope_dim + self.rope_dim
         kvd = self.nope_dim + self.v_dim
         init = Xavier()
-        params = {
-            "q_weight": init.init(child_rng(rng, 0), (h * qd, d), d, h * qd),
+        qr = self.q_rank
+        if qr:
+            params = {
+                "qa_weight": init.init(child_rng(rng, 5), (qr, d), d, qr),
+                "qa_norm": jnp.ones((qr,), jnp.float32),
+                "qb_weight": init.init(child_rng(rng, 0), (h * qd, qr), qr,
+                                       h * qd)}
+        else:
+            params = {"q_weight": init.init(child_rng(rng, 0), (h * qd, d), d,
+                                            h * qd)}
+        gated = h * self.v_dim if self.gate == "element" else h
+        params.update({
             "q_norm": jnp.ones((qd,), jnp.float32),
             "kva_weight": init.init(child_rng(rng, 1),
                                     (r + self.rope_dim, d), d, r),
@@ -124,10 +193,10 @@ class LatentAttention(Module):
             "kr_norm": jnp.ones((self.rope_dim,), jnp.float32),
             "kvb_weight": init.init(child_rng(rng, 2), (h * kvd, r), r,
                                     h * kvd),
-            "gate_weight": init.init(child_rng(rng, 3), (h, d), d, h),
+            "gate_weight": init.init(child_rng(rng, 3), (gated, d), d, gated),
             "out_weight": init.init(child_rng(rng, 4), (d, h * self.v_dim),
                                     h * self.v_dim, d),
-        }
+        })
         absent = (() if self.qk_norm else ("q_norm", "kr_norm")) \
             + (() if self.gate else ("gate_weight",))
         return {k: v for k, v in params.items() if k not in absent}, ()
@@ -146,8 +215,13 @@ class LatentAttention(Module):
         dt = x.dtype
         h, rope = self.num_heads, self.rope_dim
         turn = lambda a: rotary_at(a, positions, self.rope_theta,
-                                   self.rope_interleave)
-        q = (x @ params["q_weight"].astype(dt).T).reshape(n, t, h, -1)
+                                   self.rope_interleave, self.inv_freq)
+        if self.q_rank:
+            q = _rms(x @ params["qa_weight"].astype(dt).T, params["qa_norm"],
+                     self.norm_eps) @ params["qb_weight"].astype(dt).T
+        else:
+            q = x @ params["q_weight"].astype(dt).T
+        q = q.reshape(n, t, h, -1)
         if self.qk_norm:
             q = _rms(q, params["q_norm"], self.norm_eps)
         q = jnp.concatenate([q[..., :-rope], turn(q[..., -rope:])], -1)
@@ -172,12 +246,15 @@ class LatentAttention(Module):
                 kv[..., self.nope_dim:])
 
     def _output(self, params, o, x):
-        """``o (N, T, H, v_dim)`` -> ``(N, T, D)``, gated head by head
-        where the layer has a gate."""
+        """``o (N, T, H, v_dim)`` -> ``(N, T, D)``, gated head by head or
+        channel by channel where the layer has a gate."""
         n, t = o.shape[:2]
         dt = x.dtype
         y = o.astype(dt)
-        if self.gate:
+        if self.gate == "element":
+            y = y.reshape(n, t, -1) * jax.nn.sigmoid(
+                x @ params["gate_weight"].astype(dt).T)
+        elif self.gate:
             gate = jax.nn.sigmoid(x @ params["gate_weight"].astype(dt).T)
             y = y * gate[..., None]
         return y.reshape(n, t, -1) @ params["out_weight"].astype(dt).T

@@ -154,7 +154,9 @@ class DroplessMoE(Module):
     ``topk_group`` groups stay, and the top ``k`` are taken among their
     experts.  ``shared_width > 0`` adds one gated MLP of that width that
     every token passes through, whatever the router says (every share of
-    an expert-parallel layer computes it alike: count it once).  Of the
+    an expert-parallel layer computes it alike: count it once).
+    ``swiglu_limit`` clamps every expert's two inputs to its SiLU product,
+    routed and shared: ``silu(min(W_1 u, L)) * clip(W_3 u, -L, L)``.  Of the
     ``num_experts`` this
     layer HOLDS ``experts_held = (first, count)``: the assignments to held
     experts are sorted by expert into a buffer sized for the worst case,
@@ -184,8 +186,10 @@ class DroplessMoE(Module):
                  k: int, experts_held=None, norm_topk_prob: bool = True,
                  routed_scaling_factor: float = 1.0,
                  use_kernel: str = "auto", n_group: int = 1,
-                 topk_group: int = 1, shared_width: int = 0, name=None):
+                 topk_group: int = 1, shared_width: int = 0,
+                 swiglu_limit: float = None, name=None):
         super().__init__(name)
+        self.swiglu_limit = swiglu_limit
         assert use_kernel in ("auto", "never", "interpret")
         assert num_experts % n_group == 0 and 1 <= topk_group <= n_group
         self.n_group, self.topk_group = n_group, topk_group
@@ -269,6 +273,16 @@ class DroplessMoE(Module):
         return block, lambda a, w, sizes: gm.grouped_matmul_reference(
             a, w, sizes, block)
 
+    def _swiglu(self, gate, up):
+        """``silu(gate) * up``, each clamped where the layer has a
+        ``swiglu_limit``; ``gate`` and ``up`` are thunks, computed in that
+        order (the programs without a limit lower as they always have)."""
+        limit = self.swiglu_limit
+        gate = gate() if limit is None else jnp.minimum(gate(), limit)
+        gate = jax.nn.silu(gate)
+        up = up() if limit is None else jnp.clip(up(), -limit, limit)
+        return gate * up
+
     def apply(self, params, state, input, *, training=False, rng=None):
         out, sizes, assigned = self._run(params, input)
         held = self.experts_held[1]
@@ -279,6 +293,8 @@ class DroplessMoE(Module):
     #: what ``generate()`` counts, in order
     generate_counts = ("rows_routed", "rows_here", "rows_busiest_expert",
                        "rows_mean_expert", "experts_touched")
+    #: of those, the counts that are a largest one and not a sum
+    generate_peaks = ("rows_busiest_expert",)
 
     def generate(self, params, input, live=None):
         """The layer inside a step of generation: ``(out, int32[5])``, the
@@ -349,8 +365,9 @@ class DroplessMoE(Module):
                                  assignment_at // k, tokens)
             xs = _take_rows(x, token_at, slot_of.reshape(tokens, k))
         with jax.named_scope("moe_experts"):
-            hidden = jax.nn.silu(product(xs, params["w1"].astype(dt), sizes)) \
-                * product(xs, params["w3"].astype(dt), sizes)
+            hidden = self._swiglu(
+                lambda: product(xs, params["w1"].astype(dt), sizes),
+                lambda: product(xs, params["w3"].astype(dt), sizes))
             ys = product(hidden, params["w2"].astype(dt), sizes)
         with jax.named_scope("moe_combine"):
             picked = _take_rows(ys, slot_of, assignment_at[:, None])
@@ -359,9 +376,9 @@ class DroplessMoE(Module):
         if self.shared_width:
             with jax.named_scope("moe_shared"):
                 p = params["shared"]
-                out = out + (jax.nn.silu(x @ p["w1"].astype(dt).T)
-                             * (x @ p["w3"].astype(dt).T)) \
-                    @ p["w2"].astype(dt).T
+                out = out + self._swiglu(
+                    lambda: x @ p["w1"].astype(dt).T,
+                    lambda: x @ p["w3"].astype(dt).T) @ p["w2"].astype(dt).T
         if live is not None:
             assigned = live.sum().astype(jnp.int32) * k
         return out, sizes, assigned

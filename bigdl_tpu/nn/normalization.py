@@ -156,6 +156,24 @@ class RMSNorm(Module):
         return input * inv * params["weight"].astype(input.dtype), state
 
 
+class ZeroCenteredGatedNorm(RMSNorm):
+    """RMS norm whose scale a channel is ``gate * sigmoid(w)``: ``w``
+    centred on nought (the scale is 1 there at ``gate`` 2), bounded in
+    ``(0, gate)``."""
+
+    def __init__(self, n_output, eps=1e-6, gate=2.0, name=None):
+        super().__init__(n_output, eps, name)
+        self.gate = float(gate)
+
+    def setup(self, rng, input_spec):
+        return {"weight": jnp.zeros((self.n_output,), jnp.float32)}, ()
+
+    def apply(self, params, state, input, *, training=False, rng=None):
+        scale = self.gate * jax.nn.sigmoid(
+            params["weight"].astype(jnp.float32))
+        return super().apply({"weight": scale}, state, input)
+
+
 class Dropout(Module):
     """Inverted dropout (reference: nn/Dropout.scala -- scales by 1/(1-p) at train)."""
 

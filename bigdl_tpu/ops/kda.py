@@ -9,15 +9,21 @@ One token::
     S  = S' + beta k (v - u)^T            = (I - beta k k^T) S' + beta k v^T
     o  = S^T q
 
-Three forms of it:
+Four forms of it:
 
 - ``kda_step``: plain ``jax.numpy``; every product is a multiply and a
   sum on the vector unit, so that no float32 operand is rounded on its
   way through the MXU.
 - ``kda_scan``: ``kda_step`` over the tokens of a chunk, one after the
   other (``lax.scan``), carrying the state; padding tokens leave it as it
-  is.  What prefill runs; a chunkwise form (matrix products inside a
-  chunk) is queued in ROADMAP.md.
+  is.  What prefill runs for a decay per channel (Kimi Delta Attention),
+  and the definition the chunked form is held to.
+- ``gated_delta_chunk_scan``: the same recurrence for a decay that is ONE
+  number a head (``g (d,)`` all equal: the gated delta rule), in the
+  chunkwise form of Gated Delta Networks (Yang, Kautz, Hatamizadeh,
+  arXiv:2412.06464, section 3): inside a chunk of ``CHUNK`` tokens
+  matrix products and one unit lower-triangular solve, from chunk to
+  chunk one carried state.
 - ``kda_decode_step``: one token a row in ONE Pallas kernel: a row's
   state is fetched once, decayed, corrected, written back in place and
   read out in one pass (plain XLA makes two reads and a write of it).
@@ -35,6 +41,9 @@ from jax.experimental.pallas import tpu as pltpu
 #: heads one grid step of the kernel takes (8 x 64 KiB of state, in and
 #: out, double-buffered: 2 MiB of VMEM)
 HEADS_PER_STEP = 8
+#: tokens a chunk of ``gated_delta_chunk_scan`` (its triangular solve is
+#: ``CHUNK x CHUNK`` a head)
+CHUNK = 64
 
 
 def kda_step(state, q, k, v, g, beta):
@@ -64,6 +73,77 @@ def kda_scan(state, q, k, v, g, beta, valid):
     state, o = jax.lax.scan(
         step, state, tuple(time_major(a) for a in (q, k, v, g, beta, valid)))
     return state, jnp.moveaxis(o, 0, 1)
+
+
+def gated_delta_chunk_scan(state, q, k, v, g, beta, valid):
+    """``kda_scan`` for a decay that is one number a head, chunk by chunk.
+    ``state (B, H, dk, dv)`` float32, carried in that layout; ``q``, ``k``
+    ``(B, T, H, dk)``, ``v (B, T, H, dv)``, ``g`` and ``beta (B, T, H)``,
+    ``valid (B, T)`` bool.  Returns ``(state after the last valid token,
+    o (B, T, H, dv))``: a token that is not valid decays nothing and
+    writes nothing, so it leaves the state as it is; its output means
+    nothing.
+
+    A chunk of ``C`` tokens from the state ``S0``, ``gamma`` the running
+    sum of the decays inside the chunk (every exponent taken is a
+    difference <= 0)::
+
+        A   = tril_-1(exp(gamma_t - gamma_i) k_t . k_i)             (C, C)
+        U   = (I + diag(beta) A)^-1 diag(beta) (V - diag(exp(gamma)) K S0)
+        O   = diag(exp(gamma)) Q S0 + tril(exp(gamma_t - gamma_i) q_t . k_i) U
+        S_C = exp(gamma_C) S0 + K^T diag(exp(gamma_C - gamma)) U
+
+    ``U`` is what each token writes: the solve corrects a token's write by
+    those of the tokens before it in the chunk.  Everything that does not
+    depend on ``S0`` is formed for all chunks at once; the chunks then
+    follow each other (``lax.scan``).  Float32 throughout, products at
+    precision ``highest``."""
+    f32, hp = jnp.float32, jax.lax.Precision.HIGHEST
+    b, t, h, _ = q.shape
+    c = min(CHUNK, t)
+    n = -(-t // c)
+    pad = lambda a, fill=0: jnp.pad(
+        a, ((0, 0), (0, n * c - t)) + ((0, 0),) * (a.ndim - 2),
+        constant_values=fill)
+    valid = pad(valid, False)[:, :, None]                       # (B, nC, 1)
+    g = jnp.where(valid, pad(g.astype(f32)), 0.0)
+    beta = jnp.where(valid, pad(beta.astype(f32)), 0.0)
+    # (B, T, H, ...) -> (N, B, H, C, ...): chunks first, heads before tokens
+    chunked = lambda a: jnp.moveaxis(
+        a.reshape((b, n, c, h) + a.shape[3:]), (1, 3), (0, 2))
+    q, k, v = (chunked(pad(a.astype(f32))) for a in (q, k, v))
+    g, beta = chunked(g), chunked(beta)
+    gamma = jnp.cumsum(g, axis=-1)                              # (N, B, H, C)
+    diff = gamma[..., :, None] - gamma[..., None, :]            # t, i
+    causal = jnp.tril(jnp.ones((c, c), bool))
+    strict = jnp.tril(jnp.ones((c, c), bool), -1)
+    decay = lambda mask: jnp.exp(jnp.where(mask, diff, -jnp.inf))
+    dot = lambda spec, x, y: jnp.einsum(spec, x, y, precision=hp)
+    a = decay(strict) * dot("...td,...id->...ti", k, k)
+    scores = decay(causal) * dot("...td,...id->...ti", q, k)
+    lower = jnp.eye(c, dtype=f32) + beta[..., :, None] * a
+    dv = v.shape[-1]
+    w = jax.scipy.linalg.solve_triangular(
+        lower, jnp.concatenate([beta[..., None] * v,
+                                (beta * jnp.exp(gamma))[..., None] * k], -1),
+        lower=True, unit_diagonal=True)
+    w_v, w_k = w[..., :dv], w[..., dv:]
+
+    def chunk(s, xs):
+        q, k, w_v, w_k, scores, gamma = xs
+        u = w_v - dot("bhck,bhkv->bhcv", w_k, s)
+        o = jnp.exp(gamma)[..., None] * dot("bhck,bhkv->bhcv", q, s) \
+            + dot("bhti,bhiv->bhtv", scores, u)
+        last = gamma[..., -1:]
+        s = jnp.exp(last)[..., None] * s + dot(
+            "bhck,bhcv->bhkv", jnp.exp(last - gamma)[..., None] * k, u)
+        return s, o
+
+    state, o = jax.lax.scan(chunk, state.astype(f32),
+                            (q, k, w_v, w_k, scores, gamma))
+    # (N, B, H, C, dv) -> (B, T, H, dv)
+    o = jnp.moveaxis(o, (0, 2), (1, 3)).reshape(b, n * c, h, dv)
+    return state, o[:, :t]
 
 
 def _decode_kernel(slots_ref, state_ref, q_ref, k_ref, v_ref, g_ref,

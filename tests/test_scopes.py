@@ -64,6 +64,12 @@ PROGRAMS = {
                               LAYER + ("state_mixer", "sampler")),
     "granite.decode": ("granite-4.0-h-micro.serve.short-chat",
                        LAYER + ("state_mixer", "sampler")),
+    "gigachat.chunk_prefill": ("gigachat3.5-432b-a28b.serve.long-reasoning",
+                               LAYER + ("state_mixer", "moe_shared",
+                                        "sampler") + EXPERTS),
+    "gigachat.decode": ("gigachat3.5-432b-a28b.serve.long-reasoning",
+                        LAYER + ("state_mixer", "moe_shared", "sampler")
+                        + EXPERTS),
 }
 TRAIN_STEPS = [p for p in PROGRAMS if p.endswith("train_step")]
 

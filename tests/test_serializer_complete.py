@@ -149,6 +149,8 @@ EXAMPLES = {
     "Maxout": (lambda: nn.Maxout(4, 3, 2), lambda: _r(2, 4)),
     "PReLU": (lambda: nn.PReLU(), X34),
     "RMSNorm": (lambda: nn.RMSNorm(4), lambda: _r(2, 4)),
+    "ZeroCenteredGatedNorm": (lambda: nn.ZeroCenteredGatedNorm(4),
+                              lambda: _r(2, 4)),
     "SReLU": (lambda: nn.SReLU(), X34),
     "Scale": (lambda: nn.Scale((4,)), lambda: _r(2, 4)),
     "Normalize": (lambda: nn.Normalize(2.0), lambda: _r(2, 4)),
@@ -264,6 +266,9 @@ EXAMPLES = {
                     lambda: _r(2, 5, 8)),
     "KimiDeltaAttention": (
         lambda: nn.KimiDeltaAttention(8, 2, head_dim=4, use_kernel="never"),
+        lambda: _r(2, 5, 8)),
+    "GatedDeltaNet": (
+        lambda: nn.GatedDeltaNet(8, 1, 2, head_dim=4, use_kernel="never"),
         lambda: _r(2, 5, 8)),
     "Mamba2Mixer": (
         lambda: nn.Mamba2Mixer(8, 2, 4, state_dim=4, chunk_size=4,
