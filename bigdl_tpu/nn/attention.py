@@ -368,7 +368,9 @@ class MultiHeadAttention(Module):
         return dequantize_blockwise(q8, sc, self.head_dim).astype(dt)
 
     def _flash_paged_ok(self, block_size, dtype):
-        """Whether decode goes through ``flash_paged_decode_attention``:
+        """Whether paged attention goes through the Pallas kernels --
+        decode through ``flash_paged_decode_attention``, a chunk of a
+        pool that is not int8 through ``flash_paged_prefill_attention``:
         in ``auto`` on a TPU, when a block is whole tiles of the pool's
         dtype -- 8 rows of fp32, 16 of bf16, 32 of int8 -- and a row of
         it, ``Hkv * D`` values, whole lanes of 128."""
@@ -402,8 +404,8 @@ class MultiHeadAttention(Module):
         ``pool`` is the layer-STACKED leaves ``(L, NB, bs, Hkv * D)`` of a
         layer-scanned model and block ``b`` is ``leaf[layer, b]``: rows
         are written ``.at[layer, block, offset]``, a row's context is one
-        gather over ``(layer, tables)`` and the decode kernel indexes
-        ``(layer, block)`` itself.  ``leaf[layer]`` is never formed (it
+        gather over ``(layer, tables)`` and the two kernels index
+        ``(layer, block)`` themselves.  ``leaf[layer]`` is never formed (it
         would be a copy of a layer's whole pool, 84 MB in the serving
         cell), so the loop that carries the leaf updates it in place and
         no program copies the pool.  ``new_pool`` has the shape of
@@ -415,16 +417,23 @@ class MultiHeadAttention(Module):
           is one fixed-size chunk per row ``(N, Tc, D)`` whose first
           ``lengths[i]`` tokens are real and start at absolute position
           ``pos[i]``; K/V scatter token-by-token through the table
-          (padding tokens redirect to the trash block) and attention
-          gathers the row's FULL mapped context, masked causally at
-          each token's absolute position -- so a chunk attends to all
-          previously-filled blocks (including shared prefix blocks it
-          never computed) plus its own earlier tokens.
+          (padding tokens redirect to the trash block), then each token
+          attends to the row's context up to its own absolute position
+          -- all previously-filled blocks (including shared prefix
+          blocks it never computed) plus its own earlier tokens, read
+          back from the pool.  Where ``_flash_paged_ok`` admits the pool
+          and it is not int8, ``flash_paged_prefill_attention`` fetches a
+          row's blocks through its table only up to its frontier;
+          otherwise attention gathers the row's FULL mapped context and
+          masks it.
         - DECODE (``lengths is None``): ``input`` is one token per row
           ``(N, 1, D)`` written at ``pos[i]``; rows whose table is all
           trash (empty slots, rows mid-prefill) write garbage into the
           trash block and read garbage out -- harmless by the same
-          frontier argument as the contiguous slot pool.
+          frontier argument as the contiguous slot pool.  Where
+          ``_flash_paged_ok`` admits the pool, the decode kernel reads a
+          row's blocks up to ``pos``; otherwise the gather and mask as
+          above.
         """
         n, t, d = input.shape
         dt = input.dtype
@@ -493,11 +502,20 @@ class MultiHeadAttention(Module):
             off = gpos % bs
             new_pool = scatter(phys.reshape(n * t), off.reshape(n * t),
                                k.reshape(n * t, kvw), v.reshape(n * t, kvw))
-            # (N, 1, Tc, ctx): key at logical position kp is visible to
-            # the chunk token at absolute position gpos iff kp <= gpos
-            mask = (jnp.arange(ctx, dtype=jnp.int32)[None, None, :]
-                    <= gpos[:, :, None])[:, None]
-            y = gathered_attention(new_pool, mask)
+            if self._flash_paged_ok(bs, cdt) and not quant:
+                from bigdl_tpu.ops.flash_attention import \
+                    flash_paged_prefill_attention
+
+                y = flash_paged_prefill_attention(
+                    q.reshape(heads), new_pool["k"], new_pool["v"], tables,
+                    pos, lengths, layer=layer,
+                    interpret=self.use_flash == "interpret").astype(dt)
+            else:
+                # (N, 1, Tc, ctx): key at logical position kp is visible to
+                # the chunk token at absolute position gpos iff kp <= gpos
+                mask = (jnp.arange(ctx, dtype=jnp.int32)[None, None, :]
+                        <= gpos[:, :, None])[:, None]
+                y = gathered_attention(new_pool, mask)
         else:                                             # one-token step
             if t != 1:
                 raise ValueError(

@@ -1,5 +1,5 @@
-"""Fused flash-attention kernels in Pallas (Mosaic/TPU): forward, backward
-and the two decode-shaped forwards.
+"""Fused flash-attention kernels in Pallas (Mosaic/TPU): forward, backward,
+the decode-shaped forwards and the paged chunk-prefill forward.
 
 The TPU-native analogue of the reference's hand-tuned native kernels
 (bigdl-core MKL-DNN primitives, SURVEY.md section 2.8): where XLA's fusion
@@ -48,6 +48,13 @@ step's worth at a time into a double buffer, up to the slot's frontier and
 no further: VMEM holds two steps whatever the pool's size, so no gate
 bounds it by bytes; its gate asks only that a block be whole tiles of the
 pool's dtype.
+
+``flash_paged_prefill_attention`` (the paged pool's chunk-prefill kernel)
+fetches a chunk row's blocks the decode kernel's way, up to the row's
+frontier, for a tile of its queries at a time: the query heads that share
+a lane tile of the pool row are stacked as the rows of one product, each
+on its own head's lanes, so the scores and the weighted values are MXU
+products; the softmax state is float32 in VMEM.
 
 ``latent_paged_decode_attention`` (the latent paged pool's decode kernel)
 fetches the same way from a leaf of ONE shared row a token, ``(NB, bs, W)``
@@ -845,6 +852,310 @@ def flash_paged_decode_attention(q, k_pool, v_pool, tables, pos,
     if groups > 1:
         out = out.reshape(b, groups, kv_heads, d).transpose(0, 2, 1, 3)
     return out.reshape(b, 1, h, d)
+
+
+# The paged prefill kernel's arithmetic, in helpers jitted once a process:
+# a chunk bucket's program traces the kernel anew, and each call below is
+# then a cached trace, not tens of operations traced again for every lane
+# chunk of every bucket (what the set-up of the serving cell pays for).
+
+def _on_heads(width, heads, head_dim):
+    """``(1, width)`` masks of the lanes of each of ``heads`` heads."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
+    return [jnp.logical_and(lane >= h * head_dim, lane < (h + 1) * head_dim)
+            for h in range(heads)]
+
+
+@functools.partial(jax.jit, static_argnames=("heads", "head_dim"))
+def _stack_heads(q, *, heads, head_dim):
+    """``(T, W)`` queries of ``heads`` heads -> ``(heads * T, W)``: block
+    ``h`` holds head ``h``'s values on its own lanes, zeros elsewhere."""
+    return jnp.concatenate([jnp.where(on, q, 0.0) for on in
+                            _on_heads(q.shape[1], heads, head_dim)])
+
+
+@functools.partial(jax.jit, static_argnames=("heads", "head_dim"))
+def _unstack_heads(acc, l, *, heads, head_dim):
+    """``_stack_heads``' inverse for the softmax-weighted sums: each
+    block's own head's lanes, over its sum of weights."""
+    t = acc.shape[0] // heads
+    out = acc / jnp.maximum(l, 1e-30)
+    return sum(jnp.where(on, out[h * t:(h + 1) * t], 0.0) for h, on in
+               enumerate(_on_heads(acc.shape[1], heads, head_dim)))
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "dtype", "precision"))
+def _prefill_softmax_step(q, k, v, seen, fetched, m, l, acc, *, scale, dtype,
+                          precision):
+    """One step of the online softmax of stacked query rows ``q (S, W)``
+    over a step's keys and values ``(R, W)``: the new ``(m, l, acc)``."""
+    # a row never fetched holds whatever the buffer held: its weight is
+    # 0, and 0 * nan must not reach the sum
+    v = jnp.where(fetched, v, 0).astype(dtype)
+    s = jax.lax.dot_general(q.astype(dtype), k.astype(dtype), _CONTRACT_LAST,
+                            precision=precision,
+                            preferred_element_type=jnp.float32)
+    s = jnp.where(seen, s * scale, _MASKED)
+    new_m = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+    w = jnp.exp(s - new_m)
+    corr = jnp.exp(m - new_m)
+    return new_m, l * corr + jnp.sum(w, axis=1, keepdims=True), \
+        acc * corr + jax.lax.dot_general(
+            w.astype(dtype), v, _MATMUL, precision=precision,
+            preferred_element_type=jnp.float32)
+
+
+def _paged_prefill_kernel(start_ref, len_ref, table_ref, layer_ref, q_ref,
+                          k_hbm, v_hbm, o_ref, k_buf, v_buf, sem, chain_ref,
+                          q_scr, m_ref, l_ref, acc_ref, *, block_size: int,
+                          blocks_per_step: int, max_blocks: int, chunk: int,
+                          head_dim: int, scale: float, dtype, precision):
+    """One query tile of one chunk row a grid step, ``_paged_decode_kernel``'s
+    way of fetching: the pool in HBM as ``(L, NB, bs, W)``, a block at
+    ``(layer_ref[0], table entry)``, a step's blocks by one DMA each into the
+    other half of a double buffer, and the next tile's first step started
+    by this tile's last (``chain_ref``: which half the next step lands in,
+    and whether the tile before started this tile's first step).  A tile
+    fetches and computes only the blocks up to its own last real token's
+    position; a tile with no real token (padding, a row of length 0) does
+    neither and comes back nought.
+
+    The lanes of a pool row hold ``(kv head, d)``.  A product takes
+    ``chunk`` of them, whole heads (two heads of 64 fill a lane tile): the
+    queries of each of those heads, ``G`` query heads a KV head, are
+    stacked as rows ``(group, kv head, position)`` holding their values on
+    their own head's lanes and zeros elsewhere, so that ONE product over
+    the chunk's lanes gives every stacked row its own head's scores, and
+    the product of the weights with the chunk's values gives each row its
+    head's output on that head's lanes.  Softmax state is per stacked row,
+    float32, in VMEM."""
+    bs, g = block_size, blocks_per_step
+    i, j = pl.program_id(0), pl.program_id(1)
+    n_rows, n_tiles = pl.num_programs(0), pl.num_programs(1)
+    groups, tile, width = q_ref.shape
+    rows = g * bs
+    per = chunk // head_dim
+    stack = per * groups * tile
+    chunks = width // chunk
+
+    def last(row, t):
+        """The last key position tile ``t`` of ``row`` reads: its last
+        real token's; -1 where it holds none, or where ``row`` is past the
+        last row."""
+        at = jnp.minimum(row, n_rows - 1)
+        end = start_ref[at] + jnp.minimum((t + 1) * tile, len_ref[at]) - 1
+        real = jnp.logical_and(row < n_rows, t * tile < len_ref[at])
+        return jnp.where(real, jnp.minimum(end, max_blocks * bs - 1), -1)
+
+    def each_copy(row, end, step, half, act):
+        """``act`` on the DMAs of one step of a tile of ``row`` whose last
+        key is ``end``: its blocks at or before ``end``'s, one loop over
+        them (no branch a block: the kernel's code stays small to lower,
+        and each chunk bucket's program lowers it anew)."""
+        first = step * g
+        live = jnp.where(end < 0, 0, jnp.clip(
+            jax.lax.div(end, bs) - first + 1, 0, g))
+
+        def one(b, carry):
+            phys = table_ref[row * max_blocks + first + b]
+            rows_of_b = pl.ds(pl.multiple_of(b * bs, bs), bs)
+            for a, (pool, buf) in enumerate(((k_hbm, k_buf),
+                                             (v_hbm, v_buf))):
+                act(pltpu.make_async_copy(pool.at[layer_ref[0], phys],
+                                          buf.at[half, rows_of_b],
+                                          sem.at[half, a]))
+            return carry
+
+        jax.lax.fori_loop(0, live, one, None)
+
+    def start(dma):
+        dma.start()
+
+    def wait(dma):
+        dma.wait()
+
+    @pl.when(jnp.logical_and(i == 0, j == 0))
+    def _():
+        chain_ref[0] = 0
+        chain_ref[1] = 0
+
+    end = last(i, j)
+    steps = jnp.where(end >= 0, jax.lax.div(end, rows) + 1, 0)
+    first_half = chain_ref[0]
+    # this tile's first step, unless the tile before started it
+    each_copy(i, jnp.where(chain_ref[1] == 0, end, -1), 0, first_half,
+              start)
+    # the tile after this one, started by this one's last step
+    nxt_row = i + jax.lax.div(j + 1, n_tiles)
+    nxt_tile = jax.lax.rem(j + 1, n_tiles)
+    nxt_end = last(nxt_row, nxt_tile)
+    m_ref[:] = jnp.full_like(m_ref, _MASKED)
+    l_ref[:] = jnp.zeros_like(l_ref)
+    acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    def stacked(c, grp):
+        """The stacked rows of lane chunk ``c`` and query group ``grp``."""
+        return pl.ds((c * groups + grp) * per * tile, per * tile)
+
+    for c in range(chunks):
+        for grp in range(groups):
+            q_scr[stacked(c, grp)] = _stack_heads(
+                q_ref[grp, :, c * chunk:(c + 1) * chunk], heads=per,
+                head_dim=head_dim)
+    # a stacked row's last visible key: its own position, or the tile's
+    # last real one for a padding token
+    qlim = jnp.minimum(start_ref[i] + j * tile + jax.lax.broadcasted_iota(
+        jnp.int32, (tile, 1), 0), end)
+    qlim = jnp.concatenate([qlim] * (per * groups), axis=0)   # (stack, 1)
+
+    def body(step, _):
+        half = (first_half + step) % 2
+        more = step + 1 < steps
+        each_copy(jnp.where(more, i, nxt_row), jnp.where(more, end, nxt_end),
+                  jnp.where(more, step + 1, 0), 1 - half, start)
+        each_copy(i, end, step, half, wait)
+        seen = step * rows + jax.lax.broadcasted_iota(
+            jnp.int32, (1, rows), 1) <= qlim                # (stack, R)
+        fetched = step * rows + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, 1), 0) <= end
+        for c in range(chunks):
+            lanes, of = slice(c * chunk, (c + 1) * chunk), \
+                pl.ds(c * stack, stack)
+            m_ref[of], l_ref[of], acc_ref[of] = _prefill_softmax_step(
+                q_scr[of], k_buf[half, :, lanes], v_buf[half, :, lanes],
+                seen, fetched, m_ref[of], l_ref[of], acc_ref[of],
+                scale=scale, dtype=dtype, precision=precision)
+        return _
+
+    jax.lax.fori_loop(0, steps, body, None)
+    chain_ref[0] = (first_half + steps) % 2
+    # a tile that ran a step started its successor's first one
+    chain_ref[1] = jnp.where(steps > 0, 1, 0)
+    for c in range(chunks):
+        for grp in range(groups):
+            o_ref[grp, :, c * chunk:(c + 1) * chunk] = _unstack_heads(
+                acc_ref[stacked(c, grp)], l_ref[stacked(c, grp)], heads=per,
+                head_dim=head_dim)
+
+
+#: stacked query rows (heads sharing a product, times query positions) of
+#: one product of the paged prefill kernel: a tile of queries is all of a
+#: chunk's positions while they fit, and splits where they do not
+_PREFILL_STACK_ROWS = 512
+
+
+def default_precision_rounds() -> bool:
+    """Whether ``jax_default_matmul_precision`` leaves a float32 product at
+    the TPU's default, one MXU pass over inputs rounded to bfloat16: unset,
+    ``"default"`` or ``"bfloat16"``."""
+    precision = jax.config.jax_default_matmul_precision
+    return precision is None or str(precision).lower() in ("default",
+                                                           "bfloat16")
+
+
+def _prefill_operands(dtype):
+    """The dtype and precision of the paged prefill kernel's two products,
+    for queries of ``dtype``: the arithmetic the XLA path's product has on
+    the TPU (float32 rounded to bfloat16, one MXU pass) where
+    ``default_precision_rounds``; float32 at ``HIGHEST`` where a float32
+    caller asks for more; bfloat16 queries as they come."""
+    if jnp.dtype(dtype) != jnp.float32 or default_precision_rounds():
+        return jnp.bfloat16, jax.lax.Precision.DEFAULT
+    return jnp.float32, jax.lax.Precision.HIGHEST
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def flash_paged_prefill_attention(q, k_pool, v_pool, tables, start, lengths,
+                                  layer=None, *, interpret: bool = False):
+    """Chunk-prefill attention through a PAGED K/V pool: ``q (N, Tc, H,
+    D)``, row ``i``'s first ``lengths[i]`` tokens real and at absolute
+    positions ``start[i] + t``, against pools addressed by the rows' block
+    tables ``tables (N, max_blocks)`` -> ``(N, Tc, H, D)``.  Query ``t`` of
+    row ``i`` sees key position ``kp`` iff ``kp <= start[i] + t``: the
+    row's earlier chunks, prefix blocks it shares and never computed, and
+    its own tokens up to itself, all read back from the pool (its own
+    K/V written there before the call).
+
+    The pools are ``flash_paged_decode_attention``'s, in the same two
+    layouts (one layer's leaf ``(NB, bs, Hkv * D)``, or the stacked
+    ``(L, NB, bs, Hkv * D)`` with ``layer`` traced inside the layer loop),
+    read where they lie: a row's blocks come through its table by the
+    kernel's own DMAs, only up to its frontier ``start + lengths - 1``, and
+    a tile of queries stops at its own last position.  The gather of a
+    row's whole table (``(N, max_blocks * bs, Hkv, D)`` a layer, whatever
+    the lengths) never exists.  Grouped queries (``H = Hkv * G``) read a
+    fetched block once for the ``G`` query heads of each KV head.
+
+    Adapts to the shapes it is handed: a tile of queries is all of ``Tc``
+    (rounded up to 8) while its stacked rows fit ``_PREFILL_STACK_ROWS``;
+    a product takes whole heads of a lane tile; a step fetches
+    ``_paged_blocks_per_step`` blocks; the products take what
+    ``_prefill_operands`` says.  Padding tokens and rows of length 0 come
+    back finite.  ``interpret=True`` runs on the CPU for tests.  On a TPU
+    ``bs`` must be a multiple of the pool dtype's sublane tile and
+    ``Hkv * D`` of 128 (``MultiHeadAttention._flash_paged_ok``)."""
+    n, tc, h, d = q.shape
+    if k_pool.ndim == 3:
+        assert layer is None, "a single layer's leaf has no layer to ask for"
+        layer = 0
+        k_pool, v_pool = k_pool[None], v_pool[None]
+    assert layer is not None, "a stacked pool needs the layer"
+    width = k_pool.shape[3]
+    assert width % d == 0 and (h * d) % width == 0, \
+        f"a pool row of {width} values is not whole KV heads of {d} that " \
+        f"divide the {h} query heads"
+    kv_heads = width // d
+    groups = h // kv_heads
+    bs, max_blocks = k_pool.shape[2], tables.shape[1]
+    g = _paged_blocks_per_step(bs, width, max_blocks)
+    chunk = math.lcm(d, _LANES)
+    if width % chunk:
+        chunk = width
+    per_row = chunk // d * groups
+    tile = min(-(-tc // 8) * 8,
+               max(8, _PREFILL_STACK_ROWS // per_row // 8 * 8))
+    tiles = -(-tc // tile)
+    # every product's stacked rows, one block of them a lane chunk
+    stacked = width // chunk * per_row * tile
+    dtype, precision = _prefill_operands(q.dtype)
+    # (N, G, Tc, Hkv * D): block g holds the query heads kv * G + g, each
+    # on its KV head's lanes
+    qg = q.reshape(n, tc, kv_heads, groups, d).transpose(0, 3, 1, 2, 4)
+    qg = jnp.pad(qg.reshape(n, groups, tc, width).astype(jnp.float32),
+                 ((0, 0), (0, 0), (0, tiles * tile - tc), (0, 0)))
+    spec = pl.BlockSpec((None, groups, tile, width),
+                        lambda i, j, *prefetch: (i, 0, j, 0))
+    out = pl.pallas_call(
+        functools.partial(_paged_prefill_kernel, block_size=bs,
+                          blocks_per_step=g, max_blocks=max_blocks,
+                          chunk=chunk, head_dim=d,
+                          scale=1.0 / math.sqrt(d), dtype=dtype,
+                          precision=precision),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(n, tiles),
+            in_specs=[spec] + [pl.BlockSpec(memory_space=pltpu.HBM)] * 2,
+            out_specs=spec,
+            scratch_shapes=[
+                pltpu.VMEM((2, g * bs, width), k_pool.dtype),
+                pltpu.VMEM((2, g * bs, width), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((2,), jnp.int32),
+                pltpu.VMEM((stacked, chunk), jnp.float32),
+                pltpu.VMEM((stacked, 1), jnp.float32),
+                pltpu.VMEM((stacked, 1), jnp.float32),
+                pltpu.VMEM((stacked, chunk), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct(qg.shape, jnp.float32),
+        # tiles run in order: each starts the next one's first fetch
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+        name="flash_paged_prefill_attention",
+    )(jnp.asarray(start, jnp.int32), jnp.asarray(lengths, jnp.int32),
+      jnp.asarray(tables, jnp.int32).reshape(-1),
+      jnp.asarray(layer, jnp.int32).reshape(1), qg, k_pool, v_pool)
+    out = out[:, :, :tc].reshape(n, groups, tc, kv_heads, d)
+    return out.transpose(0, 2, 3, 1, 4).reshape(n, tc, h, d).astype(q.dtype)
 
 
 #: latent rows one step of the latent paged decode kernel computes on

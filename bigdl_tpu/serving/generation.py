@@ -148,10 +148,9 @@ def rounds_matmul_inputs():
     ``jax_default_matmul_precision`` unset, ``"default"`` or
     ``"bfloat16"``.  Elsewhere (the CPU's float32 products, a user's
     ``"highest"``) a bfloat16 weight would change the result."""
-    precision = jax.config.jax_default_matmul_precision
-    return _backend() == "tpu" and (
-        precision is None or str(precision).lower() in ("default",
-                                                        "bfloat16"))
+    from bigdl_tpu.ops.flash_attention import default_precision_rounds
+
+    return _backend() == "tpu" and default_precision_rounds()
 
 
 class ServedWeights:

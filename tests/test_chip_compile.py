@@ -23,6 +23,7 @@ from bigdl_tpu.ops.ssd import ssd_decode_step
 from bigdl_tpu.ops.flash_attention import (flash_attention,
                                            flash_decode_attention,
                                            flash_paged_decode_attention,
+                                           flash_paged_prefill_attention,
                                            kv_blocks_fit,
                                            latent_paged_decode_attention)
 
@@ -139,6 +140,19 @@ def _paged(b, nb, bs, d, dtype, h=16, mb=64, layers=None, groups=1):
             scale, scale, None if layers is None else ((), i32)]
 
 
+def _prefill(q, k, v, tables, start, lengths, layer):
+    return flash_paged_prefill_attention(q, k, v, tables, start, lengths,
+                                         layer)
+
+
+def _paged_chunk(rows, tc, nb, d, dtype, h=16, mb=64, layers=24, groups=1):
+    """A chunk step's rows of ``tc`` tokens against the stacked pool of
+    ``_paged`` (the layer an argument), with their starts and lengths."""
+    pool = ((layers, nb, 16, h * d), dtype)
+    return [((rows, tc, h * groups, d), f32), pool, pool, ((rows, mb), i32),
+            ((rows,), i32), ((rows,), i32), ((), i32)]
+
+
 def _latent(q, pool, tables, pos, layer=None):
     return latent_paged_decode_attention(q, pool, tables, pos, layer,
                                          rank=512, scale=192 ** -0.5)
@@ -229,6 +243,18 @@ CASES = {
     "paged-grouped-int8": (
         flash_paged_decode_attention,
         _paged(8, 641, 32, 64, i8, h=8, mb=32, layers=2, groups=4), None),
+    # the paged prefill kernel: the serving cell's largest chunk step of
+    # 64 tokens (8 rows, fp32, all 24 layers in one leaf), the speculative
+    # verify step's k + 1 = 5 tokens, and granite's chunk step (4 rows of
+    # 512 tokens, 32 query heads on 8 KV heads, its 4 attention layers'
+    # bf16 pool of 9216 blocks and 144 table entries)
+    "paged-prefill-cell-fp32": (_prefill,
+                                _paged_chunk(8, 64, 1281, 64, f32), None),
+    "paged-prefill-verify": (_prefill, _paged_chunk(32, 5, 1281, 64, f32),
+                             None),
+    "paged-prefill-grouped-bf16": (
+        _prefill, _paged_chunk(4, 512, 9217, 64, bf16, h=8, mb=144,
+                               layers=4, groups=4), None),
     # the kanana serving cell: the latent decode kernel over a layer's own
     # leaf (the leading dense layer) and over the scanned layers' stacked one
     "latent-decode-cell": (_latent, _latent_pool(), None),
@@ -298,24 +324,26 @@ def test_paged_gate_reads_tile_and_width(monkeypatch, block_size, dtype,
     assert not attn._flash_paged_ok(block_size, dtype)
 
 
-def _cell_paged_programs(one_chip, monkeypatch, scan_layers, served=False):
+def _cell_paged_programs(one_chip, monkeypatch, scan_layers, served=False,
+                         rows=2, tpu=True):
     """The engine's paged programs at the serving cell's widths and pool
     (32 slots, 1280 blocks of 16 and the trash block, 64 table entries;
     two layers, a small vocabulary), lowered for the described chip with
-    the gate seeing a TPU: ``{"decode": ..., "chunk": ...}`` (the chunk
-    program at two rows of 64 tokens) and the pool's bytes.  ``served``
-    hands them the tree ``ServedWeights`` builds where the backend is a
-    TPU, in place of the float32 weights."""
+    the gate seeing a TPU (or, ``tpu=False``, the CPU's gather path):
+    ``{"decode": ..., "chunk": ...}`` (the chunk program at ``rows`` rows
+    of 64 tokens) and the pool's bytes.  ``served`` hands them the tree
+    ``ServedWeights`` builds where the backend is a TPU, in place of the
+    float32 weights."""
     import bigdl_tpu.nn.attention as attention
     from bigdl_tpu.serving import generation
     from bigdl_tpu.serving.generation import paged_generate_steps
 
-    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
-    slots, rows, blocks, bs, entries = 32, 2, 1280, 16, 64
+    monkeypatch.setattr(attention, "_on_tpu", lambda: tpu)
+    slots, blocks, bs, entries = 32, 1280, 16, 64
     model = attention.TransformerLM(512, 1024, 16, 2, max_len=1024,
                                     scan_layers=scan_layers)
     model.build(jax.ShapeDtypeStruct((2, 16), i32))
-    assert model.blocks[0].attn._flash_paged_ok(bs, f32)
+    assert model.blocks[0].attn._flash_paged_ok(bs, f32) is tpu
 
     def described(x):
         return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
@@ -368,10 +396,12 @@ def test_scan_layout_leaves_the_pool_where_it_lies(one_chip, monkeypatch,
     """The same two programs of a ``scan_layers`` model: the stacked pool
     rides the layer loop's carry and is updated in place.  No copy, slice
     or update-slice, fused or not, has the stacked leaf or one layer's
-    leaf as its result, the loop body holds the one Pallas kernel, and the
-    program's temporaries are a small part of the pool (they held a second
-    pool while it went through the loop as ``xs`` and ``ys``: 0.49 and
-    0.51 GiB beside a pool of 0.31)."""
+    leaf as its result, the loop body holds the one Pallas kernel (the
+    chunk program's since its attention reads the pool through
+    ``flash_paged_prefill_attention``; it held none while it gathered),
+    and the program's temporaries are a small part of the pool (they held
+    a second pool while it went through the loop as ``xs`` and ``ys``:
+    0.49 and 0.51 GiB beside a pool of 0.31)."""
     import re
 
     programs, pool_bytes = _cell_paged_programs(one_chip, monkeypatch, True)
@@ -383,8 +413,93 @@ def test_scan_layout_leaves_the_pool_where_it_lies(one_chip, monkeypatch,
     assert not re.search(leaf + rf"\S* ({moved})\(", text)
     assert not re.search(rf"%\S*({moved})\S* = " + leaf, text)
     assert " while(" in text
-    assert text.count("tpu_custom_call") == (program == "decode")
+    assert text.count("tpu_custom_call") == 1
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes / 8
+
+
+def test_chunk_step_reads_the_pool_through_the_prefill_kernel(one_chip,
+                                                              monkeypatch):
+    """The cell's chunk program (scanned layers, the served tree at the
+    cell's default precision) at the buckets its traffic runs, 1, 2, 4
+    and 8 rows: accepted for the chip, ONE ``flash_paged_prefill_attention``
+    call in the layer loop, no gathered ``(rows, 1024, 16, 64)`` or
+    ``(rows, 64, 16, 1024)`` context in any shape (the gather program, the
+    control, holds them from 2 rows up), and temporaries no larger than
+    the gather program's.  The largest over the buckets is compared: a
+    process reserves for its programs together, and at one bucket the two
+    programs' prefetch buffers for the head's weight differ by 64 KiB
+    either way (the gather's ``(8, 64, 16, 1024)`` context is 32 MiB at 8
+    rows)."""
+    import re
+
+    temps = {True: [], False: []}
+    for rows in (1, 2, 4, 8):
+        gathered = rf"\[{rows},(1024,16|64,16,1024)\]"
+        for kernel in (True, False):
+            with jax.default_matmul_precision("default"):
+                programs, _ = _cell_paged_programs(
+                    one_chip, monkeypatch, True, served=True, rows=rows,
+                    tpu=kernel)
+                compiled = programs["chunk"].compile()
+            text = compiled.as_text()
+            temps[kernel].append(
+                compiled.memory_analysis().temp_size_in_bytes)
+            found = re.search(gathered, text)
+            if kernel:
+                comps, entry = _computations(text)
+                (loop,) = [line for line in comps[entry]
+                           if " while(" in line]
+                body = re.search(r"body=%([\w.\-]+)", loop).group(1)
+                calls = [line for line in _reached(comps, body)
+                         if "custom_call_target=\"tpu_custom_call\"" in line]
+                assert len(calls) == 1
+                assert "flash_paged_prefill_attention" in calls[0]
+                assert not found
+            elif rows > 1:
+                assert found
+    assert max(temps[True]) <= max(temps[False])
+
+
+def test_granite_chunk_attention_reads_the_pool_through_the_kernel(
+        one_chip, monkeypatch):
+    """Granite's attention layer on a chunk step of its ``chunk_rows``
+    of 4 rows of 512 tokens (32 query heads on 8 KV heads of 64, the
+    multiplier as the softmax scale, the 4 attention layers' stacked bf16
+    pool of 9216 blocks and 144 table entries, the layer traced): one
+    prefill kernel and no gathered ``(4, 2304, ...)`` context, and
+    temporaries no larger than the gather's."""
+    import re
+
+    import bigdl_tpu.nn.attention as attention
+
+    def arg(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def step(kernel):
+        monkeypatch.setattr(attention, "_on_tpu", lambda: kernel)
+        attn = attention.MultiHeadAttention(2048, 32, causal=True,
+                                            num_kv_heads=8, bias=False,
+                                            scale=0.015625)
+        params = jax.eval_shape(lambda k: attn.setup(k, None)[0],
+                                jax.random.key(0))
+        params = jax.tree.map(lambda x: arg(bf16, *x.shape), params)
+        pool = {name: arg(bf16, 4, 9217, 16, 512) for name in "kv"}
+        with jax.default_matmul_precision("default"):
+            return jax.jit(
+                lambda p, pool, x, tables, start, lengths, layer:
+                attn.apply_paged(p, x, pool, tables, start, lengths, layer),
+                donate_argnums=(1,)).lower(
+                    params, pool, arg(bf16, 4, 512, 2048), arg(i32, 4, 144),
+                    arg(i32, 4), arg(i32, 4), arg(i32)).compile()
+
+    kernel, gather = step(True), step(False)
+    text = kernel.as_text()
+    assert text.count("flash_paged_prefill_attention") >= 1
+    assert text.count("tpu_custom_call") == 1
+    assert not re.search(r"\[4,(2304,8|144,16,512)\]", text)
+    assert re.search(r"\[4,(2304,8|144,16,512)\]", gather.as_text())
+    assert kernel.memory_analysis().temp_size_in_bytes \
+        <= gather.memory_analysis().temp_size_in_bytes
 
 
 @pytest.mark.parametrize("program", ["decode", "chunk"])

@@ -686,6 +686,185 @@ class TestFlashPagedKernel:
         assert all(len(s) == 7 for s in streams["never"])
 
 
+def _prefill_case(tc, kv_heads, groups, dtype, layers, seed=0):
+    """Chunk rows against a pool of 64-wide heads in blocks of 16, 24
+    table entries a row (two steps of the kernel at 1024 values a row):
+    row 0 a first chunk; rows 1-3 frontiers at, before and just past a
+    block's last row; row 4 of length 0; row 5 mapping row 1's first four
+    blocks as a shared prefix and reading past a step's edge, its last
+    tokens padding.  ``(q, k, v, tables, start, lengths)``; ``layers``
+    stacks the pool ``(L, NB, bs, Hkv * D)``."""
+    rng = np.random.default_rng(seed)
+    d, bs, mb = 64, 16, 24
+    lengths = np.asarray([tc, tc, max(tc - 1, 1), tc, 0, max(tc - 3, 1)],
+                         np.int32)
+    last = np.asarray([tc - 1, 6 * bs - 1, 8 * bs - 2, 10 * bs, 40, 0])
+    start = np.asarray(last - lengths + 1, np.int32)
+    start[4:] = 40, 300
+    nb = len(start) * mb + 1
+    order = iter(rng.permutation(nb - 1))
+    tables = np.asarray([[next(order) for _ in range(mb)]
+                         for _ in start], np.int32)
+    tables[5, :4] = tables[1, :4]
+    shape = (nb, bs, kv_heads * d)
+    if layers:
+        shape = (layers,) + shape
+    k, v = (jnp.asarray(rng.normal(size=shape), jnp.float32).astype(dtype)
+            for _ in "kv")
+    q = jnp.asarray(rng.normal(size=(len(start), tc, kv_heads * groups, d)),
+                    jnp.float32)
+    return q, k, v, tables, start, lengths
+
+
+def _gathered_prefill(q, k, v, tables, start):
+    """The chunk branch's XLA path: each row's whole mapped context,
+    masked at each token's absolute position."""
+    from bigdl_tpu.nn.attention import dot_product_attention
+
+    n, tc, h, d = q.shape
+    ctx = tables.shape[1] * k.shape[1]
+    kv_heads = k.shape[2] // d
+
+    def gather(leaf):
+        x = jnp.take(leaf, tables, axis=0).astype(jnp.float32).reshape(
+            n, ctx, kv_heads, d)
+        return jnp.repeat(x, h // kv_heads, axis=2)
+
+    gpos = start[:, None] + np.arange(tc)[None, :]
+    mask = (np.arange(ctx)[None, None, :] <= gpos[:, :, None])[:, None]
+    return dot_product_attention(q, gather(k), gather(v),
+                                 mask=jnp.asarray(mask))
+
+
+class TestFlashPagedPrefillKernel:
+    @pytest.mark.parametrize("dtype,groups,tc,layers,precision", [
+        ("fp32", 1, 64, 3, "highest"),       # the GPT-2 cell's chunk
+        ("fp32", 1, 5, None, "highest"),     # the verify step's k + 1
+        ("fp32", 4, 16, 2, "highest"),
+        ("bf16", 4, 64, 4, "highest"),       # granite's heads and pool
+        ("bf16", 4, 80, None, "highest"),    # two tiles of queries
+        ("bf16", 1, 16, None, "highest"),
+        ("fp32", 1, 64, 2, "default"),       # products in bf16, one pass
+    ])
+    def test_interpret_matches_gather_path(self, dtype, groups, tc, layers,
+                                           precision):
+        """The kernel in interpreter mode against the gather path on every
+        real token, fp32 and bf16 pools, one and four query heads a KV
+        head (1024 and 512 values a pool row), a layer's own leaf and the
+        stacked one (the layer traced; bit for bit the call on that
+        layer's own leaf).  Padding tokens and the row of length 0 come
+        back finite.  At ``highest`` both paths multiply in float32; at
+        ``default`` the kernel rounds its operands to bfloat16 as the
+        chip's default product does, and differs by that rounding."""
+        from bigdl_tpu.ops.flash_attention import \
+            flash_paged_prefill_attention
+
+        kv_heads = 16 if groups == 1 else 8
+        q, k, v, tables, start, lengths = _prefill_case(
+            tc, kv_heads, groups,
+            jnp.bfloat16 if dtype == "bf16" else jnp.float32, layers)
+        with jax.default_matmul_precision(precision):
+            if layers:
+                out = jax.jit(lambda l: flash_paged_prefill_attention(
+                    q, k, v, tables, start, lengths, l, interpret=True))(
+                        jnp.int32(layers - 1))
+                k, v = k[layers - 1], v[layers - 1]
+                alone = flash_paged_prefill_attention(
+                    q, k, v, tables, start, lengths, interpret=True)
+                np.testing.assert_array_equal(np.asarray(out),
+                                              np.asarray(alone))
+            else:
+                out = flash_paged_prefill_attention(
+                    q, k, v, tables, start, lengths, interpret=True)
+        with jax.default_matmul_precision("highest"):
+            ref = _gathered_prefill(q, k, v, tables, start)
+        out, ref = np.asarray(out), np.asarray(ref)
+        assert out.shape == ref.shape == q.shape
+        assert np.isfinite(out).all()
+        real = np.arange(tc)[None, :] < lengths[:, None]
+        assert (~real).any() and not real[4].any()
+        np.testing.assert_allclose(
+            out[real], ref[real], atol=2e-5 if precision == "highest"
+            else 3e-2)
+
+    def test_rows_never_fetched_cannot_poison_a_row(self):
+        """A row after a long one computes on a buffer that still holds the
+        long one's blocks: whatever they are (here not finite), nothing of
+        them reaches its output, nor the padding tokens' or the empty
+        row's."""
+        from bigdl_tpu.ops.flash_attention import \
+            flash_paged_prefill_attention
+
+        q, k, v, tables, start, lengths = _prefill_case(16, 16, 1,
+                                                        jnp.float32, None)
+        # the long row first, then row 0 (one block), then the empty row
+        order = [5, 0, 4]
+        q, tables, start, lengths = (x[np.asarray(order)] for x in
+                                     (q, tables, start, lengths))
+        clean = flash_paged_prefill_attention(q, k, v, tables, start,
+                                              lengths, interpret=True)
+        k, v = np.array(k), np.array(v)
+        for blk in tables[0, 1:]:
+            k[blk], v[blk] = np.nan, np.inf
+        out = flash_paged_prefill_attention(q, k, v, tables, start, lengths,
+                                            interpret=True)
+        assert not np.isfinite(np.asarray(out[0])).all()
+        np.testing.assert_array_equal(np.asarray(out[1:]),
+                                      np.asarray(clean[1:]))
+        assert np.isfinite(np.asarray(out[1:])).all()
+
+    @pytest.mark.parametrize("scan", [False, True])
+    def test_chunks_then_decode_give_the_gather_paths_tokens(self, scan):
+        """A ``TransformerLM`` with two heads of 64 (one lane tile a pool
+        row) prefilled in two ragged chunks, then decoded greedily, through
+        the kernels (interpreter mode) and through the gather: the same
+        tokens, and logits that agree to float32's order of sums."""
+        layers, nb, bs = 2, 12, 8
+        tables = jnp.asarray([[3, 7, 0, 9, 1, nb], [2, 6, 5, 4, 8, nb]],
+                             jnp.int32)
+        toks = np.random.default_rng(8).integers(
+            0, VOCAB, size=(2, 11)).astype(np.int32)
+        second = np.zeros((2, 9), np.int32)
+        second[0, :2], second[1, :6] = toks[0, 9:], toks[1, 5:]
+        got = {}
+        for mode in ("never", "interpret"):
+            m = TransformerLM(vocab_size=VOCAB, hidden_size=128, num_heads=2,
+                              num_layers=layers, max_len=48,
+                              scan_layers=scan)
+            m.build(jax.ShapeDtypeStruct((2, 16), jnp.int32),
+                    rng=jax.random.PRNGKey(3))
+            for block in m.blocks:
+                block.attn.use_flash = mode
+            params = m.parameters()[0]
+            step = jax.jit(lambda p, x, c, pos, n=None: m.apply_paged(
+                p, x, c, tables, pos=pos, lengths=n))
+            pool = m.init_paged_cache(nb, bs)
+            lg1, pool = step(params, toks[:, :9], pool,
+                             jnp.asarray([0, 0], jnp.int32),
+                             jnp.asarray([9, 5], jnp.int32))
+            lg2, pool = step(params, second, pool,
+                             jnp.asarray([9, 5], jnp.int32),
+                             jnp.asarray([2, 6], jnp.int32))
+            nxt = jnp.asarray([jnp.argmax(lg2[0, 1]), jnp.argmax(lg2[1, 5])],
+                              jnp.int32)
+            pos = np.asarray([11, 11], np.int32)
+            out, logits = [nxt], [lg1, lg2]
+            for t in range(6):
+                lg, pool = step(params, nxt[:, None], pool,
+                                jnp.asarray(pos + t))
+                nxt = jnp.argmax(lg[:, 0], axis=-1).astype(jnp.int32)
+                out.append(nxt)
+                logits.append(lg)
+            got[mode] = np.asarray(jnp.stack(out)), logits
+        np.testing.assert_array_equal(got["interpret"][0], got["never"][0])
+        for a, b, real in zip(got["interpret"][1], got["never"][1],
+                              ([9, 5], [2, 6]) + ([1, 1],) * 6):
+            for row, n in enumerate(real):
+                np.testing.assert_allclose(np.asarray(a[row, :n]),
+                                           np.asarray(b[row, :n]),
+                                           atol=1e-4)
+
+
 class TestStackedPoolStaysInPlace:
     """The ``scan_layers`` layout carries the stacked pool through the layer
     loop and addresses ``(layer, block)`` on it; the unrolled layout hands
