@@ -1132,9 +1132,10 @@ class _PagedSlot:
     what the device has been ASKED for and move when a tick is
     launched; ``tokens`` and ``last`` say what has come back.
     ``riding`` counts the launched ticks that carry the slot's row and
-    have not been fetched; ``ended`` marks a request that finished (EOS,
-    abandoned) while one still did: its slot and blocks are given back
-    when ``riding`` falls to nought."""
+    have not been fetched (two where an iteration's decode tick was
+    launched before the last one was fetched); ``ended`` marks a request
+    that finished (EOS, abandoned) while one still did: its slot and
+    blocks are given back when ``riding`` falls to nought."""
 
     __slots__ = ("fut", "prompt", "seq", "consumed", "tokens", "last",
                  "pos", "seed", "launched", "riding", "ended")
@@ -1185,11 +1186,15 @@ class _Launched(NamedTuple):
 
 
 @jax.jit
-def _feed_tokens(host, src, prev):
+def _feed_tokens(host, feeds):
     """A decode tick's input tokens when some rows' newest token is still
-    on the device: row ``i`` takes ``prev[src[i]]`` (the unfetched call's
-    output) where ``src[i] >= 0``, else the host's ``host[i]``."""
-    return jnp.where(src >= 0, prev[jnp.maximum(src, 0)], host)
+    on the device: for each ``(src, prev)`` of ``feeds`` (an unfetched
+    call's ``src`` and output ``prev``, in launch order) row ``i`` takes
+    ``prev[src[i]]`` where ``src[i] >= 0``; a row no call feeds keeps the
+    host's ``host[i]``.  One or two calls: one program either way."""
+    for src, prev in feeds:
+        host = jnp.where(src >= 0, prev[jnp.maximum(src, 0)], host)
+    return host
 
 
 @jax.jit
@@ -1221,28 +1226,35 @@ class PagedGenerateScheduler(GenerateScheduler):
       chunk's latency per token, never head-of-line-blocks them.
     - Decode ticks sample in-jit per the request's ``SamplingParams``
       (greedy by default, bit-identical to the contiguous argmax).
-    - The dispatcher LAUNCHES AHEAD, one deep: tick k+1 is prepared and
-      launched before tick k's tokens are fetched, so the device's queue
-      is never empty while there is work and the host's share of a call
-      (arguments, dispatch, the transfer back, delivery) runs beside a
-      program and not between two.  At most one call is launched and
-      not fetched (``_inflight``).  A chunk tick needs nothing of the
-      tick before it; a decode tick needs each row's newest token, and
-      takes it on the device from the unfetched call's output
-      (``_feed_tokens``).  Who rides tick k+1 is known without tick k's
-      tokens for a row that ends by length; a row that may end on
-      ``eos_id`` rides regardless, and if tick k ended it, its row of
-      tick k+1 is computed and thrown away (``rows_wasted``).  A slot
-      whose request ends while a launched tick still carries its row
-      keeps its blocks and its per-slot state until that tick has been
-      fetched.  Rows are independent and program order on the device is
-      launch order, so the tokens are those of one tick at a time.
+    - The dispatcher LAUNCHES AHEAD: an iteration launches its calls
+      (a chunk tick, a decode tick, or both) before it fetches the call
+      left in flight by the iteration before, then fetches everything
+      but the last call it launched, in launch order.  So the device's
+      queue is never empty while there is work, and the host's share of
+      a call (arguments, dispatch, the transfer back, delivery) runs
+      beside a program and not between two: in an iteration of both
+      kinds, the chunk AND the decode tick are queued while the host
+      fetches and delivers the decode tick before them.
+      ``_inflight`` holds the launched and unfetched calls in launch
+      order: one between iterations, up to three inside one of both
+      kinds.  A chunk tick needs nothing of the tick before it; a decode
+      tick needs each row's newest token, and takes it on the device
+      from the unfetched calls' outputs (``_feed_tokens``: the last
+      decode tick's, and the chunk's for a prompt it ended).  Who rides
+      tick k+1 is known without tick k's tokens for a row that ends by
+      length; a row that may end on ``eos_id`` rides regardless, and if
+      tick k ended it, its row of tick k+1 is computed and thrown away
+      (``rows_wasted``).  A slot whose request ends while a launched
+      tick still carries its row keeps its blocks and its per-slot state
+      until every such tick has been fetched.  Rows are independent and
+      program order on the device is launch order, so the tokens are
+      those of one tick at a time.
 
     The executable set stays closed and warmable: ONE decode shape,
     one chunk shape per admission-batch rung, one block-copy, and the
-    token feed at each rung's length -- zero steady-state recompiles
-    across mixed lengths, chunked prefill and sampled decoding (the
-    acceptance contract, tests/test_paged.py).
+    token feed from one call and from two at each rung's length -- zero
+    steady-state recompiles across mixed lengths, chunked prefill and
+    sampled decoding (the acceptance contract, tests/test_paged.py).
     """
 
     supports_sampling = True
@@ -1288,8 +1300,9 @@ class PagedGenerateScheduler(GenerateScheduler):
         self._hit_tokens_delta = 0
         self._prompt_tokens_delta = 0
         self._seq_counter = 0
-        #: the call that has been launched and not fetched, or None
-        self._inflight = None
+        #: the calls that have been launched and not fetched, in launch
+        #: order
+        self._inflight = collections.deque()
         self._launched_ahead = 0
         self._rows_wasted = 0
         super().__init__(model, slots=slots, max_len=max_len,
@@ -1311,10 +1324,15 @@ class PagedGenerateScheduler(GenerateScheduler):
         self._slot_state = _has_slot_leaves(self._kinds)
         self._build_pool()
         # the two small programs of launching ahead, at every shape a
-        # tick can ask for: they are nobody's rung to warm
+        # tick can ask for: they are nobody's rung to warm.  A decode
+        # tick is fed by the call before it (a decode tick's output, or
+        # a chunk's at any rung) or, in an iteration that launched a
+        # chunk too, by the decode tick before it AND that chunk
         host = np.zeros((self.slots,), np.int32)
         for n in self.batch_ladder:
-            _feed_tokens(host, host - 1, np.zeros((int(n),), np.int32))
+            prev = np.zeros((int(n),), np.int32)
+            _feed_tokens(host, ((host - 1, prev),))
+            _feed_tokens(host, ((host - 1, host), (host - 1, prev)))
         self._counters()
 
     def _build_pool(self):
@@ -1417,7 +1435,7 @@ class PagedGenerateScheduler(GenerateScheduler):
 
     # ----- dispatcher ticks -------------------------------------------------- #
     def _busy(self):
-        return self._inflight is not None or super()._busy()
+        return bool(self._inflight) or super()._busy()
 
     def _release_slot(self, index, slot):
         if slot.riding:
@@ -1429,11 +1447,12 @@ class PagedGenerateScheduler(GenerateScheduler):
         super()._release_slot(index, slot)
 
     def _tick_failed(self, e, futs, extra_free):
-        """The call in flight was launched on the pool that is lost, or is
-        the one that failed: it is dropped untouched, and nothing rides
-        any more, so every slot goes back now.  The riders of both calls
-        are the active slots, and the base fails each once."""
-        self._inflight = None
+        """The calls in flight were launched on the pool that is lost, or
+        one of them is the one that failed: all are dropped untouched, and
+        nothing rides any more, so every slot goes back now.  The riders
+        of every call are the active slots, and the base fails each
+        once."""
+        self._inflight.clear()
         for _i, slot in self._active():
             slot.riding = 0
         super()._tick_failed(e, futs, extra_free)
@@ -1564,44 +1583,40 @@ class PagedGenerateScheduler(GenerateScheduler):
         """One dispatcher iteration of device work: at most ONE prefill
         chunk per currently-prefilling sequence, then one decode tick
         over every decoding slot -- the interleave that keeps chunked
-        prefill from starving live streams.  Each is launched before
-        the call ahead of it is fetched; with nothing to launch, what
-        is in flight is fetched."""
-        idle = True
+        prefill from starving live streams.  Both are launched before
+        anything is fetched; then every call in flight but the last one
+        launched is fetched, while the device runs what follows it.
+        With nothing to launch, what is in flight is fetched."""
+        newest = None
         if any(s.prefilling and not s.ended for _i, s in self._active()):
-            idle = False
-            self._run_chunk_tick(qdepth)
+            newest = self._launch_chunk(qdepth)
         # asked again: a chunk just launched may have ended a prompt
         if any(s.decoding for _i, s in self._active()):
-            idle = False
-            self._run_decode_tick(qdepth)
-        if idle:
-            self._land(None)
-
-    def _run_chunk_tick(self, qdepth):
-        self._land(self._launch_chunk(qdepth))
+            newest = self._run_decode_tick(qdepth)
+        self._land(newest)
 
     def _run_decode_tick(self, qdepth):
-        self._land(self._launch_decode(qdepth))
+        """Launch one decode tick; the call, or None where the launch
+        failed or (the speculative round) nothing is left in flight."""
+        return self._launch_decode(qdepth)
 
-    def _land(self, launched):
-        """``launched`` (a call just launched, or None) becomes the call
-        in flight, and the one that was is fetched, delivered and
-        recorded while the device runs the new one."""
-        ahead, self._inflight = self._inflight, launched
-        if ahead is not None:
-            self._complete(ahead)
+    def _land(self, newest):
+        """Fetch, deliver and record, in launch order, every call in
+        flight but ``newest`` (the last call this iteration launched; with
+        None, all of them) while the device runs the calls after it."""
+        while self._inflight and self._inflight[0] is not newest:
+            self._complete(self._inflight.popleft())
 
     def _launch_chunk(self, qdepth):
         """Prepare and launch one chunk of every prefilling row; None
         where the launch failed (``_tick_failed`` has run)."""
         execs_before = self._compiles()
-        ahead = self._inflight
+        ahead = len(self._inflight)
         rows = [(i, s) for i, s in self._active()
                 if s.prefilling and not s.ended]
         n = len(rows)
         with span("prefill_prep", rows=n, slots_total=self.slots,
-                  ahead=int(ahead is not None)) as prep:
+                  ahead=ahead) as prep:
             bucket = self.batch_ladder.bucket_for(n) \
                 or self.batch_ladder.add(n)
             tc = self.prefill_chunk
@@ -1633,7 +1648,7 @@ class PagedGenerateScheduler(GenerateScheduler):
                      tokens_computed=int(bucket) * int(tc),
                      context_tokens=int(start.sum()),
                      rows_sampling=int((knobs[0] > 0).sum()))
-        tick = self._tick + (ahead is not None)
+        tick = self._tick + ahead
         try:
             with span("generate_prefill", tick=tick, records=n):
                 with span("launch"):
@@ -1660,27 +1675,31 @@ class PagedGenerateScheduler(GenerateScheduler):
                 s.pos = int(s.prompt.size)
                 s.launched = 1
                 riding.append((i, s, r))
-        self._launched_ahead += ahead is not None
-        return _Launched("prefill", tick, riding, first, counted,
+        self._launched_ahead += ahead > 0
+        call = _Launched("prefill", tick, riding, first, counted,
                          prep.start_ns, qdepth, execs_before,
                          dict(records=n, bucket=int(bucket),
                               prompt_bucket=tc))
+        self._inflight.append(call)
+        return call
 
     def _launch_decode(self, qdepth):
         """Prepare and launch one token of every decoding row; None where
-        the launch failed.  A row whose newest token is in the unfetched
+        the launch failed.  A row whose newest token is in an unfetched
         call's output is fed it on the device."""
         execs_before = self._compiles()
         s_n = self.slots
-        ahead = self._inflight
-        unfetched = {} if ahead is None else \
-            {i: at for i, _s, at in ahead.rows if at is not None}
+        ahead = list(self._inflight)
+        # a row's newest token, where a call not yet fetched holds it:
+        # (that call, the row's index in its output); the later call wins
+        unfetched = {i: (c, at) for c, call in enumerate(ahead)
+                     for i, _s, at in call.rows if at is not None}
         active = [(i, s) for i, s in self._active() if s.decoding]
         with span("decode_prep", rows=len(active), slots_total=s_n,
-                  ahead=int(ahead is not None)) as prep:
+                  ahead=len(ahead)) as prep:
             mb = self.max_blocks_per_seq
             tokens = np.zeros((s_n,), np.int32)
-            src = np.full((s_n,), -1, np.int32)
+            srcs = [np.full((s_n,), -1, np.int32) for _c in ahead]
             pos = np.zeros((s_n,), np.int32)
             tables = np.full((s_n, mb), self._alloc.trash, np.int32)
             knobs = self._sampling_rows(s_n)
@@ -1688,7 +1707,8 @@ class PagedGenerateScheduler(GenerateScheduler):
             for i, s in active:
                 self._cow_guard(s, s.pos, s.pos)
                 if i in unfetched:
-                    src[i] = unfetched[i]
+                    c, at = unfetched[i]
+                    srcs[c][i] = at
                 else:
                     tokens[i] = s.last
                 pos[i] = s.pos
@@ -1696,18 +1716,20 @@ class PagedGenerateScheduler(GenerateScheduler):
                     slot_ids[0][i] = i
                 tables[i] = self._alloc.table_row(s.seq, mb)
                 self._fill_sampling(knobs, i, s)
-            rows_ahead = int((src >= 0).sum())
+            feeds = tuple((src, call.out) for src, call in zip(srcs, ahead)
+                          if (src >= 0).any())
+            rows_ahead = sum(int((src >= 0).sum()) for src, _out in feeds)
             # the cache rows this tick's attention reads: every live
             # slot's positions up to and with the one it writes
             prep.set(context_tokens=int(pos.sum()) + len(active),
                      rows_sampling=int((knobs[0] > 0).sum()),
                      rows_ahead=rows_ahead)
-        tick = self._tick + (ahead is not None)
+        tick = self._tick + len(ahead)
         try:
             with span("generate_decode", tick=tick, records=len(active)):
                 with span("launch"):
-                    if rows_ahead:
-                        tokens = _feed_tokens(tokens, src, ahead.out)
+                    if feeds:
+                        tokens = _feed_tokens(tokens, feeds)
                     nxt, self._cache = self._decode_fn(
                         self._params(), self._cache, tokens, pos, tables,
                         *knobs, *slot_ids)
@@ -1720,15 +1742,17 @@ class PagedGenerateScheduler(GenerateScheduler):
             s.pos += 1
             s.launched += 1
             s.riding += 1
-        self._launched_ahead += ahead is not None
-        return _Launched("decode", tick, [(i, s, i) for i, s in active],
+        self._launched_ahead += bool(ahead)
+        call = _Launched("decode", tick, [(i, s, i) for i, s in active],
                          nxt, counted, prep.start_ns, qdepth, execs_before,
                          dict(records=0, slots_before=len(active)))
+        self._inflight.append(call)
+        return call
 
     def _complete(self, call):
         """Fetch a launched call's tokens, deliver them and record the
-        tick.  A failure surfaces here, one call late, when the next call
-        is already launched on a pool that is lost with this one."""
+        tick.  A failure surfaces here, late, when the calls after this
+        one are already launched on a pool that is lost with it."""
         try:
             with span("generate_" + call.kind, tick=call.tick,
                       records=len(call.rows)):

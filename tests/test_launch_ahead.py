@@ -1,20 +1,26 @@
 """The paged dispatcher launches tick k+1 before it fetches tick k
-(``PagedGenerateScheduler``, one call deep).  What has to hold:
+(``PagedGenerateScheduler``); an iteration that launches a chunk and a
+decode tick launches both before it fetches the decode tick before them.
+What has to hold:
 
 - the tokens are those of the same requests served one at a time (rows
   are independent, launch order is program order), on a transformer and
   on a model with per-slot state and counters, whose ``tick_counters``
-  spans count the same rows either way;
-- it engages: in steady state call k+1 is made before fetch k;
+  spans count the same rows either way, also where prompts arrive while
+  other rows decode;
+- it engages: in steady state call k+1 is made before fetch k, and beside
+  a chunk the decode tick is made before the last decode tick is fetched;
 - a request that ends (EOS, abandoned) while a launched call carries its
   row keeps its blocks until that call has been fetched, and the row is
   counted as wasted; a ``cancel()`` of a running request changes nothing;
-- a failure surfaces one call late and fails every rider once;
-- ``drain()`` and ``close()`` wait for the call in flight.
+- a failure surfaces late and fails every rider once, also with two calls
+  queued behind the one that failed;
+- ``drain()`` and ``close()`` wait for every call in flight.
 """
 
 import os
 import sys
+import threading
 import time
 
 import jax
@@ -98,11 +104,23 @@ def _loads(recs):
             for k in ("rows_routed", "rows_here")}
 
 
-@pytest.mark.parametrize("name,eos", [("lm", True), ("lm_scan", True),
-                                      ("ling", False), ("ling", True)])
-def test_a_mixed_batch_equals_one_request_at_a_time(models, name, eos):
+@pytest.mark.parametrize("name,eos,staggered", [
+    pytest.param("lm", True, False, id="lm-True"),
+    pytest.param("lm_scan", True, False, id="lm_scan-True"),
+    pytest.param("ling", False, False, id="ling-False"),
+    pytest.param("ling", True, False, id="ling-True"),
+    pytest.param("lm", True, True, id="lm-True-staggered")])
+def test_a_mixed_batch_equals_one_request_at_a_time(models, name, eos,
+                                                    staggered):
     model, vocab = models(name)
     reqs = _requests(vocab, eos)
+    if staggered:
+        # greedy rows only, sent once a long answer decodes: a prompt's
+        # last chunk shares its iteration with that row's decode tick, so
+        # the decode tick takes its tokens from two unfetched calls
+        long = np.random.default_rng(12).integers(0, vocab, 4)
+        reqs = [(long.astype(np.int32), 56, {}, None)] + [
+            (prompt, new, {}, stop) for prompt, new, _kw, stop in reqs]
     # one at a time, and from that pass the token each stopping row stops on
     before = len(recorder().snapshot())
     alone = []
@@ -120,8 +138,11 @@ def test_a_mixed_batch_equals_one_request_at_a_time(models, name, eos):
     # together: more requests than slots, so rows join and leave mid-flight
     before = len(recorder().snapshot())
     with _engine(model, 3) as eng:
-        futs = [eng.generate(prompt, max_new_tokens=new, **kw)
-                for prompt, new, kw, _stop in reqs]
+        futs = []
+        for prompt, new, kw, _stop in reqs:
+            futs.append(eng.generate(prompt, max_new_tokens=new, **kw))
+            if staggered and len(futs) == 1:
+                next(futs[0].stream(600))
         together = [f.result(600) for f in futs]
         assert eng._generation().drain(60)
         stats = eng._generation().stats()
@@ -135,6 +156,14 @@ def test_a_mixed_batch_equals_one_request_at_a_time(models, name, eos):
     assert wasted == stats["rows_wasted"]
     if not eos:
         assert wasted == 0
+    if staggered:
+        # a decode tick was launched with the decode tick before it and a
+        # chunk unfetched, and fed rows from both
+        assert any(r.attrs["ahead"] == 2 and r.attrs["rows_ahead"]
+                   for r in recs if r.name == "decode_prep")
+        # every row that stopped on its eos_id short of its budget rode
+        # the call launched before its stop was fetched, once
+        assert wasted == sum(f.finish_reason == "eos" for f in futs) > 0
     if name == "ling" and not eos:
         # the counters come back with each tick's tokens, whichever call
         # has the pool by then: the same rows were routed, to the same
@@ -145,35 +174,81 @@ def test_a_mixed_batch_equals_one_request_at_a_time(models, name, eos):
     assert any(r.attrs["rows_ahead"] for r in recs if r.name == "decode_prep")
 
 
-def test_the_next_call_is_made_before_the_last_is_fetched(models):
+def _alone(eng, gate):
+    """One prompt of three chunks, then eleven decode ticks."""
+    gate.set()
+    return [(eng.generate(np.arange(1, 20, dtype=np.int32),
+                          max_new_tokens=12), 12)]
+
+
+def _beside_a_decode(eng, gate):
+    """A prompt of five chunks sent while another row decodes; the
+    dispatcher waits in its second fetch until it is sent, so it is
+    admitted in the third iteration."""
+    first = eng.generate(np.arange(1, 5, dtype=np.int32), max_new_tokens=40)
+    next(first.stream(120))                             # it decodes
+    second = eng.generate(np.arange(5, 45, dtype=np.int32), max_new_tokens=6)
+    gate.set()
+    return [(first, 40), (second, 6)]
+
+
+@pytest.mark.parametrize("send,at_fetch,mixed,calls", [
+    # steady state: two calls made before the first fetch, then one call
+    # a fetch, until there is nothing left to launch; the iteration of
+    # the last chunk launches the first decode tick too before it fetches
+    # the chunk before them.  3 chunks and 11 decode ticks
+    pytest.param(_alone, [2, 3] + [2] * 11 + [1], 0, 14, id="alone"),
+    # each of the five chunks shares its iteration with a decode tick,
+    # made before the decode tick ahead of the chunk is fetched: three
+    # calls unfetched at that fetch.  1 + 5 chunks and 39 decode ticks
+    pytest.param(_beside_a_decode, [2, 2] + [3, 2] * 5 + [2] * 32 + [1], 5,
+                 45, id="beside_a_decode")])
+def test_the_next_call_is_made_before_the_last_is_fetched(
+        models, send, at_fetch, mixed, calls):
     model, _vocab = models("lm")
+    gate = threading.Event()
     with _engine(model, 2) as eng:
         sched = eng._generation()
-        log = []
+        log = []          # ("call", kind, out) and ("fetch", out)
 
-        def logged(name, fn):
+        def logged(kind, fn):
             def wrapper(*a, **k):
-                log.append(name)
-                return fn(*a, **k)
+                out = fn(*a, **k)
+                log.append(("call", kind, out[0]))
+                return out
             return wrapper
 
-        sched._chunk_fn = logged("call", sched._chunk_fn)
-        sched._decode_fn = logged("call", sched._decode_fn)
-        sched._fetch = logged("fetch", sched._fetch)
-        out = eng.generate(np.arange(1, 20, dtype=np.int32),
-                           max_new_tokens=12).result(120)
+        def fetch(tokens, counted):
+            log.append(("fetch", tokens))
+            if sum(what[0] == "fetch" for what in log) == 2:
+                assert gate.wait(60)
+            return real_fetch(tokens, counted)
+
+        real_fetch = sched._fetch
+        sched._chunk_fn = logged("chunk", sched._chunk_fn)
+        sched._decode_fn = logged("decode", sched._decode_fn)
+        sched._fetch = fetch
+        for fut, n in send(eng, gate):
+            assert len(fut.result(120)) == n
         assert sched.drain(30)
         stats = sched.stats()
-    assert len(out) == 12
-    # 3 chunks and 11 decode ticks, each fetched once
-    assert log.count("call") == log.count("fetch") == 14
-    # steady state: two calls made before the first fetch, then one call a
-    # fetch, until there is nothing left to launch
-    at_fetch = [log[:i].count("call") - log[:i].count("fetch")
-                for i, what in enumerate(log) if what == "fetch"]
-    assert at_fetch[:-1] == [2] * 13 and at_fetch[-1] == 1
-    assert stats["launched_ahead"] == 13
-    assert stats["ticks"] == 14 and stats["rows_wasted"] == 0
+    counts, unfetched, made = [], [], 0     # (kind, out) launched, not fetched
+    for what in log:
+        if what[0] == "fetch":
+            counts.append(len(unfetched))
+            # in launch order
+            assert unfetched.pop(0)[1] is what[1]
+            continue
+        if what[1] == "decode" \
+                and [k for k, _o in unfetched] == ["decode", "chunk"]:
+            made += 1
+        unfetched.append(what[1:])
+    assert unfetched == []
+    assert counts == at_fetch and made == mixed
+    # every call but the first launched with another in flight
+    assert stats["launched_ahead"] == calls - 1
+    assert stats["ticks"] == calls and stats["rows_wasted"] == 0
+    assert stats["kv"]["blocks_used"] == 0
 
 
 @pytest.mark.parametrize("how", ["eos", "abandon", "cancel"])
@@ -189,9 +264,8 @@ def test_a_row_in_flight_keeps_its_blocks(models, how):
         free = sched._alloc.free_sequence
 
         def checked(seq):
-            call = sched._inflight
-            if call is not None and any(s.seq == seq
-                                        for _i, s, _at in call.rows):
+            if any(s.seq == seq for call in sched._inflight
+                   for _i, s, _at in call.rows):
                 freed_while_riding.append(seq)
             return free(seq)
 
@@ -238,7 +312,7 @@ def test_a_row_in_flight_keeps_its_blocks(models, how):
     assert stats["kv"]["blocks_used"] == 0 and stats["slots_active"] == 0
 
 
-@pytest.mark.parametrize("where", ["launch", "fetch"])
+@pytest.mark.parametrize("where", ["launch", "fetch", "fetch_ahead_of_two"])
 def test_a_failure_one_call_late_fails_every_rider_once(models, where):
     model, _vocab = models("lm")
     prompts = [np.arange(1, 10, dtype=np.int32),
@@ -252,8 +326,17 @@ def test_a_failure_one_call_late_fails_every_rider_once(models, where):
         calls = []
 
         def boom(*a, **k):
-            calls.append(sched._inflight is not None)
-            if len(calls) == 3:
+            # the calls launched and not fetched besides the one at hand
+            calls.append(len(sched._inflight))
+            if where == "fetch_ahead_of_two":
+                # the second fetch with two calls queued behind it: the
+                # first decode tick, behind which the next iteration has
+                # launched its chunk and its decode tick (the first such
+                # fetch is the first chunk's)
+                fail = calls[-1] == 2 and calls.count(2) == 2
+            else:
+                fail = len(calls) == 3
+            if fail:
                 raise RuntimeError("injected, a call late")
             return good(*a, **k)
 
@@ -268,17 +351,20 @@ def test_a_failure_one_call_late_fails_every_rider_once(models, where):
                          if isinstance(x, BaseException)])
         assert all(len(s) == 1 for s in seen)
         # the failing call was made with another one launched and unfetched
-        assert calls[2] is True
+        if where == "fetch_ahead_of_two":
+            assert calls.count(2) == 2 and calls[-1] == 2
+        else:
+            assert calls[2] >= 1
         setattr(sched, attr, good)
         assert sched.drain(30)
-        assert sched._inflight is None
+        assert not sched._inflight
         assert sched._cache is not pool_before
         stats = sched.stats()
         assert stats["kv"]["blocks_used"] == 0 and stats["slots_active"] == 0
         assert eng.generate(prompts[0], max_new_tokens=6).result(120) == ref
 
 
-@pytest.mark.parametrize("how", ["drain", "close"])
+@pytest.mark.parametrize("how", ["drain", "close", "close_two_in_flight"])
 def test_drain_and_close_wait_for_the_call_in_flight(models, how):
     model, _vocab = models("lm")
     before = len(recorder().snapshot())
@@ -286,8 +372,11 @@ def test_drain_and_close_wait_for_the_call_in_flight(models, how):
     try:
         sched = eng._generation()
         real = sched._fetch
+        two = threading.Event()
 
         def slow(*a, **k):
+            if len(sched._inflight) == 2:
+                two.set()          # a chunk and a decode tick behind it
             time.sleep(0.03)
             return real(*a, **k)
 
@@ -295,14 +384,22 @@ def test_drain_and_close_wait_for_the_call_in_flight(models, how):
         fut = eng.generate(np.arange(1, 8, dtype=np.int32),
                            max_new_tokens=10)
         next(fut.stream(60))                 # calls are being launched
+        futs, want = [fut], [10]
+        if how == "close_two_in_flight":
+            # a prompt of four chunks beside the decoding row: close while
+            # a fetch has two calls queued behind it
+            futs.append(eng.generate(np.arange(8, 40, dtype=np.int32),
+                                     max_new_tokens=4))
+            want.append(4)
+            assert two.wait(60)
         if how == "drain":
             assert sched.drain(60)
         else:
             eng.close()
             assert not sched._dispatcher.is_alive()
             assert sched._cache is None
-        assert sched._inflight is None
-        assert len(fut.result(0)) == 10
+        assert not sched._inflight
+        assert [len(f.result(0)) for f in futs] == want
     finally:
         eng.close()
     # the dispatcher never went idle between a launch and its fetch

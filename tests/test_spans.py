@@ -385,6 +385,30 @@ class TestServingSpans:
         assert max(r.attrs["queue_wait_ns"] for r in reqs.values()) \
             > min(r.attrs["prefill_ns"] for r in reqs.values())
 
+    def test_a_decode_tick_beside_a_chunk_counts_both_calls_ahead(self):
+        """A prompt prefills while another row decodes: each of those
+        iterations launches its decode tick with the decode tick before it
+        and its chunk unfetched (``ahead`` 2), and the decoding row's token
+        is taken on the device from the first (``rows_ahead``)."""
+        with ServingEngine(_lm(), decode_slots=2, decode_max_len=32,
+                           kv_block_size=4, prefill_chunk=4) as eng:
+            first = eng.generate([1, 2, 3], max_new_tokens=16)
+            next(first.stream(120))                  # it decodes
+            second = eng.generate(list(range(4, 16)), max_new_tokens=2)
+            assert len(second.result(120)) == 2
+            assert len(first.result(120)) == 16
+        recs = recorder().snapshot()
+        ahead = {name: {r.attrs["ahead"] for r in recs if r.name == name}
+                 for name in ("prefill_prep", "decode_prep")}
+        assert ahead["prefill_prep"] <= {0, 1}
+        assert ahead["decode_prep"] <= {0, 1, 2}
+        two = [r.attrs for r in recs
+               if r.name == "decode_prep" and r.attrs["ahead"] == 2]
+        # one a chunk of the second prompt; the last chunk's decode tick
+        # also takes the second row's first token from that chunk
+        assert [(a["rows"], a["rows_ahead"]) for a in two] \
+            == [(1, 1), (1, 1), (2, 2)]
+
     def test_a_failed_request_is_recorded_once_with_its_reason(self):
         with ServingEngine(_lm(), decode_slots=2, decode_max_len=32,
                            kv_block_size=4, kv_blocks=2) as eng:

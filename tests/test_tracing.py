@@ -538,7 +538,9 @@ class TestExemplars:
             spans = _wait_spans(tmp_path, {"fleet_request"})
         finally:
             fleet.close()
-        tid = spans[-1]["trace"]
+        # the request's trace: a tick span of its own trace may be
+        # written after it
+        tid = [s for s in spans if s["name"] == "fleet_request"][-1]["trace"]
         out = reg.render()
         assert "bigdl_serving_request_latency_seconds_bucket" in out
         assert 'trace_id="%s"' % tid in out
