@@ -53,8 +53,8 @@ class MultiHeadAttention(Module):
 
     ``num_kv_heads`` (default: ``num_heads``) makes it GROUPED-QUERY
     attention: query head ``g`` reads key/value head ``g // groups``,
-    ``groups = num_heads // num_kv_heads``; K and V are projected, cached
-    and paged at ``num_kv_heads * head_dim`` (``kv_width``) and the paged
+    ``groups = num_heads // num_kv_heads``; K and V are projected and
+    paged at ``num_kv_heads * head_dim`` (``kv_width``) and the paged
     decode kernel fetches a block once for a group's query heads.
     ``bias=False`` leaves the two biases out of the parameter tree;
     ``scale`` replaces the softmax scale ``head_dim ** -0.5`` (the
@@ -78,8 +78,8 @@ class MultiHeadAttention(Module):
         assert num_heads % self.num_kv_heads == 0, \
             (num_heads, self.num_kv_heads)
         self.groups = num_heads // self.num_kv_heads
-        #: what a token's K (and V) row holds: the width the caches and
-        #: the paged pool are stored at
+        #: what a token's K (and V) row holds: the width the paged pool
+        #: is stored at
         self.kv_width = self.num_kv_heads * self.head_dim
         assert self.groups == 1 or seq_axis_name is None, \
             "grouped-query attention has no sequence-parallel path"
@@ -95,10 +95,9 @@ class MultiHeadAttention(Module):
         self.seq_mode = seq_mode
         #: "auto": the Pallas flash kernel (ops/flash_attention.py) on TPU
         #: when T is block-aligned (the forward streams K/V a block at a
-        #: time, so no length is too long for it; the contiguous decode
-        #: kernel keeps a head's whole K/V in VMEM and also asks
-        #: ``kv_blocks_fit``; the paged one streams a slot's blocks and
-        #: asks only that a block be whole tiles); plain attention
+        #: time, so no length is too long for it; the paged kernels
+        #: stream a slot's blocks and ask only that a block be whole
+        #: tiles); plain attention
         #: otherwise.  "interpret" forces the kernel in
         #: interpreter mode (CPU tests).
         self.use_flash = use_flash
@@ -115,11 +114,6 @@ class MultiHeadAttention(Module):
         if t < 128:
             return t % 8 == 0
         return t % 128 == 0
-
-    def _kv_fit(self, rows, dtype):
-        from bigdl_tpu.ops.flash_attention import kv_blocks_fit
-
-        return kv_blocks_fit(rows, self.head_dim, dtype)
 
     def _flash_ok(self, t):
         if self.use_flash == "never" or self.seq_axis_name is not None:
@@ -163,7 +157,7 @@ class MultiHeadAttention(Module):
 
     def _project_qkv(self, params, input):
         """Fused qkv projection; ONE implementation for the full-sequence
-        and cached (prefill/decode) paths, so the int8 branch covers
+        and paged (chunk prefill/decode) paths, so the int8 branch covers
         generation with no second code path."""
         dt = input.dtype
         if "qkv_weight_q" in params:
@@ -190,119 +184,16 @@ class MultiHeadAttention(Module):
         return self._with_bias(matmul_t(y, params["out_weight"]),
                                params, "out_bias")
 
-    # ----- KV-cache decode mode -------------------------------------------- #
-    def init_cache(self, batch: int, max_len: int, dtype=jnp.float32):
-        """Per-layer K/V buffers for autoregressive decode: fixed-shape
-        ``(batch, max_len, heads, head_dim)`` zero tensors the cached
-        ``apply`` fills with ``dynamic_update_slice`` writes.  Fixed
-        shapes are the whole point -- every decode step reuses ONE
-        compiled executable regardless of how many tokens are live
-        (docs/performance.md, "Generation serving")."""
-        shape = (batch, int(max_len), self.num_kv_heads, self.head_dim)
-        return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
-
-    def _flash_decode_ok(self, max_len, dtype=jnp.float32):
-        if self.use_flash == "never" or self.seq_axis_name is not None:
-            return False
-        # the decode kernel tiles the cache with block_k = min(128,
-        # max_len): a cache at or under 128 is one block, a longer one
-        # must tile exactly -- this gates the FORCED modes too, or an
-        # unaligned decode_max_len would trip the kernel's assert on
-        # every tick instead of quietly taking the plain path
-        if max_len > 128 and max_len % 128:
-            return False
-        if self.use_flash in ("always", "interpret"):
-            return True
-        return _on_tpu() and self._kv_fit(max_len, dtype)
-
-    def _apply_cached(self, params, input, cache, pos):
-        """Incremental attention against a K/V cache.
-
-        Two shapes, one contract (returns ``(y, new_cache)``):
-
-        - PREFILL (``pos is None``): ``input`` is the whole (padded)
-          prompt ``(N, T, D)``; K/V are written at positions ``[0, T)``
-          and attention is plain causal over the prompt itself --
-          identical math to the full-sequence path, so prefill logits
-          ARE full-forward logits.
-        - DECODE (``pos`` an ``(N,)`` int vector): ``input`` is ONE
-          token per row ``(N, 1, D)``; row ``i``'s K/V land at
-          ``pos[i]`` (a per-row ``dynamic_update_slice``) and attention
-          masks ``kpos <= pos[i]``, so stale positions beyond the
-          frontier -- a previous occupant's K/V, or prompt padding not
-          yet overwritten -- are invisible until the decode write that
-          replaces them makes them real.  Rows only ever write their
-          OWN cache row, which is what lets a slot scheduler run
-          inactive slots as harmless garbage instead of recompiling.
-        """
-        n, t, d = input.shape
-        dt = input.dtype
-        q, k, v = self._split_qkv(self._project_qkv(params, input))
-        kv_shape = (n, t, self.num_kv_heads, self.head_dim)
-        q = q.reshape(n, t, self.num_heads, self.head_dim)
-        k, v = k.reshape(kv_shape), v.reshape(kv_shape)
-        cdt = cache["k"].dtype
-        if pos is None:                                   # prefill
-            max_len = cache["k"].shape[1]
-            if t > max_len:
-                raise ValueError(
-                    f"prompt length {t} exceeds the cache's max_len "
-                    f"{max_len}")
-            new_cache = {"k": cache["k"].at[:, :t].set(k.astype(cdt)),
-                         "v": cache["v"].at[:, :t].set(v.astype(cdt))}
-            # forced flash modes bypass _flash_ok's block gate, but a
-            # prompt rung that doesn't tile (e.g. an unaligned
-            # decode_max_len on the ladder) would trip the kernel's
-            # shape assert on every prefill -- take the plain path
-            if self._flash_ok(t) and self._flash_block_ok(t):
-                from bigdl_tpu.ops.flash_attention import flash_attention
-
-                y = flash_attention(q, self._grouped(k), self._grouped(v),
-                                    causal=self.causal,
-                                    interpret=self.use_flash == "interpret")
-            else:
-                y = dot_product_attention(q, self._grouped(k),
-                                          self._grouped(v),
-                                          causal=self.causal)
-        else:                                             # one-token step
-            if t != 1:
-                raise ValueError(
-                    f"decode steps take one token per row, got T={t}")
-            pos = jnp.asarray(pos, jnp.int32)
-            write = jax.vmap(
-                lambda c, new, p: jax.lax.dynamic_update_slice(
-                    c, new, (p, 0, 0)))
-            new_cache = {"k": write(cache["k"], k.astype(cdt), pos),
-                         "v": write(cache["v"], v.astype(cdt), pos)}
-            max_len = cache["k"].shape[1]
-            ck = self._grouped(new_cache["k"].astype(dt))
-            cv = self._grouped(new_cache["v"].astype(dt))
-            if self._flash_decode_ok(max_len, dt):
-                from bigdl_tpu.ops.flash_attention import \
-                    flash_decode_attention
-
-                y = flash_decode_attention(
-                    q, ck, cv, pos, interpret=self.use_flash == "interpret")
-            else:
-                # scores (N, H, 1, max_len); the position mask broadcasts
-                # over heads and the single query row
-                mask = (jnp.arange(max_len)[None, :]
-                        <= pos[:, None])[:, None, None, :]
-                y = dot_product_attention(q, ck, cv, mask=mask)
-        y = y.reshape(n, t, d)
-        return self._project_out(params, y, dt), new_cache
-
     # ----- paged KV-cache decode mode --------------------------------------- #
     def init_paged_cache(self, num_blocks: int, block_size: int,
                          dtype=jnp.float32):
         """Per-layer K/V BLOCK POOL for paged decode: fixed-shape
         ``(num_blocks, block_size, kv_heads * head_dim)`` zero tensors that
         ``_apply_paged`` reads and writes THROUGH per-sequence block
-        tables (serving/paging.py).  Unlike ``init_cache`` the leading
-        axis is physical blocks, not slots: memory scales with tokens
-        actually resident, not ``slots x max_len`` worst case.  The
-        caller includes the trash block in ``num_blocks`` (by
-        convention the last id).
+        tables (serving/paging.py).  The leading axis is physical
+        blocks, not slots: memory scales with tokens actually resident,
+        not ``slots x max_len`` worst case.  The caller includes the
+        trash block in ``num_blocks`` (by convention the last id).
 
         Block ``b`` of this layer is ``leaf[b]``.  A ``scan_layers``
         model stacks its layers' leaves (``TransformerLM.
@@ -411,7 +302,7 @@ class MultiHeadAttention(Module):
         no program copies the pool.  ``new_pool`` has the shape of
         ``pool`` either way.
 
-        Two shapes, mirroring ``_apply_cached``:
+        Two shapes:
 
         - CHUNK PREFILL (``lengths`` an ``(N,)`` int vector): ``input``
           is one fixed-size chunk per row ``(N, Tc, D)`` whose first
@@ -429,8 +320,9 @@ class MultiHeadAttention(Module):
         - DECODE (``lengths is None``): ``input`` is one token per row
           ``(N, 1, D)`` written at ``pos[i]``; rows whose table is all
           trash (empty slots, rows mid-prefill) write garbage into the
-          trash block and read garbage out -- harmless by the same
-          frontier argument as the contiguous slot pool.  Where
+          trash block and read garbage out -- harmless, because a live
+          row masks every position past its frontier and never maps the
+          trash block below it.  Where
           ``_flash_paged_ok`` admits the pool, the decode kernel reads a
           row's blocks up to ``pos``; otherwise the gather and mask as
           above.
@@ -539,13 +431,7 @@ class MultiHeadAttention(Module):
         y = y.reshape(n, t, d)
         return self._project_out(params, y, dt), new_pool
 
-    def apply(self, params, state, input, *, training=False, rng=None,
-              cache=None, pos=None):
-        if cache is not None:
-            # decode mode returns (output, updated_cache) -- the cache
-            # takes the state slot (these eval-mode paths carry no
-            # module state); see _apply_cached
-            return self._apply_cached(params, input, cache, pos)
+    def apply(self, params, state, input, *, training=False, rng=None):
         n, t, d = input.shape
         dt = input.dtype
         q, k, v = self._split_qkv(self._project_qkv(params, input))
@@ -725,10 +611,6 @@ class TransformerBlock(Container):
         return [("ln1", self.ln1), ("attn", self.attn), ("ln2", self.ln2),
                 ("fc1", self.fc1), ("fc2", self.fc2)]
 
-    def init_cache(self, batch: int, max_len: int, dtype=jnp.float32):
-        """This block's K/V decode cache (the attention sublayer's)."""
-        return self.attn.init_cache(batch, max_len, dtype)
-
     def init_paged_cache(self, num_blocks: int, block_size: int,
                          dtype=jnp.float32):
         """This block's paged K/V pool (the attention sublayer's)."""
@@ -756,17 +638,7 @@ class TransformerBlock(Container):
             h, _ = self.fc2.apply(params["fc2"], (), h)
             return x + h
 
-    def apply(self, params, state, input, *, training=False, rng=None,
-              cache=None, pos=None):
-        if cache is not None:
-            # cached prefill/decode: eval-mode block, returns
-            # (out, new_cache) like MultiHeadAttention's cached apply
-            with jax.named_scope("attention"):
-                h, _ = self.ln1.apply(params["ln1"], (), input)
-                a, new_cache = self.attn.apply(params["attn"], (), h,
-                                               cache=cache, pos=pos)
-                x = input + a
-            return self._mlp(params, x), new_cache
+    def apply(self, params, state, input, *, training=False, rng=None):
         with jax.named_scope("attention"):
             h, _ = self.ln1.apply(params["ln1"], (), input)
             a, _ = self.attn.apply(params["attn"], (), h, training=training,
@@ -879,27 +751,6 @@ class TransformerLM(Container):
                              ("fc1", "weight"), ("fc2", "weight"))] \
             + [("head",)]
 
-    # ----- KV-cache decode mode -------------------------------------------- #
-    def init_cache(self, batch: int, max_len: Optional[int] = None,
-                   dtype=jnp.float32):
-        """Per-layer K/V decode buffers in THIS model's param layout:
-        unrolled models return ``{"block{i}": {"k", "v"}}``;
-        ``scan_layers`` models return ``{"blocks": {"k", "v"}}`` with
-        every leaf gaining a leading layer axis (``stack_layer_trees``,
-        the same convention the params use), so the decode loop scans
-        layers exactly like the forward does.  ``max_len`` caps how far
-        a sequence can ever grow (prompt + generated tokens) and is the
-        fixed time extent of every buffer; it defaults to the model's
-        ``max_len`` but serving usually passes something smaller --
-        cache bytes scale linearly with it."""
-        max_len = self.max_len if max_len is None else int(max_len)
-        if max_len > self.max_len:
-            raise ValueError(
-                f"cache max_len {max_len} exceeds the model's positional "
-                f"table ({self.max_len})")
-        return self._layer_caches(
-            lambda b: b.init_cache(batch, max_len, dtype))
-
     def _layer_caches(self, init):
         """``init(block)`` for every block, in this model's layout.  The
         stacked layout is allocated directly (the blocks are alike and a
@@ -916,7 +767,7 @@ class TransformerLM(Container):
                          dtype=jnp.float32):
         """Per-layer paged K/V pools in THIS model's param layout
         (``"block{i}"`` unrolled / stacked ``"blocks"`` under
-        ``scan_layers``, mirroring ``init_cache``).  ``num_blocks`` is
+        ``scan_layers``, as the params are laid out).  ``num_blocks`` is
         the allocator's pool size; every layer gets ONE EXTRA block on
         top -- the TRASH block, id ``num_blocks`` -- that padded table
         entries and inactive rows write into (serving/paging.py).
@@ -1011,52 +862,7 @@ class TransformerLM(Container):
             x, _ = self.ln_f.apply(params["ln_f"], (), x)
             return matmul_t(x, params["head"])
 
-    def _apply_cached(self, params, input, cache, pos):
-        """Prefill (``pos=None``: whole padded prompt, K/V written at
-        ``[0, T)``) or single-token decode (``pos`` (N,): one token per
-        row at per-row positions).  Returns ``(logits, new_cache)``.
-        Ragged prompts ride the prefill contract: pad the prompt batch
-        to one length, prefill once, and read each row's logits at its
-        TRUE ``length - 1`` -- padding positions hold garbage K/V that
-        the decode frontier mask keeps invisible until the step that
-        overwrites them (see MultiHeadAttention._apply_cached)."""
-        if self.seq_axis_name is not None:
-            raise ValueError("cached decode runs on a replicated model; "
-                             "sequence-parallel serving is not a thing "
-                             "(shard the BATCH axis instead)")
-        t = input.shape[1]
-        with jax.named_scope("embed"):
-            x = jnp.take(params["wte"], input.astype(jnp.int32), axis=0)
-            if pos is None:
-                x = x + params["wpe"][:t][None]
-            else:
-                pos = jnp.asarray(pos, jnp.int32)
-                # jnp.take clips out-of-range rows; an inactive slot's
-                # clamped position writes only into its own dead cache row
-                x = x + jnp.take(params["wpe"], pos, axis=0)[:, None, :]
-        if self.scan_layers:
-            inner = self.blocks[0]
-
-            def body(h, sliced):
-                p, c = sliced
-                y, nc = inner.apply(p, (), h, cache=c, pos=pos)
-                return y, nc
-
-            x, stacked = jax.lax.scan(
-                body, x, (params["blocks"], cache["blocks"]))
-            new_cache = {"blocks": stacked}
-        else:
-            new_cache = {}
-            for i, b in enumerate(self.blocks):
-                x, nc = b.apply(params[f"block{i}"], (), x,
-                                cache=cache[f"block{i}"], pos=pos)
-                new_cache[f"block{i}"] = nc
-        return self._head(params, x), new_cache
-
-    def apply(self, params, state, input, *, training=False, rng=None,
-              cache=None, pos=None):
-        if cache is not None:
-            return self._apply_cached(params, input, cache, pos)
+    def apply(self, params, state, input, *, training=False, rng=None):
         t = input.shape[1]
         with jax.named_scope("embed"):
             x = jnp.take(params["wte"], input.astype(jnp.int32), axis=0)

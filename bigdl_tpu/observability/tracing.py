@@ -6,7 +6,7 @@ attempts, hedges and retries become child spans, the context crosses
 the ``serving/worker.py`` socket protocol as a versioned ``trace``
 field (W3C-traceparent encoding inside, so a future cross-host
 transport can interop), and lands in ``ServingEngine`` /
-``GenerateScheduler`` where batch ticks record span links back to
+``PagedGenerateScheduler`` where batch ticks record span links back to
 every request riding them.  Span records are durable JSONL lines
 (``traces.jsonl``, written by ``StepTelemetry.record_trace``) plus a
 chrome-trace mirror when a ``SpanTracer`` is attached;

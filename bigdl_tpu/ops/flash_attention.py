@@ -1,5 +1,5 @@
 """Fused flash-attention kernels in Pallas (Mosaic/TPU): forward, backward,
-the decode-shaped forwards and the paged chunk-prefill forward.
+and the paged pool's decode and chunk-prefill forwards.
 
 The TPU-native analogue of the reference's hand-tuned native kernels
 (bigdl-core MKL-DNN primitives, SURVEY.md section 2.8): where XLA's fusion
@@ -33,12 +33,6 @@ take seven.  dk and dv accumulate over the query axis in fp32 scratch; a
 head's whole dq (``T x D`` fp32) stays in VMEM across both axes, the one
 part that grows with T and what ``_bwd_vmem_bytes`` asks the compiler for.
 The same blocks are skipped and masked as in the forward.
-
-``flash_decode_attention`` (the contiguous cache's decode kernel) keeps one
-head's whole K and V resident in VMEM, so what the TPU compiler accepts of
-it is bounded by bytes, not only by tile alignment: ``kv_blocks_fit`` is
-that bound, and its ``auto`` gate in nn/attention.py asks it before
-selecting the kernel.
 
 ``flash_paged_decode_attention`` (the paged pool's decode kernel) leaves
 the pool in HBM as it is stored, ``(NB, bs, H * D)`` a layer or
@@ -75,13 +69,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-#: VMEM the contiguous decode kernel's resident K/V blocks may take.  The compiler's
-#: default scoped limit on a v5e is 16 MiB (``flash_decode_attention`` over
-#: a cache of 8192 fp32 positions is refused there with "size 16.00M and
-#: limit 16.00M exceeded ... by 1.0K"); the rest is left to the q/o rows
-#: and the score tiles.
-_VMEM_KV_BUDGET = 12 * 2 ** 20
-
 
 def _vmem_block_bytes(rows: int, cols: int, dtype) -> int:
     """Bytes one (rows, cols) block occupies in VMEM: lanes pad to 128,
@@ -89,13 +76,6 @@ def _vmem_block_bytes(rows: int, cols: int, dtype) -> int:
     item = jnp.dtype(dtype).itemsize
     sub = 8 * max(1, 4 // item)
     return -(-rows // sub) * sub * -(-cols // 128) * 128 * item
-
-
-def kv_blocks_fit(rows: int, head_dim: int, dtype) -> bool:
-    """Whether ``flash_decode_attention`` compiles with ``rows`` K/V
-    positions per head resident (the cache length).  K and V are each
-    double-buffered by the pipeline."""
-    return 4 * _vmem_block_bytes(rows, head_dim, dtype) <= _VMEM_KV_BUDGET
 
 
 #: What a masked score is set to: finite, so that no ``inf - inf`` can
@@ -464,100 +444,6 @@ def flash_attention(q, k, v, causal: bool = True,
     block_k = min(block_k or _tile(t), t)
     assert t % block_q == 0 and t % block_k == 0, (t, block_q, block_k)
     return _flash(q, k, v, causal, block_q, block_k, interpret)
-
-
-def _online_softmax_step(q, kblk, vblk, kpos, p, carry):
-    """One K/V block of ``_decode_kernel``'s q_len=1 online softmax:
-    ``q (1, d)``, ``kblk/vblk (n, d)`` fp32, ``kpos (1,
-    n)`` the block's logical positions, ``p`` the row's frontier."""
-    acc, m, l = carry
-    s = jax.lax.dot_general(q, kblk, _CONTRACT_LAST,
-                            preferred_element_type=jnp.float32)  # (1, n)
-    mask = kpos <= p
-    s = jnp.where(mask, s, -jnp.inf)
-    new_m = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-    safe_m = jnp.where(jnp.isfinite(new_m), new_m, 0.0)
-    pr = jnp.where(mask, jnp.exp(s - safe_m), 0.0)
-    corr = jnp.where(jnp.isfinite(m), jnp.exp(m - safe_m), 0.0)
-    l = l * corr + jnp.sum(pr, axis=1, keepdims=True)
-    acc = acc * corr + jnp.dot(pr, vblk,
-                               preferred_element_type=jnp.float32)
-    return acc, new_m, l
-
-
-def _online_softmax_init(d):
-    # (1, 1) carries, not (1,): Mosaic keeps vectors 2-D
-    return (jnp.zeros((1, d), jnp.float32),
-            jnp.full((1, 1), -jnp.inf, jnp.float32),
-            jnp.zeros((1, 1), jnp.float32))
-
-
-def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, *, block_k: int,
-                   scale: float):
-    """q_len=1 decode step: one query row against a K/V cache, masked
-    at the per-row frontier ``kpos <= pos``.  The k-loop's trip count is
-    DYNAMIC -- ``ceil((pos + 1) / block_k)`` -- so a short sequence in a
-    long cache reads only the blocks its mask can see: the O(1)-per-
-    token work the cache exists to buy, not O(max_len).  ``pos_ref`` is
-    the whole ``(B,)`` frontier vector, scalar-prefetched into SMEM."""
-    d = q_ref.shape[-1]
-    p = pos_ref[pl.program_id(0)]
-    q = q_ref[:].astype(jnp.float32) * scale          # (1, d)
-    nk = (p + block_k) // block_k                     # blocks with kpos <= p
-
-    def body(j, carry):
-        start = pl.multiple_of(j * block_k, block_k)
-        kblk = k_ref[pl.ds(start, block_k), :].astype(jnp.float32)
-        vblk = v_ref[pl.ds(start, block_k), :].astype(jnp.float32)
-        kpos = j * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_k), 1)
-        return _online_softmax_step(q, kblk, vblk, kpos, p, carry)
-
-    acc, m, l = jax.lax.fori_loop(0, nk, body, _online_softmax_init(d))
-    o_ref[:] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
-def flash_decode_attention(q, k, v, pos, block_k: int = 128,
-                           interpret: bool = False):
-    """Single-token decode attention: ``q (B, 1, H, D)`` against a K/V
-    cache ``k, v (B, T, H, D)`` with per-row frontier positions ``pos
-    (B,)`` (row ``i`` attends ``kpos <= pos[i]``) -> ``(B, 1, H, D)``.
-
-    The decode-shaped sibling of :func:`flash_attention`: same online
-    softmax, but the grid is one program per (batch, head) and the
-    query block is a single row, so the kernel streams cache blocks
-    through VMEM without ever materialising a score matrix.  T must be
-    a multiple of ``block_k`` (the cache allocator picks aligned
-    ``max_len``).  ``interpret=True`` runs on CPU for tests.
-    """
-    b, t1, h, d = q.shape
-    tk = k.shape[1]
-    assert t1 == 1, f"decode takes one query token per row, got {t1}"
-    block_k = min(block_k, tk)
-    assert tk % block_k == 0, (tk, block_k)
-    scale = 1.0 / math.sqrt(d)
-
-    def heads_first(x):
-        return x.transpose(0, 2, 1, 3)                # (B, H, T, D)
-
-    def row(t):
-        return pl.BlockSpec((None, None, t, d),
-                            lambda i, j, pos: (i, j, 0, 0))
-
-    out = pl.pallas_call(
-        functools.partial(_decode_kernel, block_k=block_k, scale=scale),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(b, h),
-            in_specs=[row(1), row(tk), row(tk)],
-            out_specs=row(1)),
-        out_shape=jax.ShapeDtypeStruct((b, h, 1, d), q.dtype),
-        interpret=interpret,
-        name="flash_decode_attention",
-    )(jnp.asarray(pos, jnp.int32), heads_first(q), heads_first(k),
-      heads_first(v))
-    return out.transpose(0, 2, 1, 3)
 
 
 def _split3(x):

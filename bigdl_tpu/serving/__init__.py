@@ -15,22 +15,21 @@ reference's ``optim/PredictionService.scala`` instance pool).
   whose fp32-vs-int8 divergence exceeds tolerance.
 
 - ``ServingEngine.generate()`` (``serving/generation.py``) --
-  autoregressive generation: KV-cache prefill/decode steps compiled
-  once (cache donated in place), a slot-based continuous-batching
-  scheduler (sequences join/leave a fixed decode-slot pool mid-flight
-  with zero steady-state recompiles), per-request
-  ``max_new_tokens``/EOS stops, and streaming ``GenerateFuture``
-  handles that yield tokens as decode ticks complete.
+  autoregressive generation: paged-KV chunk-prefill/decode steps
+  compiled once (pool donated in place), a slot-based
+  continuous-batching scheduler (sequences join/leave a fixed
+  decode-slot pool mid-flight with zero steady-state recompiles),
+  per-request ``max_new_tokens``/EOS stops, and streaming
+  ``GenerateFuture`` handles that yield tokens as decode ticks
+  complete.
 - ``PagedGenerateScheduler`` / ``BlockAllocator``
-  (``serving/generation.py`` + ``serving/paging.py``) -- the paged KV
+  (``serving/generation.py`` + ``serving/paging.py``) -- the KV
   cache: a fixed device block pool with host-side block tables,
   refcounted prefix sharing (content-hashed full blocks, LRU-cached,
   copy-on-write on divergence), chunked prefill interleaved with
   decode ticks, typed ``BlockPoolExhausted`` admission sheds, and
   in-jit ``SamplingParams`` temperature/top-k/top-p sampling
-  (``serving/sampling.py``).  The engine default
-  (``kv_cache="paged"``); ``kv_cache="contiguous"`` keeps the flat
-  pool as the A/B baseline.
+  (``serving/sampling.py``).
 - ``ModelRegistry`` / ``RolloutController`` (``serving/deploy.py``) --
   the train->serve loop closed: versioned hot-swap with shadow/canary
   staged exposure, atomic cutover, automatic rollback to the retained
@@ -71,7 +70,6 @@ from bigdl_tpu.serving.fleet import (CircuitBreaker, FleetOverloadedError,
                                      InProcessReplica, ServingFleet,
                                      SubprocessReplica)
 from bigdl_tpu.serving.generation import (GenerateFuture,
-                                          GenerateScheduler,
                                           PagedGenerateScheduler)
 from bigdl_tpu.serving.paging import BlockAllocator, BlockPoolExhausted
 from bigdl_tpu.serving.sampling import SamplingParams
@@ -84,7 +82,7 @@ from bigdl_tpu.serving.transport import (ReplicaCallError, WireAuthError,
 __all__ = ["BlockAllocator", "BlockPoolExhausted", "BucketLadder",
            "CircuitBreaker", "EngineDraining", "FleetOverloadedError",
            "FleetSupervisor", "FleetUnavailableError", "GenerateFuture",
-           "GenerateScheduler", "InProcessReplica", "ModelRegistry",
+           "InProcessReplica", "ModelRegistry",
            "ModelVersion", "PagedGenerateScheduler", "ReplicaCallError",
            "RolloutController", "SamplingParams", "ServeFuture",
            "ServingEngine", "ServingFleet", "SubprocessReplica",

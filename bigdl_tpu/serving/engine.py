@@ -327,10 +327,9 @@ class ServingEngine:
     drafting ``k`` tokens per tick and ONE fp32 forward verifying them
     -- the output stream is bit-identical to fp32-only decoding
     (greedy and seeded sampling both), it's just emitted 1..k+1 tokens
-    per verify step.  Both need ``kv_cache='paged'``; ``accuracy_gate``
-    composes with ``speculative`` to gate the drafter the same way it
-    gates an int8 serving twin (docs/performance.md, "Generation
-    serving").
+    per verify step.  ``accuracy_gate`` composes with ``speculative``
+    to gate the drafter the same way it gates an int8 serving twin
+    (docs/performance.md, "Generation serving").
     """
 
     def __init__(self, model, max_batch_size: int = 32,
@@ -345,7 +344,6 @@ class ServingEngine:
                  quantize=False, accuracy_gate=None,
                  decode_slots: Optional[int] = None,
                  decode_max_len: Optional[int] = None,
-                 prompt_ladder: Optional[BucketLadder] = None,
                  kv_cache: str = "paged", kv_block_size: int = 16,
                  kv_blocks: Optional[int] = None,
                  prefill_chunk: Optional[int] = None,
@@ -477,35 +475,20 @@ class ServingEngine:
         # first generate() after precompile must not pay compiles,
         # whether or not decode_slots was spelled out).
         if decode_slots is None:
-            decode_slots = 8 if hasattr(model, "init_cache") \
-                or hasattr(model, "init_paged_cache") else 0
+            decode_slots = 8 if hasattr(model, "init_paged_cache") else 0
         self.decode_slots = int(decode_slots)
         self.decode_max_len = decode_max_len
-        self._prompt_ladder = prompt_ladder
-        # paged-KV knobs (serving/paging.py): "paged" virtualizes the
-        # generation cache into a block pool with prefix sharing,
-        # chunked prefill and in-jit sampling; "contiguous" keeps the
-        # PR 15 slots x max_len pool (greedy only -- the A/B baseline).
-        # Models without init_paged_cache fall back to contiguous.
-        if kv_cache not in ("paged", "contiguous"):
+        # the generation cache is the paged block pool (serving/paging.py:
+        # prefix sharing, chunked prefill, in-jit sampling), the one KV
+        # cache there is; ``kv_cache`` names it and takes no other value
+        if kv_cache != "paged":
             raise ValueError(
-                f"kv_cache must be 'paged' or 'contiguous', got "
-                f"{kv_cache!r}")
-        self.kv_cache = kv_cache
+                f"kv_cache must be 'paged', the one KV cache (the "
+                f"contiguous one was removed), got {kv_cache!r}")
         if kv_cache_dtype not in ("fp32", "int8"):
             raise ValueError(
                 f"kv_cache_dtype must be 'fp32' or 'int8', got "
                 f"{kv_cache_dtype!r}")
-        if kv_cache_dtype != "fp32" and kv_cache != "paged":
-            raise ValueError(
-                "int8 KV blocks live in the paged pool (per-block "
-                "payload + scale leaves); kv_cache_dtype='int8' needs "
-                "kv_cache='paged'")
-        if self.speculative and kv_cache != "paged":
-            raise ValueError(
-                "speculative decoding rides the paged block table "
-                "(drafter pool shares the verifier's allocator); "
-                "speculative=k needs kv_cache='paged'")
         if (kv_cache_dtype != "fp32" or self.speculative) \
                 and not hasattr(model, "init_paged_cache"):
             raise TypeError(
@@ -662,8 +645,7 @@ class ServingEngine:
                             "(decode_slots=0); construct with "
                             "decode_slots >= 1")
                     from bigdl_tpu.serving.generation import (
-                        GenerateScheduler, PagedGenerateScheduler,
-                        SpeculativeScheduler)
+                        PagedGenerateScheduler, SpeculativeScheduler)
 
                     serve_model = self._qmodel if self._quantized \
                         else self.model
@@ -672,7 +654,6 @@ class ServingEngine:
                     paged_kw = dict(
                         slots=self.decode_slots,
                         max_len=self.decode_max_len,
-                        prompt_ladder=self._prompt_ladder,
                         queue_capacity=self.queue_capacity,
                         cache_dtype=cache_dtype,
                         telemetry=self.telemetry,
@@ -688,19 +669,9 @@ class ServingEngine:
                         self._gen = SpeculativeScheduler(
                             self.model, self._qmodel,
                             spec_k=self.speculative, **paged_kw)
-                    elif self.kv_cache == "paged" \
-                            and hasattr(serve_model, "init_paged_cache"):
+                    else:
                         self._gen = PagedGenerateScheduler(
                             serve_model, **paged_kw)
-                    else:
-                        self._gen = GenerateScheduler(
-                            serve_model, slots=self.decode_slots,
-                            max_len=self.decode_max_len,
-                            prompt_ladder=self._prompt_ladder,
-                            queue_capacity=self.queue_capacity,
-                            telemetry=self.telemetry,
-                            admission_check=self._gen_admission_check,
-                            exhausted_hook=self._on_pool_exhausted)
         return self._gen
 
     def _gen_admission_check(self):
@@ -730,9 +701,7 @@ class ServingEngine:
         Decoding is greedy by default; ``temperature > 0`` samples
         in-jit (optionally truncated by ``top_k`` / nucleus ``top_p``),
         with an explicit ``seed`` making the stream deterministic per
-        (seed, prompt) -- sampling needs the paged scheduler
-        (``kv_cache='paged'``, the default; the contiguous pool refuses
-        it at submission).
+        (seed, prompt).
 
         Admission honors the engine's lifecycle exactly like
         ``submit``: a draining engine raises ``EngineDraining``, a
@@ -843,8 +812,7 @@ class ServingEngine:
         # paid every generation compile (tests/test_paged.py pins this)
         gen_compiles = 0
         served = self._qmodel if self._quantized else self.model
-        if self.decode_slots > 0 and (hasattr(served, "init_cache")
-                                      or hasattr(served, "init_paged_cache")):
+        if self.decode_slots > 0 and hasattr(served, "init_paged_cache"):
             gen_compiles = self._generation().precompile()
         if self.length_ladder is None:
             return self._backend.precompile(spec, buckets) + gen_compiles
@@ -1252,10 +1220,9 @@ class ServingEngine:
                 "replicas": self._backend.replicas}
         if self.decode_slots > 0:
             info["decode_slots"] = self.decode_slots
-            info["kv_cache"] = self.kv_cache
-            if self.kv_cache == "paged":
-                info["kv_block_size"] = self.kv_block_size
-                info["kv_cache_dtype"] = self.kv_cache_dtype
+            info["kv_cache"] = "paged"
+            info["kv_block_size"] = self.kv_block_size
+            info["kv_cache_dtype"] = self.kv_cache_dtype
             if self.speculative:
                 info["speculative"] = self.speculative
         if self._version_info is not None:

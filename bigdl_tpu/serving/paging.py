@@ -1,10 +1,10 @@
 """Host-side paged KV-cache allocation: block tables, refcounted
 prefix sharing, and copy-on-write.
 
-PR 15's decode cache is a contiguous ``slots x max_len`` pool: memory
-scales with the WORST-CASE sequence length regardless of what requests
-actually use, so concurrency is capped by memory long before compute.
-This module virtualizes that cache the way an OS virtualizes RAM: the
+A contiguous ``slots x max_len`` decode cache scales memory with the
+WORST-CASE sequence length regardless of what requests actually use, so
+concurrency is capped by memory long before compute.  This module
+virtualizes that cache the way an OS virtualizes RAM: the
 device holds one fixed pool of ``num_blocks`` blocks of ``block_size``
 positions each (``nn``'s ``init_paged_cache``), and every sequence owns
 a host-side BLOCK TABLE -- a list of physical block ids its logical
@@ -14,7 +14,7 @@ pad to ``max_blocks_per_seq`` with a TRASH block id, so sequences of
 any length share one decode executable and join/leave without a
 recompile.
 
-On top of the tables, three properties the contiguous pool cannot have:
+On top of the tables, three properties a contiguous pool cannot have:
 
 - **prefix caching** -- a FULL block's content hash (chained over its
   prefix, so equal hashes imply equal token histories) is registered

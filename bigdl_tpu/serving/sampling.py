@@ -1,8 +1,8 @@
 """In-jit token sampling: temperature / top-k / top-p drawn INSIDE the
 compiled decode step.
 
-The contiguous scheduler (PR 15) only ever argmaxes, which kept the
-decode step pure but locks serving to greedy output.  The obvious
+A decode step that only argmaxes stays pure but locks serving to
+greedy output.  The obvious
 extension -- ship logits to the host and sample there -- adds a
 device->host round-trip of ``(slots, vocab)`` floats per generated
 token, exactly the transfer the decode path was built to avoid.

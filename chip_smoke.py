@@ -16,9 +16,8 @@ data and weights made from ``--seed``:
   cross-entropy, Adam, default ``use_flash="auto"``, 3 iterations.
 - ``serve_lm``: the same model behind ``ServingEngine`` with its default
   options and ``decode_max_len=2048``: 8 concurrent ``generate()`` calls,
-  prompts of 64-1024 tokens, 32 greedy tokens each, first with the paged
-  cache, then with ``kv_cache="contiguous"``.  One request is held to a
-  plain full-recompute argmax loop (no cache, ``use_flash="never"``),
+  prompts of 64-1024 tokens, 32 greedy tokens each, through the paged KV
+  cache.  One request is held to a plain full-recompute argmax loop (no cache, ``use_flash="never"``),
   everything under matmul precision ``highest``.
 
 ``--chips 4`` runs only the path across chips and what it is compared
@@ -179,7 +178,7 @@ def path_taken(kernels, *names):
 
 
 ATTENTION_KERNELS = ("flash_attention", "attention_bwd",
-                     "flash_decode_attention",
+                     "flash_paged_prefill_attention",
                      "flash_paged_decode_attention")
 
 
@@ -343,55 +342,41 @@ def engine_first_logits(model, params, prompt, sched):
     """First-step logits by the model call the scheduler's prefill step
     makes.  The step itself hands back tokens, never logits, so this is
     the one check that cannot go through the entry point; every size in
-    it is read from the live scheduler: the paged one prefills in
+    it is read from the live scheduler: it prefills in
     ``prefill_chunk``-token chunks through a block table
-    ``max_blocks_per_seq`` wide over ``block_size``-token blocks, the
-    contiguous one in one call at the prompt's ladder rung, both into a
-    cache of the scheduler's storage type."""
+    ``max_blocks_per_seq`` wide over ``block_size``-token blocks, into a
+    pool of the scheduler's storage type."""
     n = len(prompt)
-    dtype = sched._cache_dtype
-    if hasattr(sched, "prefill_chunk"):
-        bs, tc, mb = (sched.block_size, sched.prefill_chunk,
-                      sched.max_blocks_per_seq)
-        table = np.full((1, mb), mb, np.int32)      # block mb: the trash
-        table[0, :-(-n // bs)] = np.arange(-(-n // bs))
+    bs, tc, mb = (sched.block_size, sched.prefill_chunk,
+                  sched.max_blocks_per_seq)
+    table = np.full((1, mb), mb, np.int32)          # block mb: the trash
+    table[0, :-(-n // bs)] = np.arange(-(-n // bs))
 
-        @functools.partial(jax.jit, donate_argnums=1)
-        def first_logits(p, pool, tokens, start, lens):
-            return model.apply_paged(p, tokens, pool, table, pos=start,
-                                     lengths=lens)
+    @functools.partial(jax.jit, donate_argnums=1)
+    def first_logits(p, pool, tokens, start, lens):
+        return model.apply_paged(p, tokens, pool, table, pos=start,
+                                 lengths=lens)
 
-        pool = model.init_paged_cache(mb, bs, dtype)
-        for start in range(0, n, tc):
-            chunk = prompt[start:start + tc]
-            tokens = np.zeros((1, tc), np.int32)
-            tokens[0, :len(chunk)] = chunk
-            logits, pool = first_logits(
-                params, pool, tokens, np.full(1, start, np.int32),
-                np.full(1, len(chunk), np.int32))
-        return np.asarray(logits[0, len(chunk) - 1])
-
-    rung = sched.prompt_ladder.bucket_for(n)
-    tokens = np.zeros((1, rung), np.int32)
-    tokens[0, :n] = prompt
-
-    @jax.jit
-    def first_logits(p, t):
-        return model.apply(p, (), t, cache=model.init_cache(1, rung, dtype))
-
-    return np.asarray(first_logits(params, tokens)[0][0, n - 1])
+    pool = model.init_paged_cache(mb, bs, sched._cache_dtype)
+    for start in range(0, n, tc):
+        chunk = prompt[start:start + tc]
+        tokens = np.zeros((1, tc), np.int32)
+        tokens[0, :len(chunk)] = chunk
+        logits, pool = first_logits(
+            params, pool, tokens, np.full(1, start, np.int32),
+            np.full(1, len(chunk), np.int32))
+    return np.asarray(logits[0, len(chunk) - 1])
 
 
-def serve_once(model, params, cfg, prompts, held, kv_cache, dev, log):
+def serve_once(model, params, cfg, prompts, held, dev, log):
     """One engine's life: build it, take the held request's first-step
     logits with its scheduler's sizes, serve every request, close it.
     Returns the tokens and what was observed on the way."""
-    eng = ServingEngine(model, decode_max_len=cfg["seq"], kv_cache=kv_cache)
+    eng = ServingEngine(model, decode_max_len=cfg["seq"])
     try:
         sched = eng._generation()
         first = engine_first_logits(model, params, prompts[held], sched)
-        steps = {"prefill": getattr(sched, "_chunk_fn", None)
-                 or sched._prefill_fn, "decode": sched._decode_fn}
+        steps = {"prefill": sched._chunk_fn, "decode": sched._decode_fn}
         log.drain()
         t0 = time.perf_counter()
         futs = [eng.generate(p, max_new_tokens=cfg["new_tokens"])
@@ -408,7 +393,7 @@ def serve_once(model, params, cfg, prompts, held, kv_cache, dev, log):
         module = f"jit_{fn.__name__}"
         if module not in programs:
             raise RuntimeError(
-                f"{kv_cache}: the engine compiled no program {module!r} "
+                f"the engine compiled no program {module!r} "
                 f"(it compiled {sorted(programs)})")
         paths[role] = {"program": module, "attention": path_taken(
             programs[module], *ATTENTION_KERNELS)}
@@ -442,29 +427,26 @@ def phase_serve_lm(cfg, seed, dev, run_dir, log):
     try:
         ref_first, ref_tokens, gaps = reference_greedy(
             model, params, prompts[held], cfg["new_tokens"])
-        for kv_cache in ("paged", "contiguous"):
-            tokens, first, seen = serve_once(
-                model, params, cfg, prompts, held, kv_cache, dev, log)
-            if any(len(t) != cfg["new_tokens"] for t in tokens):
-                raise RuntimeError(
-                    f"{kv_cache}: wrong token counts "
-                    f"{[len(t) for t in tokens]}")
-            got = tokens[held]
-            err = float(np.max(np.abs(first - ref_first)))
-            out[kv_cache] = {"first_logits_max_abs_err": err,
-                             "tokens_equal": got == ref_tokens, **seen}
-            if not np.all(np.isfinite(first)) or err > LOGIT_ATOL:
-                raise RuntimeError(
-                    f"{kv_cache}: first-step logits differ from the "
-                    f"reference by {err} (tolerance {LOGIT_ATOL})")
-            if got != ref_tokens:
-                at = next(i for i, (a, b) in enumerate(zip(got, ref_tokens))
-                          if a != b)
-                raise RuntimeError(
-                    f"{kv_cache}: greedy tokens part from the reference at "
-                    f"step {at} (engine {got[at]}, reference "
-                    f"{ref_tokens[at]}; the reference's top-2 logit gap "
-                    f"there is {gaps[at]:.3e})")
+        tokens, first, seen = serve_once(
+            model, params, cfg, prompts, held, dev, log)
+        if any(len(t) != cfg["new_tokens"] for t in tokens):
+            raise RuntimeError(
+                f"wrong token counts {[len(t) for t in tokens]}")
+        got = tokens[held]
+        err = float(np.max(np.abs(first - ref_first)))
+        out.update(first_logits_max_abs_err=err,
+                   tokens_equal=got == ref_tokens, **seen)
+        if not np.all(np.isfinite(first)) or err > LOGIT_ATOL:
+            raise RuntimeError(
+                f"first-step logits differ from the reference by {err} "
+                f"(tolerance {LOGIT_ATOL})")
+        if got != ref_tokens:
+            at = next(i for i, (a, b) in enumerate(zip(got, ref_tokens))
+                      if a != b)
+            raise RuntimeError(
+                f"greedy tokens part from the reference at step {at} "
+                f"(engine {got[at]}, reference {ref_tokens[at]}; the "
+                f"reference's top-2 logit gap there is {gaps[at]:.3e})")
     finally:
         jax.config.update("jax_default_matmul_precision", saved)
     out["reference_min_top2_gap"] = min(gaps)

@@ -60,8 +60,7 @@ def main(argv=None):
     eng = ServingEngine(model, max_batch_size=2, max_wait_ms=1.0,
                         ladder=BucketLadder(2, min_size=1),
                         telemetry=tel, decode_slots=2,
-                        decode_max_len=32,
-                        prompt_ladder=BucketLadder(8, min_size=8))
+                        decode_max_len=32)
     example = np.zeros((8,), np.int32)
     eng.precompile(example_feature=example)
 

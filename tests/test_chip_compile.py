@@ -21,10 +21,8 @@ from bigdl_tpu.ops.grouped_matmul import buffer_rows, grouped_matmul
 from bigdl_tpu.ops.kda import kda_decode_step
 from bigdl_tpu.ops.ssd import ssd_decode_step
 from bigdl_tpu.ops.flash_attention import (flash_attention,
-                                           flash_decode_attention,
                                            flash_paged_decode_attention,
                                            flash_paged_prefill_attention,
-                                           kv_blocks_fit,
                                            latent_paged_decode_attention)
 
 f32, bf16, i8, i32 = jnp.float32, jnp.bfloat16, jnp.int8, jnp.int32
@@ -122,11 +120,6 @@ def _qkv(b, t, d, dt, h=16):
     return [((b, t, h, d), dt)] * 3
 
 
-def _decode(b, t, d, dt, h=16):
-    return [((b, 1, h, d), dt), ((b, t, h, d), dt), ((b, t, h, d), dt),
-            ((b,), i32)]
-
-
 def _paged(b, nb, bs, d, dtype, h=16, mb=64, layers=None, groups=1):
     """The pool as the engine stores it: ``(NB, bs, H * D)``, an int8
     one with ``(NB, bs, H)`` fp32 scales; with ``layers`` the stacked leaf
@@ -168,109 +161,95 @@ def _latent_pool(layers=None, width=640):
         + ([] if layers is None else [((), i32)])
 
 
-# (kernel, shapes, what the auto gate is asked: rows, head_dim, dtype)
+# (kernel, shapes)
 CASES = {
     # train_lm's sequence, bf16 compute.  The forward kernel streams K/V
-    # a block at a time: no gate bounds it by bytes (gate None)
-    "flash-medium": (flash_attention, _qkv(2, 2048, 64, bf16), None),
-    "flash-large": (flash_attention, _qkv(2, 2048, 96, bf16), None),
-    "flash-grad-medium": (_flash_grad, _qkv(2, 2048, 64, bf16), None),
+    # a block at a time: no gate bounds it by bytes
+    "flash-medium": (flash_attention, _qkv(2, 2048, 64, bf16)),
+    "flash-large": (flash_attention, _qkv(2, 2048, 96, bf16)),
+    "flash-grad-medium": (_flash_grad, _qkv(2, 2048, 64, bf16)),
     # the benchmark's training cell: 16 sequences of 1024, 16 heads of 64
-    "flash-cell": (flash_attention, _qkv(16, 1024, 64, bf16), None),
-    "flash-grad-cell": (_flash_grad, _qkv(16, 1024, 64, bf16), None),
-    # serve_lm's contiguous cache: 8 slots + trash row, 2048 long, fp32;
-    # and the longest fp32 cache the gate admits
-    "decode-medium": (flash_decode_attention, _decode(9, 2048, 64, f32),
-                      (2048, 64, f32)),
-    "decode-large-longest": (flash_decode_attention,
-                             _decode(9, 6144, 96, f32),
-                             (6144, 96, f32)),
+    "flash-cell": (flash_attention, _qkv(16, 1024, 64, bf16)),
+    "flash-grad-cell": (_flash_grad, _qkv(16, 1024, 64, bf16)),
     # the paged decode kernel streams a slot's blocks through a double
     # buffer: no gate bounds it by bytes.  The benchmark's serving cell
     # (32 slots, 1280 blocks of 16 and the trash block, 16 heads of 64,
     # 64 table entries, fp32), the same tokens in a bf16 pool, and the
     # large model's heads of 96 in an int8 pool of 32-row blocks
     "paged-cell-fp32": (flash_paged_decode_attention,
-                        _paged(32, 1281, 16, 64, f32), None),
+                        _paged(32, 1281, 16, 64, f32)),
     "paged-cell-bf16": (flash_paged_decode_attention,
-                        _paged(32, 1281, 16, 64, bf16), None),
+                        _paged(32, 1281, 16, 64, bf16)),
     "paged-large-int8": (flash_paged_decode_attention,
-                         _paged(8, 641, 32, 96, i8, mb=32), None),
+                         _paged(8, 641, 32, 96, i8, mb=32)),
     # the cell's pool as its scan_layers model holds it, all 24 layers in
     # one leaf (3.75 GiB each of K and V) and the layer an argument; and
     # an int8 one with its stacked scales
     "paged-cell-stacked-fp32": (flash_paged_decode_attention,
-                                _paged(32, 1281, 16, 64, f32, layers=24),
-                                None),
+                                _paged(32, 1281, 16, 64, f32, layers=24)),
     "paged-stacked-int8": (flash_paged_decode_attention,
-                           _paged(8, 641, 32, 96, i8, mb=32, layers=4),
-                           None),
+                           _paged(8, 641, 32, 96, i8, mb=32, layers=4)),
     # the LFM2 cell: one call of the attention layer takes a row's 8 KV
     # heads as 8 pairs of 4 query heads at 4096 positions
-    "flash-grad-lfm2": (_flash_grad, _qkv(8, 4096, 64, bf16, h=4), None),
+    "flash-grad-lfm2": (_flash_grad, _qkv(8, 4096, 64, bf16, h=4)),
     # the backward at the other shapes the forward accepts: fp32 operands,
     # one block under 128, and a head's whole dq resident at 8192 x 128
-    "flash-grad-fp32": (_flash_grad, _qkv(2, 1024, 64, f32), None),
-    "flash-grad-short": (_flash_grad, _qkv(2, 64, 64, bf16, h=4), None),
-    "flash-grad-long": (_flash_grad, _qkv(1, 8192, 128, bf16, h=2), None),
+    "flash-grad-fp32": (_flash_grad, _qkv(2, 1024, 64, f32)),
+    "flash-grad-short": (_flash_grad, _qkv(2, 64, 64, bf16, h=4)),
+    "flash-grad-long": (_flash_grad, _qkv(1, 8192, 128, bf16, h=2)),
     # its experts: 2048 -> 1792 (w1, w3) and 1792 -> 2048 (w2), 8 groups,
     # 65,536 rows at worst; forward and both backward products
-    "grouped-up": (grouped_matmul, _grouped(2048, 1792), None),
-    "grouped-grad-up": (_grouped_grad, _grouped(2048, 1792), None),
-    "grouped-grad-down": (_grouped_grad, _grouped(1792, 2048), None),
+    "grouped-up": (grouped_matmul, _grouped(2048, 1792)),
+    "grouped-grad-up": (_grouped_grad, _grouped(2048, 1792)),
+    "grouped-grad-down": (_grouped_grad, _grouped(1792, 2048)),
     # the Ling serving cell: the delta-rule decode step over 32 slots of 32
     # heads of 128 x 128, and its experts at decode rows (2560 -> 768 and
     # back, 128 held, 256 assignments at worst, tiles of 16 rows)
-    "kda-decode-cell": (kda_decode_step, _kda(32), None),
+    "kda-decode-cell": (kda_decode_step, _kda(32)),
     "grouped-decode-up": (_grouped_small, [
         ((buffer_rows(256, 128, 16), 2560), bf16), ((128, 2560, 768), bf16),
-        ((128,), i32)], None),
+        ((128,), i32)]),
     "grouped-decode-down": (_grouped_small, [
         ((buffer_rows(256, 128, 16), 768), bf16), ((128, 768, 2560), bf16),
-        ((128,), i32)], None),
+        ((128,), i32)]),
     # the granite serving cell: the state-space decode step over 64 slots
     # of 64 heads of 64 x 128 (a layer's own leaf, and the 36 Mamba layers
     # in one leaf of 4.9 GB), and the grouped paged decode, 32 query heads
     # on 8 KV heads of 64 in the 4 attention layers' stacked bf16 pool
-    "ssd-decode-cell": (ssd_decode_step, _ssd(64), None),
+    "ssd-decode-cell": (ssd_decode_step, _ssd(64)),
     "ssd-decode-cell-stacked": (
-        lambda *a: ssd_decode_step(*a[:-1], layer=a[-1]), _ssd(64, 36),
-        None),
+        lambda *a: ssd_decode_step(*a[:-1], layer=a[-1]), _ssd(64, 36)),
     "paged-grouped-cell-bf16": (
         flash_paged_decode_attention,
-        _paged(64, 9217, 16, 64, bf16, h=8, mb=144, layers=4, groups=4),
-        None),
+        _paged(64, 9217, 16, 64, bf16, h=8, mb=144, layers=4, groups=4)),
     "paged-grouped-int8": (
         flash_paged_decode_attention,
-        _paged(8, 641, 32, 64, i8, h=8, mb=32, layers=2, groups=4), None),
+        _paged(8, 641, 32, 64, i8, h=8, mb=32, layers=2, groups=4)),
     # the paged prefill kernel: the serving cell's largest chunk step of
     # 64 tokens (8 rows, fp32, all 24 layers in one leaf), the speculative
     # verify step's k + 1 = 5 tokens, and granite's chunk step (4 rows of
     # 512 tokens, 32 query heads on 8 KV heads, its 4 attention layers'
     # bf16 pool of 9216 blocks and 144 table entries)
     "paged-prefill-cell-fp32": (_prefill,
-                                _paged_chunk(8, 64, 1281, 64, f32), None),
-    "paged-prefill-verify": (_prefill, _paged_chunk(32, 5, 1281, 64, f32),
-                             None),
+                                _paged_chunk(8, 64, 1281, 64, f32)),
+    "paged-prefill-verify": (_prefill, _paged_chunk(32, 5, 1281, 64, f32)),
     "paged-prefill-grouped-bf16": (
         _prefill, _paged_chunk(4, 512, 9217, 64, bf16, h=8, mb=144,
-                               layers=4, groups=4), None),
+                               layers=4, groups=4)),
     # the kanana serving cell: the latent decode kernel over a layer's own
     # leaf (the leading dense layer) and over the scanned layers' stacked one
-    "latent-decode-cell": (_latent, _latent_pool(), None),
-    "latent-decode-cell-stacked": (_latent, _latent_pool(15), None),
+    "latent-decode-cell": (_latent, _latent_pool()),
+    "latent-decode-cell-stacked": (_latent, _latent_pool(15)),
     # train_lm's head: 2 sequences of 2048 tokens, vocab 32000
     "ce-forward": (fused_softmax_cross_entropy,
-                   [((4096, 32000), f32), ((4096,), i32)], None),
-    "ce-gradient": (_ce_grad, [((4096, 32000), f32), ((4096,), i32)], None),
+                   [((4096, 32000), f32), ((4096,), i32)]),
+    "ce-gradient": (_ce_grad, [((4096, 32000), f32), ((4096,), i32)]),
 }
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_kernel_compiles_for_v5e(one_chip, case):
-    fn, shapes, gate = CASES[case]
-    if gate is not None:
-        assert kv_blocks_fit(*gate), "the auto gate must admit this shape"
+    fn, shapes = CASES[case]
     assert _compile(one_chip, fn, *shapes) >= 1
 
 
@@ -278,7 +257,7 @@ def test_kernel_compiles_for_v5e(one_chip, case):
 def test_gradient_is_two_kernels(one_chip, case):
     """The differentiated call is the forward kernel and ``attention_bwd``
     and nothing of XLA's over ``(T, T)`` scores."""
-    fn, shapes, _ = CASES[case]
+    fn, shapes = CASES[case]
     text = _compiled_text(one_chip, fn, *shapes)
     assert text.count("tpu_custom_call") == 2
     t = shapes[0][0][1]
@@ -288,19 +267,8 @@ def test_gradient_is_two_kernels(one_chip, case):
 def test_forward_outgrows_the_gate(one_chip):
     """T=8192 fp32 was refused while the forward kept one head's whole K
     and V in VMEM (16.25 MiB against a 16 MiB limit).  With the key blocks
-    on the grid it compiles, though ``kv_blocks_fit`` -- still the decode
-    kernels' gate -- would not admit that many resident rows."""
-    assert not kv_blocks_fit(8192, 64, f32)
+    on the grid it compiles."""
     assert _compile(one_chip, flash_attention, *_qkv(1, 8192, 64, f32)) == 1
-
-
-def test_gate_refuses_what_the_compiler_refuses(one_chip):
-    """``flash_decode_attention`` keeps a head's whole cache in VMEM: at
-    8192 fp32 positions the gate refuses it, and so does the compiler
-    ("size 16.00M and limit 16.00M exceeded scoped vmem limit by 1.0K")."""
-    assert not kv_blocks_fit(8192, 64, f32)
-    with pytest.raises(Exception, match="vmem"):
-        _compile(one_chip, flash_decode_attention, *_decode(9, 8192, 64, f32))
 
 
 @pytest.mark.parametrize("block_size,dtype,hidden,admitted", [

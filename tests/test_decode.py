@@ -3,17 +3,15 @@ prefill/decode split, continuous batching.
 
 Pins, per the acceptance criteria:
 
-- cached single-step decode logits match the full-context forward
-  within 1e-4 across BOTH block layouts (unrolled and scan-stacked),
-  with causal masking honest at every position (garbage beyond the
-  frontier is invisible);
-- ragged-prompt prefill: one padded prefill call serves rows of
-  different true lengths, each row's first token read at its own
-  ``length - 1``;
-- a full generate loop spanning multiple admission/prompt buckets
-  performs ZERO steady-state compiles after ``precompile()`` (the
-  ``compiles`` tick stamp stays absent and the backend counter is
-  flat);
+- paged chunk-prefill and single-step decode logits match the
+  full-context forward within 1e-4 across BOTH block layouts (unrolled
+  and scan-stacked), with causal masking honest at every position
+  (garbage beyond the frontier is invisible);
+- ragged-prompt prefill: one chunk call serves rows of different true
+  lengths, each row's logits read at its own ``length - 1``;
+- a full generate loop spanning multiple admission counts performs
+  ZERO steady-state compiles after ``precompile()`` (the ``compiles``
+  tick stamp stays absent and the backend counter is flat);
 - int8: ``ServingEngine(quantize=True)`` serves generation through the
   same ``AccuracyDeltaGate``, and fp32-vs-int8 top-1 agreement on
   GENERATED tokens is pinned;
@@ -34,9 +32,8 @@ import jax.numpy as jnp
 
 from bigdl_tpu.nn.attention import TransformerLM
 from bigdl_tpu.observability.watchdogs import backend_compile_count
-from bigdl_tpu.serving import (BucketLadder, EngineDraining,
-                               InProcessReplica, ServingEngine,
-                               ServingFleet)
+from bigdl_tpu.serving import (EngineDraining, InProcessReplica,
+                               ServingEngine, ServingFleet)
 
 VOCAB = 50
 
@@ -67,145 +64,112 @@ def _greedy_reference(m, prompt, n_new):
     return out
 
 
+def _paged_step(m, tables):
+    """``m.apply_paged`` jitted over its pool's ``tables``: a chunk with
+    ``n`` (the rows' true lengths) or a decode step without."""
+    return jax.jit(lambda p, x, pool, pos, n=None: m.apply_paged(
+        p, x, pool, tables, pos=pos, lengths=n))
+
+
 class TestDecodeAgreement:
-    """Cached decode is a restructuring of the forward, not an
+    """Paged decode is a restructuring of the forward, not an
     approximation: logits agree with the full-context forward."""
 
     @pytest.mark.parametrize("scan", [False, True])
-    def test_cached_steps_match_full_forward(self, scan):
-        m = _lm(layers=3, scan=scan)
+    def test_paged_steps_match_full_forward(self, scan):
+        """Eight positions as one chunk, then one decode step a position
+        across block boundaries, each row through its own scattered
+        blocks: the chunk's logits and every step's are the forward's."""
+        m = _lm(layers=2, scan=scan)
         params = m.parameters()[0]
         toks = np.random.default_rng(0).integers(
             0, VOCAB, size=(2, 16)).astype(np.int32)
         full = np.asarray(m.apply(params, (), jnp.asarray(toks))[0])
-
-        cache = m.init_cache(2, 24)
-        pre, cache = m.apply(params, (), jnp.asarray(toks[:, :8]),
-                             cache=cache)
-        # prefill logits ARE full-forward logits (identical math)
+        nb = 10
+        tables = jnp.asarray([[7, 2, 9, 4, nb], [1, 8, 3, 6, nb]], jnp.int32)
+        step = _paged_step(m, tables)
+        pool = m.init_paged_cache(nb, 4)
+        pre, pool = step(params, toks[:, :8], pool,
+                         jnp.zeros((2,), jnp.int32),
+                         jnp.full((2,), 8, jnp.int32))
         assert np.max(np.abs(np.asarray(pre) - full[:, :8])) < 1e-4
         for t in range(8, 16):
-            pos = jnp.full((2,), t, jnp.int32)
-            lg, cache = m.apply(params, (), jnp.asarray(toks[:, t:t + 1]),
-                                cache=cache, pos=pos)
-            # the cached single-step logits at EVERY position
+            lg, pool = step(params, toks[:, t:t + 1], pool,
+                            jnp.full((2,), t, jnp.int32))
             assert np.max(np.abs(np.asarray(lg)[:, 0] - full[:, t])) \
                 < 1e-4, f"position {t} diverged"
 
-    @pytest.mark.parametrize("scan", [False, True])
-    def test_layouts_agree_with_each_other(self, scan):
-        """The two cache layouts decode the same stream from the same
-        per-block weights (stack/unstack round trip)."""
-        from bigdl_tpu.nn.attention import stack_block_params
-
-        m_u = _lm(layers=3, scan=False)
-        m_s = _lm(layers=3, scan=True)
-        m_s.set_parameters(stack_block_params(m_u.parameters()[0]))
-        prompt = np.random.default_rng(1).integers(
-            0, VOCAB, size=6).astype(np.int32)
-        assert _greedy_reference(m_u, prompt, 6) == \
-            _greedy_reference(m_s, prompt, 6)
-
-    def test_causal_masking_at_every_position(self):
+    def test_paged_causal_frontier_at_every_position(self):
         """Garbage beyond the decode frontier -- a previous occupant's
-        K/V, prompt padding -- must be invisible: poisoning every cache
-        position past ``pos`` changes nothing."""
+        K/V, the rest of a block, the trash block -- must be invisible:
+        poisoning every pool position past ``pos`` changes nothing."""
         m = _lm(layers=2)
         params = m.parameters()[0]
         toks = np.random.default_rng(2).integers(
             0, VOCAB, size=(1, 8)).astype(np.int32)
-        cache = m.init_cache(1, 20)
-        _, cache = m.apply(params, (), jnp.asarray(toks), cache=cache)
+        nb, bs = 6, 4
+        tables = jnp.asarray([[0, 1, 2, nb]], jnp.int32)
+        step = _paged_step(m, tables)
+        _, pool = step(params, toks, m.init_paged_cache(nb, bs),
+                       jnp.zeros((1,), jnp.int32),
+                       jnp.full((1,), 8, jnp.int32))
+        tok = jnp.asarray([[3]], jnp.int32)
         for t in range(8, 12):
             pos = jnp.full((1,), t, jnp.int32)
-            tok = jnp.asarray([[3]], jnp.int32)
-            lg, new_cache = m.apply(params, (), tok, cache=cache, pos=pos)
-            poisoned = jax.tree.map(
-                lambda c: c.at[..., t + 1:, :, :].set(1e4), cache)
-            lg2, _ = m.apply(params, (), tok, cache=poisoned, pos=pos)
+            lg, new_pool = step(params, tok, pool, pos)
+
+            def poison(leaf, t=t):
+                # the rest of t's block, the blocks the table never maps
+                # and the trash block its padding entry points at
+                leaf = leaf.at[t // bs, t % bs + 1:].set(1e4)
+                return leaf.at[jnp.asarray([3, 4, 5, nb])].set(1e4)
+
+            lg2, _ = step(params, tok, jax.tree.map(poison, pool), pos)
             np.testing.assert_array_equal(np.asarray(lg), np.asarray(lg2))
-            cache = new_cache
-
-    def test_flash_decode_matches_plain(self):
-        """The q_len=1 Pallas kernel (interpret mode on CPU) agrees
-        with masked plain attention, including at frontier 0."""
-        from bigdl_tpu.nn.attention import dot_product_attention
-        from bigdl_tpu.ops.flash_attention import flash_decode_attention
-
-        rng = np.random.default_rng(3)
-        b, t, h, d = 3, 16, 2, 8
-        q = jnp.asarray(rng.standard_normal((b, 1, h, d)), jnp.float32)
-        k = jnp.asarray(rng.standard_normal((b, t, h, d)), jnp.float32)
-        v = jnp.asarray(rng.standard_normal((b, t, h, d)), jnp.float32)
-        pos = jnp.asarray([0, 7, 15], jnp.int32)
-        y = flash_decode_attention(q, k, v, pos, block_k=8,
-                                   interpret=True)
-        mask = (jnp.arange(t)[None, :] <= pos[:, None])[:, None, None, :]
-        ref = dot_product_attention(q, k, v, mask=mask)
-        assert float(jnp.max(jnp.abs(y - ref))) < 1e-5
-
-    def test_mha_decode_flash_interpret_path(self):
-        """MultiHeadAttention's cached apply routes through the flash
-        decode kernel under use_flash='interpret' and agrees with the
-        plain path."""
-        from bigdl_tpu.nn.attention import MultiHeadAttention
-
-        rng = np.random.default_rng(4)
-        x = jnp.asarray(rng.standard_normal((2, 1, 32)), jnp.float32)
-        outs = {}
-        for mode in ("never", "interpret"):
-            mha = MultiHeadAttention(32, 4, causal=True, use_flash=mode)
-            p, _ = mha.setup(jax.random.PRNGKey(0),
-                             jax.ShapeDtypeStruct((2, 8, 32), jnp.float32))
-            cache = mha.init_cache(2, 16)
-            pre = jnp.asarray(rng.standard_normal((2, 8, 32)), jnp.float32) \
-                if mode == "never" else outs["prefill_x"]
-            outs.setdefault("prefill_x", pre)
-            _, cache = mha.apply(p, (), outs["prefill_x"], cache=cache)
-            y, _ = mha.apply(p, (), x, cache=cache,
-                             pos=jnp.asarray([8, 8], jnp.int32))
-            outs[mode] = np.asarray(y)
-        assert np.max(np.abs(outs["never"] - outs["interpret"])) < 1e-5
+            pool = new_pool
 
 
 class TestRaggedPrefill:
-    def test_ragged_prompts_one_prefill_call(self):
-        """Rows of true lengths 3 and 9 share one padded prefill; each
-        row's first generated token comes from ITS ``length - 1``
-        logits, and the whole continuation matches the per-row
-        full-recompute reference."""
-        from bigdl_tpu.serving.generation import generate_steps
-
+    def test_ragged_prompts_one_chunk_call(self):
+        """Rows of true lengths 3 and 9 share one padded chunk call, then
+        decode greedily side by side: each row's logits, at every
+        position it owns, are those of its own full forward over its
+        prompt and what it generated, and each generated token is that
+        forward's argmax (the full-recompute reference, teacher-forced)."""
         m = _lm(layers=2, max_len=32)
         params = m.parameters()[0]
         rng = np.random.default_rng(5)
-        p_short = rng.integers(0, VOCAB, size=3).astype(np.int32)
-        p_long = rng.integers(0, VOCAB, size=9).astype(np.int32)
-        ref_short = _greedy_reference(m, p_short, 4)
-        ref_long = _greedy_reference(m, p_long, 4)
-
-        prefill, decode = generate_steps(m)
-        cache = m.init_cache(3, 16)          # 2 rows + a trash row
+        prompts = [rng.integers(0, VOCAB, size=n).astype(np.int32)
+                   for n in (3, 9)]
+        nb = 8
+        tables = jnp.asarray([[0, 1, 2, nb], [3, 4, 5, nb]], jnp.int32)
+        step = _paged_step(m, tables)
+        forward = jax.jit(lambda p, t: m.apply(p, (), t)[0])
         tokens = np.zeros((2, 12), np.int32)
-        tokens[0, :3] = p_short
-        tokens[1, :9] = p_long
-        first, cache = prefill(params, cache, tokens,
-                               np.array([3, 9], np.int32),
-                               np.array([0, 1], np.int32))
-        first = np.asarray(first)
-        assert [int(first[0]), int(first[1])] == [ref_short[0],
-                                                  ref_long[0]]
-        got = [[int(first[0])], [int(first[1])]]
-        last = np.array([first[0], first[1], 0], np.int32)
-        pos = np.array([3, 9, 0], np.int32)
+        for row, p in enumerate(prompts):
+            tokens[row, :p.size] = p
+        lengths = np.asarray([p.size for p in prompts], np.int32)
+        lg, pool = step(params, tokens, m.init_paged_cache(nb, 4),
+                        jnp.zeros((2,), jnp.int32), jnp.asarray(lengths))
+        lg = np.asarray(lg)
+        logits = [[lg[row, :n]] for row, n in enumerate(lengths)]
+        last = lg[[0, 1], lengths - 1].argmax(-1).astype(np.int32)
+        gen = [[int(t)] for t in last]
+        pos = lengths.copy()
         for _ in range(3):
-            nxt, cache = decode(params, cache, last, pos)
-            nxt = np.asarray(nxt)
-            got[0].append(int(nxt[0]))
-            got[1].append(int(nxt[1]))
-            last = nxt.astype(np.int32)
+            out, pool = step(params, last[:, None], pool, jnp.asarray(pos))
+            out = np.asarray(out)[:, 0]
+            last = out.argmax(-1).astype(np.int32)
+            for row in range(2):
+                logits[row].append(out[row][None])
+                gen[row].append(int(last[row]))
             pos = pos + 1
-        assert got[0] == ref_short and got[1] == ref_long
+        for row, p in enumerate(prompts):
+            seq = np.concatenate([p, gen[row][:-1]]).astype(np.int32)
+            want = np.asarray(forward(params, seq[None]))
+            assert np.max(np.abs(np.concatenate(logits[row]) - want[0])) \
+                < 1e-4
+            assert list(want[0, p.size - 1:].argmax(-1)) == gen[row]
 
 
 class TestGenerateServing:
@@ -224,9 +188,8 @@ class TestGenerateServing:
             assert fut.finish_reason == "length"
             assert fut.prompt_len == 7 and fut.latency_s > 0
 
-    @pytest.mark.parametrize("kv_cache", ["paged", "contiguous"])
     @pytest.mark.parametrize("scan", [False, True])
-    def test_close_gives_the_cache_back(self, kv_cache, scan):
+    def test_close_gives_the_cache_back(self, scan):
         """A live engine and its scheduler refer to each other; a KV
         cache that waited for a cycle collection after close() would
         leave no room on the chip for the next engine of the process.
@@ -239,8 +202,7 @@ class TestGenerateServing:
         gc.collect()
         gc.disable()
         try:
-            eng = ServingEngine(m, decode_slots=2, decode_max_len=32,
-                                kv_cache=kv_cache)
+            eng = ServingEngine(m, decode_slots=2, decode_max_len=32)
             assert eng.generate(np.arange(5, dtype=np.int32),
                                 max_new_tokens=3).result(60)
             sched = eng._generation()
@@ -274,7 +236,7 @@ class TestGenerateServing:
     def test_zero_recompiles_across_mixed_buckets(self):
         """THE acceptance pin: precompile() closes the generation
         executable set; a closed-loop workload spanning multiple
-        admission counts AND prompt-length rungs -- sequences joining
+        admission counts AND prompt lengths -- sequences joining
         and leaving slots mid-flight -- performs zero backend compiles,
         and no tick event carries the ``compiles`` stamp."""
         import tempfile
@@ -285,15 +247,13 @@ class TestGenerateServing:
         rng = np.random.default_rng(8)
         with tempfile.TemporaryDirectory() as d:
             tel = StepTelemetry(d, run_name="gen", trace=False)
-            eng = ServingEngine(
-                m, decode_slots=2, decode_max_len=32,
-                prompt_ladder=BucketLadder(16, min_size=8),
-                telemetry=tel)
+            eng = ServingEngine(m, decode_slots=2, decode_max_len=32,
+                                telemetry=tel)
             try:
                 eng.precompile(
                     example_feature=np.zeros((16,), np.int32))
                 before = backend_compile_count()
-                # wave 1: both length rungs, staggered max_new so slots
+                # wave 1: short and long prompts, staggered max_new so slots
                 # free at different ticks; wave 2 joins mid-flight
                 futs = [eng.generate(rng.integers(0, VOCAB, size=n),
                                      max_new_tokens=k)
@@ -374,7 +334,7 @@ class TestGenerateServing:
             assert 0 < gen["slot_fill_mean"] <= 1.0
 
     def test_tick_failure_resets_the_pool_and_keeps_serving(self):
-        """Both compiled steps DONATE the cache, so a runtime tick
+        """Every compiled step DONATES the pool, so a runtime tick
         failure invalidates the whole pool: the tick's futures fail
         honestly, the cache reallocates, and NEW requests serve
         normally afterwards (no 'Array has been deleted' forever)."""
@@ -382,20 +342,16 @@ class TestGenerateServing:
         ref = _greedy_reference(m, [1, 2, 3], 4)
         with ServingEngine(m, decode_slots=2, decode_max_len=32) as eng:
             sched = eng._generation()
-            # the paged scheduler prefills in chunks (_chunk_fn); the
-            # contiguous one in a single step (_prefill_fn)
-            attr = "_chunk_fn" if hasattr(sched, "_chunk_fn") \
-                else "_prefill_fn"
-            good = getattr(sched, attr)
+            good = sched._chunk_fn
 
             def boom(*a, **k):
                 raise RuntimeError("injected tick failure")
 
-            setattr(sched, attr, boom)
+            sched._chunk_fn = boom
             fut = eng.generate([1, 2, 3], max_new_tokens=4)
             with pytest.raises(RuntimeError, match="injected"):
                 fut.result(30)
-            setattr(sched, attr, good)
+            sched._chunk_fn = good
             assert eng.generate([1, 2, 3],
                                 max_new_tokens=4).result(60) == ref
 

@@ -283,22 +283,27 @@ def test_the_pool_is_sized_by_kv_heads_and_says_which_width_failed():
                                      pos, interpret=True)
 
 
-def test_grouped_queries_through_the_contiguous_cache():
-    """Prefill and then decode steps through ``init_cache`` (sized by KV
-    heads) against the full forward, with a softmax scale of its own."""
+def test_grouped_queries_through_the_paged_cache():
+    """A chunk and then decode steps through ``init_paged_cache`` (sized
+    by KV heads) and ``apply_paged`` against the full forward, with a
+    softmax scale of its own."""
     attn = MultiHeadAttention(64, 4, causal=True, use_flash="never",
                               num_kv_heads=2, bias=False, scale=0.05)
     params, _ = attn.setup(jax.random.key(1), None)
     x = jnp.asarray(np.random.default_rng(0).normal(size=(2, 12, 64)),
                     jnp.float32)
     want, _ = attn.apply(params, (), x)
-    cache = attn.init_cache(2, 16)
-    assert cache["k"].shape == (2, 16, 2, 16)
-    got, cache = attn.apply(params, (), x[:, :9], cache=cache)
+    pool = attn.init_paged_cache(9, 4)      # 8 blocks and the trash block
+    assert pool["k"].shape == (9, 4, 2 * 16)
+    tables = jnp.asarray([[5, 0, 3, 8], [1, 6, 2, 8]], jnp.int32)
+    step = jax.jit(lambda p, h, pool, pos, n=None: attn.apply_paged(
+        p, h, pool, tables, pos, n))
+    got, pool = step(params, x[:, :9], pool, jnp.zeros((2,), jnp.int32),
+                     jnp.full((2,), 9, jnp.int32))
     steps = [got]
     for t in range(9, 12):
-        y, cache = attn.apply(params, (), x[:, t:t + 1], cache=cache,
-                              pos=jnp.full((2,), t, jnp.int32))
+        y, pool = step(params, x[:, t:t + 1], pool,
+                       jnp.full((2,), t, jnp.int32))
         steps.append(y)
     close(jnp.concatenate(steps, 1), want, 1e-5)
     # the scale is the one asked for, not head_dim ** -0.5

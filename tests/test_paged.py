@@ -7,9 +7,9 @@ Pins, per the acceptance criteria:
   matching with LRU ref-0 reuse, copy-on-write detach (and the cheaper
   own-block unregister), typed ``BlockPoolExhausted`` admission sheds
   that leave neighbours untouched, and zero block leaks;
-- paged-vs-contiguous GREEDY AGREEMENT on both block layouts (unrolled
-  and scan-stacked): the block indirection is a restructuring of the
-  cache, not an approximation;
+- GREEDY AGREEMENT with the full-recompute reference on both block
+  layouts (unrolled and scan-stacked): the block indirection is a
+  restructuring of the cache, not an approximation;
 - chunked prefill interleaves with decode ticks (a long prompt never
   starves a live stream);
 - abandoned mid-flight sequences release their blocks at the sweep;
@@ -317,18 +317,14 @@ class TestPagedServing:
     """The scheduler + engine: agreement, reuse, interleave, sheds."""
 
     @pytest.mark.parametrize("scan", [False, True])
-    def test_paged_matches_contiguous_and_reference(self, scan):
+    def test_paged_matches_reference(self, scan):
         m = _lm(layers=2, max_len=64, scan=scan)
         prompts = [[1, 2, 3], [7, 8, 9, 10, 11], [4] * 9]
         refs = [_greedy_reference(m, p, 5) for p in prompts]
-        streams = {}
-        for kv in ("contiguous", "paged"):
-            with ServingEngine(m, decode_slots=3, decode_max_len=48,
-                               kv_cache=kv, kv_block_size=4) as eng:
-                futs = [eng.generate(p, max_new_tokens=5)
-                        for p in prompts]
-                streams[kv] = [f.result(60) for f in futs]
-        assert streams["paged"] == streams["contiguous"] == refs
+        with ServingEngine(m, decode_slots=3, decode_max_len=48,
+                           kv_block_size=4) as eng:
+            futs = [eng.generate(p, max_new_tokens=5) for p in prompts]
+            assert [f.result(60) for f in futs] == refs
 
     def test_prefix_reuse_end_to_end(self):
         m = _lm(layers=2, max_len=64)
@@ -428,11 +424,11 @@ class TestPagedServing:
             # unseeded sampling mints a seed and still serves
             assert len(eng.generate([1, 2, 3], max_new_tokens=3,
                                     temperature=0.8).result(60)) == 3
-        with ServingEngine(m, decode_slots=1, decode_max_len=40,
-                           kv_cache="contiguous") as eng:
-            with pytest.raises(ValueError, match="paged"):
-                eng.generate([1, 2, 3], max_new_tokens=2,
-                             temperature=0.8)
+        # the contiguous cache, which could only argmax, is gone: the
+        # engine refuses it outright
+        with pytest.raises(ValueError, match="kv_cache must be 'paged'"):
+            ServingEngine(m, decode_slots=1, decode_max_len=40,
+                          kv_cache="contiguous")
 
     def test_zero_steady_state_recompiles_mixed_and_sampled(self):
         m = _lm(layers=2, max_len=64)

@@ -20,7 +20,7 @@ Pins, per the acceptance criteria:
 - the engine end-to-end: ``kv_cache_dtype="int8"`` serves, the
   MemoryLedger kv_cache source reports real NARROW bytes (>2.5x less
   than fp32 at head_dim 8), recompiles stay 0 after precompile, and
-  int8 without the paged layout is refused.
+  any KV cache but the paged one is refused.
 """
 
 import numpy as np
@@ -203,7 +203,8 @@ class TestEngineInt8KV:
 
     def test_int8_needs_the_paged_layout(self):
         m = _lm(layers=1, max_len=48)
-        with pytest.raises(ValueError, match="paged"):
+        # the paged pool is the only KV cache: any other is refused
+        with pytest.raises(ValueError, match="kv_cache must be 'paged'"):
             ServingEngine(m, decode_slots=1, decode_max_len=40,
                           kv_cache="contiguous", kv_cache_dtype="int8")
         with pytest.raises(ValueError, match="kv_cache_dtype"):

@@ -151,25 +151,24 @@ def test_served_paged_logits_are_the_rounded_fp32_logits(tpu_rule,
         np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-5)
 
 
-def _streams(model, kv_cache):
+def _streams(model):
     with ServingEngine(model, decode_slots=3, decode_max_len=48,
-                       kv_cache=kv_cache, kv_block_size=4) as eng:
+                       kv_block_size=4) as eng:
         futs = [eng.generate(p, max_new_tokens=8) for p in PROMPTS]
         return [f.result(60) for f in futs]
 
 
-@pytest.mark.parametrize("kv_cache", ["paged", "contiguous"])
 @pytest.mark.parametrize("scan", [False, True], ids=["unrolled", "scan"])
 def test_served_greedy_tokens_are_the_rounded_fp32_tokens(tpu_rule,
                                                           monkeypatch,
-                                                          scan, kv_cache):
+                                                          scan):
     since = now_ns()
-    served = _streams(_lm(scan), kv_cache)
+    served = _streams(_lm(scan))
     assert len(_serve_spans(since)) == 1
     monkeypatch.setattr(generation, "_backend", lambda: "cpu")
     monkeypatch.setattr(linear, "matmul_t", _through_bf16)
     monkeypatch.setattr(attention, "matmul_t", _through_bf16)
-    assert served == _streams(_lm(scan), kv_cache)
+    assert served == _streams(_lm(scan))
 
 
 def test_a_served_model_and_an_int8_twin_are_served_as_they_are(tpu_rule):
@@ -215,7 +214,7 @@ def test_a_weight_version_is_cast_once(tpu_rule):
                  for p in PROMPTS]
         assert len(_serve_spans(since)) == 2
     assert after != before
-    assert after == _streams(_lm(True, key=1), "paged")
+    assert after == _streams(_lm(True, key=1))
 
 
 def test_readers_never_see_a_torn_tree_while_versions_swap(tpu_rule):

@@ -267,13 +267,10 @@ def _children(recs):
 
 
 class TestServingSpans:
-    @pytest.mark.parametrize("kv_cache", ["paged", "contiguous"])
-    def test_three_requests_leave_every_span_of_the_table(self, kv_cache):
+    def test_three_requests_leave_every_span_of_the_table(self):
         """No StepTelemetry, no SpanTracer: the ring alone."""
-        kw = dict(kv_block_size=4, prefill_chunk=4) \
-            if kv_cache == "paged" else {}
         with ServingEngine(_lm(), decode_slots=2, decode_max_len=32,
-                           kv_cache=kv_cache, **kw) as eng:
+                           kv_block_size=4, prefill_chunk=4) as eng:
             futs = [eng.generate([1 + i, 2, 3, 4, 5, 6], max_new_tokens=3)
                     for i in range(3)]
             outs = [f.result(120) for f in futs]
@@ -290,77 +287,51 @@ class TestServingSpans:
             names = [c.name for c in kids[t.span_id]]
             assert names[0] == "admit"
             assert {"tick", "queue_depth", "slots_decoding",
-                    "slots_prefilling", "slots_total"} <= set(t.attrs)
-            assert ("blocks_free" in t.attrs) == (kv_cache == "paged")
+                    "slots_prefilling", "slots_total",
+                    "blocks_free"} <= set(t.attrs)
             assert t.attrs["slots_total"] == 2
             rest = kids[t.span_id][1:]
-            if kv_cache == "paged":
-                # the paged dispatcher launches a call before it fetches
-                # the one ahead of it: pairs of (prep, generate_* > launch)
-                # and of (generate_* > fetch, deliver), in any order
-                assert len(rest) % 2 == 0
-                for a, b in zip(*[iter(rest)] * 2):
-                    gen = b if a.name.endswith("_prep") else a
-                    kind = gen.name.split("_")[1]
-                    assert gen.name in ("generate_prefill",
-                                        "generate_decode")
-                    assert {"tick", "records"} <= set(gen.attrs)
-                    did = [c.name for c in kids[gen.span_id]
-                           if c.name != "compile"]
-                    key = (kind, gen.attrs["tick"])
-                    if gen is b:
-                        assert a.name == kind + "_prep" \
-                            and did == ["launch"]
-                        assert {"rows", "slots_total", "ahead"} \
-                            <= set(a.attrs)
-                        assert key not in launched
-                        launched[key] = a
-                    else:
-                        assert b.name == "deliver" and did == ["fetch"]
-                        assert {"tokens", "finished", "rows_wasted"} \
-                            <= set(b.attrs)
-                        assert key in launched and key not in fetched
-                        fetched[key] = b
-                    assert t.start_ns <= a.start_ns <= a.end_ns \
-                        <= b.start_ns <= b.end_ns <= t.end_ns
-                continue
-            # after admission: groups of (prep, generate_*, deliver)
-            assert len(rest) % 3 == 0
-            for prep, gen, dlv in zip(*[iter(rest)] * 3):
-                kind = prep.name.split("_")[0]
-                assert prep.name in ("prefill_prep", "decode_prep")
-                assert gen.name == "generate_" + kind
-                assert dlv.name == "deliver"
-                assert [c.name for c in kids[gen.span_id]
-                        if c.name != "compile"] == ["launch", "fetch"]
-                assert {"rows", "slots_total"} <= set(prep.attrs)
-                assert {"tokens", "finished"} <= set(dlv.attrs)
+            # the dispatcher launches a call before it fetches the one
+            # ahead of it: pairs of (prep, generate_* > launch) and of
+            # (generate_* > fetch, deliver), in any order
+            assert len(rest) % 2 == 0
+            for a, b in zip(*[iter(rest)] * 2):
+                gen = b if a.name.endswith("_prep") else a
+                kind = gen.name.split("_")[1]
+                assert gen.name in ("generate_prefill", "generate_decode")
                 assert {"tick", "records"} <= set(gen.attrs)
-                if kind == "prefill":
-                    prefills += 1
-                    assert {"bucket", "prompt_tokens"} <= set(prep.attrs)
+                did = [c.name for c in kids[gen.span_id]
+                       if c.name != "compile"]
+                key = (kind, gen.attrs["tick"])
+                if gen is b:
+                    assert a.name == kind + "_prep" and did == ["launch"]
+                    assert {"rows", "slots_total", "ahead"} <= set(a.attrs)
+                    assert key not in launched
+                    launched[key] = a
                 else:
-                    decodes += 1
-                    assert prep.attrs["rows"] == dlv.attrs["tokens"]
-                assert t.start_ns <= prep.start_ns <= gen.start_ns \
-                    <= gen.end_ns <= dlv.end_ns <= t.end_ns
-        if kv_cache == "paged":
-            # every call launched was fetched, one call later at most, and
-            # delivered a token for each of its rows that was still wanted
-            assert sorted(launched) == sorted(fetched)
-            ticks_of = sorted(n for _kind, n in launched)
-            assert ticks_of == list(range(ticks_of[0], ticks_of[-1] + 1))
-            for (kind, n), prep in launched.items():
-                dlv = fetched[kind, n]
-                if kind == "prefill":
-                    prefills += 1
-                    assert {"bucket", "prompt_tokens"} <= set(prep.attrs)
-                else:
-                    decodes += 1
-                    assert {"rows_ahead"} <= set(prep.attrs)
-                    assert prep.attrs["rows"] == dlv.attrs["tokens"] \
-                        + dlv.attrs["rows_wasted"]
-            assert any(p.attrs["ahead"] for p in launched.values())
+                    assert b.name == "deliver" and did == ["fetch"]
+                    assert {"tokens", "finished", "rows_wasted"} \
+                        <= set(b.attrs)
+                    assert key in launched and key not in fetched
+                    fetched[key] = b
+                assert t.start_ns <= a.start_ns <= a.end_ns \
+                    <= b.start_ns <= b.end_ns <= t.end_ns
+        # every call launched was fetched, one call later at most, and
+        # delivered a token for each of its rows that was still wanted
+        assert sorted(launched) == sorted(fetched)
+        ticks_of = sorted(n for _kind, n in launched)
+        assert ticks_of == list(range(ticks_of[0], ticks_of[-1] + 1))
+        for (kind, n), prep in launched.items():
+            dlv = fetched[kind, n]
+            if kind == "prefill":
+                prefills += 1
+                assert {"bucket", "prompt_tokens"} <= set(prep.attrs)
+            else:
+                decodes += 1
+                assert {"rows_ahead"} <= set(prep.attrs)
+                assert prep.attrs["rows"] == dlv.attrs["tokens"] \
+                    + dlv.attrs["rows_wasted"]
+        assert any(p.attrs["ahead"] for p in launched.values())
         assert prefills >= 2 and decodes >= 4
         assert sum(kids[t.span_id][0].attrs["requests"]
                    for t in ticks) == 3
@@ -374,8 +345,7 @@ class TestServingSpans:
             a = r.attrs
             assert a["prompt_tokens"] == 6 and a["new_tokens"] == 3
             assert a["finish_reason"] == "length"
-            assert a["chunks"] == (2 - (a["prefix_hit_tokens"] > 0)
-                                   if kv_cache == "paged" else 1)
+            assert a["chunks"] == 2 - (a["prefix_hit_tokens"] > 0)
             parts = a["queue_wait_ns"] + a["prefill_ns"] + a["decode_ns"]
             assert abs(parts * 1e-9 - f.latency_s) < 1e-3
             assert abs((r.end_ns - r.start_ns) * 1e-9 - f.latency_s) < 1e-3

@@ -168,7 +168,8 @@ class TestSpeculativeSteadyState:
 class TestSpeculativeGuards:
     def test_needs_the_paged_layout_and_a_sane_k(self):
         m = _lm(layers=1, max_len=48)
-        with pytest.raises(ValueError, match="paged"):
+        # the paged pool is the only KV cache: any other is refused
+        with pytest.raises(ValueError, match="kv_cache must be 'paged'"):
             ServingEngine(m, decode_slots=1, decode_max_len=40,
                           kv_cache="contiguous", speculative=2)
         with pytest.raises(ValueError, match="speculative"):
@@ -222,8 +223,6 @@ class TestLongPromptLegs:
         ``precompile()`` on any leg, a sampled stretch on the
         speculative one included, and the speculative greedy streams
         the plain ones."""
-        from bigdl_tpu.serving import BucketLadder
-
         vocab, max_len, block, new_tokens = 64, 512, 16, 8
         plens = (64, 96, 160, 256)
         m = TransformerLM(vocab, 32, 4, 2, max_len=max_len)
@@ -237,8 +236,6 @@ class TestLongPromptLegs:
         def leg(kv_dtype, spec):
             with ServingEngine(
                     m, decode_slots=len(plens), decode_max_len=max_len,
-                    prompt_ladder=BucketLadder(max(plens),
-                                               min_size=min(plens)),
                     kv_block_size=block, kv_blocks=kv_blocks,
                     kv_cache_dtype=kv_dtype, speculative=spec) as eng:
                 sched = eng._generation()
